@@ -12,11 +12,11 @@ Three engines share one front door and one findings schema:
 * ``mem``   — memcheck, the static HBM/VMEM footprint analysis (same
   CPU-mesh lowerings, cross-checking an analytic jaxpr-liveness model
   against XLA's ``memory_analysis()``, banking docs/mem_contracts/;
-  ``--fit`` runs the batch-fit solver the window runner's queue
-  pre-flight consults).
+  ``--fit`` runs the batch-fit solver whose table the serving
+  admission gate consults).
 * ``conc``  — conccheck, the static concurrency-contract analysis
   (lock-discipline inference, lock-order + blocking-call audit, and
-  the thread/process taxonomy over the serving/feed/loop plane,
+  the thread/process roles over the serving/feed/loop plane,
   banking docs/conc_contracts/; the chaos scheduler
   ``SPARKNET_CHAOS_SCHED`` cross-validates the banked graph at
   dryrun time).  Pure AST — no jax, no lowering, zero chip time.
@@ -207,7 +207,7 @@ def mem_main(argv: list[str] | None = None) -> int:
         "memory_analysis()), audit pallas-kernel VMEM bounds, and diff "
         "against the banked manifests (docs/mem_contracts/) — zero chip "
         "time.  --fit solves max safe batch per zoo family x dtype x "
-        "mode (the table the window runner's queue pre-flight consults)",
+        "mode (the table the serving admission gate consults)",
     )
     ap.add_argument("--mode", action="append", default=[],
                     help="check only this mode (repeatable; default all "
@@ -294,7 +294,7 @@ def conc_main(argv: list[str] | None = None) -> int:
         prog="python -m sparknet_tpu.analysis conc",
         description="conccheck: infer lock discipline and the static "
         "lock-acquisition graph over the serving/feed/loop plane "
-        "(serve/, loop/, obs/, the process feed, the window runner), "
+        "(serve/, loop/, obs/, the process feed), "
         "fail on lock-order cycles, blocking calls under a lock, and "
         "jax reachable from ring workers, and diff against the banked "
         "manifests (docs/conc_contracts/) — pure AST, zero chip time",

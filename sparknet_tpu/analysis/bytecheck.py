@@ -2,23 +2,19 @@
 
 The fifth analysis engine.  graphcheck audits what the compiled
 program says on the wire, memcheck what it holds in memory; this one
-audits what it MOVES — the step's HBM byte bill, the quantity the
-bytes-bound headline (12.33 GB/step, MFU 0.240, docs/BENCHMARKS.md)
-says prices every image.  Two legs:
+audits what it MOVES — the step's HBM byte bill.  Two legs:
 
 * **traffic census** (the default run): every parallel mode's step is
   traced + lowered on the virtual CPU mesh (no compile, no execution —
   cheaper than memcheck, zero chip time) and two estimators of its
   byte bill are computed from the extracted jaxpr
   (``byte_model.py``): the gross eqn-level census (the pre-fusion
-  analog of XLA's "bytes accessed" — the convention the banked
-  headline figure uses) and the per-op-class floor (params, grads,
+  analog of XLA's "bytes accessed") and the per-op-class floor (params, grads,
   slots, saved activations out of the jaxpr liveness walk, collective
   bytes from ``comm_model``, feed wire bytes).  Banked as a manifest
   family in ``docs/byte_contracts/`` and drift-diffed on every run;
-  the headline config's census must reconcile with the measured
-  12.33 GB/step within the stated ``HEADLINE_RATIO_WINDOW`` — the
-  "bytes-bound" sentence as a machine-checked contract.
+  given a measured step-bytes figure, the headline config's census
+  must reconcile with it within the stated ``HEADLINE_RATIO_WINDOW``.
 
 * **schedule search** (``--remat``): per zoo family x dtype, every
   ``Config.remat`` policy (none/dots/blocks/full) is traced fully
@@ -37,8 +33,7 @@ says prices every image.  Two legs:
   more saved bytes).
 
 Import contract: stdlib-only at import; jax loads lazily inside the
-run functions after the CPU platform is pinned via the config route
-(CLAUDE.md "Platform gotcha").
+run functions after the CPU platform is pinned.
 """
 
 from __future__ import annotations
@@ -86,7 +81,6 @@ __all__ = [
 MANIFEST_DIR = os.path.join(_REPO, "docs", "byte_contracts")
 HEADLINE_PATH = os.path.join(MANIFEST_DIR, "headline.json")
 REMAT_TABLE_PATH = os.path.join(MANIFEST_DIR, "remat_policy.json")
-BENCH_LAST_GOOD = os.path.join(_REPO, "docs", "bench_last_good.json")
 
 BYTE_RULES = {
     "byte-floor-exceeds-census": "the per-op-class floor prices more "
@@ -132,8 +126,8 @@ BYTE_SOURCE_PATTERNS = (
     "sparknet_tpu/analysis/mem_model.py",
 )
 
-# the headline bench shape the reconciliation gate prices
-# (docs/bench_last_good.json provenance: bench.py defaults)
+# the headline bench shape the reconciliation gate prices (bench.py's
+# defaults on the chip)
 HEADLINE_FAMILY = "alexnet"
 HEADLINE_BATCH = 256
 HEADLINE_DTYPE = "bf16"
@@ -343,7 +337,7 @@ def _diff_or_missing(manifest: dict, mpath: str, problems: list,
 
 def _write_manifest(manifest: dict, mpath: str) -> None:
     os.makedirs(os.path.dirname(mpath), exist_ok=True)
-    # graftlint: disable-next-line=bank-guard -- chip-free contract manifest (docs/byte_contracts/), not banked chip evidence; bench_last_good.json is only ever READ here (headline reconciliation)
+    # graftlint: disable-next-line=bank-guard -- chip-free contract manifest (docs/byte_contracts/), not banked chip evidence
     with open(mpath, "w", encoding="utf-8") as fh:
         json.dump(manifest, fh, indent=1, sort_keys=True)
         fh.write("\n")
@@ -487,14 +481,18 @@ def _family_step_bytes(cen: dict, policy: str) -> dict:
 
 def run_headline(*, update: bool = False,
                  banked_path: str | None = None,
-                 n_devices: int = 8) -> tuple:
-    """Census the headline bench shape (alexnet b256 bf16 solo) and
-    reconcile its gross census with the banked measured step bytes
-    (docs/bench_last_good.json) within ``HEADLINE_RATIO_WINDOW``.
+                 n_devices: int = 8,
+                 measured_step_bytes: float | None = None) -> tuple:
+    """Census the headline bench shape (alexnet b256 bf16 solo) and,
+    when the caller supplies the step bytes a chip run measured,
+    reconcile the gross census with them within
+    ``HEADLINE_RATIO_WINDOW``.  No measurement is on record yet (the
+    ledger starts with ROADMAP S1), so the default run states a
+    vacuous pass.
 
-    Only the CENSUS side is drift-pinned: the measured figure moves
-    whenever the bench re-banks, and re-measuring must not read as
-    model drift — the tolerance window is the contract between the two
+    Only the CENSUS side is drift-pinned: a measured figure moves
+    whenever the bench re-measures, and that must not read as model
+    drift — the tolerance window is the contract between the two
     sides, the manifest diff only guards the analytic half."""
     _pin_cpu_mesh(n_devices)
     path = banked_path or HEADLINE_PATH
@@ -517,15 +515,7 @@ def run_headline(*, update: bool = False,
         "allow": {},
     }
 
-    measured = None
-    if os.path.exists(BENCH_LAST_GOOD):
-        try:
-            with open(BENCH_LAST_GOOD, encoding="utf-8") as f:
-                rec = json.load(f)
-            if "step_gbytes" in rec:
-                measured = float(rec["step_gbytes"]) * 1e9
-        except (OSError, ValueError):
-            measured = None
+    measured = measured_step_bytes
     if measured:
         verdict = reconcile(measured, gross)
         manifest["reconciliation"] = verdict
@@ -538,11 +528,11 @@ def run_headline(*, update: bool = False,
                            f"stated window {verdict['window']}",
             })
     else:
-        # no banked measurement to reconcile against: vacuous pass, but
-        # say so in the manifest rather than silently gating nothing
+        # no measurement to reconcile against: vacuous pass, but say so
+        # in the manifest rather than silently gating nothing
         manifest["reconciliation"] = {
-            "note": "no banked step_gbytes in docs/bench_last_good.json "
-                    "— reconciliation vacuous until the bench banks one",
+            "note": "no measured step bytes on record — reconciliation "
+                    "vacuous until the ledger has a row",
         }
 
     allow = _diff_or_missing(manifest, path, problems, update)

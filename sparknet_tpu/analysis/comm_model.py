@@ -9,8 +9,8 @@ program must (and must not) contain, and how many bytes per round the
 required ones may move — so ``graphcheck`` can assert the compiled
 graph against the theory instead of trusting it.
 
-Deliberately stdlib-only (the analysis-package contract: importable on
-a box with a wedged relay).  All byte figures come from the caller's
+Deliberately stdlib-only (the analysis-package contract: importable
+without initializing a backend).  All byte figures come from the caller's
 actual variable trees; nothing here touches jax.
 
 The arithmetic, per mode (W = data-axis width, S = param bytes,

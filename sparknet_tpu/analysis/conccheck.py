@@ -21,11 +21,11 @@ fail on blocking calls made while holding a lock: AOT ``.compile()``,
 zero-arg ``queue.get()`` with no timeout, zero-arg ``.join()``,
 shared-memory ``.unlink()`` — PR 10's "compile on the caller's thread,
 execute drained tickets OUTSIDE the lock" rules, machine-checked.  The
-thread/process taxonomy also machine-checks "ring workers never touch
+thread/process roles also machine-checks "ring workers never touch
 jax" (``conc-jax-in-worker``).
 
-**(c) banked manifests** — the acquisition graph and the taxonomy are
-banked as ``docs/conc_contracts/{lock_graph,taxonomy}.json`` with a
+**(c) banked manifests** — the acquisition graph and the roles are
+banked as ``docs/conc_contracts/{lock_graph,roles}.json`` with a
 ``SOURCES.json`` fingerprint (the ``conc-manifest-fresh`` graftlint
 rule refuses stale banks; regenerate with ``--update``).  The chaos
 scheduler (``SPARKNET_CHAOS_SCHED``, sparknet_tpu/_chaoslock.py) diffs
@@ -99,7 +99,6 @@ CONC_SOURCE_PATTERNS = (
     "sparknet_tpu/_chaoslock.py",
     "sparknet_tpu/analysis/conc_model.py",
     "sparknet_tpu/analysis/conccheck.py",
-    "tools/tpu_window_runner.py",
 )
 
 # name-match fallback for attribute calls with no type evidence skips
@@ -659,7 +658,7 @@ def run_conccheck(paths=None, *, update: bool = False,
                             for v in c.values()]}),
         "edges": sorted([a, b] for a, b in edges),
     }
-    taxonomy = {
+    roles = {
         "thread_roots": sorted({f"{k} @ {root_labels[k]}"
                                 for k in roots["thread"]}),
         "process_roots": sorted({f"{k} @ {root_labels[k]}"
@@ -671,7 +670,7 @@ def run_conccheck(paths=None, *, update: bool = False,
 
     manifests = {}
     for name, contract in (("lock_graph", lock_graph),
-                           ("taxonomy", taxonomy)):
+                           ("roles", roles)):
         probs, manifest = _check_manifest(
             name, contract, manifest_dir, update)
         findings.extend(probs)

@@ -39,8 +39,7 @@ legs:
   healthy family costs one baseline + one mixed eval.
 
 Import contract: stdlib-only at import; jax loads lazily inside the
-run functions after the CPU platform is pinned via the config route
-(CLAUDE.md "Platform gotcha").
+run functions after the CPU platform is pinned.
 """
 
 from __future__ import annotations
@@ -192,7 +191,7 @@ def _walk_jaxpr(jaxpr, census: dict) -> None:
     sub-jaxprs.  Round-trip detection is per-scope — a convert chain
     never crosses a call boundary in this codebase's lowerings, and a
     missed cross-scope chain fails SAFE (not flagged)."""
-    from jax.core import Literal
+    from jax.extend.core import Literal
 
     use_count: dict = {}
     for eqn in jaxpr.eqns:

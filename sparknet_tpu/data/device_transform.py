@@ -7,8 +7,7 @@ Device-side, the host→HBM link carries full-size **uint8** instead of
 cropped **f32** — 3.2× fewer bytes for the ImageNet recipe (256²×3 u8 =
 196 KB/img vs 227²×3 f32 = 618 KB/img) — and the augment itself fuses
 into the step's XLA program where it is bandwidth-trivial.  Matters most
-when the feed link is the scarce resource (remote-relay chips, DCN-fed
-pods).
+when the feed link is the scarce resource (DCN-fed pods).
 
 Semantics match ``DataTransformer`` exactly in TEST mode (deterministic
 center crop: bit-identical outputs) and distributionally in TRAIN mode

@@ -680,7 +680,7 @@ class ProcessPipeline:
                     try:
                         msg = self._next_msg()
                     except _WorkerDeath as death:
-                        self._respawn_or_raise(death.wid, death.message)  # graftlint: disable=stale-args-dispatch -- host-side failure path (death rebinds per except), never a timed device dispatch
+                        self._respawn_or_raise(death.wid, death.message)
                         continue
                     kind, wid, gg, slot, extra = msg
                     if kind == "batch":
@@ -936,7 +936,7 @@ def device_feed(pipeline: ProcessPipeline, sharding=None, depth: int = 2,
         # Transfer-completion gate for slot recycling — memory safety,
         # not evidence: nothing here times a device PROGRAM (the walls
         # feed the host-side `feed` event, whose stages are host work).
-        jax.block_until_ready(feeds)  # graftlint: disable=fence-by-value -- slot-recycle gate on a put, not an execution fence for timing evidence
+        jax.block_until_ready(feeds)
         state["put_s"] += time.perf_counter() - t0
         state["puts"] += 1
         if rec and state["puts"] % rec_every == 0:

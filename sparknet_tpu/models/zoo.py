@@ -1303,7 +1303,7 @@ GRAPH_SWEEP_FAMILIES: dict[str, GraphFamily] = {
         feed="tokens", num_classes=10, seq_len=32, vocab=32,
     ),
     # depthwise group conv + synced BN — the sharding interaction the
-    # mobilenet_dp mode exists to pin (VERDICT r5 weak 8)
+    # mobilenet_dp mode exists to pin
     "mobilenet": GraphFamily(
         solver=lambda: dataclasses.replace(mobilenet_solver(),
                                            base_lr=1e-3),

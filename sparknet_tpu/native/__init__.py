@@ -10,7 +10,9 @@ its db layer and data transformer in C++; ours live in
 - :func:`transform_batch` — multithreaded uint8→float32 crop/mirror/mean
   augmenter (role of data_transformer.cpp's per-sample hot loop).
 
-``build()`` compiles the .so on first use with the in-tree Makefile;
+``build()`` compiles the .so with the in-tree Makefile under a name keyed
+on the tracked sources and on this machine, so the library loaded is
+always one this machine built from ``native/sparknet_native.cpp``;
 ``available()`` gates callers so pure-Python paths keep working without a
 toolchain.
 """
@@ -18,29 +20,69 @@ toolchain.
 from __future__ import annotations
 
 import ctypes
+import glob
+import hashlib
 import os
+import platform
 import subprocess
 import threading
 
 import numpy as np
 
-_NATIVE_DIR = os.path.join(os.path.dirname(__file__), "..", "..", "native")
-_SO_PATH = os.path.abspath(os.path.join(_NATIVE_DIR, "libsparknet_native.so"))
+_NATIVE_DIR = os.path.abspath(
+    os.path.join(os.path.dirname(__file__), "..", "..", "native"))
 
 _lib = None
 _lock = threading.Lock()
 
 
+def _so_path() -> str:
+    """Where this machine's build of the current sources lives.  The
+    Makefile compiles with ``-march=native`` and ``*.so`` is untracked,
+    so a copied checkout can carry a binary built for another CPU or
+    from older sources; keying the name on both means such a file is
+    never the one loaded."""
+    h = hashlib.sha256()
+    for name in ("sparknet_native.cpp", "Makefile"):
+        with open(os.path.join(_NATIVE_DIR, name), "rb") as f:
+            h.update(f.read())
+    try:
+        with open("/proc/sys/kernel/random/boot_id") as f:
+            machine = f.read().strip()
+    except OSError:
+        machine = platform.node()
+    h.update(machine.encode())
+    return os.path.join(
+        _NATIVE_DIR, f"libsparknet_native.{h.hexdigest()[:12]}.so")
+
+
 def build(force: bool = False) -> str:
-    """Compile the shared library via make (idempotent)."""
+    """Compile the shared library via make (idempotent) and drop builds
+    left by other machines or older sources."""
+    so = _so_path()
     with _lock:
-        if force or not os.path.exists(_SO_PATH):
-            subprocess.run(
-                ["make", "-C", os.path.abspath(_NATIVE_DIR)],
-                check=True,
-                capture_output=True,
-            )
-    return _SO_PATH
+        if force or not os.path.exists(so):
+            # build beside the target and rename: a concurrent loader
+            # (feed worker processes) sees a whole library or none
+            tmp = f"{so}.{os.getpid()}.tmp"
+            try:
+                subprocess.run(
+                    ["make", "-C", _NATIVE_DIR, f"SO={os.path.basename(tmp)}"],
+                    check=True,
+                    capture_output=True,
+                )
+                os.replace(tmp, so)
+            finally:
+                if os.path.exists(tmp):
+                    os.unlink(tmp)
+        for stale in glob.glob(
+                os.path.join(_NATIVE_DIR, "libsparknet_native*.so")):
+            if stale != so:
+                try:
+                    os.unlink(stale)
+                except OSError:
+                    pass  # another process removed it first
+    return so
 
 
 def _load(auto_build: bool = True):
@@ -48,12 +90,13 @@ def _load(auto_build: bool = True):
     with _lock:
         if _lib is not None:
             return _lib
-    if not os.path.exists(_SO_PATH):
+    so = _so_path()
+    if not os.path.exists(so):
         if not auto_build:
-            raise FileNotFoundError(_SO_PATH)
+            raise FileNotFoundError(so)
         build()
     with _lock:
-        lib = ctypes.CDLL(_SO_PATH)
+        lib = ctypes.CDLL(so)
         lib.sndb_open.restype = ctypes.c_void_p
         lib.sndb_open.argtypes = [ctypes.c_char_p, ctypes.c_int]
         lib.sndb_put.restype = ctypes.c_int

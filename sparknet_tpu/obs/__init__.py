@@ -5,8 +5,7 @@ the source promises, graphcheck audits what the lowered graphs do):
 this package records what a RUN actually did — fenced span walls,
 per-round training metrics with the comm_model-predicted collective
 budget attached, live recompile flags, and every bank_guard evidence
-write — as schema-validated JSONL (``obs/schema.py``, the same line
-format the TPU window runner journals).
+write — as schema-validated JSONL (``obs/schema.py``).
 
 Off by default; arm with ``SPARKNET_OBS=<path>.jsonl``.  With obs off
 the instrumented hot paths are bit-identical (same lowered StableHLO,
@@ -16,9 +15,8 @@ CLI: ``python -m sparknet_tpu.obs {report|validate|dryrun}``.  Docs:
 ``docs/OBSERVABILITY.md``.
 
 This ``__init__`` stays import-light on purpose: ``schema`` is
-stdlib-only and never initializes a backend (the window runner imports
-it while babysitting a wedged relay), and the Recorder loads lazily
-behind :func:`get_recorder`.
+stdlib-only and never initializes a backend, and the Recorder loads
+lazily behind :func:`get_recorder`.
 """
 
 from __future__ import annotations

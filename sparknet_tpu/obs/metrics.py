@@ -27,8 +27,8 @@ Histogram contract (the part tests pin):
 - Values ``<= 0`` land in a dedicated zero bucket represented as 0.0
   (walls and latencies are non-negative; a zero wall is a zero wall).
 
-Deliberately stdlib-only (the obs-package contract: importable next to
-a wedged relay; nothing here touches jax or numpy).
+Deliberately stdlib-only (the obs-package contract: nothing here
+touches jax or numpy).
 """
 
 from __future__ import annotations

@@ -11,8 +11,8 @@ obs touch behind ``if rec:``, so the disabled hot path is bit-identical
 Walls are only evidence when they are FENCE-STAMPED.  A span that
 encloses device work must close through :meth:`Span.fence`, which fetches
 the VALUE of the producing program's own output via
-``common.value_fence`` — the round-5 anti-trap contract (readiness is
-not execution on relay backends; a derived computation is not a fence).
+``common.value_fence`` — a wall closed without a fence times the
+enqueue, not the work.
 A span that never touches the device declares ``host=True`` instead.
 Spans that do neither are journaled with ``fenced: false`` and the
 report renderer refuses their walls.  The ``obs-fenced-span`` graftlint

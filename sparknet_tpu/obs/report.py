@@ -1,15 +1,13 @@
 """Render an obs journal into a markdown run report.
 
-The rendering twin of ``tools/tunnel_log.py`` / ``tools/trace_report.py``
-for the runtime journal: deterministic markdown from JSONL, safe to
-regenerate, honest about what is and is not evidence.  Two refusals are
-load-bearing:
+The rendering twin of ``tools/trace_report.py`` for the runtime
+journal: deterministic markdown from JSONL, safe to regenerate, honest
+about what is and is not evidence.  Two refusals are load-bearing:
 
 * **Unstamped walls are refused.**  A span or round journaled with
   ``fenced: false`` (and not declared ``host``) renders with its wall
-  withheld — the pre-round-5 tools banked physically impossible walls
-  off exactly such numbers (probe-40's 8.2M img/s, the 7,860% MFU
-  artifacts), and this renderer will not launder a new one.
+  withheld — a wall that was never fenced times the enqueue, not the
+  work, and this renderer will not launder one.
 * **No throughput above its stated roofline bound.**  A bench record
   whose value exceeds its own ``roofline_img_s_upper_bound`` (or that
   carries a ``bound_inconsistency``) renders as a named conflict, never
@@ -20,8 +18,7 @@ log-bucket histograms (obs/metrics.py) as they stream past — the
 latency table is O(models x buckets), never O(requests), so a pod-scale
 journal with 10k+ request lines renders in constant space.  Every event
 name in the schema vocabulary renders somewhere in this module (the
-``obs-vocab-coverage`` lint rule machine-checks that), including the
-window-runner ledger events that used to be tunnel_log.py-only.
+``obs-vocab-coverage`` lint rule machine-checks that).
 ``--lineage`` adds the causal waterfall (obs/lineage.py): the last
 round and the last request walked up their parent edges to a root.
 """
@@ -35,12 +32,8 @@ from sparknet_tpu.obs import schema
 
 __all__ = ["render", "render_path"]
 
-# window-runner events carry no run_id: they are the host-side evidence
-# ledger (tools/tpu_window_runner.py) and render as one flat timeline
-_RUNNER_EVENTS = ("runner_start", "dial_start", "dial_end",
-                  "dial_abandoned", "job_start", "job_end",
-                  "queue_reload_failed", "preflight_oom", "setup_failed",
-                  "slo", "sched", "runner_done")
+# SLO verdicts carry no run_id: they judge a whole journal
+_UNSCOPED_EVENTS = ("slo",)
 
 
 def _fmt_comm(comm: dict) -> str:
@@ -68,7 +61,7 @@ def _round_rows(rounds: list[dict]) -> list[str]:
             wall = f"{ev.get('wall_s', 0):.3f}"
             ips = f"{ev.get('images_per_sec', 0):,.1f}"
         else:
-            # an unstamped wall is not evidence on relay backends
+            # an unstamped wall is not evidence
             wall = "REFUSED"
             ips = "REFUSED (unfenced)"
         lines.append(
@@ -160,8 +153,7 @@ def _member_rows(members: list[dict]) -> list[str]:
 
 
 def _serve_lines(serves: list[dict]) -> list[str]:
-    """Engine lifecycle: loads, priced refusals, drains — the serving
-    twin of the runner's preflight_oom lines."""
+    """Engine lifecycle: loads, priced refusals, drains."""
     lines = []
     for ev in serves:
         kind = ev.get("kind", "?")
@@ -390,8 +382,7 @@ def _metrics_lines(ev: dict) -> list[str]:
 
 
 def _slo_lines(ev: dict) -> list[str]:
-    """One SLO verdict (obs/slo.py, journaled by the window runner):
-    which gates were applicable, the burn list when any failed, and
+    """One SLO verdict (obs/slo.py): which gates were applicable, the burn list when any failed, and
     which greens passed VACUOUSLY (zero subject events) — a reader
     citing this verdict as evidence must see which gates never
     measured anything."""
@@ -510,106 +501,6 @@ def _token_lines(toks: list[dict]) -> list[str]:
     return lines
 
 
-def _runner_lines(events: list[dict]) -> list[str]:
-    """The window-runner evidence ledger (tools/tpu_window_runner.py):
-    dials, jobs, refusals, and per-job SLO verdicts — rendered here so
-    one report covers a whole evidence journal, not only Recorder runs
-    (tools/tunnel_log.py stays the round-narrative renderer)."""
-    lines = []
-    for ev in events:
-        kind = ev.get("event", "?")
-        if kind == "runner_start":
-            jobs = ev.get("jobs") or []
-            lines.append(
-                f"- runner start: queue `{ev.get('queue', '?')}`, "
-                f"{len(jobs)} job(s)")
-        elif kind == "dial_start":
-            lines.append(f"- dial (probe {ev.get('probe', '?')}) started")
-        elif kind == "dial_end":
-            if ev.get("ok"):
-                lines.append(
-                    f"- dial (probe {ev.get('probe', '?')}): backend "
-                    f"`{ev.get('platform') or '?'}` up in "
-                    f"{ev.get('dt_s', 0):.1f} s")
-            else:
-                lines.append(
-                    f"- dial (probe {ev.get('probe', '?')}): DEAD after "
-                    f"{ev.get('dt_s', 0):.1f} s — "
-                    f"{ev.get('error') or 'no backend'}")
-        elif kind == "dial_abandoned":
-            lines.append(
-                f"- dial (probe {ev.get('probe', '?')}) abandoned — "
-                f"{ev.get('note', '?')}")
-        elif kind == "job_start":
-            setup = " [setup]" if ev.get("setup") else ""
-            lines.append(
-                f"- job `{ev.get('job', '?')}`{setup} started "
-                f"(deadline {ev.get('deadline_s', 0):g} s)")
-        elif kind == "job_end":
-            status = ("TIMED OUT" if ev.get("timed_out")
-                      else f"rc {ev.get('rc')}")
-            death = " — window death" if ev.get("window_death") else ""
-            lines.append(
-                f"- job `{ev.get('job', '?')}`: {status} in "
-                f"{ev.get('dt_s', 0):.1f} s{death}")
-        elif kind == "queue_reload_failed":
-            lines.append(
-                f"- **queue reload FAILED**: {ev.get('error', '?')} "
-                "(runner kept the previous queue)")
-        elif kind == "preflight_oom":
-            lines.append(
-                f"- **preflight OOM refusal** `{ev.get('job', '?')}`: "
-                f"{ev.get('model', '?')} batch {ev.get('batch', '?')} "
-                f"{ev.get('dtype', '?')} predicts "
-                f"{ev.get('predicted_bytes', 0):,} B against the "
-                f"{ev.get('budget_bytes', 0):,} B budget — refused "
-                "without burning a dial")
-        elif kind == "setup_failed":
-            lines.append(
-                f"- **setup FAILED** `{ev.get('job', '?')}`: "
-                f"{ev.get('note', '?')}")
-        elif kind == "slo":
-            lines += _slo_lines(ev)
-        elif kind == "sched":
-            lines += _sched_lines(ev)
-        elif kind == "runner_done":
-            lines.append(f"- runner done: {ev.get('reason', '?')}")
-    return lines
-
-
-def _sched_lines(ev: dict) -> list[str]:
-    """One survival-policy scheduler decision (tools/window_policy.py;
-    journaled only under ``--policy survival``), keyed on ``kind``."""
-    k = ev.get("kind", "?")
-    if k == "fit":
-        return [f"- sched fit [{ev.get('policy', '?')}]: "
-                f"{ev.get('windows', 0)} window(s) "
-                f"({ev.get('window_deaths', 0)} death(s), median "
-                f"{ev.get('median_window_s', 0):g} s), "
-                f"{ev.get('heals', 0)} heal obs (median "
-                f"{ev.get('heal_median_s', 0):g} s) from "
-                f"{len(ev.get('sources') or [])} journal(s)"]
-    if k == "pick":
-        return [f"- sched pick `{ev.get('job', '?')}` at window age "
-                f"{ev.get('window_age_s', 0):g} s: value "
-                f"{ev.get('value', 0):g} x p_survive "
-                f"{ev.get('p_survive', 0):g} = score "
-                f"{ev.get('score', 0):g} over "
-                f"{ev.get('candidates', 0)} candidate(s)"]
-    if k == "window_summary":
-        return [f"- sched window summary (probe {ev.get('probe', '?')}): "
-                f"expected {ev.get('expected_value', 0):g}, banked "
-                f"{ev.get('banked_value', 0):g} across "
-                f"{ev.get('jobs_banked', 0)} job(s) in "
-                f"{ev.get('window_age_s', 0):g} s"]
-    if k == "redial_backoff":
-        return [f"- sched redial backoff: deferring dial "
-                f"{ev.get('delay_s', 0):g} s after "
-                f"{ev.get('consecutive_dead', 0)} consecutive death(s) "
-                f"(fitted heal median {ev.get('heal_median_s', 0):g} s)"]
-    return [f"- sched {k}: {ev.get('note', '')}"]
-
-
 def _waterfall_lines(defining: list[dict], lin: dict,
                      label: str) -> list[str]:
     """One causal chain (obs/lineage.py chain) as an indented list:
@@ -684,8 +575,6 @@ def _bench_lines(benches: list[dict]) -> list[str]:
         tags.append("measured" if ev.get("measured") else "UNMEASURED")
         if not ev.get("fenced"):
             tags.append("unfenced")
-        if rec.get("probe") is not None:
-            tags.append(f"probe {rec['probe']}")
         tag = ", ".join(tags)
         if conflict is not None:
             why = rec.get("bound_inconsistency",
@@ -739,7 +628,7 @@ def render(events: Iterable[dict], source: str = "journal",
     ]
     runs: list[str] = []
     by_run: dict[str, dict[str, list]] = {}
-    runner_events: list[dict] = []
+    slo_events: list[dict] = []
     request_aggs: dict[str, _RequestAgg] = {}
     last_round: dict | None = None
     last_request_lin: dict | None = None
@@ -749,8 +638,8 @@ def render(events: Iterable[dict], source: str = "journal",
         kind = ev.get("event")
         run_id = ev.get("run_id")
         if run_id is None:
-            if kind in _RUNNER_EVENTS:
-                runner_events.append(ev)
+            if kind in _UNSCOPED_EVENTS:
+                slo_events.append(ev)
             continue
         if run_id not in by_run:
             runs.append(run_id)
@@ -784,13 +673,14 @@ def render(events: Iterable[dict], source: str = "journal",
             if key == "round":
                 last_round = ev
 
-    if not runs and not runner_events:
+    if not runs and not slo_events:
         lines += ["", "_No obs events in this journal._", ""]
         return "\n".join(lines)
 
-    if runner_events:
-        lines += ["", "## window-runner ledger", ""]
-        lines += _runner_lines(runner_events)
+    if slo_events:
+        lines += ["", "## SLO verdicts", ""]
+        for ev in slo_events:
+            lines += _slo_lines(ev)
 
     for run_id in runs:
         group = by_run[run_id]

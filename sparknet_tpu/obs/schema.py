@@ -1,29 +1,14 @@
-"""The one journal-line schema: window-runner events + obs runtime events.
+"""The one journal-line schema for the obs runtime events.
 
-Until this module existed the journal format lived informally in three
-tools — ``tools/tpu_window_runner.py`` wrote lines, ``tools/tunnel_log.py``
-and the judge read them, and nothing checked that the two sides agreed
-(the round-3 journal silently lacks per-dial probe ids, which is exactly
-how a bench record's provenance field became unmatchable).  This module
-states the format once, as checkable data: every line is one JSON object
-with an ``event`` discriminator, a ``utc`` wall stamp, and per-event
-required/optional fields.  Writers build lines through :func:`make_event`
+Every line is one JSON object with an ``event`` discriminator, a ``utc``
+wall stamp, and per-event required/optional fields, stated once as
+checkable data.  Writers build lines through :func:`make_event`
 (validates before the bytes hit disk); readers validate through
-:func:`validate_line` / :func:`validate_journal`.
+:func:`validate_line` / :func:`validate_journal`, so one validator
+audits the whole evidence chain and one renderer vocabulary covers it.
 
-Two event families share the format deliberately — the window runner's
-host-side ledger (dials, jobs) and the obs Recorder's runtime telemetry
-(spans, rounds, recompiles, banked evidence) — so one validator audits
-the whole evidence chain and one renderer vocabulary covers both.
-
-Deliberately stdlib-only (the analysis-package contract: importable on a
-box with a wedged relay; nothing here touches jax, and nothing it
-triggers may initialize a backend).
-
-Legacy journals: lines that predate the schema are NOT silently skipped.
-:data:`LEGACY_ALLOWLIST` names each known-deviant (journal, event,
-error) triple with the reason; the validator forgives exactly those and
-reports everything else.
+Deliberately stdlib-only (the analysis-package contract: nothing here
+touches jax, and nothing it triggers may initialize a backend).
 """
 
 from __future__ import annotations
@@ -36,7 +21,6 @@ from typing import Any, Iterator
 __all__ = [
     "SCHEMA_VERSION",
     "EVENTS",
-    "LEGACY_ALLOWLIST",
     "utc_now",
     "make_event",
     "validate_line",
@@ -47,8 +31,7 @@ __all__ = [
 
 SCHEMA_VERSION = 1
 
-# the journal's wall-stamp format, shared verbatim with the window
-# runner's historical lines: "2026-07-31 15:35:45Z"
+# the journal's wall-stamp format: "2026-07-31 15:35:45Z"
 _UTC_FMT = "%Y-%m-%d %H:%M:%SZ"
 _UTC_RE = re.compile(r"^\d{4}-\d{2}-\d{2} \d{2}:\d{2}:\d{2}Z$")
 
@@ -60,63 +43,15 @@ _OPT_STR = (str, type(None))
 # unknown fields are validation errors: both writers live in this repo,
 # so drift is a bug, not forward compatibility.
 EVENTS: dict[str, tuple[dict, dict]] = {
-    # -- tools/tpu_window_runner.py (host-side evidence ledger) ---------
-    "runner_start": ({"queue": str, "jobs": list}, {}),
-    "dial_start": ({"probe": int}, {}),
-    "dial_end": (
-        {"probe": int, "ok": bool, "dt_s": _NUM},
-        {"platform": _OPT_STR, "error": _OPT_STR},
-    ),
-    # post-hoc adjudication of a dial whose runner died mid-flight
-    "dial_abandoned": ({"probe": int, "note": str}, {}),
-    "job_start": (
-        {"job": str, "argv": list, "deadline_s": _NUM},
-        {"setup": bool},
-    ),
-    "job_end": (
-        {"job": str, "rc": (int, type(None)), "dt_s": _NUM,
-         "timed_out": bool},
-        {"window_death": bool, "setup": bool},
-    ),
-    "queue_reload_failed": ({"error": str}, {}),
-    # the memcheck queue pre-flight refused a job whose predicted
-    # per-device footprint exceeds the chip (analysis/mem_model
-    # preflight_job against docs/mem_contracts/batch_fit.json): the job
-    # is marked dead WITHOUT burning a dial — the refusal, not a 25-min
-    # OOM-then-wedge, is the round's record of it
-    "preflight_oom": (
-        {"job": str, "model": str, "batch": int, "dtype": str,
-         "predicted_bytes": int, "budget_bytes": int},
-        {"note": str},
-    ),
-    "setup_failed": ({"job": str, "note": str}, {}),
-    # the runner's per-job SLO verdict (obs/slo.py evaluated against the
-    # obs journal(s) a drained job produced): ``gates`` is the manifest
-    # size, ``applicable`` how many gates had subject events in the
-    # journal (the rest pass vacuously), ``burned`` the failing gate ids
+    # -- obs/slo.py ------------------------------------------------------
+    # one SLO verdict (obs/slo.py evaluated against an obs journal):
+    # ``gates`` is the manifest size, ``applicable`` how many gates had
+    # subject events in the journal (the rest pass vacuously),
+    # ``burned`` the failing gate ids
     "slo": (
         {"job": str, "ok": bool, "gates": int, "applicable": int},
         {"burned": list, "vacuous": list, "journal": str,
          "manifest": str, "note": str},
-    ),
-    "runner_done": ({"reason": str}, {"blocked_jobs": list}),
-    # one survival-policy scheduling decision (tools/window_policy.py;
-    # only written under ``--policy survival`` — the default runner path
-    # stays byte-compatible).  ``kind`` discriminates: "fit" (model
-    # summary at runner start), "pick" (value x P(survive) argmax inside
-    # a window), "window_summary" (per-window expected-vs-banked
-    # evidence reconciliation), "redial_backoff" (survival-seeded
-    # deferred dial while the relay is wedged)
-    "sched": (
-        {"kind": str},
-        {"policy": str, "job": str, "probe": int, "window_age_s": _NUM,
-         "est_runtime_s": _NUM, "p_survive": _NUM, "value": _NUM,
-         "score": _NUM, "candidates": int, "expected_value": _NUM,
-         "banked_value": _NUM, "jobs_banked": int, "delay_s": _NUM,
-         "consecutive_dead": int, "heal_median_s": _NUM,
-         "windows": int, "window_deaths": int, "median_window_s": _NUM,
-         "heals": int, "heals_observed": int, "sources": list,
-         "note": str},
     ),
     # -- sparknet_tpu/obs Recorder (runtime telemetry) ------------------
     "run_start": ({"run_id": str}, {"pid": int, "argv": list, "note": str}),
@@ -212,7 +147,7 @@ EVENTS: dict[str, tuple[dict, dict]] = {
     # -- serving engine (sparknet_tpu/serve) ----------------------------
     # one engine lifecycle event, discriminated by ``kind``:
     # model_loaded / load_refused (the priced-residency admission gate,
-    # serve/residency.py — the serving twin of ``preflight_oom``) /
+    # serve/residency.py) /
     # model_unloaded / shutdown / summary (a load-run roll-up) /
     # candidate_built / rollout / rollback (the hot-reload protocol,
     # sparknet_tpu/loop: ``version`` is the swap generation, ``drained``
@@ -333,29 +268,8 @@ EVENTS: dict[str, tuple[dict, dict]] = {
     ),
 }
 
-# Known-deviant legacy lines, forgiven explicitly (never silently): each
-# entry names the journal (path suffix), the event, the exact error
-# prefix being excused, and why.
-LEGACY_ALLOWLIST: tuple[dict, ...] = (
-    {
-        "journal": "docs/evidence_r3/journal.jsonl",
-        "event": "dial_start",
-        "error": "missing required field 'probe'",
-        "reason": "round-3 journal predates per-dial probe ids "
-                  "(introduced for r4 provenance matching)",
-    },
-    {
-        "journal": "docs/evidence_r3/journal.jsonl",
-        "event": "dial_end",
-        "error": "missing required field 'probe'",
-        "reason": "round-3 journal predates per-dial probe ids "
-                  "(introduced for r4 provenance matching)",
-    },
-)
-
-
 def utc_now() -> str:
-    """The journal wall stamp, in the format every round has used."""
+    """The journal wall stamp."""
     return time.strftime(_UTC_FMT, time.gmtime())
 
 
@@ -418,28 +332,15 @@ def make_event(event: str, **fields) -> dict:
     return line
 
 
-def _allowlisted(path: str, event: str, error: str,
-                 allowlist: tuple) -> bool:
-    norm = path.replace("\\", "/")
-    for entry in allowlist:
-        if (norm.endswith(entry["journal"]) and event == entry["event"]
-                and error.startswith(entry["error"])):
-            return True
-    return False
-
-
-def validate_journal(
-    path: str, allowlist: tuple = LEGACY_ALLOWLIST,
-) -> tuple[int, int, list[str]]:
+def validate_journal(path: str) -> tuple[int, list[str]]:
     """Validate every line of a journal file.
 
-    Returns ``(n_lines, n_allowlisted, errors)`` where ``errors`` holds
-    one ``"path:lineno: message"`` string per non-allowlisted violation.
-    Unparseable lines are errors too — the runner appends atomically
-    enough that a torn line means something worth knowing about.
+    Returns ``(n_lines, errors)`` where ``errors`` holds one
+    ``"path:lineno: message"`` string per violation.  Unparseable lines
+    are errors too — writers append atomically enough that a torn line
+    means something worth knowing about.
     """
     n_lines = 0
-    n_allowlisted = 0
     errors: list[str] = []
     with open(path, encoding="utf-8") as f:
         for lineno, raw in enumerate(f, start=1):
@@ -451,16 +352,10 @@ def validate_journal(
             except ValueError as e:
                 errors.append(f"{path}:{lineno}: unparseable JSON ({e})")
                 continue
-            line_errors = validate_line(obj)
-            if not line_errors:
-                continue
             event = obj.get("event") if isinstance(obj, dict) else None
-            kept = [e for e in line_errors
-                    if not _allowlisted(path, str(event), e, allowlist)]
-            if len(kept) < len(line_errors):
-                n_allowlisted += 1
-            errors.extend(f"{path}:{lineno}: [{event}] {e}" for e in kept)
-    return n_lines, n_allowlisted, errors
+            errors.extend(f"{path}:{lineno}: [{event}] {e}"
+                          for e in validate_line(obj))
+    return n_lines, errors
 
 
 def load_journal(path: str) -> list[dict]:

@@ -2,8 +2,8 @@
 
 Every gate below already existed — as an exit-1 branch in a dryrun, a
 bound in a bench tool, or prose in docs/BENCHMARKS.md: warm queue p99 ≤
-its deadline bound (serve/loadgen.py), ``slot_wait`` share ≤ 5%
-(tools/feed_train_slotwait.py), post-warmup compiles == 0 (the
+its deadline bound (serve/loadgen.py), ``slot_wait`` share ≤ 5% (the
+ring feed's starvation gate), post-warmup compiles == 0 (the
 recompile sentinel), the pod zero-drop ledger == 0 (serve/router.py
 ``submitted − resolved``), and measured throughput ≤ its stated
 roofline (bench.py, CLAUDE.md "never print a value above its own
@@ -13,15 +13,12 @@ SLO and nothing noticed until a human read the markdown.
 
 This module loads ``docs/slo_manifest.json`` and evaluates each gate
 against a journal's events.  Gates are VACUOUS (pass, not applicable)
-when the journal has no subject events — a window-runner ledger with no
-obs telemetry passes trivially, a serve journal answers the serve
-gates.  ``obs slo`` exits nonzero on any burn; the window runner
-evaluates each drained job's journals and journals a schema-valid
-``slo`` verdict event (the substrate ROADMAP item 5's evidence-per-
-window scheduler needs).
+when the journal has no subject events — a journal with no serve
+telemetry passes the serve gates trivially, a serve journal answers
+them.  ``obs slo`` exits nonzero on any burn; :func:`verdict_fields`
+shapes a verdict as a schema-valid ``slo`` event.
 
-Deliberately stdlib-only (the obs-package contract: must run next to a
-wedged relay, inside the runner, with no jax import).
+Deliberately stdlib-only (the obs-package contract: no jax import).
 """
 
 from __future__ import annotations
@@ -302,8 +299,8 @@ def evaluate_journal(path: str,
 def verdict_fields(job: str, results: list[dict], *,
                    journal: str | None = None,
                    manifest_path: str | None = None) -> dict:
-    """The ``slo`` journal event's fields for one evaluated job (the
-    window runner writes this through schema.make_event)."""
+    """The ``slo`` journal event's fields for one evaluated job (valid
+    input to schema.make_event)."""
     burned = [r["id"] for r in results if not r["ok"]]
     vacuous = [r["id"] for r in results
                if r["ok"] and not r["applicable"]]

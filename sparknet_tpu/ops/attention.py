@@ -110,19 +110,14 @@ def _sp_attention(mesh, impl, q, k, v, causal):
     core = ring_attention if impl == "ring" else ulysses_attention
     spec = jax.sharding.PartitionSpec(dax, None, sax, None)
     # ring's fully-masked-block skip is a lax.cond whose branches jax's
-    # replication checker mis-types on some releases (its own error text
-    # prescribes disabling the check); the kwarg name also moved
-    # check_rep -> check_vma across releases
-    import inspect
-
-    params = inspect.signature(shard_map).parameters
-    check_kw = "check_vma" if "check_vma" in params else "check_rep"
+    # varying-axes checker mis-types (its own error text prescribes
+    # disabling the check)
     return shard_map(
         partial(core, axis_name=sax, causal=causal),
         mesh=mesh,
         in_specs=(spec, spec, spec),
         out_specs=spec,
-        **{check_kw: False},
+        check_vma=False,
     )(q, k, v)
 
 

@@ -13,8 +13,10 @@ the x^2 buffer computed once.
 
 ``lrn_across_channels`` defaults to the XLA formulation everywhere; the
 pallas kernel is opt-in via ``SPARKNET_LRN_IMPL=pallas`` (or
-``force='pallas'``) until it has been validated on the target TPU
-generation.  Interpret mode is used by tests to pin equivalence.
+``force='pallas'``).  Every kernel here compiles under Mosaic on the
+installed toolchain and matches its XLA twin on a v5e at its caller's
+full-width shapes (``chip_smoke.py`` re-proves it on every run);
+interpret mode is used by tests to pin equivalence chip-free.
 """
 
 from __future__ import annotations
@@ -25,13 +27,8 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 
-try:  # pallas is part of jax, but guard exotic builds
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    _HAS_PALLAS = True
-except Exception:  # pragma: no cover
-    _HAS_PALLAS = False
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 # spatial tile on the minor (lane) axis; multiple of 128
 _TILE = 512
@@ -200,15 +197,14 @@ def lrn_across_channels(x, size, alpha, beta, k, force: str | None = None,
     'xla' | None.
 
     None consults ``SPARKNET_LRN_IMPL`` (fused|pallas|xla); the default
-    is the XLA formulation — flip the env var (or pass force=...) on TPU
-    after a shootout validates the challenger on the target generation
-    (tools/pallas_bench.py).  Differentiable on every path.
+    is the XLA formulation.  Differentiable on every path.
 
     ``channel_axis``: 1 for NCHW blobs (default), 3 for NHWC
     (``Config.layout = "nhwc"``).  The hand-written pallas kernel is
     NCHW-tuned (it exists to move the window onto the minor axis, which
-    NHWC already has), so channels-last inputs route pallas/interpret
-    requests to the XLA formulation instead."""
+    NHWC already has): a pallas/interpret request it cannot honour — a
+    channels-last or non-rank-4 input — raises, as does an unknown
+    ``force``; no request is quietly answered by another formulation."""
     import os
 
     if size % 2 == 0:
@@ -218,14 +214,18 @@ def lrn_across_channels(x, size, alpha, beta, k, force: str | None = None,
     if force == "fused":
         return lrn_across_channels_fused(x, size, alpha, beta, k,
                                          channel_axis)
-    if force == "xla" or not _HAS_PALLAS or channel_axis != 1:
+    if force == "xla":
         return lrn_across_channels_xla(x, size, alpha, beta, k,
                                        channel_axis)
-    if force == "interpret":
-        return _lrn_diff(x, size, alpha, beta, k, True)
-    if force == "pallas" and x.ndim == 4:
-        return _lrn_diff(x, size, alpha, beta, k, False)
-    return lrn_across_channels_xla(x, size, alpha, beta, k)
+    if force in ("pallas", "interpret"):
+        if x.ndim != 4 or channel_axis != 1:
+            raise ValueError(
+                f"LRN impl {force!r} takes a rank-4 NCHW input "
+                f"(channel_axis=1); got rank {x.ndim}, channel_axis="
+                f"{channel_axis} — use 'xla' or 'fused' there")
+        return _lrn_diff(x, size, alpha, beta, k, force == "interpret")
+    raise ValueError(f"unknown LRN impl {force!r} "
+                     "(fused|pallas|interpret|xla)")
 
 
 # ---------------------------------------------------------------------------
@@ -573,9 +573,8 @@ def fused_update(rule: str, st: UpdateStatics, w, g, slots,
     if force is None:
         force = os.environ.get("SPARKNET_FUSED_IMPL", "auto")
     if force == "auto":
-        force = ("pallas" if _HAS_PALLAS
-                 and jax.default_backend() == "tpu" else "xla")
-    if force == "xla" or not _HAS_PALLAS:
+        force = "pallas" if jax.default_backend() == "tpu" else "xla"
+    if force == "xla":
         return _fused_update_xla(st, rule, w, g, slots, lr_tiles,
                                  decay_tiles, scalars)
     if force in ("pallas", "interpret"):
@@ -614,22 +613,18 @@ def fused_update_hbm_bytes(arena_bytes: int, n_slots: int) -> int:
 
 def fused_update_tpu_custom_calls(rule: str = "SGD", n_slots: int = 1,
                                   n_tiles: int = 2,
-                                  dtype=None) -> int | None:
+                                  dtype=None) -> int:
     """Count the custom calls in a CROSS-PLATFORM TPU lowering of the
     fused pallas sweep — zero chip time (jax.export lowers Mosaic
     host-side; the kernel binary compiles at XLA compile time, which
     never runs here).  The graph-contract twins (solo_fused/dp_fused)
     bank this as the 'update chain collapsed to one custom call' pin:
     the whole normalize/regularize/clip/rule chain must lower as
-    exactly ONE tpu_custom_call.  Returns None when this jax build has
-    no export API (the finding side treats that as a failure to pin,
-    not a pass)."""
+    exactly ONE tpu_custom_call."""
     import re
 
-    try:
-        from jax import export as jexport
-    except ImportError:  # pragma: no cover - jax API drift
-        return None
+    from jax import export as jexport
+
     dtype = dtype or jnp.float32
     T = n_tiles * ARENA_TILE
     st = UpdateStatics(momentum=0.9, reg="l2")
@@ -724,13 +719,12 @@ def flash_attention(q, k, v, causal: bool = False, force: str | None = None):
 
     if force is None:
         force = os.environ.get("SPARKNET_ATTN_IMPL", "xla")
-    if force == "xla" or not _HAS_PALLAS:
+    if force == "xla":
         return attention_xla(q, k, v, causal)
-    if force == "interpret":
-        return _flash_diff(q, k, v, causal, True)
-    if force == "pallas":
-        return _flash_diff(q, k, v, causal, False)
-    return attention_xla(q, k, v, causal)
+    if force in ("pallas", "interpret"):
+        return _flash_diff(q, k, v, causal, force == "interpret")
+    raise ValueError(f"unknown attention impl {force!r} "
+                     "(pallas|interpret|xla)")
 
 
 # ---------------------------------------------------------------------------
@@ -750,10 +744,11 @@ def flash_attention(q, k, v, causal: bool = False, force: str | None = None):
 # That independence is the paged exactness gate: interleaved decode is
 # bitwise equal to decoding alone under the SAME compiled program.
 #
-# The pallas path DMAs each table-named block from ANY-space pools into
-# a VMEM scratch (PrefetchScalarGridSpec scalar-prefetches the tables so
-# the copy addresses are known before the body runs) — the kernel never
-# materializes the [B, MB*T, H, D] gather the XLA twin pays for.
+# The pallas path grids over (row, table entry) and lets the pipeline
+# fetch each table-named block: PrefetchScalarGridSpec scalar-prefetches
+# the tables, so the K/V BlockSpec index_map reads the block id before
+# the body runs — the kernel never materializes the [B, MB*T, H, D]
+# gather the XLA twin pays for.
 # Forward-only by design (decode is inference; no vjp), so unlike the
 # flash kernel there is no custom_vjp pairing.
 
@@ -782,53 +777,48 @@ def paged_attention_xla(q, k_pool, v_pool, tables, positions):
                       v.astype(jnp.float32)).astype(q.dtype)
 
 
-def _paged_kernel(block_tokens: int, blocks_per_slot: int, scale: float,
-                  tbl_ref, pos_ref, q_ref, kp_ref, vp_ref, o_ref):
-    """One grid cell = one slot row: walk the row's block table, DMA
-    each named K/V block from the ANY-space pools into VMEM scratch,
-    and fold it into the flash-style online-softmax carry."""
-    b = pl.program_id(0)
-    H, D = q_ref.shape[1], q_ref.shape[2]
-    q = q_ref[0].astype(jnp.float32)  # [H, D]
+def _paged_kernel(block_tokens: int, scale: float,
+                  tbl_ref, pos_ref, q_ref, k_ref, v_ref, o_ref,
+                  acc_ref, m_ref, l_ref):
+    """One grid cell = (slot row b, table entry m): the K/V refs hold
+    the [1, T, H, D] pool block the row's table names at m (gathered by
+    the BlockSpec index_map off the scalar-prefetched table), folded
+    into the flash-style online-softmax carry kept in VMEM scratch
+    across the row's m cells."""
+    b, m = pl.program_id(0), pl.program_id(1)
 
-    def body(kb, vb, sem):
-        def step(m, carry):
-            o_acc, mx, l = carry
-            blk = tbl_ref[b, m]
-            cp = pltpu.make_async_copy(kp_ref.at[blk], kb, sem)
-            cp.start()
-            cp.wait()
-            cp = pltpu.make_async_copy(vp_ref.at[blk], vb, sem)
-            cp.start()
-            cp.wait()
-            k = kb[...].astype(jnp.float32)  # [T, H, D]
-            v = vb[...].astype(jnp.float32)
-            s = jnp.einsum("hd,thd->ht", q, k) * scale
-            cols = m * block_tokens + jax.lax.broadcasted_iota(
-                jnp.int32, s.shape, 1)
-            s = jnp.where(cols <= pos_ref[b], s, -1e30)
-            m_new = jnp.maximum(mx, jnp.max(s, axis=1))
-            p = jnp.exp(s - m_new[:, None])
-            corr = jnp.exp(mx - m_new)
-            l_new = l * corr + jnp.sum(p, axis=1)
-            o_new = o_acc * corr[:, None] + jnp.einsum("ht,thd->hd", p, v)
-            return o_new, m_new, l_new
+    @pl.when(m == 0)
+    def _():
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+        m_ref[...] = jnp.full_like(m_ref, -1e30)
+        l_ref[...] = jnp.zeros_like(l_ref)
 
-        o0 = jnp.zeros((H, D), jnp.float32)
-        m0 = jnp.full((H,), -1e30, jnp.float32)
-        l0 = jnp.zeros((H,), jnp.float32)
-        o_acc, _, l = jax.lax.fori_loop(0, blocks_per_slot, step,
-                                        (o0, m0, l0))
+    # One query token per row leaves the MXU nothing to do, and Mosaic
+    # takes no dot whose batch axis (H) sits in the middle of [T, H, D]:
+    # scores and the weighted sum are VPU multiplies + reductions, every
+    # carry kept rank-3 ([., H, .], heads on sublanes) so no step
+    # changes layout.
+    q = q_ref[...].astype(jnp.float32) * scale  # [1, H, D]
+    k = k_ref[0].astype(jnp.float32)  # [T, H, D]
+    v = v_ref[0].astype(jnp.float32)
+    s = jnp.sum(k * q, axis=2, keepdims=True)  # [T, H, 1]
+    cols = m * block_tokens + jax.lax.broadcasted_iota(
+        jnp.int32, s.shape, 0)
+    s = jnp.where(cols <= pos_ref[b], s, -1e30)
+    m_old = m_ref[...]
+    m_new = jnp.maximum(m_old, jnp.max(s, axis=0, keepdims=True))
+    p = jnp.exp(s - m_new)
+    corr = jnp.exp(m_old - m_new)  # [1, H, 1]
+    l_ref[...] = l_ref[...] * corr + jnp.sum(p, axis=0, keepdims=True)
+    acc_ref[...] = acc_ref[...] * corr + jnp.sum(p * v, axis=0,
+                                                  keepdims=True)
+    m_ref[...] = m_new
+
+    @pl.when(m == pl.num_programs(1) - 1)
+    def _():
         # positions are clamped >= 0, so column 0 is always live and
         # l > 0 for every row (idle slots included)
-        o_ref[0] = (o_acc / l[:, None]).astype(o_ref.dtype)
-
-    pl.run_scoped(
-        body,
-        kb=pltpu.VMEM((block_tokens, H, D), kp_ref.dtype),
-        vb=pltpu.VMEM((block_tokens, H, D), vp_ref.dtype),
-        sem=pltpu.SemaphoreType.DMA(()),
-    )
+        o_ref[...] = (acc_ref[...] / l_ref[...]).astype(o_ref.dtype)
 
 
 def _paged_pallas(q, k_pool, v_pool, tables, positions,
@@ -836,21 +826,29 @@ def _paged_pallas(q, k_pool, v_pool, tables, positions,
     B, H, D = q.shape
     T = k_pool.shape[1]
     MB = tables.shape[1]
-    kernel = functools.partial(
-        _paged_kernel, T, MB, 1.0 / float(D) ** 0.5)
+    kernel = functools.partial(_paged_kernel, T, 1.0 / float(D) ** 0.5)
+    row = lambda b, m, tbl, pos: (b, 0, 0)  # noqa: E731
+    block = lambda b, m, tbl, pos: (tbl[b, m], 0, 0, 0)  # noqa: E731
     return pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
-            grid=(B,),
+            grid=(B, MB),
             in_specs=[
-                pl.BlockSpec((1, H, D), lambda b, *_: (b, 0, 0)),
-                pl.BlockSpec(memory_space=pltpu.ANY),
-                pl.BlockSpec(memory_space=pltpu.ANY),
+                pl.BlockSpec((1, H, D), row),
+                pl.BlockSpec((1, T, H, D), block),
+                pl.BlockSpec((1, T, H, D), block),
             ],
-            out_specs=pl.BlockSpec((1, H, D), lambda b, *_: (b, 0, 0)),
+            out_specs=pl.BlockSpec((1, H, D), row),
+            scratch_shapes=[
+                pltpu.VMEM((1, H, D), jnp.float32),
+                pltpu.VMEM((1, H, 1), jnp.float32),
+                pltpu.VMEM((1, H, 1), jnp.float32),
+            ],
         ),
         out_shape=jax.ShapeDtypeStruct((B, H, D), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(tables, positions, q, k_pool, v_pool)
 
@@ -865,33 +863,31 @@ def paged_attention(q, k_pool, v_pool, tables, positions,
 
     if force is None:
         force = os.environ.get("SPARKNET_PAGED_IMPL", "xla")
-    if force == "xla" or not _HAS_PALLAS:
+    if force == "xla":
         return paged_attention_xla(q, k_pool, v_pool, tables, positions)
-    if force == "interpret":
+    if force in ("pallas", "interpret"):
         return _paged_pallas(q, k_pool, v_pool, tables, positions,
-                             interpret=True)
-    if force == "pallas":
-        return _paged_pallas(q, k_pool, v_pool, tables, positions,
-                             interpret=False)
-    return paged_attention_xla(q, k_pool, v_pool, tables, positions)
+                             interpret=force == "interpret")
+    raise ValueError(f"unknown paged attention impl {force!r} "
+                     "(pallas|interpret|xla)")
 
 
 def paged_vmem_bytes(block_tokens: int, heads: int, head_dim: int,
                      itemsize: int = 4) -> int:
     """Static VMEM bound for one ``_paged_kernel`` grid cell.  Unlike
     the flash kernel's full-fiber K/V residency, the paged kernel keeps
-    exactly ONE [T, H, D] block of K and V resident (the run_scoped
-    scratch the DMA lands in), so the bound is linear in block_tokens
-    and INDEPENDENT of sequence length — the arithmetic form of "per
-    token decode work stops paying O(seq_len)".  Terms: q + o [1, H, D]
-    blocks (double-buffered by the pipeline, x2 each), the K/V scratch
-    at pool itemsize, and the f32 compute temporaries (k/v casts, the
-    s/p [H, T] score tiles, o_acc, and the m/l running stats)."""
+    ONE [T, H, D] block of K and V in flight (the pipeline's two
+    buffers each), so the bound is linear in block_tokens and
+    INDEPENDENT of sequence length — the arithmetic form of "per token
+    decode work stops paying O(seq_len)".  Terms: q + o [1, H, D] and
+    K + V [1, T, H, D] blocks (double-buffered by the pipeline, x2
+    each) at pool itemsize, the f32 carry scratch (acc, m, l), and the
+    f32 compute temporaries (k/v casts, the s/p [T, H] score tiles,
+    corr and m_new)."""
     hd = heads * head_dim
-    blocks = 2 * (2 * hd) * itemsize            # q + o, double-buffered
-    scratch = 2 * block_tokens * hd * itemsize  # kb + vb DMA landing
+    blocks = 2 * (2 * hd + 2 * block_tokens * hd) * itemsize
+    scratch = (hd + 2 * heads) * 4              # acc, m, l carry
     temps = (2 * block_tokens * hd              # k/v f32 casts
              + 2 * heads * block_tokens         # s, p score tiles
-             + heads * head_dim                 # o_acc
-             + 4 * heads) * 4                   # m, l, m_new, corr
+             + 2 * heads) * 4                   # m_new, corr
     return blocks + scratch + temps

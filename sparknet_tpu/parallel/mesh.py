@@ -10,18 +10,12 @@ from `jax.distributed.initialize` over DCN.
 
 from __future__ import annotations
 
-
 import jax
 import numpy as np
+from jax import shard_map  # noqa: F401 — re-exported for the trainers
 from jax.sharding import Mesh
 
 from sparknet_tpu.common import get_config
-
-
-try:  # jax >= 0.4.35 exposes shard_map at top level
-    from jax import shard_map  # noqa: F401
-except ImportError:  # pragma: no cover - older jax
-    from jax.experimental.shard_map import shard_map  # noqa: F401
 
 
 def local_device_count() -> int:

@@ -158,16 +158,8 @@ class ParallelTrainer:
         else:
             # stack a worker axis: leaf [R, ...] sharded over 'data' — each
             # device owns its own (initially identical) model replica
-            R = self.num_workers
-            stack = lambda t: jax.tree_util.tree_map(
-                lambda x: jnp.broadcast_to(x[None], (R,) + x.shape), t
-            )
-            spec = NamedSharding(self.mesh, P(self.data_axis))
-            put = lambda t: jax.tree_util.tree_map(
-                lambda x: jax.device_put(x, spec), t
-            )
-            self.variables = put(stack(solver.variables))
-            self.slots = put(stack(solver.slots))
+            self.variables = self._stack_replicas(solver.variables)
+            self.slots = self._stack_replicas(solver.slots)
             if self._elastic:
                 # EASGD (Zhang, Choromanska, LeCun 2015 — the reference's
                 # unrealized ROADMAP.md:11 item): workers couple to a
@@ -192,6 +184,20 @@ class ParallelTrainer:
         )
 
     # ------------------------------------------------------------------
+    def _stack_replicas(self, tree):
+        """Leaf ``x`` -> ``[R, ...]`` sharded over 'data', every device
+        handed its own ``[1, ...]`` row straight from the host copy —
+        the R-fold stack never exists on any one device."""
+        R = self.num_workers
+        spec = NamedSharding(self.mesh, P(self.data_axis))
+
+        def one(x):
+            row = np.asarray(x)[None]
+            return jax.make_array_from_callback(
+                (R,) + row.shape[1:], spec, lambda _idx: row)
+
+        return jax.tree_util.tree_map(one, tree)
+
     def _place_slots(self, slots):
         """Slots shard exactly like the param they track."""
         out = {}
@@ -346,8 +352,13 @@ class ParallelTrainer:
                     spec_for(k, v), v, gshape
                 )
             return out
+        # host batches go straight to their shards: staging the global
+        # batch on the default device first would put every worker's
+        # share on chip 0
         return {
-            k: jax.device_put(jnp.asarray(v), spec_for(k, v))
+            k: jax.device_put(
+                v if isinstance(v, jax.Array) else np.asarray(v),
+                spec_for(k, v))
             for k, v in feeds.items()
         }
 
@@ -605,14 +616,7 @@ class ParallelTrainer:
         if self.tau == 1 and not self._elastic:
             self.variables = place(v, self._pshard)
         else:
-            R = self.num_workers
-            spec = NamedSharding(self.mesh, P(self.data_axis))
-            self.variables = jax.tree_util.tree_map(
-                lambda x: jax.device_put(
-                    jnp.broadcast_to(x[None], (R,) + x.shape), spec
-                ),
-                v,
-            )
+            self.variables = self._stack_replicas(v)
             if self._elastic:
                 rep = NamedSharding(self.mesh, P())
                 self.center = jax.tree_util.tree_map(

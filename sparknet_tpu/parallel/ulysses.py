@@ -80,18 +80,12 @@ def ulysses_self_attention(
     import os
 
     attn_impl = os.environ.get("SPARKNET_ATTN_IMPL", "xla")
-    # the replication-check kwarg was renamed check_rep -> check_vma
-    # across jax releases; pass whichever this build's shard_map takes
-    import inspect
-
-    params = inspect.signature(_shard_map).parameters
-    check_kw = "check_vma" if "check_vma" in params else "check_rep"
     fn = _shard_map(
         partial(ulysses_attention, axis_name=seq_axis, causal=causal),
         mesh=mesh,
         in_specs=(spec, spec, spec),
         out_specs=spec,
-        **{check_kw: attn_impl == "xla"},
+        check_vma=attn_impl == "xla",
     )
     sharding = NamedSharding(mesh, spec)
     q, k, v = (jax.device_put(x, sharding) for x in (q, k, v))

@@ -310,7 +310,6 @@ class PagedDecoder:
         self.prefill_buckets = tuple(buckets) + (self.slots,)
         self._prefill_exec = {}
         for pb in self.prefill_buckets:
-            # graftlint: disable-next-line=stale-args-dispatch -- each iteration compiles a DIFFERENT bucket program (pb rebinds the lowered shapes); the wall is host compile time, not a timed device loop
             self._prefill_exec[pb] = jax.jit(
                 prefill_fn, donate_argnums=(
                     () if not donate else (3, 4))).lower(

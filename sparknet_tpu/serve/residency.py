@@ -1,12 +1,12 @@
 """Multi-model HBM admission pricing for the serving engine.
 
-The queue pre-flight (analysis/mem_model.preflight_job) refuses a TRAIN
-job the banked batch-fit table predicts won't fit the chip — the same
-policy extended to model LOADS: before the engine compiles a single
-bucket, the model's worst-case resident footprint is priced off
-``docs/mem_contracts/batch_fit.json`` and the load is refused when it
-would not fit next to the models already resident.  A refusal costs
-nothing; an OOM mid-serve costs the whole relay window.
+The banked batch-fit table (``docs/mem_contracts/batch_fit.json``)
+predicts what a TRAIN step holds on the chip; here it prices model
+LOADS: before the engine compiles a single bucket, the model's
+worst-case resident footprint is priced off the table and the load is
+refused when it would not fit next to the models already resident.  A
+refusal costs nothing; an OOM mid-serve takes every resident model
+down.
 
 The inference footprint is derived from the banked TRAIN fit (the only
 fit the table holds) conservatively:
@@ -52,8 +52,8 @@ FIT_TABLE_PATH = os.path.join(_REPO, "docs", "mem_contracts",
 
 def load_fit_table(path: str | None = None) -> dict | None:
     """The banked batch-fit table, or None when it isn't banked (an
-    engine without a table admits everything — the pre-flight stance:
-    a refusal we cannot justify numerically is worse than none)."""
+    engine without a table admits everything: a refusal we cannot
+    justify numerically is worse than none)."""
     path = path or FIT_TABLE_PATH
     try:
         with open(path, encoding="utf-8") as f:
@@ -66,7 +66,7 @@ def price_residency(family: str, max_bucket: int,
                     fit_table: dict | None) -> int | None:
     """Predicted resident bytes for one served model at its largest
     bucket, or None when the table has no row for the family (unknown
-    => unpriceable => the policy admits, like preflight_job)."""
+    => unpriceable => the policy admits)."""
     entry = ((fit_table or {}).get("families", {})
              .get(family, {}).get("f32"))
     if entry is None:

@@ -604,8 +604,7 @@ class Solver:
                           stacked_feeds: bool = False, step_fn=None):
         """``n`` full solver iterations fused into ONE device program via
         ``lax.scan`` — the TPU-native training loop (SURVEY §3: everything
-        under jit is traced once; host dispatch is not free, especially
-        over a remote-relay backend where every dispatch is an RPC).
+        under jit is traced once; host dispatch is not free).
 
         Returns ``(fn, variables, slots, key)`` with
         ``fn(variables, slots, it0, feeds, key) -> (variables, slots,
@@ -704,8 +703,8 @@ class Solver:
         every iteration on the host (display/snapshot hooks).
 
         ``scan_chunk > 1`` fuses that many iterations per device dispatch
-        (lax.scan over staged minibatches — the TPU-native loop; over a
-        remote-relay backend each dispatch is an RPC).  The chunk size is
+        (lax.scan over staged minibatches — the TPU-native loop).  The
+        chunk size is
         shrunk to divide the display and snapshot cadences so those fire
         at their exact reference iterations; callbacks then run in order
         AFTER each chunk (each still sees its per-iteration loss, but

@@ -1,6 +1,6 @@
-"""bench.py helper units: the pieces that must fail fast BEFORE a dial
-(a malformed A/B knob costing chip time is a round-4-class loss) and the
-zoo guard added for the crop-96 GoogLeNet walkthrough."""
+"""bench.py helper units: the pieces that must fail fast before anything
+compiles (a malformed A/B knob must not cost chip time) and the zoo
+guard added for the crop-96 GoogLeNet walkthrough."""
 
 import os
 import sys
@@ -26,7 +26,7 @@ def test_parse_compiler_options_malformed_fails_fast():
 
 def test_googlenet_rejects_non_multiple_of_32_crop():
     """ceil-mode pooling would silently leave pool5 non-global for such
-    crops (round-5 review finding) — the builder rejects them loudly."""
+    crops — the builder rejects them loudly."""
     from sparknet_tpu.models import zoo
 
     with pytest.raises(ValueError, match="multiple of 32"):
@@ -40,7 +40,7 @@ def test_googlenet_rejects_non_multiple_of_32_crop():
 def test_bank_guard_measured_writes_in_place(tmp_path):
     from sparknet_tpu.common import bank_guard
 
-    path = str(tmp_path / "int8_bench_last.json")
+    path = str(tmp_path / "serve_bench_last.json")
     written = bank_guard(path, {"arms": [1, 2]}, measured=True)
     assert written == path
     import json
@@ -54,19 +54,18 @@ def test_bank_guard_measured_writes_in_place(tmp_path):
 @pytest.mark.smoke
 def test_bank_guard_unmeasured_diverts_and_stamps(tmp_path):
     """A CPU rehearsal must land OUTSIDE the requested (docs/) location,
-    stamped so it can never read as chip evidence — the round-5 rule
-    after a smoke run overwrote docs/int8_bench_last.json."""
+    stamped so it can never read as chip evidence."""
     import json
     import tempfile
 
     from sparknet_tpu.common import bank_guard
 
-    path = str(tmp_path / "docs" / "int8_bench_last.json")
+    path = str(tmp_path / "docs" / "serve_bench_last.json")
     written = bank_guard(path, {"arms": []}, measured=False)
     assert written is not None
     assert not os.path.exists(path)  # nothing under the evidence path
     assert written == os.path.join(tempfile.gettempdir(),
-                                   "int8_bench_last_rehearsal.json")
+                                   "serve_bench_last_rehearsal.json")
     with open(written) as f:
         payload = json.load(f)
     assert payload["rehearsal"] is True
@@ -77,36 +76,6 @@ def test_bank_guard_unmeasured_diverts_and_stamps(tmp_path):
 def test_bank_path_idempotent_on_rehearsal_names():
     from sparknet_tpu.common import bank_path
 
-    p1 = bank_path("docs/bench_extra_last.json", measured=False)
+    p1 = bank_path("docs/feed_bench_last.json", measured=False)
     assert bank_path(p1, measured=False) == p1  # no _rehearsal_rehearsal
     assert bank_path("docs/x_last.json", measured=True) == "docs/x_last.json"
-
-
-@pytest.mark.smoke
-def test_record_last_good_refuses_unmeasured_records(tmp_path, monkeypatch):
-    """Defense in depth behind the callers' platform gate: a rec without
-    measured:true diverts away from docs/bench_last_good.json."""
-    import bench
-
-    path = str(tmp_path / "bench_last_good.json")
-    monkeypatch.setattr(bench, "LAST_GOOD_PATH", path)
-    bench.record_last_good({"metric": "m", "value": 1.0, "measured": False})
-    assert not os.path.exists(path)
-    bench.record_last_good({"metric": "m", "value": 2.0, "measured": True})
-    assert os.path.exists(path)
-
-
-@pytest.mark.smoke
-def test_measured_bw_frac_reads_newest_banked_artifact():
-    """The measured half of the bandwidth story (VERDICT item 4): the
-    record field comes from the newest banked
-    docs/evidence_r*/traffic_<model>_*_<dtype>.json, or is absent."""
-    import bench
-
-    hit = bench.measured_bw_frac("alexnet", "f32")
-    assert hit is not None
-    assert 0 < hit["measured_bw_frac"] <= 1.2  # GoogLeNet-style >1 is real
-    assert hit["measured_bw_source"].startswith("docs/evidence_r")
-    # no banked bf16 traffic artifact yet -> no field, never a guess
-    assert bench.measured_bw_frac("alexnet", "bf16") is None
-    assert bench.measured_bw_frac("nope", "f32") is None

@@ -277,7 +277,7 @@ def test_jax_in_worker_positive(tmp_path):
     found = _hits(findings, "conc-jax-in-worker")
     assert len(found) == 1
     assert "worker" in found[0].message
-    tax = manifests["taxonomy"]["contract"]
+    tax = manifests["roles"]["contract"]
     assert any("worker" in r for r in tax["process_roots"])
     assert "fix.py::worker" in tax["process_reachable"]
 
@@ -342,7 +342,7 @@ class JaxSource(Source):
     findings, manifests = _run(tmp_path, files)
     found = _hits(findings, "conc-jax-in-worker")
     assert any("JaxSource.get" in f.message for f in found)
-    reach = manifests["taxonomy"]["contract"]["process_reachable"]
+    reach = manifests["roles"]["contract"]["process_reachable"]
     assert "sub.py::JaxSource.get" in reach
 
 
@@ -360,7 +360,7 @@ def test_manifest_bank_drift_allow_loop(tmp_path):
     _run(tmp_path, files, update=True)
     mdir = tmp_path / "docs" / "conc_contracts"
     assert sorted(p.name for p in mdir.iterdir()) == [
-        "SOURCES.json", "lock_graph.json", "taxonomy.json"]
+        "SOURCES.json", "lock_graph.json", "roles.json"]
     findings, _ = _run(tmp_path, files)
     assert not [f for f in findings if not f.suppressed]
 
@@ -374,7 +374,7 @@ def test_manifest_bank_drift_allow_loop(tmp_path):
     assert drift and "lock_graph" in drift[0].message
 
     # 4. allow: an explicit allow entry suppresses the drift finding
-    for name in ("lock_graph", "taxonomy"):
+    for name in ("lock_graph", "roles"):
         path = mdir / f"{name}.json"
         data = json.loads(path.read_text())
         data["allow"] = {"conc-manifest-drift":
@@ -422,8 +422,7 @@ def test_repo_wide_conc_is_clean_and_manifests_fresh(capsys):
 
 
 def test_repo_manifests_match_sources_fingerprint():
-    """SOURCES.json covers exactly the audited surface, window runner
-    included (the /tools/ anchor of conc-manifest-fresh)."""
+    """SOURCES.json covers exactly the audited surface."""
     from sparknet_tpu.analysis.conccheck import (
         MANIFEST_DIR, sources_fingerprint)
 
@@ -431,4 +430,3 @@ def test_repo_manifests_match_sources_fingerprint():
               encoding="utf-8") as f:
         banked = json.load(f)
     assert banked == sources_fingerprint()
-    assert "tools/tpu_window_runner.py" in banked

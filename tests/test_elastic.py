@@ -266,7 +266,7 @@ def test_membership_events_schema_valid_and_rendered(tmp_path):
             tr.train_round(shard_fn)
     finally:
         set_recorder(None)
-    n, allowed, errors = schema.validate_journal(out)
+    n, errors = schema.validate_journal(out)
     assert not errors, errors
     events = [e["event"] for e in schema.load_journal(out)]
     assert "worker_lost" in events

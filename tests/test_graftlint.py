@@ -5,12 +5,11 @@ with a justified directive, and a clean rewrite — so the rule's
 boundary is pinned from both sides.  ``test_repo_self_lint_is_clean``
 is the CI wiring: it runs the analyzer over the repo's contract surface
 (``sparknet_tpu/``, ``tools/``, ``bench.py``) and fails on any
-unsuppressed finding, so future PRs cannot reintroduce unfenced timing
-or unguarded evidence banking (the probe-40 / round-4 artifact class).
+unsuppressed finding, so future PRs cannot reintroduce unguarded
+evidence banking, unfenced obs spans or stale contract manifests.
 
 All smoke-marked: the analyzer is stdlib-AST only, no jax dispatch.
 """
-# graftlint: disable-file=no-pkill-self -- PKILL_BAD/PKILL_GOOD are this rule's own fixture strings
 
 import json
 import os
@@ -24,12 +23,7 @@ from sparknet_tpu.analysis.__main__ import main as cli_main
 pytestmark = pytest.mark.smoke
 
 EXPECTED_RULES = {
-    "fence-by-value",
-    "no-env-platform",
     "bank-guard",
-    "require-measured",
-    "stale-args-dispatch",
-    "no-pkill-self",
     "graph-manifest-fresh",
     "mem-manifest-fresh",
     "fused-update-manifest",
@@ -38,8 +32,6 @@ EXPECTED_RULES = {
     "loop-manifest-fresh",
     "replica-manifest-fresh",
     "paged-manifest-fresh",
-    "queue-job-hygiene",
-    "queue-policy-fields",
     "obs-fenced-span",
     "feed-shm-cleanup",
     "obs-vocab-coverage",
@@ -70,115 +62,6 @@ def test_rule_catalog_complete():
         assert info.summary, info.id
 
 
-# -- fence-by-value ---------------------------------------------------------
-
-FENCE_BAD = """
-import time
-import jax
-
-def timed(step, x):
-    out = step(x)
-    jax.block_until_ready(out)
-    t0 = time.perf_counter()
-    out = step(out)
-    jax.block_until_ready(out)
-    return time.perf_counter() - t0
-"""
-
-FENCE_GOOD = """
-import time
-from sparknet_tpu.common import value_fence
-
-def timed(step, x):
-    out = step(x)
-    value_fence(out)
-    t0 = time.perf_counter()
-    out = step(out)
-    value_fence(out)
-    return time.perf_counter() - t0
-"""
-
-
-def test_fence_by_value_positive():
-    found = hits(FENCE_BAD, "fence-by-value")
-    assert len(found) == 2
-    assert "value_fence" in found[0].message
-
-
-def test_fence_by_value_suppressed():
-    src = FENCE_BAD.replace(
-        "    jax.block_until_ready(out)",
-        "    jax.block_until_ready(out)  "
-        "# graftlint: disable=fence-by-value -- local-backend test rig")
-    assert not hits(src, "fence-by-value")
-    assert len(suppressed_hits(src, "fence-by-value")) == 2
-
-
-def test_fence_by_value_clean():
-    assert not hits(FENCE_GOOD, "fence-by-value")
-
-
-def test_fence_outside_timing_window_is_fine():
-    # readiness sync with no clock in scope is not a timing lie
-    src = "import jax\ndef sync(x):\n    jax.block_until_ready(x)\n"
-    assert not hits(src, "fence-by-value")
-
-
-# -- no-env-platform --------------------------------------------------------
-
-ENV_BAD = """
-import os
-import jax
-
-os.environ["JAX_PLATFORMS"] = "cpu"
-print(jax.devices())
-"""
-
-ENV_GOOD_PAIRED = """
-import os
-import jax
-
-os.environ["JAX_PLATFORMS"] = "cpu"          # for subprocesses
-jax.config.update("jax_platforms", "cpu")    # the route that wins
-"""
-
-ENV_GOOD_NO_JAX = """
-import os
-
-os.environ["JAX_PLATFORMS"] = "cpu"  # consumed by a child's own contract
-"""
-
-
-def test_no_env_platform_positive():
-    found = hits(ENV_BAD, "no-env-platform")
-    assert len(found) == 1
-    assert "site hook" in found[0].message
-
-
-def test_no_env_platform_setdefault_positive():
-    src = ENV_BAD.replace('os.environ["JAX_PLATFORMS"] = "cpu"',
-                          'os.environ.setdefault("JAX_PLATFORMS", "cpu")')
-    assert len(hits(src, "no-env-platform")) == 1
-
-
-def test_no_env_platform_suppressed():
-    src = ENV_BAD.replace(
-        'os.environ["JAX_PLATFORMS"] = "cpu"',
-        'os.environ["JAX_PLATFORMS"] = "cpu"  '
-        "# graftlint: disable=no-env-platform -- child processes only")
-    assert not hits(src, "no-env-platform")
-    assert suppressed_hits(src, "no-env-platform")
-
-
-def test_no_env_platform_clean_when_config_pinned():
-    # the conftest.py / multihost_worker.py shape: env var AND config pin
-    assert not hits(ENV_GOOD_PAIRED, "no-env-platform")
-
-
-def test_no_env_platform_clean_without_jax():
-    assert not hits(ENV_GOOD_NO_JAX, "no-env-platform")
-
-
 # -- bank-guard -------------------------------------------------------------
 
 BANK_BAD = """
@@ -186,7 +69,7 @@ import json
 import os
 
 def save(rec):
-    path = "docs/int8_bench_last.json"
+    path = "docs/serve_bench_last.json"
     with open(path + ".tmp", "w") as f:
         json.dump(rec, f)
     os.replace(path + ".tmp", path)
@@ -196,13 +79,13 @@ BANK_GOOD = """
 from sparknet_tpu.common import bank_guard
 
 def save(rec, on_accel):
-    bank_guard("docs/int8_bench_last.json", rec, measured=on_accel)
+    bank_guard("docs/serve_bench_last.json", rec, measured=on_accel)
 """
 
 BANK_MODULE_CONST = """
 import json
 
-PATH = "docs/bench_last_good.json"
+PATH = "docs/feed_bench_last.json"
 
 def save(rec):
     with open(PATH, "w") as f:
@@ -217,8 +100,8 @@ def test_bank_guard_positive():
 
 
 def test_bank_guard_sees_module_level_path_constants():
-    # the bench.py LAST_GOOD_PATH shape: string at module scope, write in
-    # a function — module strings are ambient
+    # string at module scope, write in a function — module strings are
+    # ambient
     assert len(hits(BANK_MODULE_CONST, "bank-guard")) == 1
 
 
@@ -238,7 +121,7 @@ def test_bank_guard_clean_via_helper():
 def test_bank_guard_read_is_fine():
     src = ('import json\n'
            'def load():\n'
-           '    with open("docs/bench_last_good.json") as f:\n'
+           '    with open("docs/feed_bench_last.json") as f:\n'
            '        return json.load(f)\n')
     assert not hits(src, "bank-guard")
 
@@ -249,190 +132,6 @@ def test_bank_guard_non_evidence_write_is_fine():
            '    with open("docs/tau_sweep_alexnet.json", "w") as f:\n'
            '        json.dump(rec, f)\n')
     assert not hits(src, "bank-guard")
-
-
-# -- require-measured -------------------------------------------------------
-
-REQ_BAD = """
-import json
-
-def main():
-    print(json.dumps({"metric": "x_img_s", "measured": False}))
-    return 0
-
-if __name__ == "__main__":
-    main()
-"""
-
-REQ_GOOD = """
-import json
-import os
-
-def main():
-    print(json.dumps({"metric": "x_img_s", "measured": False}))
-    if os.environ.get("SPARKNET_BENCH_REQUIRE_MEASURED") == "1":
-        return 4
-    return 0
-
-if __name__ == "__main__":
-    main()
-"""
-
-REQ_GOOD_HELPER = """
-import json
-import bench
-
-def main():
-    print(json.dumps({"metric": "x_img_s", "measured": False}))
-    return 4 if bench._require_measured() else 0
-
-if __name__ == "__main__":
-    main()
-"""
-
-
-def test_require_measured_positive():
-    found = hits(REQ_BAD, "require-measured")
-    assert len(found) == 1
-    assert "SPARKNET_BENCH_REQUIRE_MEASURED" in found[0].message
-
-
-def test_require_measured_suppressed():
-    src = REQ_BAD.replace(
-        '    print(json.dumps({"metric": "x_img_s", "measured": False}))',
-        '    print(json.dumps({"metric": "x_img_s", "measured": False}))  '
-        "# graftlint: disable=require-measured -- never queued on chip")
-    assert not hits(src, "require-measured")
-    assert suppressed_hits(src, "require-measured")
-
-
-def test_require_measured_clean_env_literal():
-    assert not hits(REQ_GOOD, "require-measured")
-
-
-def test_require_measured_clean_bench_helper():
-    assert not hits(REQ_GOOD_HELPER, "require-measured")
-
-
-def test_require_measured_ignores_libraries_and_hostside_tools():
-    # no __main__ guard -> library module, not a queueable script
-    assert not hits("x = {'measured': True}\n", "require-measured")
-    # script without measured records (host-side tool) is fine too
-    src = ('import json\n'
-           'def main():\n'
-           '    print(json.dumps({"metric": "feed_ms"}))\n'
-           'if __name__ == "__main__":\n'
-           '    main()\n')
-    assert not hits(src, "require-measured")
-
-
-# -- stale-args-dispatch ----------------------------------------------------
-
-STALE_BAD = """
-import time
-import jax
-
-def bench(step, feeds):
-    t0 = time.perf_counter()
-    for _ in range(20):
-        loss = step(feeds)
-    return time.perf_counter() - t0
-"""
-
-STALE_GOOD_THREADED = """
-import time
-import jax
-
-def bench(step, variables, slots, feeds, key):
-    t0 = time.perf_counter()
-    for i in range(20):
-        variables, slots, loss = step(variables, slots, i, feeds, key)
-    float(loss)
-    return time.perf_counter() - t0
-"""
-
-STALE_NO_JAX = """
-import time
-
-def bench(xform, raw):
-    t0 = time.perf_counter()
-    for _ in range(20):
-        out = xform(raw)
-    return time.perf_counter() - t0
-"""
-
-
-def test_stale_args_positive():
-    found = hits(STALE_BAD, "stale-args-dispatch")
-    assert len(found) == 1
-    assert "thread" in found[0].message
-
-
-def test_stale_args_suppressed():
-    src = STALE_BAD.replace(
-        "        loss = step(feeds)",
-        "        loss = step(feeds)  "
-        "# graftlint: disable=stale-args-dispatch -- local diagnostic")
-    assert not hits(src, "stale-args-dispatch")
-    assert suppressed_hits(src, "stale-args-dispatch")
-
-
-def test_stale_args_clean_when_threaded():
-    assert not hits(STALE_GOOD_THREADED, "stale-args-dispatch")
-
-
-def test_stale_args_ignores_hostside_modules():
-    # no jax import: a numpy/PIL loop really does the work every call
-    assert not hits(STALE_NO_JAX, "stale-args-dispatch")
-
-
-def test_stale_args_ignores_untimed_loops():
-    src = ('import jax\n'
-           'def warmup(step, feeds):\n'
-           '    for _ in range(3):\n'
-           '        loss = step(feeds)\n'
-           '    return loss\n')
-    assert not hits(src, "stale-args-dispatch")
-
-
-# -- no-pkill-self ----------------------------------------------------------
-
-PKILL_BAD = """
-import subprocess
-
-def stop_runner():
-    subprocess.run("pkill -f tpu_window_runner", shell=True)
-"""
-
-PKILL_GOOD = """
-import subprocess
-
-def stop_runner():
-    pids = subprocess.run(["pgrep", "-f", "tools/tpu_window_[r]unner"],
-                          capture_output=True, text=True).stdout.split()
-    for pid in pids:
-        subprocess.run(["kill", pid])
-"""
-
-
-def test_no_pkill_positive():
-    found = hits(PKILL_BAD, "no-pkill-self")
-    assert len(found) == 1
-    assert "pgrep" in found[0].message
-
-
-def test_no_pkill_suppressed():
-    src = PKILL_BAD.replace(
-        '    subprocess.run("pkill -f tpu_window_runner", shell=True)',
-        '    subprocess.run("pkill -f tpu_window_runner", shell=True)  '
-        "# graftlint: disable=no-pkill-self -- pattern can never match a "
-        "shell cmdline here")
-    assert not hits(src, "no-pkill-self")
-    assert suppressed_hits(src, "no-pkill-self")
-
-
-def test_no_pkill_clean():
-    assert not hits(PKILL_GOOD, "no-pkill-self")
 
 
 # -- graph-manifest-fresh ---------------------------------------------------
@@ -719,14 +418,6 @@ def test_conc_manifest_fresh_positive_when_never_banked(tmp_path):
     found = hits(FRESH_SRC, "conc-manifest-fresh", path=path)
     assert len(found) == 1
     assert "SOURCES.json missing" in found[0].message
-
-
-def test_conc_manifest_fresh_covers_window_runner(tmp_path):
-    # the one audited file OUTSIDE sparknet_tpu/: the /tools/ anchor
-    path = _conc_tree(tmp_path, rel="tools/tpu_window_runner.py",
-                      stale=True)
-    found = hits(FRESH_SRC, "conc-manifest-fresh", path=path)
-    assert len(found) == 1
 
 
 def test_conc_manifest_fresh_suppressed(tmp_path):
@@ -1188,164 +879,6 @@ def test_loop_manifest_fresh_ignores_other_packages(tmp_path):
     assert not hits(FRESH_SRC, "loop-manifest-fresh")
 
 
-# -- queue-job-hygiene ------------------------------------------------------
-
-RUNNER_SRC = "def main():\n    return 0\n"
-
-
-def _runner_tree(tmp_path, queues):
-    """A fake tools/ dir: the runner + queue JSON files beside it."""
-    import json as _json
-
-    tools = tmp_path / "tools"
-    tools.mkdir()
-    runner = tools / "tpu_window_runner.py"
-    runner.write_text(RUNNER_SRC)
-    for fname, spec in queues.items():
-        body = spec if isinstance(spec, str) else _json.dumps(spec)
-        (tools / fname).write_text(body)
-    return str(runner)
-
-
-def _bench_job(name, u=True, rm=True):
-    j = {"name": name,
-         "argv": ["python"] + (["-u"] if u else []) + ["bench.py"],
-         "deadline_s": 60}
-    if rm:
-        j["env"] = {"SPARKNET_BENCH_REQUIRE_MEASURED": "1"}
-    return j
-
-
-def _trace_job(name):
-    return {"name": name,
-            "argv": ["python", "-u", "-m", "sparknet_tpu.cli", "time",
-                     "--trace"],
-            "deadline_s": 60}
-
-
-def test_queue_hygiene_flags_all_three_contracts(tmp_path):
-    path = _runner_tree(tmp_path, {"tpu_queue_r9.json": {"jobs": [
-        _bench_job("no_unbuffered", u=False),
-        _bench_job("no_measured", rm=False),
-        _trace_job("trace_early"),
-        _bench_job("after_trace"),
-    ]}})
-    found = hits(RUNNER_SRC, "queue-job-hygiene", path=path)
-    msgs = "\n".join(f.message for f in found)
-    assert len(found) == 3
-    assert "no_unbuffered" in msgs and "without -u" in msgs
-    assert "no_measured" in msgs and "REQUIRE_MEASURED" in msgs
-    assert "after_trace" in msgs and "LAST" in msgs
-
-
-def test_queue_hygiene_legacy_queues_excused(tmp_path):
-    bad = {"jobs": [_bench_job("no_measured", rm=False)]}
-    path = _runner_tree(tmp_path, {"tpu_queue_r3.json": bad,
-                                   "tpu_queue_r4.json": bad})
-    assert not hits(RUNNER_SRC, "queue-job-hygiene", path=path)
-
-
-def test_queue_hygiene_unreadable_queue_is_flagged(tmp_path):
-    path = _runner_tree(tmp_path, {"tpu_queue_r9.json": "{not json"})
-    found = hits(RUNNER_SRC, "queue-job-hygiene", path=path)
-    assert len(found) == 1
-    assert "unreadable" in found[0].message
-
-
-def test_queue_hygiene_clean_queue_passes(tmp_path):
-    path = _runner_tree(tmp_path, {"tpu_queue_r9.json": {
-        "jobs": [_bench_job("headline"), _trace_job("trace_last")],
-        "setup": [{"name": "fixture", "argv": ["python", "x.py"]}],
-    }})
-    assert not hits(RUNNER_SRC, "queue-job-hygiene", path=path)
-
-
-def test_queue_hygiene_only_fires_from_the_runner(tmp_path):
-    """Another tool in the same dir must not re-report every queue."""
-    path = _runner_tree(tmp_path, {"tpu_queue_r9.json": {"jobs": [
-        _bench_job("no_measured", rm=False)]}})
-    other = os.path.join(os.path.dirname(path), "tunnel_log.py")
-    assert hits(RUNNER_SRC, "queue-job-hygiene", path=path)
-    assert not hits(RUNNER_SRC, "queue-job-hygiene", path=other)
-
-
-def test_queue_hygiene_suppressible(tmp_path):
-    path = _runner_tree(tmp_path, {"tpu_queue_r9.json": {"jobs": [
-        _bench_job("no_measured", rm=False)]}})
-    src = ("# graftlint: disable-file=queue-job-hygiene -- "
-           "fixture queue under construction\n" + RUNNER_SRC)
-    assert not hits(src, "queue-job-hygiene", path=path)
-    assert suppressed_hits(src, "queue-job-hygiene", path=path)
-
-
-# -- queue-policy-fields ----------------------------------------------------
-
-
-def _priced(job, value=5, est=300):
-    j = dict(job)
-    j["value"] = value
-    j["est_runtime_s"] = est
-    return j
-
-
-def test_queue_policy_flags_missing_and_invalid_fields(tmp_path):
-    path = _runner_tree(tmp_path, {"tpu_queue_r9.json": {"jobs": [
-        _bench_job("unpriced"),                              # both missing
-        _priced(_bench_job("zero_value"), value=0),          # non-positive
-        dict(_priced(_bench_job("bool_value")), value=True),  # bool sneaks
-        _priced(_bench_job("clean")),
-    ]}})
-    found = hits(RUNNER_SRC, "queue-policy-fields", path=path)
-    msgs = "\n".join(f.message for f in found)
-    assert len(found) == 4  # unpriced x2 fields + zero_value + bool_value
-    assert "unpriced" in msgs and "'value'" in msgs
-    assert "'est_runtime_s'" in msgs
-    assert "zero_value" in msgs and "bool_value" in msgs
-    assert "clean" not in msgs
-
-
-def test_queue_policy_legacy_rounds_excused_r8_not(tmp_path):
-    bare = {"jobs": [_bench_job("unpriced")]}
-    queues = {f"tpu_queue_r{n}.json": dict(bare) for n in range(3, 8)}
-    queues["tpu_queue_r8.json"] = bare
-    path = _runner_tree(tmp_path, queues)
-    found = hits(RUNNER_SRC, "queue-policy-fields", path=path)
-    assert found
-    assert all("tpu_queue_r8.json" in f.message for f in found)
-
-
-def test_queue_policy_clean_priced_queue_passes(tmp_path):
-    path = _runner_tree(tmp_path, {"tpu_queue_r8.json": {"jobs": [
-        _priced(_bench_job("headline"), value=10, est=900),
-        _priced(_trace_job("trace_last"), value=3, est=900),
-    ]}})
-    assert not hits(RUNNER_SRC, "queue-policy-fields", path=path)
-
-
-def test_queue_policy_unreadable_left_to_hygiene(tmp_path):
-    # one finding per rule, not two for the same broken file
-    path = _runner_tree(tmp_path, {"tpu_queue_r8.json": "{not json"})
-    assert not hits(RUNNER_SRC, "queue-policy-fields", path=path)
-    assert hits(RUNNER_SRC, "queue-job-hygiene", path=path)
-
-
-def test_queue_policy_only_fires_from_the_runner(tmp_path):
-    path = _runner_tree(tmp_path, {"tpu_queue_r8.json": {"jobs": [
-        _bench_job("unpriced")]}})
-    other = os.path.join(os.path.dirname(path), "tunnel_log.py")
-    assert hits(RUNNER_SRC, "queue-policy-fields", path=path)
-    assert not hits(RUNNER_SRC, "queue-policy-fields", path=other)
-
-
-def test_queue_policy_suppressible(tmp_path):
-    path = _runner_tree(tmp_path, {"tpu_queue_r8.json": {"jobs": [
-        _bench_job("unpriced")]}})
-    src = ("# graftlint: disable-file=queue-policy-fields -- "
-           "draft queue not yet priced\n" + RUNNER_SRC)
-    assert not hits(src, "queue-policy-fields", path=path)
-    assert suppressed_hits(src, "queue-policy-fields", path=path)
-
-
 # -- feed-shm-cleanup -------------------------------------------------------
 
 SHM_BAD = """
@@ -1507,42 +1040,48 @@ def test_obs_fenced_span_ignores_non_jax_modules():
 
 # -- suppression machinery --------------------------------------------------
 
+BANK_TWO = BANK_BAD + """
+def save_again(rec):
+    with open("docs/serve_bench_last.json", "w") as f:
+        json.dump(rec, f)
+"""
+
 
 def test_disable_next_line_directive():
-    src = FENCE_BAD.replace(
-        "    jax.block_until_ready(out)",
-        "    # graftlint: disable-next-line=fence-by-value -- rig\n"
-        "    jax.block_until_ready(out)")
-    assert not hits(src, "fence-by-value")
-    assert len(suppressed_hits(src, "fence-by-value")) == 2
+    src = BANK_BAD.replace(
+        '    with open(path + ".tmp", "w") as f:',
+        "    # graftlint: disable-next-line=bank-guard -- rig\n"
+        '    with open(path + ".tmp", "w") as f:')
+    assert not hits(src, "bank-guard")
+    assert len(suppressed_hits(src, "bank-guard")) == 1
 
 
 def test_disable_file_directive():
-    src = ("# graftlint: disable-file=fence-by-value -- whole-file rig\n"
-           + FENCE_BAD)
-    assert not hits(src, "fence-by-value")
-    assert len(suppressed_hits(src, "fence-by-value")) == 2
+    src = ("# graftlint: disable-file=bank-guard -- whole-file rig\n"
+           + BANK_TWO)
+    assert not hits(src, "bank-guard")
+    assert len(suppressed_hits(src, "bank-guard")) == 2
 
 
 def test_disable_all_and_comma_lists():
-    src = FENCE_BAD.replace(
-        "    jax.block_until_ready(out)",
-        "    jax.block_until_ready(out)  # graftlint: disable=all")
-    assert not hits(src, "fence-by-value")
-    src2 = STALE_BAD.replace(
-        "        loss = step(feeds)",
-        "        loss = step(feeds)  "
-        "# graftlint: disable=stale-args-dispatch,fence-by-value -- x")
-    assert not hits(src2, "stale-args-dispatch")
+    src = BANK_BAD.replace(
+        'with open(path + ".tmp", "w") as f:',
+        'with open(path + ".tmp", "w") as f:  # graftlint: disable=all')
+    assert not hits(src, "bank-guard")
+    src2 = SHM_BAD.replace(
+        "create=True, size=nbytes)",
+        "create=True, size=nbytes)  "
+        "# graftlint: disable=feed-shm-cleanup,bank-guard -- x")
+    assert not hits(src2, "feed-shm-cleanup")
 
 
 def test_suppression_is_per_line_not_per_file():
     # a directive on ONE hit must not hide the other
-    src = FENCE_BAD.replace(
-        "    out = step(out)\n    jax.block_until_ready(out)",
-        "    out = step(out)\n    jax.block_until_ready(out)  "
-        "# graftlint: disable=fence-by-value -- only this one")
-    assert len(hits(src, "fence-by-value")) == 1
+    src = BANK_TWO.replace(
+        'with open(path + ".tmp", "w") as f:',
+        'with open(path + ".tmp", "w") as f:  '
+        "# graftlint: disable=bank-guard -- only this one")
+    assert len(hits(src, "bank-guard")) == 1
 
 
 def test_parse_error_is_reported_not_raised():
@@ -1555,17 +1094,17 @@ def test_parse_error_is_reported_not_raised():
 
 def test_cli_json_format_and_exit_code(tmp_path, capsys):
     bad = tmp_path / "bad.py"
-    bad.write_text(PKILL_BAD)
+    bad.write_text(SHM_BAD)
     rc = cli_main([str(bad), "--format", "json"])
     out = json.loads(capsys.readouterr().out)
     assert rc == 1
     assert out["unsuppressed"] == 1
-    assert out["findings"][0]["rule"] == "no-pkill-self"
+    assert out["findings"][0]["rule"] == "feed-shm-cleanup"
 
 
 def test_cli_clean_file_exits_zero(tmp_path, capsys):
     good = tmp_path / "good.py"
-    good.write_text(FENCE_GOOD)
+    good.write_text(BANK_GOOD)
     rc = cli_main([str(good)])
     assert rc == 0
     assert "0 finding(s)" in capsys.readouterr().out
@@ -1573,11 +1112,11 @@ def test_cli_clean_file_exits_zero(tmp_path, capsys):
 
 def test_cli_single_rule_filter(tmp_path, capsys):
     bad = tmp_path / "bad.py"
-    bad.write_text(PKILL_BAD + ENV_BAD)
-    rc = cli_main([str(bad), "--rule", "no-env-platform", "--format", "json"])
+    bad.write_text(SHM_BAD + BANK_BAD)
+    rc = cli_main([str(bad), "--rule", "bank-guard", "--format", "json"])
     out = json.loads(capsys.readouterr().out)
     assert rc == 1
-    assert {f["rule"] for f in out["findings"]} == {"no-env-platform"}
+    assert {f["rule"] for f in out["findings"]} == {"bank-guard"}
 
 
 def test_cli_list_rules(capsys):

@@ -150,7 +150,7 @@ class TestWriterValidation:
 
 
 class TestDataLayerIngest:
-    """The VERDICT round-trip: a fixture LMDB (Caffe Datum values) feeds
+    """The round-trip: a fixture LMDB (Caffe Datum values) feeds
     the Data-layer minibatch path unchanged."""
 
     def _images(self, n, shape=(3, 8, 8)):

@@ -6,16 +6,11 @@ donation credit whose absence double-counts the carry), the two
 estimators must agree on the cheap real modes (solo + dp) within the
 documented tolerance, the batch-fit arithmetic is monotone by
 construction, the VMEM audit flags an over-budget kernel, the manifest
-loop round-trips bank/drift/allow, and the window runner's queue
-pre-flight refuses a predicted-OOM job — journaled ``preflight_oom``,
-dial never attempted.  The full mode sweep is the slow-marked twin
-(tests/test_memcheck_sweep.py).
+loop round-trips bank/drift/allow.  The full mode sweep is the
+slow-marked twin (tests/test_memcheck_sweep.py).
 """
 
-import importlib.util
 import json
-import os
-import sys
 
 import jax
 import jax.numpy as jnp
@@ -33,10 +28,8 @@ from sparknet_tpu.analysis.mem_model import (
     affine_fit,
     max_fit_batch,
     mode_footprint,
-    parse_bench_job,
     peak_residency,
     predicted_bytes,
-    preflight_job,
 )
 from sparknet_tpu.analysis.memcheck import (
     MEM_RULES,
@@ -49,8 +42,6 @@ from sparknet_tpu.analysis.memcheck import (
 )
 
 pytestmark = pytest.mark.smoke
-
-ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 # -- the liveness walk vs a hand-computed toy program -----------------------
@@ -299,210 +290,6 @@ def test_rule_catalog():
         "mem-hbm-exceeded", "mem-vmem-exceeded", "mem-fit-infeasible",
         "mem-manifest-missing", "mem-manifest-drift",
     }
-
-
-# -- queue pre-flight (mem_model side: stdlib-only, runner-consumable) ------
-
-
-def test_parse_bench_job_shapes():
-    assert parse_bench_job({
-        "name": "headline", "argv": ["python", "-u", "bench.py"],
-        "env": {"SPARKNET_BENCH_MODEL": "vgg16",
-                "SPARKNET_BENCH_BATCH": "128"},
-    }) == {"model": "vgg16", "batch": 128, "dtype": "bf16"}
-    # bench.py defaulting mirrors the tool (alexnet/256/bf16)
-    assert parse_bench_job({"argv": ["python", "-u", "bench.py"]}) == \
-        {"model": "alexnet", "batch": 256, "dtype": "bf16"}
-    assert parse_bench_job({
-        "argv": ["python", "-u", "tools/layout_ab.py", "--model",
-                 "alexnet", "--batch", "256"],
-    }) == {"model": "alexnet", "batch": 256, "dtype": "bf16"}
-    # A/B tools start from their OWN argparse defaults (layout_ab is a
-    # vgg16 tool, not an alexnet one)
-    assert parse_bench_job({
-        "argv": ["python", "-u", "tools/layout_ab.py"],
-    }) == {"model": "vgg16", "batch": 128, "dtype": "bf16"}
-    assert parse_bench_job({
-        "argv": ["python", "-u", "tools/scaling_bench.py",
-                 "--batch-per-device", "64"],
-    }) == {"model": "alexnet", "batch": 64, "dtype": "bf16"}
-    assert parse_bench_job({
-        "argv": ["python", "-u", "-m", "sparknet_tpu.cli", "time",
-                 "--solver", "zoo:googlenet", "--batch", "128",
-                 "--dtype", "bf16"],
-    }) == {"model": "googlenet", "batch": 128, "dtype": "bf16"}
-    # host-side setup steps have no bench shape: never priced
-    assert parse_bench_job({
-        "argv": ["python", "tools/setup_e2e_db.py"]}) is None
-    # pallas_bench must not substring-match bench.py, and the forward-
-    # only deploy bench is deliberately unpriceable by a TRAIN model
-    assert parse_bench_job({
-        "argv": ["python", "-u", "tools/pallas_bench.py", "--op",
-                 "flash"]}) is None
-    assert parse_bench_job({
-        "argv": ["python", "-u", "tools/int8_bench.py", "--model",
-                 "resnet50", "--batch", "128"]}) is None
-
-
-def test_preflight_job_verdicts():
-    table = {"families": {"alexnet": {"bf16": {"c0": 10_000, "c1": 10}}}}
-    fits = preflight_job(
-        {"name": "ok", "argv": ["python", "-u", "bench.py"]}, table)
-    assert fits["fits"] and fits["model"] == "alexnet"
-    oom = preflight_job(
-        {"name": "oom", "argv": ["python", "-u", "bench.py"],
-         "env": {"SPARKNET_BENCH_BATCH": "256"}},
-        {"families": {"alexnet": {"bf16": {"c0": 2**34, "c1": 2**30}}}})
-    assert oom["fits"] is False
-    assert oom["predicted_bytes"] > oom["budget_bytes"]
-    # unknown family => None => pass (the pre-flight saves dials, it
-    # never blocks a job it cannot price)
-    assert preflight_job(
-        {"name": "x", "argv": ["python", "-u", "bench.py"],
-         "env": {"SPARKNET_BENCH_MODEL": "not_a_zoo_family"}},
-        table) is None
-
-
-# -- queue pre-flight (runner side: refusal journaled, dial never tried) ----
-
-
-@pytest.fixture
-def runner(tmp_path, monkeypatch):
-    spec = importlib.util.spec_from_file_location(
-        "tpu_window_runner",
-        os.path.join(ROOT, "tools", "tpu_window_runner.py"))
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    monkeypatch.setattr(mod, "EVIDENCE_DIR", str(tmp_path / "evidence"))
-    monkeypatch.setattr(
-        mod, "JOURNAL", str(tmp_path / "evidence" / "journal.jsonl"))
-    monkeypatch.setattr(mod, "MIN_DIAL_PERIOD_S", 0.05)
-    return mod
-
-
-def _queue(tmp_path, jobs, **kw):
-    p = tmp_path / "queue.json"
-    p.write_text(json.dumps({"max_hours": 0.01, "jobs": jobs, **kw}))
-    return str(p)
-
-
-def _fit_table(tmp_path, c0, c1):
-    p = tmp_path / "batch_fit.json"
-    p.write_text(json.dumps(
-        {"families": {"alexnet": {"bf16": {"c0": c0, "c1": c1}}}}))
-    return str(p)
-
-
-def test_runner_refuses_predicted_oom_without_dialing(
-        runner, tmp_path, monkeypatch):
-    """The acceptance path: an over-HBM bench job is journaled as
-    preflight_oom and marked dead; with nothing else runnable the
-    runner exits blocked — and the dial subprocess NEVER runs."""
-    monkeypatch.setattr(runner, "FIT_TABLE_PATH",
-                        _fit_table(tmp_path, 2**34, 2**30))
-    dialed = []
-    monkeypatch.setattr(runner, "dial",
-                        lambda probe_id=0: dialed.append(probe_id) or True)
-    q = _queue(tmp_path, [{
-        "name": "oom_bench", "argv": ["python", "-u", "bench.py"],
-        "env": {"SPARKNET_BENCH_REQUIRE_MEASURED": "1"},
-        "deadline_s": 30,
-    }])
-    monkeypatch.setattr(sys, "argv", ["runner", q])
-    assert runner.main() == 3  # queue blocked, not drained
-    assert dialed == []  # the whole point: no dial burned
-    events = [json.loads(l) for l in
-              open(os.path.join(str(tmp_path / "evidence"),
-                                "journal.jsonl"))]
-    oom = [e for e in events if e["event"] == "preflight_oom"]
-    assert len(oom) == 1  # journaled once, not once per loop pass
-    assert oom[0]["job"] == "oom_bench"
-    assert oom[0]["model"] == "alexnet" and oom[0]["batch"] == 256
-    assert oom[0]["predicted_bytes"] > oom[0]["budget_bytes"]
-    assert not any(e["event"] == "dial_start" for e in events)
-    blocked = [e for e in events if e["event"] == "runner_done"]
-    assert blocked[0]["reason"] == "queue blocked"
-    assert blocked[0]["blocked_jobs"] == ["oom_bench"]
-
-
-def test_runner_preflight_passes_fitting_and_unpriceable_jobs(
-        runner, tmp_path, monkeypatch):
-    """A job the table prices as fitting runs; a job with no bench
-    shape runs; only the OOM one is refused."""
-    monkeypatch.setattr(runner, "FIT_TABLE_PATH",
-                        _fit_table(tmp_path, 1000, 10))
-    monkeypatch.setattr(runner, "dial", lambda probe_id=0: True)
-    fits = {"name": "fits_bench",
-            "argv": [sys.executable, "-c", "print('ran bench.py')"],
-            "env": {"SPARKNET_BENCH_REQUIRE_MEASURED": "1"},
-            "deadline_s": 30}
-    oom = {"name": "oom_bench", "argv": ["python", "-u", "bench.py"],
-           "env": {"SPARKNET_BENCH_MODEL": "alexnet",
-                   "SPARKNET_BENCH_BATCH": str(2**40)},
-           "deadline_s": 30}
-    plain = {"name": "host_step",
-             "argv": [sys.executable, "-c", "print('ok')"],
-             "deadline_s": 30}
-    monkeypatch.setattr(sys, "argv",
-                        ["runner", _queue(tmp_path, [fits, oom, plain])])
-    assert runner.main() == 3  # oom_bench can never run
-    state = runner.load_done()
-    assert state["fits_bench"] == -1 and state["host_step"] == -1
-    assert "oom_bench" not in state  # never attempted, not failed
-
-
-def test_runner_preflight_refusal_not_rejournaled_on_restart(
-        runner, tmp_path, monkeypatch):
-    monkeypatch.setattr(runner, "FIT_TABLE_PATH",
-                        _fit_table(tmp_path, 2**34, 2**30))
-    monkeypatch.setattr(runner, "dial", lambda probe_id=0: True)
-    q = _queue(tmp_path, [{
-        "name": "oom_bench", "argv": ["python", "-u", "bench.py"],
-        "env": {"SPARKNET_BENCH_REQUIRE_MEASURED": "1"},
-        "deadline_s": 30}])
-    monkeypatch.setattr(sys, "argv", ["runner", q])
-    assert runner.main() == 3
-    assert runner.main() == 3  # resume against the same journal
-    events = [json.loads(l) for l in
-              open(os.path.join(str(tmp_path / "evidence"),
-                                "journal.jsonl"))]
-    assert sum(e["event"] == "preflight_oom" for e in events) == 1
-
-
-def test_preflight_oom_journal_line_is_schema_valid(
-        runner, tmp_path, monkeypatch):
-    from sparknet_tpu.obs import schema
-
-    monkeypatch.setattr(runner, "FIT_TABLE_PATH",
-                        _fit_table(tmp_path, 2**34, 2**30))
-    monkeypatch.setattr(runner, "dial", lambda probe_id=0: True)
-    q = _queue(tmp_path, [{
-        "name": "oom_bench", "argv": ["python", "-u", "bench.py"],
-        "env": {"SPARKNET_BENCH_REQUIRE_MEASURED": "1"},
-        "deadline_s": 30}])
-    monkeypatch.setattr(sys, "argv", ["runner", q])
-    runner.main()
-    journal = os.path.join(str(tmp_path / "evidence"), "journal.jsonl")
-    n_lines, n_allow, errors = schema.validate_journal(journal,
-                                                       allowlist=())
-    assert n_lines >= 2 and n_allow == 0 and errors == []
-
-
-def test_runner_without_fit_table_passes_everything(
-        runner, tmp_path, monkeypatch):
-    """No banked table => the pre-flight is inert (it exists to save
-    dials, not to gate rounds on memcheck adoption)."""
-    monkeypatch.setattr(runner, "FIT_TABLE_PATH",
-                        str(tmp_path / "no_such_table.json"))
-    monkeypatch.setattr(runner, "dial", lambda probe_id=0: True)
-    q = _queue(tmp_path, [{
-        "name": "bench_like",
-        "argv": [sys.executable, "-c", "print('bench.py stand-in')"],
-        "env": {"SPARKNET_BENCH_REQUIRE_MEASURED": "1"},
-        "deadline_s": 30}])
-    monkeypatch.setattr(sys, "argv", ["runner", q])
-    assert runner.main() == 0
-    assert runner.load_done()["bench_like"] == -1
 
 
 # -- CLI: shared schema with lint/graph -------------------------------------

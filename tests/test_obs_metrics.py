@@ -12,7 +12,7 @@ ON bucket boundaries, single samples, bimodal mass at the extremes)
 and check the claims against exact nearest-rank computed the slow way.
 
 All stdlib + numpy-free, smoke-tier: the obs package must stay
-importable (and testable) next to a wedged relay with no jax anywhere.
+importable (and testable) with no jax anywhere.
 """
 
 from __future__ import annotations

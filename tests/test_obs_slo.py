@@ -62,9 +62,10 @@ def test_unknown_gate_kind_burns_loudly():
 # -- gate semantics ---------------------------------------------------------
 
 
-def test_all_gates_vacuous_on_a_pure_runner_ledger(manifest):
-    events = [{"event": "dial_start", "probe": 1},
-              {"event": "dial_end", "probe": 1, "ok": True, "dt_s": 1.0}]
+def test_all_gates_vacuous_without_subject_events(manifest):
+    events = [{"event": "run_start", "run_id": "r1"},
+              {"event": "run_end", "run_id": "r1", "rounds": 0,
+               "spans": 0, "compiles": 0}]
     results = slo.evaluate(events, manifest)
     assert all(r["ok"] for r in results)
     assert all(not r["applicable"] for r in results)
@@ -170,11 +171,11 @@ def test_verdict_fields_name_the_burned_gates(manifest):
 
 def test_every_banked_evidence_journal_passes_the_manifest(manifest):
     """The acceptance gate: `python -m sparknet_tpu.obs slo` green over
-    all docs/evidence_r*/ journals, the four dryrun specimens included.
+    all docs/evidence_r*/ journals — the four dryrun specimens.
     """
     journals = sorted(glob.glob(
         os.path.join(ROOT, "docs", "evidence_r*", "*.jsonl")))
-    assert len(journals) >= 7  # r3/r4/r5 ledgers + the four r7 dryruns
+    assert len(journals) >= 4  # the four r7 dryruns
     names = {os.path.basename(p) for p in journals}
     for required in ("elastic_dryrun.jsonl", "serve_dryrun.jsonl",
                      "loop_dryrun.jsonl", "replica_dryrun.jsonl"):

@@ -123,7 +123,7 @@ def test_layer_time_table_cpu_fallback():
 
 def test_trace_report_renders_rows(tmp_path):
     """tools/trace_report.py renders full and partial artifacts (partial =
-    the wedge-mid-trace case the staged banking exists for)."""
+    the failed-mid-trace case the staged writes exist for)."""
     import json
     import subprocess
     import sys
@@ -168,9 +168,8 @@ def test_trace_report_renders_rows(tmp_path):
         capture_output=True, text=True, check=True).stdout
     assert "No per-layer rows banked" in out and "20.500 ms" in out
 
-    # an UNSTAMPED untraced wall (pre-round-5 artifact) is refused with
-    # an explanatory note, not silently rendered — the unstamped fence
-    # banked physically impossible walls (VERDICT r4 §weak 1)
+    # an UNSTAMPED untraced wall is refused with an explanatory note,
+    # not silently rendered — an unfenced wall times the enqueue
     del partial["fence_protocol"]
     p = tmp_path / "c.json"
     p.write_text(json.dumps(partial))
@@ -205,7 +204,7 @@ def _write_tpu_style_trace(tmp_path, lanes, ops):
 
 
 def test_tpu_stacked_lanes_counted_once(tmp_path):
-    """The probe-40 regression: Steps + XLA Modules + XLA Ops lanes each
+    """The stacked-lane regression: Steps + XLA Modules + XLA Ops lanes each
     carry the full step interval; only the op lane may be summed (the
     artifact shipped 80.5 ms 'device total' for a 26.8 ms step), and the
     L.<layer> scope lives in tf_op, not long_name (raw HLO on TPU)."""
@@ -265,7 +264,7 @@ def test_gpu_style_stream_lanes_all_counted(tmp_path):
 
 def test_reparse_trace_rewrites_artifact(tmp_path):
     """tools/reparse_trace.py: a banked artifact whose per-layer rows
-    came out wrong (the probe-40 parser bug) is re-derived offline from
+    came out wrong (a parser bug) is re-derived offline from
     its raw trace dir — iters honored, wall fallback to the untraced
     stage, reparse provenance stamped."""
     import json as _json
@@ -282,7 +281,7 @@ def test_reparse_trace_rewrites_artifact(tmp_path):
         ])
     art = tmp_path / "trace.artifact.json"
     art.write_text(_json.dumps({
-        "stage": "wall_timed",  # wedge-truncated: no final wall banked
+        "stage": "wall_timed",  # truncated run: no final wall written
         "iters": 2,
         "wall_ms_per_step_untraced": 0.6,
         "rows": [["(other)", 3000.0]],  # the triple-counted bad parse

@@ -234,3 +234,72 @@ def test_pallas_interpret_window_wider_than_channels():
     out = lrn_across_channels(x, 7, 1e-2, 0.75, 1.0, force="interpret")
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=1e-5, atol=1e-5)
+
+
+# -- dispatchers answer the request or raise: never a quiet XLA answer ------
+
+
+def test_paged_kernel_matches_its_xla_twin_in_interpret_mode():
+    from sparknet_tpu.ops.pallas_kernels import paged_attention
+
+    rs = np.random.RandomState(0)
+    B, T, H, D, NB, MB = 5, 8, 4, 16, 32, 4
+    q = jnp.asarray(rs.randn(B, H, D), jnp.float32)
+    kp = jnp.asarray(rs.randn(NB, T, H, D), jnp.float32)
+    vp = jnp.asarray(rs.randn(NB, T, H, D), jnp.float32)
+    tables = jnp.asarray(rs.randint(0, NB, (B, MB)), jnp.int32)
+    pos = jnp.asarray(rs.randint(0, MB * T, (B,)), jnp.int32)
+    ref = paged_attention(q, kp, vp, tables, pos, force="xla")
+    out = paged_attention(q, kp, vp, tables, pos, force="interpret")
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=1e-5)
+
+
+def test_lrn_dispatcher_raises_on_unknown_or_unsatisfiable_force():
+    x = jnp.ones((2, 4, 3, 3), jnp.float32)
+    with pytest.raises(ValueError, match="unknown LRN impl"):
+        lrn_across_channels(x, 5, 1e-4, 0.75, 1.0, force="palas")
+    # the kernel is NCHW-only: a channels-last or non-rank-4 request for
+    # it must not come back from the XLA formulation
+    for force in ("pallas", "interpret"):
+        with pytest.raises(ValueError, match="rank-4 NCHW"):
+            lrn_across_channels(x, 5, 1e-4, 0.75, 1.0, force=force,
+                                channel_axis=3)
+        with pytest.raises(ValueError, match="rank-4 NCHW"):
+            lrn_across_channels(x[0], 5, 1e-4, 0.75, 1.0, force=force)
+
+
+def test_lrn_dispatcher_reads_the_env_default(monkeypatch):
+    x = jnp.ones((1, 4, 2, 2), jnp.float32)
+    monkeypatch.setenv("SPARKNET_LRN_IMPL", "no-such-impl")
+    with pytest.raises(ValueError, match="unknown LRN impl"):
+        lrn_across_channels(x, 5, 1e-4, 0.75, 1.0)
+
+
+def test_attention_dispatchers_raise_on_unknown_force():
+    from sparknet_tpu.ops.pallas_kernels import (
+        flash_attention,
+        paged_attention,
+    )
+
+    q = jnp.ones((1, 1, 8, 4), jnp.float32)
+    with pytest.raises(ValueError, match="unknown attention impl"):
+        flash_attention(q, q, q, force="flash")
+    qd = jnp.ones((1, 1, 4), jnp.float32)
+    pool = jnp.ones((2, 2, 1, 4), jnp.float32)
+    with pytest.raises(ValueError, match="unknown paged attention impl"):
+        paged_attention(qd, pool, pool, jnp.zeros((1, 1), jnp.int32),
+                        jnp.zeros((1,), jnp.int32), force="gather")
+
+
+def test_fused_update_dispatcher_raises_on_unknown_force():
+    from sparknet_tpu.ops.pallas_kernels import (
+        ARENA_TILE,
+        UpdateStatics,
+        fused_update,
+    )
+
+    w = jnp.zeros((ARENA_TILE,), jnp.float32)
+    with pytest.raises(ValueError, match="unknown fused_update impl"):
+        fused_update("SGD", UpdateStatics(), w, w, [w],
+                     jnp.ones((1,)), jnp.zeros((1,)), jnp.ones((3,)),
+                     force="mosaic")

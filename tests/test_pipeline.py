@@ -209,7 +209,7 @@ def test_respawn_journals_feed_stall_event(tmp_path):
                 next(it)
     finally:
         set_recorder(None)
-    n, _, errors = schema.validate_journal(out)
+    n, errors = schema.validate_journal(out)
     assert not errors, errors
     stalls = [e for e in schema.load_journal(out)
               if e["event"] == "feed" and e["name"] == "spawny.respawn"]
@@ -395,7 +395,7 @@ def test_feed_events_are_schema_valid(tmp_path):
         rec.close()
     finally:
         set_recorder(None)
-    n_lines, _, errors = schema.validate_journal(journal)
+    n_lines, errors = schema.validate_journal(journal)
     assert not errors, errors
     feed_events = list(schema.iter_events(journal, "feed"))
     assert feed_events, "no feed telemetry journaled"

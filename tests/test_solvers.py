@@ -743,8 +743,7 @@ def test_pure_bf16_scan_slot_dtype_fixpoint(stype):
     SPARKNET_BENCH_PARAM_DTYPE=bf16 arm): the update must return slots
     in the stored dtype.  ctx.rate is an f32 scalar, so unchecked rule
     math promotes a bf16 history to f32 — under jitted_scan_steps that
-    breaks the lax.scan carry contract (probe-40 on-chip failure,
-    docs/evidence_r4/alexnet_bf16params_ab.txt)."""
+    breaks the lax.scan carry contract."""
     from sparknet_tpu.common import set_config
 
     set_config(compute_dtype=jnp.bfloat16, param_dtype=jnp.bfloat16)

@@ -1,9 +1,7 @@
-"""Pin the timing-fence contract (round-4 judge item 1).
+"""Pin the timing-fence contract.
 
-The committed round-4 trace artifacts carried physically impossible
-"untraced wall" numbers because ``tpunet time`` stage 2 fenced a derived
-device computation over un-threaded repeat calls (VERDICT r4 §weak 1).
-These tests pin the two halves of the repaired contract:
+A wall is only as good as its fence: these tests pin the two halves of
+``value_fence``'s contract:
 
 * ``value_fence`` fetches the VALUE of the last pytree leaf by direct
   buffer copy, and for a solver step's ``(variables, slots, loss)``

@@ -18,7 +18,7 @@ Four scenarios (the catalog docs/CONTROL.md narrates):
   ``lend_width`` (ElasticTrainer shrink at a round boundary) before it
   can join, then return everything when the crowd passes.
 * **straggler_storm** — two of three replicas degrade to 30% drain
-  rate for 30 s (the relay wedge, serving edition).  Expected: joins
+  rate for 30 s.  Expected: joins
   to cover the lost capacity, kills after the storm.
 * **poison_canary** — a rollout lands a model that drains at 35%.
   Expected: the burn inside the canary window answers with PR 10's

@@ -7,7 +7,7 @@ measures what that buys the zoo's deploy forward at batch ``--batch``
 caffe/examples/cpp_classification/classification.cpp).  Prints one JSON
 line per arm and banks both to ``--out``.
 
-Run (healthy window):  python tools/int8_bench.py [--model alexnet]
+Run:  python tools/int8_bench.py [--model alexnet]
 """
 
 from __future__ import annotations
@@ -43,10 +43,11 @@ def main() -> int:
     import numpy as np
 
     from sparknet_tpu import models, quant
-    from sparknet_tpu.common import Phase, set_config
+    from sparknet_tpu.common import Phase, require_chip, set_config
     from sparknet_tpu.compiler.graph import Network
 
-    on_accel = jax.devices()[0].platform != "cpu"
+    stamp = require_chip("int8_bench")  # no chip, no pin: exit 2
+    on_accel = stamp["platform"] != "cpu"
     if on_accel:
         set_config(compute_dtype=jnp.bfloat16)
     from sparknet_tpu.models import BENCH_CROPS
@@ -80,9 +81,7 @@ def main() -> int:
             # * 1e-24 added to the input — absorbed exactly by f32 at
             # data magnitude ~50, but XLA cannot elide the dependence),
             # and salted so the warm and timed dispatches never carry
-            # identical args.  Defends against both relay timing traps
-            # (see common.value_fence): the first int8 attempt banked
-            # 8.2M img/s off exactly these.
+            # identical args.
             def chained(v, f, salt):
                 def body(carry, _):
                     f2 = dict(f)
@@ -107,7 +106,7 @@ def main() -> int:
         rec = {"metric": f"{args.model}_deploy_forward_img_s", "arm": label,
                "value": round(img_s, 1), "batch": B, "iters": iters,
                # CPU plumbing checks must never read as chip evidence
-               "platform": jax.devices()[0].platform, "measured": on_accel}
+               "measured": on_accel, **stamp}
         print(json.dumps(rec), flush=True)
         return rec
 
@@ -131,14 +130,8 @@ def main() -> int:
     results.append(measure("int8", quant.quantized_inference(qstate)))
 
     if not on_accel:
-        # plumbing check only — never overwrite banked chip evidence.
-        # Under the runner's REQUIRE_MEASURED contract (same env test as
-        # bench.py/_require_measured and tpu_window_runner.window_death)
-        # a silent CPU fallback mid-window is a WINDOW death, not a
-        # success — rc 4 keeps the job in the retry ledger.
+        # plumbing check only — never bank as chip evidence
         print("int8_bench: cpu run, not banking", file=sys.stderr)
-        if os.environ.get("SPARKNET_BENCH_REQUIRE_MEASURED") == "1":
-            return 4
         return 0
 
     out_path = args.out

@@ -1,12 +1,10 @@
 """NCHW vs NHWC conv orientation at the MXU — isolated and framework A/Bs.
 
-VERDICT r4 item 6 asked for one layout experiment on the zoo's
-pure-MFU member (VGG-16); r5 item 6 asks for the FRAMEWORK-level cost
-of an NHWC-native blob orientation — the isolated-vs-framework delta is
-the verdict: how much of the raw-jax layout win the real graph-compiler
-path keeps.  The banked AlexNet f32 trace attributes 2.0 ms/step (7.5%)
-to `data formatting` — XLA's internal layout moves — so the headline
-shape gets its own arm.
+One layout experiment on the zoo's pure-MFU member (VGG-16), and the
+FRAMEWORK-level cost of an NHWC-native blob orientation — the
+isolated-vs-framework delta is the verdict: how much of the raw-jax
+layout win the real graph-compiler path keeps.  The headline shape
+(AlexNet) gets its own arm.
 
 Two modes:
 
@@ -25,10 +23,9 @@ Two modes:
 
 Timing protocol (both modes): all iters fused in ONE dispatch (scan),
 warm-vs-timed dispatches carry different args, fence on the scalar
-VALUE of the producing program's own output (both relay traps — see
-common.value_fence).
+VALUE of the producing program's own output (common.value_fence).
 
-Run (healthy window):  python tools/layout_ab.py [--batch 128]
+Run:                   python tools/layout_ab.py [--batch 128]
                        python tools/layout_ab.py --framework --model alexnet
 """
 
@@ -179,13 +176,16 @@ def measure(layout: str, model: str, batch: int, crop: int, iters: int,
     out = cfn(params, x, y, 1.0)
     fence(out)
     dt = time.perf_counter() - t0
-    platform = jax.devices()[0].platform
+    from sparknet_tpu.common import device_stamp
+
+    stamp = device_stamp()
+    platform = stamp["platform"]
     return {
         "metric": f"{model}_shape_fwd_bwd_img_s", "arm": layout,
         "value": round(batch * iters / dt, 1), "batch": batch,
         "iters": iters, "dtype": dtype_name,
         # CPU plumbing checks must never read as chip evidence
-        "platform": platform, "measured": platform != "cpu",
+        "measured": platform != "cpu", **stamp,
     }
 
 
@@ -194,8 +194,7 @@ def measure_framework(layout: str, model: str, batch: int, crop: int,
     """One arm through the REAL zoo/solver path — bench._build_step, the
     exact construction the headline number rides (full train step: LRN,
     dropout, SGD update, donated carry), with ``Config.layout`` flipping
-    the internal orientation (ops/layout.py).  The isolated-vs-framework
-    delta on the same shape is VERDICT item 6's number."""
+    the internal orientation (ops/layout.py)."""
     import jax
 
     import bench
@@ -209,7 +208,6 @@ def measure_framework(layout: str, model: str, batch: int, crop: int,
             batch, model, crop, dtype_name, scan=max(iters, 2))
         # warm dispatch compiles + runs the fused chain once; threading
         # variables/slots through gives the timed dispatch fresh args
-        # (the stale-args relay trap — common.value_fence docstring)
         variables, slots, loss = step(variables, slots, 0, feeds, key)
         fence(loss)
         t0 = time.perf_counter()
@@ -218,12 +216,14 @@ def measure_framework(layout: str, model: str, batch: int, crop: int,
         dt = time.perf_counter() - t0
     finally:
         set_config(layout=prior)
-    platform = jax.devices()[0].platform
+    from sparknet_tpu.common import device_stamp
+
+    stamp = device_stamp()
     return {
         "metric": f"{model}_framework_train_img_s", "arm": layout,
         "value": round(batch * max(iters, 2) / dt, 1), "batch": batch,
         "iters": max(iters, 2), "dtype": dtype_name,
-        "platform": platform, "measured": platform != "cpu",
+        "measured": stamp["platform"] != "cpu", **stamp,
     }
 
 
@@ -238,7 +238,7 @@ def main() -> int:
                     help="build both arms through the real zoo/solver "
                     "path (bench._build_step + Config.layout) instead "
                     "of raw jax — the isolated-vs-framework delta is "
-                    "the VERDICT item-6 verdict")
+                    "the verdict")
     ap.add_argument("--batch", type=int, default=128)
     ap.add_argument("--crop", type=int, default=None)
     ap.add_argument("--iters", type=int, default=10)
@@ -251,7 +251,9 @@ def main() -> int:
 
     if args.platform:
         jax.config.update("jax_platforms", args.platform)
-    on_accel = jax.devices()[0].platform != "cpu"
+    from sparknet_tpu.common import require_chip
+
+    on_accel = require_chip("layout_ab")["platform"] != "cpu"
 
     if args.framework:
         # the net is built at the zoo's bench crop; --crop is ignored
@@ -280,13 +282,8 @@ def main() -> int:
         print(json.dumps(r), flush=True)
 
     if not on_accel:
-        # plumbing check only — never overwrite banked chip evidence.
-        # rc 4 under the runner's REQUIRE_MEASURED contract (see
-        # tpu_window_runner.window_death): a silent CPU fallback
-        # mid-window must stay in the retry ledger, not read as done.
+        # plumbing check only — never bank as chip evidence
         print("layout_ab: cpu run, not banking", file=sys.stderr)
-        if os.environ.get("SPARKNET_BENCH_REQUIRE_MEASURED") == "1":
-            return 4
         return 0
 
     out_path = args.out

@@ -3,12 +3,12 @@
 
 The staged artifact keeps ``trace_dir``/``trace_dir_short`` pointing at
 the exported profiler data, precisely so attribution can be re-derived
-OFFLINE after a parser fix — chip windows are scarce, raw traces are
-not.  (Probe-40 shipped two on-chip traces whose per-layer tables came
-out 0%-attributed and triple-counted: the parser preferred ``long_name``
-— raw HLO text on TPU, no scopes — and summed the stacked Steps/Modules/
-Ops lanes.  op_profile.py now reads ``tf_op`` and keeps only the op
-lane; this tool backfills artifacts captured before that fix.)
+OFFLINE after a parser fix — chip time is budgeted, raw traces are
+kept.  (An early parser preferred ``long_name`` — raw HLO text on TPU,
+no scopes — and summed the stacked Steps/Modules/Ops lanes, so tables
+came out 0%-attributed and triple-counted.  op_profile.py reads
+``tf_op`` and keeps only the op lane; this tool backfills artifacts
+captured before such a fix.)
 
     python tools/reparse_trace.py docs/evidence_r4/trace_alexnet_b256.artifact.json
 """

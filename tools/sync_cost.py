@@ -92,7 +92,7 @@ def main():
         # 1e-6 scale is representable against ~1e-1 params (a smaller
         # epsilon would be absorbed by f32, leaving the probe constant
         # and the chain fake); the single end-of-loop fence fetches the
-        # probe VALUE (relay timing traps — see common.value_fence).
+        # probe VALUE (common.value_fence).
         def avg_fn(t, salt):
             leaves, treedef = jax.tree_util.tree_flatten(t)
             outs = []

@@ -1,4 +1,4 @@
-"""The SparkNet tau tradeoff at AlexNet scale (VERDICT r3 item 8).
+"""The SparkNet tau tradeoff at AlexNet scale.
 
 The paper's fig. 5 axis — accuracy vs synchronization cadence at a
 fixed per-worker local-step budget — measured with the ACTUAL AlexNet

@@ -2,11 +2,11 @@
 """Render a ``tpunet time --trace`` artifact into the per-layer markdown
 table the reference prints from ``caffe time`` (ref:
 caffe/tools/caffe.cpp:290-380 — per-layer Forward/Backward walls plus
-totals).  Reads the staged artifact JSON (any stage: partial artifacts
-from a wedged window still render whatever stages landed) and writes
+totals).  Reads the staged artifact JSON (any stage: a partial artifact
+from a failed run still renders whatever stages landed) and writes
 markdown to stdout or --out.
 
-    python tools/trace_report.py docs/evidence_r4/trace_alexnet_b256.artifact.json
+    python tools/trace_report.py tpunet_trace.json
 """
 
 from __future__ import annotations
@@ -27,32 +27,22 @@ def render(a: dict) -> str:
     mfu = a.get("mfu")
     img_s = a.get("img_per_sec")
     # Untraced-wall fallback is accepted ONLY from artifacts stamped with
-    # the repaired fence protocol: the round-4 artifacts' unfenced
-    # "untraced" fields were physically impossible (7,860% MFU —
-    # VERDICT r4 §weak 1) and scrubbed artifacts carry them quarantined
-    # under `invalid_fence` instead.
+    # a fence protocol: a wall whose timing loop fenced nothing is the
+    # enqueue time, not the step.
     refused_untraced = False
     if not wall and a.get("wall_ms_per_step_untraced") is not None:
-        if a.get("fence_protocol") and not a.get("invalid_fence"):
+        if a.get("fence_protocol"):
             wall = a.get("wall_ms_per_step_untraced")
             mfu = a.get("mfu_untraced")
             img_s = a.get("img_per_sec_untraced")
         else:
             refused_untraced = True
-    if a.get("invalid_fence"):
-        lines.append("")
-        lines.append("**Note:** this artifact's stage-2 'untraced wall' "
-                     "fields were banked with the broken pre-round-5 "
-                     "fence and are quarantined (`invalid_fence`); only "
-                     "trace-derived numbers below are evidence.")
-    elif refused_untraced:
+    if refused_untraced:
         lines.append("")
         lines.append("**Note:** this artifact carries an untraced wall "
-                     "but no `fence_protocol` stamp (pre-round-5 tool) — "
-                     "the value is withheld here because the unstamped "
-                     "fence banked physically impossible walls on the "
-                     "relay backend (see docs/BENCHMARKS.md, round-5 "
-                     "fence postmortem).")
+                     "but no `fence_protocol` stamp — the value is "
+                     "withheld: an unfenced wall times the enqueue, not "
+                     "the step.")
     if wall:
         lines.append(
             f"Step: **{wall:.3f} ms** "

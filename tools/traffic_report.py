@@ -1,7 +1,7 @@
 #!/usr/bin/env python
 """Bandwidth attribution from a raw ``tpunet time --trace`` dir.
 
-VERDICT r4 item 2: the HLO-byte roofline misestimates physical HBM
+The HLO-byte roofline misestimates physical HBM
 traffic in BOTH directions — it misses tile padding and fusion-boundary
 materialization (undercount) and it counts on-chip-reuse traffic as if
 it hit HBM (overcount; GoogLeNet b128's implied bandwidth lands at
@@ -17,8 +17,7 @@ cost-analysis ``bytes_accessed``/``model_flops`` AND its measured
 Output per trace: device-busy/step, HLO GB/step, implied mean GB/s and
 its fraction of the 819 GB/s v5e peak (the honest ceiling the step can
 approach under the SAME compiler decomposition), plus per-category and
-top-op tables.  Zero chip time — runs on the banked ``/tmp`` dirs or any
-copied trace dir (CLAUDE.md: trace dirs outlive the window).
+top-op tables.  Zero chip time — runs on any copied trace dir.
 
     python tools/traffic_report.py /tmp/tpunet_time_82g3ov25 --iters 10
 """
@@ -41,7 +40,7 @@ _SCOPE = re.compile(r"\bL\.([\w.\-]+)")
 
 def device_op_events(log_dir: str) -> list[dict]:
     """Device-op-lane complete events WITH their args payload — the lane
-    selection (stacked-views vs stream-per-lane, probe-40 triple-count
+    selection (stacked-views vs stream-per-lane, the triple-count
     fix) is single-sourced in op_profile._device_events."""
     from sparknet_tpu.utils.op_profile import _device_events
 
