@@ -183,10 +183,36 @@ def _internalize(fn):
     def wrapped(it):
         return feeds_to_internal(fn(it))
 
+    return _carry_feed_attrs(fn, wrapped)
+
+
+def _carry_feed_attrs(fn, wrapped):
+    """The hooks a train data fn carries, kept across a wrapper."""
     for attr in ("device_fn", "trainer_device_fn", "pipeline_factory"):
         if hasattr(fn, attr):
             setattr(wrapped, attr, getattr(fn, attr))
     return wrapped
+
+
+def _read_span(fn, images):
+    """``sn.feed.read`` around a host data fn: one span per host batch,
+    from the cursor to the decoded, collated, cast and internalized
+    batch, on whichever thread asks for it (the DevicePrefetcher's feed
+    thread in the solo loop, the main thread inside ``_stack_tau``)."""
+    def wrapped(it):
+        with _host_span("sn.feed.read", it=it, images=images):
+            return fn(it)
+
+    return _carry_feed_attrs(fn, wrapped)
+
+
+def _host_span(name, **counts):
+    """A host-only span of the program's one span type (obs/recorder.py
+    ``Span``): a profiler annotation always, a journal line when
+    ``SPARKNET_OBS`` is armed."""
+    from sparknet_tpu.obs import get_recorder
+
+    return get_recorder().span(name, host=True, **counts)
 
 
 def _attach_device_augment(train_fn, cfg, pid, seed=None):
@@ -689,7 +715,7 @@ def _data_fns(args, net, test_net=None):
             # per-record byte offsets to index (RecordShardSource's
             # refusal names convert_db as the migration)
             train_fn.pipeline_factory = _db_pipeline_factory
-        return (_internalize(train_fn),
+        return (_read_span(_internalize(train_fn), batch),
                 _internalize(db_stream(test_path, train=False)))
 
     if args.data == "synthetic":
@@ -1031,6 +1057,14 @@ def cmd_train(args) -> int:
     return 0
 
 
+def _sum_counts(parts):
+    """``images``/``bytes`` over a list of host batches (span counts)."""
+    from sparknet_tpu.obs.recorder import feed_counts
+
+    counts = [feed_counts(p) for p in parts]
+    return {k: sum(c[k] for c in counts) for k in ("images", "bytes")}
+
+
 def _stack_tau(train_fn, tau, num_workers):
     """[tau, B*workers, ...] feeds: the net batch is per-worker; each tau
     slot concatenates one batch per worker (the global minibatch).  Owns
@@ -1039,14 +1073,22 @@ def _stack_tau(train_fn, tau, num_workers):
     counter = [0]
 
     def fn(it):
+        # sn.feed.stack: the pack's own copies, apart from its reads.
+        # Every image is counted once, on its slot's concatenate; the
+        # round's final np.stack carries bytes only
         slots = []
         for _ in range(tau):
             parts = []
             for _ in range(num_workers):
                 parts.append(train_fn(counter[0]))
                 counter[0] += 1
-            slots.append({key: np.concatenate([p[key] for p in parts]) for key in parts[0]})
-        return {key: np.stack([s[key] for s in slots]) for key in slots[0]}
+            with _host_span("sn.feed.stack", it=it, **_sum_counts(parts)):
+                slots.append({key: np.concatenate([p[key] for p in parts])
+                              for key in parts[0]})
+        with _host_span("sn.feed.stack", it=it,
+                        bytes=_sum_counts(slots)["bytes"]):
+            return {key: np.stack([s[key] for s in slots])
+                    for key in slots[0]}
 
     return fn
 
@@ -1058,7 +1100,9 @@ def _widen_batch(train_fn, num_workers):
 
     def fn(it):
         parts = [train_fn(it * num_workers + w) for w in range(num_workers)]
-        return {key: np.concatenate([p[key] for p in parts]) for key in parts[0]}
+        with _host_span("sn.feed.stack", it=it, **_sum_counts(parts)):
+            return {key: np.concatenate([p[key] for p in parts])
+                    for key in parts[0]}
 
     return fn
 

@@ -290,6 +290,13 @@ def require_chip(tool: str) -> dict:
     return stamp
 
 
+# the ``jax.named_scope`` names a trace reader may look for in this
+# program's executables (compiler/graph.py, solvers/solver.py,
+# data/device_transform.py): part of the compile-cache key.  Add a
+# scope, add it here
+CACHE_SCOPES = "scopes:L.<layer>,S.update,S.augment"
+
+
 def enable_compile_cache() -> str:
     """Place jax's persistent compilation cache; returns the directory.
 
@@ -298,7 +305,20 @@ def enable_compile_cache() -> str:
     harness.  Where ``JAX_COMPILATION_CACHE_DIR`` is set jax already
     reads it and nothing is set in code; otherwise the cache lives at
     the FIXED path ``<checkout>/.jax_cache``, so a second process run
-    from the same checkout finds what the first compiled."""
+    from the same checkout finds what the first compiled.
+
+    The cache key also names the program's device scopes
+    (``CACHE_SCOPES``).  A cached executable carries the scope names of
+    the source that compiled it, a trace reader finds device time by
+    them, and jax strips them from its key: without this a checkout
+    whose scopes differ (an older commit beside this one on a shared
+    cache) would be served this one's executables, and this one theirs.
+    ``jax._src.cache_key.custom_hook`` is jax's own door for such an
+    addition; it is private and imported plainly, so a jax upgrade that
+    moves it fails here instead of silently mixing executables."""
+    from jax._src import cache_key
+
+    cache_key.custom_hook = lambda: CACHE_SCOPES
     placed = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if placed:
         return placed

@@ -23,6 +23,7 @@ write their partition into per-worker LMDB/LevelDBs through the C API
 from __future__ import annotations
 
 import functools
+import itertools
 import os
 import struct
 from typing import Iterable, Iterator
@@ -30,6 +31,7 @@ from typing import Iterable, Iterator
 import numpy as np
 
 from sparknet_tpu.native import RecordDB
+from sparknet_tpu.obs import get_recorder
 
 _HDR = struct.Struct("<IIIi")
 COMMIT_EVERY = 1000  # ref: CreateDB.scala commit_db_txn cadence
@@ -213,22 +215,34 @@ def db_minibatches(
                 f"db holds {len(db)} records < batch_size {batch_size}; "
                 "loop=True would spin forever yielding nothing"
             )
-        while True:
-            imgs, labels = [], []
-            for _, value in db:
-                img, label = decode(value)
-                imgs.append(img)
-                labels.append(label)
-                if len(imgs) == batch_size:
-                    yield {
-                        "data": np.stack(imgs).astype(dtype),
-                        "label": np.asarray(labels, np.int32),
-                    }
-                    imgs, labels = [], []
-            if imgs and not drop_remainder:
-                yield {
+        n = 0  # the cursor's own batch index: the spans' ``it``
+
+        def collate(imgs, labels):
+            with get_recorder().span("sn.feed.collate", host=True, it=n,
+                                     images=len(imgs)):
+                return {
                     "data": np.stack(imgs).astype(dtype),
                     "label": np.asarray(labels, np.int32),
                 }
+
+        while True:
+            cursor = iter(db)
+            while True:
+                # one span per batch around the per-record loop, closed
+                # before the yield: a span never stays open across one
+                with get_recorder().span("sn.feed.decode", host=True,
+                                         it=n, images=batch_size):
+                    imgs, labels = [], []
+                    for _, value in itertools.islice(cursor, batch_size):
+                        img, label = decode(value)
+                        imgs.append(img)
+                        labels.append(label)
+                if len(imgs) < batch_size:
+                    break
+                yield collate(imgs, labels)
+                n += 1
+            if imgs and not drop_remainder:
+                yield collate(imgs, labels)
+                n += 1
             if not loop:
                 return

@@ -23,6 +23,11 @@ import jax.numpy as jnp
 from sparknet_tpu.data.transform import TransformConfig
 
 
+# device scope of the augment's ops; not ``L.``: a trace reader books
+# ``L.<name>`` as a net layer
+AUGMENT_SCOPE = "S.augment"
+
+
 class DeviceAugment:
     """jit-compatible batch transform: uint8/float device array + PRNG
     key → float32 crops, in the INTERNAL layout (``Config.layout``,
@@ -63,6 +68,14 @@ class DeviceAugment:
         self._mean = mean
 
     def __call__(self, images, key, train: bool = True):
+        # the scope lands in the HLO metadata of a JITTED caller (the
+        # trainer's aug4/aug5), so a trace names the augment's device
+        # time.  Eager callers (device_fn below) dispatch op by op and
+        # carry none: jax resets the name stack for an eager primitive
+        with jax.named_scope(AUGMENT_SCOPE):
+            return self._augment(images, key, train)
+
+    def _augment(self, images, key, train: bool):
         cfg = self.config
         nhwc = self.layout == "nhwc"
         x = jnp.asarray(images).astype(jnp.float32)

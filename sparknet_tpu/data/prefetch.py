@@ -23,6 +23,9 @@ from typing import Any, Callable
 
 import jax
 
+from sparknet_tpu.obs import get_recorder
+from sparknet_tpu.obs.recorder import feed_counts
+
 PREFETCH_COUNT = 3
 
 
@@ -57,36 +60,53 @@ class DevicePrefetcher:
         self._thread.start()
 
     def _worker(self) -> None:
+        # the feed thread's spans (sn.feed.put / augment / full; the data
+        # fn brings its own sn.feed.read) time the HOST side of each
+        # stage: the transfer and the augment are dispatched, not awaited
         try:
             for it in range(self._start, self._start + self._num):
                 if self._stop.is_set():
                     return
                 feeds = self._data_fn(it)
-                if self._sharding is not None:
-                    feeds = {
-                        k: jax.device_put(v, self._sharding)
-                        for k, v in feeds.items()
-                    }
-                else:
-                    feeds = jax.device_put(feeds)
+                with get_recorder().span("sn.feed.put", host=True, it=it,
+                                         **feed_counts(feeds)):
+                    if self._sharding is not None:
+                        feeds = {
+                            k: jax.device_put(v, self._sharding)
+                            for k, v in feeds.items()
+                        }
+                    else:
+                        feeds = jax.device_put(feeds)
                 if self._device_fn is not None:
-                    feeds = self._device_fn(feeds, it)
-                if not self._put(feeds):
+                    with get_recorder().span("sn.feed.augment", host=True,
+                                             it=it):
+                        feeds = self._device_fn(feeds, it)
+                if not self._put(feeds, it):
                     return
             self._put(_DONE)
         except BaseException as e:  # surfaced on the consumer side
             self._err = e
             self._put(_DONE)
 
-    def _put(self, item) -> bool:
+    def _put(self, item, it: int = -1) -> bool:
         """Bounded put that aborts on close() so an abandoned consumer
-        doesn't leave the worker pinning device batches forever."""
-        while not self._stop.is_set():
-            try:
-                self._q.put(item, timeout=0.1)
-                return True
-            except queue.Full:
-                continue
+        doesn't leave the worker pinning device batches forever.  The
+        time it is blocked on a full queue (the feed is ahead of the
+        device) is the ``sn.feed.full`` span."""
+        if self._stop.is_set():
+            return False
+        try:
+            self._q.put_nowait(item)
+            return True
+        except queue.Full:
+            pass
+        with get_recorder().span("sn.feed.full", host=True, it=it):
+            while not self._stop.is_set():
+                try:
+                    self._q.put(item, timeout=0.1)
+                    return True
+                except queue.Full:
+                    continue
         return False
 
     def close(self) -> None:
@@ -116,8 +136,11 @@ class DevicePrefetcher:
             if self._err is not None:
                 raise self._err
             return
+        it = self._start
         while True:
-            item = self._q.get()
+            with get_recorder().span("sn.feed.wait", host=True, it=it):
+                item = self._q.get()
+            it += 1
             if item is _DONE:
                 self._finished = True
                 if self._err is not None:

@@ -4,9 +4,18 @@ Arm it with ``SPARKNET_OBS=<path>.jsonl`` (the literal ``1`` means
 ``./obs_journal.jsonl``); anything else — unset, empty, ``0`` — keeps it
 OFF, and the off state is a hard contract: instrumented call sites
 (``Solver.step``, ``ParallelTrainer.train_round``, bench.py) guard every
-obs touch behind ``if rec:``, so the disabled hot path is bit-identical
-— same lowered StableHLO, same dispatch count — which
+JOURNAL touch behind ``if rec:``, so the disabled hot path is
+bit-identical — same lowered StableHLO, same dispatch count — which
 ``tests/test_obs.py`` pins.
+
+A :class:`Span` is also the program's one span type on the PROFILER's
+clock: entering it enters a ``jax.profiler.TraceAnnotation`` of the same
+name, armed or not, so the ``sn.*`` spans of the feed, the step and the
+round lie beside the device ops in any ``tpunet train --profile`` trace
+(docs/OBSERVABILITY.md, "Spans on the profiler's clock").  With no
+profiler session open a span costs about two microseconds (PERF.md,
+PR 24) and records nothing; it dispatches nothing either way.  Only the
+journal line is what ``SPARKNET_OBS`` arms.
 
 Walls are only evidence when they are FENCE-STAMPED.  A span that
 encloses device work must close through :meth:`Span.fence`, which fetches
@@ -31,7 +40,8 @@ from sparknet_tpu.obs import schema
 from sparknet_tpu.obs.metrics import MetricsHub
 from sparknet_tpu.obs.sentinel import get_sentinel
 
-__all__ = ["Recorder", "Span", "get_recorder", "set_recorder"]
+__all__ = ["Recorder", "Span", "feed_counts", "get_recorder",
+           "set_recorder"]
 
 _ENV = "SPARKNET_OBS"
 
@@ -40,26 +50,67 @@ _ENV = "SPARKNET_OBS"
 _EMA_DECAY = 0.9
 
 
-class Span:
-    """One fenced wall.  Use as a context manager off
-    :meth:`Recorder.span`; close device-work spans with :meth:`fence`
-    (or :meth:`fence_value` when the caller already materialized the
-    producing program's own output)."""
+_TraceAnnotation = None
 
-    __slots__ = ("_rec", "name", "host", "note", "_t0", "_fenced",
-                 "_fence_value")
+
+def _trace_annotation():
+    """``jax.profiler.TraceAnnotation``, imported on first use so
+    ``sparknet_tpu.obs`` stays import-light."""
+    global _TraceAnnotation
+    if _TraceAnnotation is None:
+        from jax.profiler import TraceAnnotation
+
+        _TraceAnnotation = TraceAnnotation
+    return _TraceAnnotation
+
+
+def feed_counts(feeds, lead_axes: int = 1) -> dict:
+    """``images``/``bytes`` of one feed dict, as a span's counts:
+    images from the first ``lead_axes`` axes of the first array (2 for
+    a ``[tau, B, ...]`` round), bytes over every array."""
+    images = 0
+    for v in feeds.values():
+        shape = getattr(v, "shape", ())
+        if len(shape) >= lead_axes:
+            images = 1
+            for n in shape[:lead_axes]:
+                images *= int(n)
+            break
+    return {"images": images,
+            "bytes": sum(int(getattr(v, "nbytes", 0)) for v in feeds.values())}
+
+
+class Span:
+    """One wall, on two clocks.  Use as a context manager off
+    :meth:`Recorder.span`.
+
+    On the profiler's clock, always: a ``TraceAnnotation(name,
+    **counts)`` is open for as long as the span is (``counts``: ``it``,
+    the batch or round index the spans of one batch share, and
+    ``images``/``bytes`` where the work has a size).  One span per batch
+    or round — never per record, layer or parameter leaf.
+
+    In the journal, only when the Recorder is armed: close device-work
+    spans with :meth:`fence` (or :meth:`fence_value` when the caller
+    already materialized the producing program's own output)."""
+
+    __slots__ = ("_rec", "name", "host", "note", "counts", "_ann", "_t0",
+                 "_fenced", "_fence_value")
 
     def __init__(self, rec: "Recorder | None", name: str,
-                 host: bool = False, note: str | None = None):
+                 host: bool = False, note: str | None = None, **counts):
         self._rec = rec if (rec is not None and rec.enabled) else None
         self.name = name
         self.host = bool(host)
         self.note = note
+        self.counts = counts
+        self._ann = _trace_annotation()(name, **counts)
         self._t0 = 0.0
         self._fenced = False
         self._fence_value: float | None = None
 
     def __enter__(self) -> "Span":
+        self._ann.__enter__()
         self._t0 = time.perf_counter()
         return self
 
@@ -86,6 +137,7 @@ class Span:
         return self._fence_value
 
     def __exit__(self, exc_type, exc, tb) -> None:
+        self._ann.__exit__(exc_type, exc, tb)
         if self._rec is None:
             return
         wall = time.perf_counter() - self._t0
@@ -98,8 +150,10 @@ class Span:
             fields["host"] = True
         if self._fence_value is not None:
             fields["fence_value"] = self._fence_value
-        if self.note:
-            fields["note"] = self.note
+        note = self.note or " ".join(
+            f"{k}={v}" for k, v in self.counts.items())
+        if note:
+            fields["note"] = note
         self._rec._emit_span(fields)
 
 
@@ -201,11 +255,12 @@ class Recorder:
     # -- public surface ----------------------------------------------------
 
     def span(self, name: str, host: bool = False,
-             note: str | None = None) -> Span:
-        """A fenced-wall context manager (works, as a no-op, when obs is
-        off).  ``host=True`` declares the span never encloses device
-        work and exempts it from the fence contract."""
-        return Span(self, name, host=host, note=note)
+             note: str | None = None, **counts) -> Span:
+        """A fenced-wall context manager.  With obs off it journals
+        nothing and is still a ``TraceAnnotation(name, **counts)`` on
+        the profiler's clock.  ``host=True`` declares the span never
+        encloses device work and exempts it from the fence contract."""
+        return Span(self, name, host=host, note=note, **counts)
 
     def round(self, *, mode: str, tau: int, devices: int, iters: int,
               batch: int, wall_s: float, loss: float, fenced: bool,

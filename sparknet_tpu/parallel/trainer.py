@@ -36,6 +36,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from sparknet_tpu.common import get_config
 from sparknet_tpu.compiler.graph import NetVars
 from sparknet_tpu.obs import get_recorder
+from sparknet_tpu.obs.recorder import Span, feed_counts
 from sparknet_tpu.net import WeightCollection, collection_to_variables, variables_to_collection
 from sparknet_tpu.parallel.mesh import data_parallel_mesh, shard_map
 from sparknet_tpu.parallel.sharding import (
@@ -45,6 +46,7 @@ from sparknet_tpu.parallel.sharding import (
     place,
 )
 from sparknet_tpu.solvers.solver import Solver
+from sparknet_tpu.utils.profiling import step_span
 
 DataFn = Callable[[int], dict[str, Any]]
 
@@ -384,37 +386,56 @@ class ParallelTrainer:
         way."""
         rec = get_recorder()
         t0 = time.perf_counter() if rec else 0.0
-        raw = data_fn(self.iter)
-        if self._elastic:
-            feeds = self._put_feeds(raw, with_tau_axis=True)
-            if self.feed_device_fn is not None:
-                feeds = self.feed_device_fn(feeds, self.iter)
-            self.variables, self.slots, self.center, loss = self._train(
-                self.variables, self.slots, self.center, self.iter, feeds,
-                self.solver._key,
-            )
+        it0 = self.iter
+        # the round's serial order, each stage with its own wall on the
+        # profiler's clock: data -> put -> augment -> dispatch -> fence
+        with step_span("sn.round", it0):
+            with rec.span("sn.round.data", host=True, it=it0):
+                raw = data_fn(it0)
+            feeds = self._stage_feeds(
+                raw, with_tau_axis=self._elastic or self.tau > 1)
+            with rec.span("sn.round.dispatch", host=True, it=it0):
+                if self._elastic:
+                    (self.variables, self.slots, self.center,
+                     loss) = self._train(
+                        self.variables, self.slots, self.center, it0,
+                        feeds, self.solver._key,
+                    )
+                elif self.tau == 1:
+                    with self._sp_context():
+                        self.variables, self.slots, loss = self._train(
+                            self.variables, self.slots, it0, feeds,
+                            self.solver._key,
+                        )
+                else:
+                    self.variables, self.slots, loss = self._train(
+                        self.variables, self.slots, it0, feeds,
+                        self.solver._key
+                    )
             self.iter += self.tau
-        elif self.tau == 1:
-            feeds = self._put_feeds(raw, with_tau_axis=False)
-            if self.feed_device_fn is not None:
+            # on the profiler's clock only (no Recorder): in the journal
+            # the round record closed on this value is the fence's line
+            with Span(None, "sn.round.fence", it=it0) as sp:
+                if rec:
+                    loss_val = self._emit_obs_round(rec, raw, t0, loss)
+                else:
+                    loss_val = float(loss)
+                sp.fence_value(loss_val)
+        return loss_val
+
+    def _stage_feeds(self, raw, with_tau_axis: bool):
+        """Host feeds -> their shards (``_put_feeds``), then the
+        post-placement device hook.  ``sn.feed.put`` / ``sn.feed.augment``
+        time the HOST side: both are dispatched, not awaited."""
+        rec = get_recorder()
+        counts = feed_counts(raw, 2 if with_tau_axis else 1)
+        with rec.span("sn.feed.put", host=True, it=self.iter, **counts):
+            feeds = self._put_feeds(raw, with_tau_axis=with_tau_axis)
+        if self.feed_device_fn is not None:
+            with rec.span("sn.feed.augment", host=True, it=self.iter,
+                          images=counts["images"]):
                 feeds = self.feed_device_fn(feeds, self.iter)
-            with self._sp_context():
-                self.variables, self.slots, loss = self._train(
-                    self.variables, self.slots, self.iter, feeds,
-                    self.solver._key,
-                )
-            self.iter += 1
-        else:
-            feeds = self._put_feeds(raw, with_tau_axis=True)
-            if self.feed_device_fn is not None:
-                feeds = self.feed_device_fn(feeds, self.iter)
-            self.variables, self.slots, loss = self._train(
-                self.variables, self.slots, self.iter, feeds, self.solver._key
-            )
-            self.iter += self.tau
-        if rec:
-            return self._emit_obs_round(rec, raw, t0, loss)
-        return float(loss)
+        return feeds
 
     def train(self, num_outer: int, data_fn: DataFn, callback=None) -> float:
         loss = 0.0
@@ -534,11 +555,9 @@ class ParallelTrainer:
         # [n, B, ...]: the tau-shaped feed placement shards axis 1 over
         # 'data' and leaves the round axis unsharded — exactly the scan
         # xs layout
-        feeds = self._put_feeds(stacked, with_tau_axis=True)
-        if self.feed_device_fn is not None:
-            # the rank-5 arm of the hook: [n, B, ...] scanned rounds
-            # take per-slot keys exactly like a [tau, B, ...] round
-            feeds = self.feed_device_fn(feeds, self.iter)
+        # (the device hook's rank-5 arm: [n, B, ...] scanned rounds take
+        # per-slot keys exactly like a [tau, B, ...] round)
+        feeds = self._stage_feeds(stacked, with_tau_axis=True)
         with self._sp_context():
             self.variables, self.slots, losses = self._round_scan_fns[n](
                 self.variables, self.slots, self.iter, feeds,

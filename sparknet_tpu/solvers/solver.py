@@ -27,9 +27,15 @@ import numpy as np
 from sparknet_tpu.common import Phase, get_config, root_key, step_key
 from sparknet_tpu.compiler.graph import Network, NetVars
 from sparknet_tpu.obs import get_recorder
+from sparknet_tpu.obs.recorder import Span
 from sparknet_tpu.proto.text_format import Message, parse_file
 from sparknet_tpu.solvers.lr_policy import learning_rate
 from sparknet_tpu.solvers.updates import apply_update, init_slots
+from sparknet_tpu.utils.profiling import step_span
+
+# device scope of the optimizer update inside every jitted train step.
+# Not an ``L.`` scope: a trace reader books ``L.<name>`` as a net layer
+UPDATE_SCOPE = "S.update"
 
 # enum (2015) and string (modern) solver types both accepted
 _TYPE_ALIASES = {
@@ -257,9 +263,10 @@ def build_train_step(cfg: SolverConfig, net: Network, specs,
                 loss_fn, has_aux=True
             )(variables.params, variables.state, feeds, rng)
         rate = learning_rate(cfg, it)
-        new_params, new_slots = apply_update(
-            cfg, variables.params, grads, slots, specs, rate, it
-        )
+        with jax.named_scope(UPDATE_SCOPE):
+            new_params, new_slots = apply_update(
+                cfg, variables.params, grads, slots, specs, rate, it
+            )
         out = NetVars(params=new_params, state=new_state), new_slots, loss
         if not debug:
             return out
@@ -332,8 +339,9 @@ def build_fused_core(cfg: SolverConfig, net: Network, layout):
                 loss_fn, has_aux=True
             )(param_arena, state, feeds, rng)
         rate = learning_rate(cfg, it)
-        new_arena, new_slots = arena_mod.arena_apply_update(
-            cfg, layout, param_arena, grad_arena, slot_arenas, rate, it)
+        with jax.named_scope(UPDATE_SCOPE):
+            new_arena, new_slots = arena_mod.arena_apply_update(
+                cfg, layout, param_arena, grad_arena, slot_arenas, rate, it)
         return new_arena, new_slots, new_state, loss
 
     return core
@@ -533,9 +541,10 @@ class Solver:
                     loss_fn, has_aux=True
                 )(variables.params, variables.state, feeds, rng)
             rate = learning_rate(cfg, it)
-            new_params, new_slots = apply_update(
-                cfg, variables.params, grads, slots, specs, rate, it
-            )
+            with jax.named_scope(UPDATE_SCOPE):
+                new_params, new_slots = apply_update(
+                    cfg, variables.params, grads, slots, specs, rate, it
+                )
             out = NetVars(params=new_params, state=new_state), new_slots, loss
             if not debug:
                 return out
@@ -739,12 +748,14 @@ class Solver:
             return self._step_scanned(num_iters, data_fn, callback,
                                       scan_chunk)
         for _ in range(num_iters):
-            feeds = data_fn(self.iter)
-            if self._obs_in_step:
-                self._obs_images_per_iter = self._feed_images(feeds)
-            out = self._train_step(
-                self.variables, self.slots, self.iter, feeds, self._key
-            )
+            # sn.step: the data wait and the dispatch of one iteration
+            with step_span("sn.step", self.iter):
+                feeds = data_fn(self.iter)
+                if self._obs_in_step:
+                    self._obs_images_per_iter = self._feed_images(feeds)
+                out = self._train_step(
+                    self.variables, self.slots, self.iter, feeds, self._key
+                )
             if cfg.debug_info:
                 self.variables, self.slots, loss, stats = out
                 self._print_debug_info(stats)
@@ -764,10 +775,16 @@ class Solver:
                     f"lr = {float(learning_rate(cfg, self.iter)):.6g}"
                 )
             if callback:
-                callback(self.iter, float(loss))
+                with Span(None, "sn.step.fence", it=self.iter) as sp:
+                    loss_val = sp.fence_value(float(loss))
+                callback(self.iter, loss_val)
             if cfg.snapshot and self.iter % cfg.snapshot == 0 and cfg.snapshot_prefix:
                 self.save(f"{cfg.snapshot_prefix}_iter_{self.iter}")
-        self.smoothed_loss = self._smoothed()
+        # sn.step.fence: the host blocked on the device for the losses.
+        # On the profiler's clock only (no Recorder): in the journal the
+        # round record that step() closes on this value is its line
+        with Span(None, "sn.step.fence", it=self.iter) as sp:
+            self.smoothed_loss = sp.fence_value(self._smoothed())
         return self.smoothed_loss
 
     def _step_scanned(self, num_iters: int, data_fn: DataFn, callback,
@@ -806,25 +823,30 @@ class Solver:
                     n, donate=False, stacked_feeds=True)
             fn = self._scan_fns[n]
             start = self.iter
-            host = [data_fn(start + i) for i in range(n)]
-            if self._obs_in_step:
-                self._obs_images_per_iter = self._feed_images(host[0])
-            if any(isinstance(v, jax.Array) for v in host[0].values()):
-                # prefetched feeds are already device-resident: stack on
-                # device — np.asarray here would force a blocking D2H of
-                # every batch, serializing the pipeline prefetch overlaps
-                stacked = {
-                    k: jnp.stack([h[k] for h in host]) for k in host[0]
-                }
-            else:
-                stacked = jax.device_put({
-                    k: np.stack([np.asarray(h[k]) for h in host])
-                    for k in host[0]
-                })
-            self.variables, self.slots, losses = fn(
-                self.variables, self.slots, start, stacked, self._key
-            )
-            losses = np.asarray(losses)
+            # sn.step: the data wait and the dispatch of one scanned chunk
+            with step_span("sn.step", start):
+                host = [data_fn(start + i) for i in range(n)]
+                if self._obs_in_step:
+                    self._obs_images_per_iter = self._feed_images(host[0])
+                if any(isinstance(v, jax.Array) for v in host[0].values()):
+                    # prefetched feeds are already device-resident: stack
+                    # on device — np.asarray here would force a blocking
+                    # D2H of every batch, serializing the pipeline
+                    # prefetch overlaps
+                    stacked = {
+                        k: jnp.stack([h[k] for h in host]) for k in host[0]
+                    }
+                else:
+                    stacked = jax.device_put({
+                        k: np.stack([np.asarray(h[k]) for h in host])
+                        for k in host[0]
+                    })
+                self.variables, self.slots, losses = fn(
+                    self.variables, self.slots, start, stacked, self._key
+                )
+            with Span(None, "sn.step.fence", it=start + n) as sp:
+                losses = np.asarray(losses)
+                sp.fence_value(losses[-1])
             # solver state is at the CHUNK END from here on: advance iter
             # BEFORE replaying the per-iteration hooks so a callback that
             # snapshots (the CLI's signal hook) or stops records iter and

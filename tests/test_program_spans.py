@@ -1,0 +1,361 @@
+"""The ``sn.*`` spans and ``S.*`` scopes the program carries on the
+profiler's clock (docs/OBSERVABILITY.md, "Spans on the profiler's clock").
+
+Everything runs on the CPU: a ``jax.profiler`` trace on the CPU backend
+holds the host annotations, and ``jax.profiler.ProfileData`` reads them
+back.  Two traces are taken once per module, through the front door's
+own functions (``cli.main`` with ``cmd_train`` swapped for the body, the
+way the benchmark drives it): two ``Solver.step`` chunks fed by a
+``DevicePrefetcher`` from a tiny ``db:`` feed, and two tau=2
+``ParallelTrainer.train_round`` calls on two virtual devices.
+"""
+
+import glob
+import json
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from sparknet_tpu import cli
+from sparknet_tpu.data import DeviceAugment, TransformConfig
+from sparknet_tpu.data.createdb import create_db
+from sparknet_tpu.data.device_transform import AUGMENT_SCOPE
+from sparknet_tpu.data.prefetch import DevicePrefetcher
+from sparknet_tpu.obs.recorder import Recorder, feed_counts, set_recorder
+from sparknet_tpu.parallel.mesh import data_parallel_mesh
+from sparknet_tpu.parallel.trainer import ParallelTrainer
+from sparknet_tpu.solvers.solver import UPDATE_SCOPE
+from sparknet_tpu.utils import profiling
+
+# ProfileData's stats mapping warns about a builtin type on this jax
+pytestmark = pytest.mark.filterwarnings(
+    "ignore:builtin type:DeprecationWarning")
+
+BATCH = 6
+RECORDS = 48  # 8 batches an epoch
+STEPS = 3  # per Solver.step chunk; two chunks are traced
+TAU, WORKERS = 2, 2
+
+NET = (
+    'name: "spans"\n'
+    'layer { name: "d" type: "Data" top: "data" top: "label"\n'
+    f'  data_param {{ source: "unused" batch_size: {BATCH} }}\n'
+    "  transform_param { crop_size: 12 mirror: true scale: 0.0039 } }\n"
+    'layer { name: "ip" type: "InnerProduct" bottom: "data" top: "ip"\n'
+    "  inner_product_param { num_output: 4 } }\n"
+    'layer { name: "loss" type: "SoftmaxWithLoss" bottom: "ip" '
+    'bottom: "label" top: "loss" }\n'
+)
+
+
+def read_spans(trace_dir):
+    """Every host event of the newest trace under ``trace_dir`` as
+    ``{name, thread, start, end, stats}`` (thread: the line's index)."""
+    path = sorted(glob.glob(os.path.join(trace_dir, "**", "*.xplane.pb"),
+                            recursive=True))[-1]
+    out = []
+    data = jax.profiler.ProfileData.from_file(path)
+    for plane in data.planes:
+        if not plane.name.startswith("/host:"):
+            continue
+        for thread, line in enumerate(plane.lines):
+            for ev in line.events:
+                out.append({"name": ev.name, "thread": thread,
+                            "start": ev.start_ns,
+                            "end": ev.start_ns + ev.duration_ns,
+                            "stats": dict(ev.stats)})
+    return sorted(out, key=lambda e: e["start"])
+
+
+def named(spans, name):
+    return [s for s in spans if s["name"] == name]
+
+
+def inside(inner, outer):
+    return (inner["thread"] == outer["thread"]
+            and outer["start"] <= inner["start"]
+            and inner["end"] <= outer["end"])
+
+
+def run_as_train(flags, body):
+    """``tpunet train <flags>`` with ``body(args)`` in cmd_train's place."""
+    orig = cli.cmd_train
+    cli.cmd_train = body
+    try:
+        assert cli.main(["train", *flags]) == 0
+    finally:
+        cli.cmd_train = orig
+
+
+@pytest.fixture(scope="module")
+def job(tmp_path_factory):
+    """The tiny job's files and flags: a RecordDB of uint8 16x16 records,
+    a net that crops them to 12x12 on the device."""
+    tmp = tmp_path_factory.mktemp("spans")
+    rs = np.random.RandomState(0)
+    db = str(tmp / "db")
+    create_db(db, [(rs.randint(0, 255, (3, 16, 16)).astype(np.uint8), i % 4)
+                   for i in range(RECORDS)])
+    (tmp / "net.prototxt").write_text(NET)
+    (tmp / "solver.prototxt").write_text(
+        f'net: "{tmp}/net.prototxt"\nbase_lr: 0.01\nmax_iter: 100\n'
+        "display: 0\n")
+    flags = ["--solver", str(tmp / "solver.prototxt"), "--data", f"db:{db}",
+             "--prefetch", "2", "--augment", "device"]
+    return tmp, flags
+
+
+def build(args):
+    net_param, solver_cfg = cli._build_net_and_solver(args)
+    solver = cli._make_solver(solver_cfg, net_param, args)
+    train_fn, _ = cli._data_fns(args, solver.train_net,
+                                test_net=solver.test_net)
+    return solver, train_fn
+
+
+@pytest.fixture(scope="module")
+def solo(job):
+    """(spans, HLO text of the train step) of two traced step chunks."""
+    tmp, flags = job
+    out = {}
+
+    def body(args):
+        solver, train_fn = build(args)
+        pf = DevicePrefetcher(train_fn, 1 << 30, depth=args.prefetch,
+                              start_iter=solver.iter,
+                              device_fn=train_fn.device_fn)
+        batches = iter(pf)
+        with pf:
+            solver.step(2, lambda it: next(batches))  # compile
+            with profiling.trace(str(tmp / "solo")):
+                for _ in range(2):
+                    solver.step(STEPS, lambda it: next(batches))
+            fn, variables, slots, key = solver.jitted_train_step(donate=False)
+            out["hlo"] = fn.lower(variables, slots, 0, next(batches),
+                                  key).as_text(debug_info=True)
+        return 0
+
+    run_as_train(flags, body)
+    return read_spans(str(tmp / "solo")), out["hlo"]
+
+
+@pytest.fixture(scope="module")
+def rounds(job):
+    """Spans of two traced tau=2 rounds over two virtual devices."""
+    tmp, flags = job
+
+    def body(args):
+        solver, train_fn = build(args)
+        trainer = ParallelTrainer(solver, mesh=data_parallel_mesh(WORKERS),
+                                  tau=args.tau)
+        trainer.feed_device_fn = train_fn.trainer_device_fn
+        tau_fn = cli._stack_tau(train_fn, args.tau,
+                                trainer.num_local_workers)
+        trainer.train_round(tau_fn)  # compile
+        with profiling.trace(str(tmp / "rounds")):
+            trainer.train_round(tau_fn)
+            trainer.train_round(tau_fn)
+        return 0
+
+    run_as_train([*flags, "--tau", str(TAU)], body)
+    return read_spans(str(tmp / "rounds"))
+
+
+# ------------------------------------------------------------ the span type
+@pytest.mark.parametrize("armed", [False, True])
+def test_a_span_annotates_armed_or_not_and_journals_only_armed(
+        tmp_path, armed):
+    journal = str(tmp_path / "journal.jsonl")
+    rec = set_recorder(Recorder(journal if armed else None, run_id="t"))
+    try:
+        with profiling.trace(str(tmp_path / "trace")):
+            with rec.span("sn.test.span", host=True, it=3, images=5, bytes=7):
+                pass
+    finally:
+        rec.close()
+        set_recorder(None)
+    (span,) = named(read_spans(str(tmp_path / "trace")), "sn.test.span")
+    assert span["stats"] == {"it": 3, "images": 5, "bytes": 7}
+    if not armed:
+        assert not os.path.exists(journal)
+        return
+    with open(journal) as f:
+        events = [json.loads(line) for line in f]
+    (ev,) = [e for e in events if e["event"] == "span"]
+    assert ev["name"] == "sn.test.span" and ev["host"] is True
+    assert ev["note"] == "it=3 images=5 bytes=7"
+
+
+def test_an_exception_inside_a_span_closes_its_annotation(tmp_path):
+    rec = Recorder(None)
+    with profiling.trace(str(tmp_path / "trace")):
+        with pytest.raises(RuntimeError):
+            with rec.span("sn.test.raises", host=True):
+                raise RuntimeError("inside")
+        with rec.span("sn.test.after", host=True):
+            pass
+    spans = read_spans(str(tmp_path / "trace"))
+    (raised,), (after,) = named(spans, "sn.test.raises"), named(spans, "sn.test.after")
+    # closed: it has an end, and the next span is beside it, not inside
+    assert raised["end"] >= raised["start"]
+    assert after["start"] >= raised["end"]
+
+
+@pytest.mark.parametrize("shape,lead,images", [
+    ((6, 3, 16, 16), 1, 6), ((2, 12, 3, 16, 16), 2, 24)])
+def test_feed_counts(shape, lead, images):
+    feeds = {"data": np.zeros(shape, np.uint8),
+             "label": np.zeros(shape[:lead], np.int32)}
+    assert feed_counts(feeds, lead) == {
+        "images": images,
+        "bytes": feeds["data"].nbytes + feeds["label"].nbytes}
+
+
+# ------------------------------------------------------- the solo step loop
+@pytest.mark.parametrize("name", [
+    "sn.feed.read", "sn.feed.decode", "sn.feed.collate", "sn.feed.put",
+    "sn.feed.augment", "sn.feed.wait", "sn.step", "sn.step.fence"])
+def test_the_solo_trace_holds(solo, name):
+    spans, _ = solo
+    assert named(spans, name), sorted({s["name"] for s in spans})
+
+
+def test_read_contains_decode_and_collate(solo):
+    spans, _ = solo
+    reads = named(spans, "sn.feed.read")
+    for kind in ("sn.feed.decode", "sn.feed.collate"):
+        parts = named(spans, kind)
+        assert parts and all(
+            any(inside(p, r) for r in reads) for p in parts)
+    for r in reads:  # each read holds one collate and its decode
+        assert sum(inside(c, r) for c in named(spans, "sn.feed.collate")) == 1
+        assert sum(inside(d, r) for d in named(spans, "sn.feed.decode")) >= 1
+
+
+def test_the_feed_works_on_another_thread_than_the_wait(solo):
+    spans, _ = solo
+    (main,) = {s["thread"] for s in named(spans, "sn.feed.wait")}
+    assert {s["thread"] for s in named(spans, "sn.step")} == {main}
+    assert {s["thread"] for s in named(spans, "sn.step.fence")} == {main}
+    for name in ("sn.feed.read", "sn.feed.put", "sn.feed.augment"):
+        threads = {s["thread"] for s in named(spans, name)}
+        assert len(threads) == 1 and main not in threads
+
+
+def test_one_read_per_batch_and_none_per_record(solo):
+    spans, _ = solo
+    waits, reads = named(spans, "sn.feed.wait"), named(spans, "sn.feed.read")
+    assert len(waits) == len(named(spans, "sn.step")) == 2 * STEPS
+    # the feed runs at most its queue depth (+ the batch in hand) ahead
+    assert 2 * STEPS - 3 <= len(reads) <= 2 * STEPS + 3
+    for r in reads:
+        assert r["stats"]["images"] == BATCH and "it" in r["stats"]
+    its = [r["stats"]["it"] for r in reads]
+    assert its == list(range(its[0], its[0] + len(its)))  # one a batch
+    for p in named(spans, "sn.feed.put"):
+        assert p["stats"]["images"] == BATCH
+        assert p["stats"]["bytes"] == BATCH * (3 * 16 * 16 + 4)
+    # nothing per record: no sn.* name occurs more than ~once a batch
+    for name in {s["name"] for s in spans if s["name"].startswith("sn.")}:
+        assert len(named(spans, name)) <= 2 * len(reads) + 2, name
+
+
+def test_a_step_waits_inside_itself_and_fences_outside(solo):
+    spans, _ = solo
+    steps = named(spans, "sn.step")
+    assert all(any(inside(w, s) for s in steps)
+               for w in named(spans, "sn.feed.wait"))
+    fences = named(spans, "sn.step.fence")
+    assert len(fences) == 2  # one per Solver.step call (no callback)
+    assert not any(inside(f, s) for f in fences for s in steps)
+    assert [s["stats"]["step_num"] for s in steps] == list(
+        range(steps[0]["stats"]["step_num"],
+              steps[0]["stats"]["step_num"] + 2 * STEPS))
+
+
+# ---------------------------------------------------------- the tau round
+@pytest.mark.parametrize("name", [
+    "sn.round", "sn.round.data", "sn.feed.read", "sn.feed.stack",
+    "sn.feed.put", "sn.feed.augment", "sn.round.dispatch", "sn.round.fence"])
+def test_the_round_trace_holds(rounds, name):
+    assert named(rounds, name), sorted({s["name"] for s in rounds})
+
+
+def test_a_round_is_data_put_augment_dispatch_fence_in_that_order(rounds):
+    order = ["sn.round.data", "sn.feed.put", "sn.feed.augment",
+             "sn.round.dispatch", "sn.round.fence"]
+    outer = named(rounds, "sn.round")
+    assert len(outer) == 2
+    assert len({s["thread"] for s in rounds
+                if s["name"].startswith("sn.")}) == 1  # all on one thread
+    for rnd in outer:
+        stages = [next(s for s in named(rounds, n) if inside(s, rnd))
+                  for n in order]
+        for a, b in zip(stages, stages[1:]):
+            assert a["end"] <= b["start"], (a["name"], b["name"])
+        data = stages[0]
+        stacks = [s for s in named(rounds, "sn.feed.stack")
+                  if inside(s, data)]
+        reads = [r for r in named(rounds, "sn.feed.read") if inside(r, data)]
+        assert len(reads) == TAU * WORKERS
+        # one concatenate a slot, after that slot's reads, then the stack
+        assert len(stacks) == TAU + 1
+        for t, stack in enumerate(stacks[:TAU]):
+            mine = reads[t * WORKERS:(t + 1) * WORKERS]
+            assert all(r["end"] <= stack["start"] for r in mine)
+            assert stack["stats"]["images"] == WORKERS * BATCH
+        images = TAU * WORKERS * BATCH
+        nbytes = images * (3 * 16 * 16 + 4)
+        # every image counted once: the final np.stack carries bytes only
+        assert sum(s["stats"].get("images", 0) for s in stacks) == images
+        assert stacks[-1]["stats"] == {"it": rnd["stats"]["step_num"],
+                                       "bytes": nbytes}
+        assert stages[1]["stats"]["images"] == images
+        assert stages[1]["stats"]["bytes"] == nbytes
+    assert outer[1]["stats"]["step_num"] == outer[0]["stats"]["step_num"] + TAU
+
+
+# ------------------------------------------------------------ names, scopes
+def test_no_span_is_a_benchmark_span_and_no_scope_a_layer(solo, rounds):
+    names = {s["name"] for s in solo[0] + rounds}
+    assert not [n for n in names if n.startswith("bench.")]
+    assert {n.split(".")[0] for n in names if "." in n} >= {"sn"}
+    for scope in (UPDATE_SCOPE, AUGMENT_SCOPE):
+        assert scope.startswith("S.") and not scope.startswith("L.")
+
+
+def test_the_compile_cache_key_names_the_scopes(monkeypatch):
+    """A cached executable carries its source's scope names and jax's key
+    strips them: the program adds them, so a checkout with other scopes
+    on a shared cache is never served this one's executables."""
+    from jax._src import cache_key
+
+    from sparknet_tpu import common
+
+    monkeypatch.setattr(cache_key, "custom_hook", lambda: "")
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/somewhere/placed")
+    common.enable_compile_cache()
+    assert cache_key.custom_hook() == common.CACHE_SCOPES
+    for scope in ("L.", UPDATE_SCOPE, AUGMENT_SCOPE):
+        assert scope in common.CACHE_SCOPES
+
+
+def test_the_train_step_names_its_update(solo):
+    _, hlo = solo
+    assert UPDATE_SCOPE in hlo
+    assert "L.ip" in hlo  # the layers keep theirs
+
+
+@pytest.mark.parametrize("rank", [4, 5])
+def test_the_jitted_augment_names_itself(rank):
+    aug = DeviceAugment(TransformConfig(crop_size=12, mirror=True))
+    x = jnp.zeros((2, BATCH, 3, 16, 16)[5 - rank:], jnp.uint8)
+    if rank == 4:
+        fn = jax.jit(lambda x, k: aug(x, k))
+    else:
+        fn = jax.jit(lambda x, k: jax.vmap(aug)(x, jax.random.split(k, 2)))
+    assert AUGMENT_SCOPE in fn.lower(
+        x, jax.random.key(0)).as_text(debug_info=True)
