@@ -174,21 +174,35 @@ def _internalize(fn):
     (whose DeviceAugment already speaks the internal layout) and
     ``pipeline_factory`` (whose sources produce the internal layout
     NATIVELY — the process feed never pays this per-batch transpose,
-    which is the wire half of the nhwc zero-transpose contract)."""
-    from sparknet_tpu.ops.layout import feeds_to_internal, is_nhwc
+    which is the wire half of the nhwc zero-transpose contract).  A
+    destination (``takes_out``) is internal too: the cursor is handed
+    its canonical view and fills it through the strides."""
+    from sparknet_tpu.ops.layout import (
+        feeds_to_internal,
+        from_internal,
+        is_nhwc,
+    )
 
     if fn is None or not is_nhwc():
         return fn
 
-    def wrapped(it):
-        return feeds_to_internal(fn(it))
+    def wrapped(it, out=None):
+        if out is None:
+            return feeds_to_internal(fn(it))
+        return feeds_to_internal(fn(it, out={
+            k: from_internal(v, "nhwc") for k, v in out.items()}))
 
     return _carry_feed_attrs(fn, wrapped)
 
 
 def _carry_feed_attrs(fn, wrapped):
-    """The hooks a train data fn carries, kept across a wrapper."""
-    for attr in ("device_fn", "trainer_device_fn", "pipeline_factory"):
+    """The hooks a train data fn carries, kept across a wrapper.
+    ``takes_out``: the fn is ``fn(it, out=None)`` and writes the batch
+    into the arrays of ``out`` (one per feed key, ``[batch, ...]``) when
+    it can, so the consumer that owns them can reuse them; the batch it
+    returns says where the data landed."""
+    for attr in ("device_fn", "trainer_device_fn", "pipeline_factory",
+                 "takes_out"):
         if hasattr(fn, attr):
             setattr(wrapped, attr, getattr(fn, attr))
     return wrapped
@@ -198,10 +212,16 @@ def _read_span(fn, images):
     """``sn.feed.read`` around a host data fn: one span per host batch,
     from the cursor to the decoded, collated, cast and internalized
     batch, on whichever thread asks for it (the DevicePrefetcher's feed
-    thread in the solo loop, the main thread inside ``_stack_tau``)."""
-    def wrapped(it):
-        with _host_span("sn.feed.read", it=it, images=images):
-            return fn(it)
+    thread in the solo loop, the main thread inside ``_stack_tau``).
+    ``alloc_bytes``: what of the batch lies in newly allocated arrays,
+    0 when it all went into the caller's ``out``."""
+    from sparknet_tpu.data.prefetch import fresh_bytes
+
+    def wrapped(it, out=None):
+        with _host_span("sn.feed.read", it=it, images=images) as span:
+            feeds = fn(it, out=out)
+            span.set(alloc_bytes=fresh_bytes(feeds, out))
+            return feeds
 
     return _carry_feed_attrs(fn, wrapped)
 
@@ -578,7 +598,7 @@ def _data_fns(args, net, test_net=None):
                 except ValueError as e:  # e.g. mean_image AND mean_value
                     raise SystemExit(f"transform_param: {e}") from None
 
-            def fn(_):
+            def fn(_, out=None):
                 if "iter" not in state:
                     try:
                         state["iter"] = db_minibatches(
@@ -593,7 +613,9 @@ def _data_fns(args, net, test_net=None):
                 else:
                     for _ in range(stride - 1):
                         next(state["iter"])
-                    b = next(state["iter"])
+                    # the cursor fills ``out`` (see ``takes_out``); its
+                    # first batch, above, is always a fresh array
+                    b = state["iter"].send(out)
                 if xform is not None:
                     try:
                         b = dict(b, data=xform(b["data"], train))
@@ -634,6 +656,9 @@ def _data_fns(args, net, test_net=None):
                         )
                 return b
 
+            # the batch is the cursor's own array unless a host transform
+            # or scale makes a new one from it
+            fn.takes_out = xform is None and (raw or p["scale"] == 1.0)
             return fn
 
         train_fn = db_stream(train_path,
@@ -840,10 +865,11 @@ def _process_feed(train_fn, num_batches, start_index, args, log,
         it = iter(pf)
         fn = lambda _it: next(it)  # noqa: E731 — the solver feed contract
     else:
-        # trainer feeds stay host-side; _stack_tau/_widen_batch hold
-        # tau*workers batches before concatenating, which outlives the
-        # ring's view-lifetime window — they need stable copies (cheap:
-        # the wire is uint8 under --augment device)
+        # trainer feeds stay host-side; _stack_tau/_widen_batch hold a
+        # slot's batches (one per worker) before copying them into their
+        # buffer, which outlives the ring's view-lifetime window — they
+        # need stable copies (cheap: the wire is uint8 under --augment
+        # device)
         fn = pipe.as_data_fn(copy=True)
     log(f"feed: process pipeline ({pipe.workers} worker(s), "
         f"{pipe.slots} slots x {pipe.spec.slot_bytes:,} B"
@@ -966,8 +992,9 @@ def cmd_train(args) -> int:
                     outer * max(args.tau, 1) * trainer.num_local_workers,
                     0, args, log, device_stage=False)
             tau_fn = _stack_tau(train_fn, args.tau, trainer.num_local_workers)
-            wide_fn = _widen_batch(train_fn, trainer.num_local_workers)
             scan_n = max(getattr(args, "scan", 1), 1)
+            wide_fn = _widen_batch(train_fn, trainer.num_local_workers,
+                                   keep=scan_n)
             with feed_ctx, SignalHandler() as sig:
                 o = 0
                 while o < outer:
@@ -1057,52 +1084,105 @@ def cmd_train(args) -> int:
     return 0
 
 
-def _sum_counts(parts):
-    """``images``/``bytes`` over a list of host batches (span counts)."""
-    from sparknet_tpu.obs.recorder import feed_counts
+class _RoundBuffer:
+    """ONE persistent host array ``[slots, workers * B, ...]`` per feed
+    key, filled one per-worker batch at a time: batch (t, w) goes to the
+    contiguous view ``buf[t, w*B:(w+1)*B]``.  A ``takes_out`` data fn is
+    handed that view and writes its records straight into it; any other
+    batch is copied there.  Nothing is concatenated or stacked.  The
+    arrays are made on the first read, from the first batch's shapes, and
+    live as long as the buffer's owner (``_stack_tau`` / ``_widen_batch``).
 
-    counts = [feed_counts(p) for p in parts]
-    return {k: sum(c[k] for c in counts) for k in ("images", "bytes")}
+    ``sn.feed.stack``, one per slot after the slot's reads, times what is
+    left of the pack's own work and counts the slot's images;
+    ``alloc_bytes`` is the buffer itself in the first one and 0 after."""
+
+    def __init__(self, train_fn, slots, workers):
+        self._fn, self._slots, self._workers = train_fn, slots, workers
+        self._takes_out = getattr(train_fn, "takes_out", False)
+        self.arrays: dict = {}
+        self._batch = 0  # B, known with the first batch
+        self._strays: list = []  # (views, batch) that missed their views
+        self._alloc = 0
+
+    def read(self, index, t, w):
+        """Batch ``index`` of the data fn into cell (t, w)."""
+        from sparknet_tpu.data.prefetch import fresh_bytes
+
+        views = self._views(t, w)
+        got = (self._fn(index, out=views) if views and self._takes_out
+               else self._fn(index))
+        if not views:
+            self._batch = len(next(iter(got.values())))
+            self.arrays = {
+                k: np.empty((self._slots, self._workers * self._batch,
+                             *v.shape[1:]), v.dtype)
+                for k, v in got.items()}
+            self._alloc = sum(a.nbytes for a in self.arrays.values())
+            views = self._views(t, w)
+        if fresh_bytes(got, views):
+            self._strays.append((views, got))
+
+    def _views(self, t, w):
+        lo = w * self._batch
+        return {k: a[t, lo:lo + self._batch] for k, a in self.arrays.items()}
+
+    def slot(self, it, t):
+        """Slot ``t``, whole: ``{key: [workers * B, ...]}``."""
+        from sparknet_tpu.obs.recorder import feed_counts
+
+        feeds = {k: a[t] for k, a in self.arrays.items()}
+        with _host_span("sn.feed.stack", it=it, alloc_bytes=self._alloc,
+                        **feed_counts(feeds)):
+            for views, got in self._strays:
+                for k, v in got.items():
+                    views[k][...] = v
+        self._strays, self._alloc = [], 0
+        return feeds
 
 
 def _stack_tau(train_fn, tau, num_workers):
     """[tau, B*workers, ...] feeds: the net batch is per-worker; each tau
-    slot concatenates one batch per worker (the global minibatch).  Owns
-    its own batch counter: each round consumes tau*num_workers fresh
-    batches regardless of how the trainer advances its iteration count."""
+    slot holds one batch per worker side by side (the global minibatch).
+    Owns its own batch counter: each round consumes tau*num_workers fresh
+    batches regardless of how the trainer advances its iteration count.
+
+    The arrays returned are ONE persistent buffer per feed key
+    (``_RoundBuffer``) that the next call overwrites: they are valid
+    until then.  ``ParallelTrainer.train_round`` fences on
+    ``float(loss)`` before it asks again, so the transfer and the round
+    that read them are done."""
+    buf = _RoundBuffer(train_fn, tau, num_workers)
     counter = [0]
 
     def fn(it):
-        # sn.feed.stack: the pack's own copies, apart from its reads.
-        # Every image is counted once, on its slot's concatenate; the
-        # round's final np.stack carries bytes only
-        slots = []
-        for _ in range(tau):
-            parts = []
-            for _ in range(num_workers):
-                parts.append(train_fn(counter[0]))
+        for t in range(tau):
+            for w in range(num_workers):
+                buf.read(counter[0], t, w)
                 counter[0] += 1
-            with _host_span("sn.feed.stack", it=it, **_sum_counts(parts)):
-                slots.append({key: np.concatenate([p[key] for p in parts])
-                              for key in parts[0]})
-        with _host_span("sn.feed.stack", it=it,
-                        bytes=_sum_counts(slots)["bytes"]):
-            return {key: np.stack([s[key] for s in slots])
-                    for key in slots[0]}
+            buf.slot(it, t)
+        return dict(buf.arrays)
 
     return fn
 
 
-def _widen_batch(train_fn, num_workers):
-    """tau=1 global batch: concatenate one per-worker batch per worker."""
+def _widen_batch(train_fn, num_workers, keep=1):
+    """tau=1 global batch ``[B*workers, ...]``: one per-worker batch per
+    worker, side by side in a slot of a ``_RoundBuffer``.  A batch is
+    valid until ``keep`` calls later (``train_round`` fences before it
+    asks again; ``train_rounds`` holds a scan chunk's worth until it has
+    stacked them)."""
     if num_workers == 1:
         return train_fn
+    buf = _RoundBuffer(train_fn, keep, num_workers)
+    calls = [0]
 
     def fn(it):
-        parts = [train_fn(it * num_workers + w) for w in range(num_workers)]
-        with _host_span("sn.feed.stack", it=it, **_sum_counts(parts)):
-            return {key: np.concatenate([p[key] for p in parts])
-                    for key in parts[0]}
+        t = calls[0] % keep
+        calls[0] += 1
+        for w in range(num_workers):
+            buf.read(it * num_workers + w, t, w)
+        return buf.slot(it, t)
 
     return fn
 

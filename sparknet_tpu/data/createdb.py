@@ -205,7 +205,16 @@ def db_minibatches(
     batch too (stats passes — compute_image_mean must see every record).
     ``loop=True`` restarts the cursor each epoch (the DataLayer's rewind).
     ``dtype=np.uint8`` hands back raw pixels (skip the float cast when a
-    transformer will cast anyway)."""
+    transformer will cast anyway).
+
+    Each record is copied ONCE, from the DB's storage to its row of the
+    batch, the cast riding on that assignment.  ``next(gen)`` allocates
+    the batch and its caller owns it.  ``gen.send(out)`` hands the cursor
+    a destination instead: ``out["data"]`` (``[batch_size, C, H, W]``)
+    and ``out["label"]`` (``[batch_size]``), of any dtype, are filled in
+    place and come back as the batch (their first rows for a final short
+    one), and nothing batch-sized is allocated.  A generator takes no
+    ``send`` before its first ``next``."""
     db, decode = _open_reader(path)
     with db:
         if loop and (
@@ -215,34 +224,61 @@ def db_minibatches(
                 f"db holds {len(db)} records < batch_size {batch_size}; "
                 "loop=True would spin forever yielding nothing"
             )
+        # RecordDB: views of its storage; LMDB/LevelDB values are bytes
+        records = getattr(db, "views", db.__iter__)
         n = 0  # the cursor's own batch index: the spans' ``it``
+        out = None  # where the next batch goes; None: a fresh array
 
-        def collate(imgs, labels):
+        def fill(cursor):
+            """Up to ``batch_size`` records, each copied once into its row
+            of ``out["data"]`` (a fresh batch's without one); (data,
+            labels)."""
+            data = out["data"] if out else None
+            if out and not len(data) == len(out["label"]) == batch_size:
+                raise ValueError(
+                    f"destination holds {len(data)} images and "
+                    f"{len(out['label'])} labels, the batch is {batch_size}")
+            labels = []
+            for _, value in itertools.islice(cursor, batch_size):
+                img, label = decode(value)
+                if data is None:
+                    data = np.empty((batch_size, *img.shape), dtype)
+                elif img.shape != data.shape[1:]:
+                    raise ValueError(
+                        f"record {len(labels)} of batch {n} is {img.shape}, "
+                        f"the batch holds {data.shape[1:]}")
+                data[len(labels)] = img
+                labels.append(label)
+            return data, labels
+
+        def collate(data, labels):
+            count = len(labels)
             with get_recorder().span("sn.feed.collate", host=True, it=n,
-                                     images=len(imgs)):
-                return {
-                    "data": np.stack(imgs).astype(dtype),
-                    "label": np.asarray(labels, np.int32),
-                }
+                                     images=count):
+                if out:
+                    out["label"][:count] = labels
+                    labels = out["label"]
+                else:
+                    labels = np.asarray(labels, np.int32)
+                if count < batch_size:  # the final short batch
+                    data, labels = data[:count], labels[:count]
+                return {"data": data, "label": labels}
 
         while True:
-            cursor = iter(db)
+            cursor = records()
             while True:
-                # one span per batch around the per-record loop, closed
-                # before the yield: a span never stays open across one
+                # one span per batch around the per-record loop and its
+                # one copy, closed before the yield: a span never stays
+                # open across one
                 with get_recorder().span("sn.feed.decode", host=True,
                                          it=n, images=batch_size):
-                    imgs, labels = [], []
-                    for _, value in itertools.islice(cursor, batch_size):
-                        img, label = decode(value)
-                        imgs.append(img)
-                        labels.append(label)
-                if len(imgs) < batch_size:
+                    data, labels = fill(cursor)
+                if len(labels) < batch_size:
                     break
-                yield collate(imgs, labels)
+                out = yield collate(data, labels)
                 n += 1
-            if imgs and not drop_remainder:
-                yield collate(imgs, labels)
+            if labels and not drop_remainder:
+                out = yield collate(data, labels)
                 n += 1
             if not loop:
                 return

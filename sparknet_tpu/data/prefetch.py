@@ -9,6 +9,16 @@ transform AND ``jax.device_put`` so transfer overlaps the previous step's
 compute; the consumer pops device-resident arrays.  Queue depth defaults
 to the reference's 3.
 
+A data fn that takes a destination (``takes_out``: ``data_fn(it, out=)``,
+the ``db:`` cursor) is handed one of ``RING`` host batches the prefetcher
+owns and refills, so the feed allocates nothing batch-sized per step.
+jax keeps the numpy source of a ``device_put`` immutable until the
+transfer completes, so a slot is refilled only once the device arrays
+placed from it are ready; with two slots that wait falls a whole read
+after the put and costs nothing.  Where ``device_put`` may alias host
+memory (the CPU backend), a placed batch IS its source for as long as it
+lives: there every batch stays a fresh array.
+
 The reference's ``InternalThread`` clones RNG/mode state into the child
 (ref: caffe/src/caffe/util/internal_thread.cpp:28-49); here the data_fn
 closure owns its own seeded numpy RandomState, so the thread needs no
@@ -22,11 +32,44 @@ import threading
 from typing import Any, Callable
 
 import jax
+import numpy as np
 
 from sparknet_tpu.obs import get_recorder
 from sparknet_tpu.obs.recorder import feed_counts
 
 PREFETCH_COUNT = 3
+RING = 2  # host batches a takes_out data fn is handed in turn
+
+
+def fresh_bytes(feeds: dict, out: dict | None) -> int:
+    """Bytes of the host batch ``feeds`` that lie outside the destination
+    ``out`` it was read with: what the read had to allocate."""
+    return sum(
+        int(getattr(v, "nbytes", 0)) for k, v in feeds.items()
+        if out is None or not np.may_share_memory(v, out[k]))
+
+
+def _empty_like(feeds: dict) -> dict:
+    return {k: np.empty_like(v) for k, v in feeds.items()}
+
+
+def _aliases_host(placed) -> bool:
+    """True where a ``device_put`` may hand back the host array's own
+    memory: the CPU backend, told by the placed arrays' platform."""
+    return any(d.platform == "cpu"
+               for x in jax.tree_util.tree_leaves(placed)
+               for d in x.devices())
+
+
+def _transferred(placed) -> bool:
+    """Wait until the arrays placed from a slot, a whole read ago, are
+    ready.  False where the consumer deleted one meanwhile: its transfer
+    cannot be awaited, so the slot's memory is left to it."""
+    try:
+        jax.block_until_ready(placed)
+    except RuntimeError:
+        return False
+    return True
 
 
 class DevicePrefetcher:
@@ -64,19 +107,39 @@ class DevicePrefetcher:
         # fn brings its own sn.feed.read) time the HOST side of each
         # stage: the transfer and the augment are dispatched, not awaited
         try:
+            # the ring: host batches and what was last placed from each.
+            # None until the first batch (read into a fresh array) has
+            # told the shapes and where device_put lands; stays None for
+            # a data fn without ``takes_out`` and on an aliasing backend
+            ring: list[dict] | None = None
+            placed: list = [None] * RING
             for it in range(self._start, self._start + self._num):
                 if self._stop.is_set():
                     return
-                feeds = self._data_fn(it)
+                slot = (it - self._start) % RING
+                if ring is None:
+                    host = self._data_fn(it)
+                else:
+                    if not _transferred(placed[slot]):
+                        ring[slot] = _empty_like(ring[slot])
+                    placed[slot] = None
+                    host = self._data_fn(it, out=ring[slot])
                 with get_recorder().span("sn.feed.put", host=True, it=it,
-                                         **feed_counts(feeds)):
+                                         **feed_counts(host)):
                     if self._sharding is not None:
                         feeds = {
                             k: jax.device_put(v, self._sharding)
-                            for k, v in feeds.items()
+                            for k, v in host.items()
                         }
                     else:
-                        feeds = jax.device_put(feeds)
+                        feeds = jax.device_put(host)
+                if ring is not None:
+                    placed[slot] = feeds
+                elif (it == self._start
+                        and getattr(self._data_fn, "takes_out", False)
+                        and not _aliases_host(feeds)):
+                    ring = [_empty_like(host) for _ in range(RING)]
+                del host
                 if self._device_fn is not None:
                     with get_recorder().span("sn.feed.augment", host=True,
                                              it=it):

@@ -166,6 +166,16 @@ class RecordDB:
         return int(self._lib.sndb_count(self._h))
 
     def __iter__(self):
+        for key, value in self.views():
+            yield key, bytes(value)
+
+    def views(self):
+        """The cursor without the copy of the value: ``(key, value)``
+        with ``value`` a read-only memoryview of the handle's own storage
+        (``sndb_next`` points into it), valid until the handle closes.
+        For a reader that copies each record somewhere itself
+        (``createdb.db_minibatches``); ``iter(db)`` hands out ``bytes``
+        that outlive the handle."""
         cur = self._lib.sndb_cursor(self._h)
         if not cur:
             raise OSError("cursors require a read-mode handle")
@@ -177,9 +187,10 @@ class RecordDB:
             while self._lib.sndb_next(
                 cur, ctypes.byref(k), ctypes.byref(kl), ctypes.byref(v), ctypes.byref(vl)
             ):
+                value = (ctypes.c_ubyte * vl.value).from_address(v.value or 0)
                 yield (
                     ctypes.string_at(k, kl.value),
-                    ctypes.string_at(v, vl.value),
+                    memoryview(value).toreadonly(),
                 )
         finally:
             self._lib.sndb_cursor_free(cur)

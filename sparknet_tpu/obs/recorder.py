@@ -87,8 +87,9 @@ class Span:
     On the profiler's clock, always: a ``TraceAnnotation(name,
     **counts)`` is open for as long as the span is (``counts``: ``it``,
     the batch or round index the spans of one batch share, and
-    ``images``/``bytes`` where the work has a size).  One span per batch
-    or round — never per record, layer or parameter leaf.
+    ``images``/``bytes`` where the work has a size; :meth:`set` adds
+    what only the work itself can tell).  One span per batch or round —
+    never per record, layer or parameter leaf.
 
     In the journal, only when the Recorder is armed: close device-work
     spans with :meth:`fence` (or :meth:`fence_value` when the caller
@@ -113,6 +114,12 @@ class Span:
         self._ann.__enter__()
         self._t0 = time.perf_counter()
         return self
+
+    def set(self, **counts) -> None:
+        """Counts known only once the work is done (``alloc_bytes``: what
+        a read had to allocate), added to the open span on both clocks."""
+        self.counts.update(counts)
+        self._ann.set_metadata(**counts)
 
     def fence(self, out) -> float | None:
         """Fence-stamp this span on the VALUE of ``out`` (the enclosed

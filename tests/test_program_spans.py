@@ -172,13 +172,15 @@ def test_a_span_annotates_armed_or_not_and_journals_only_armed(
     rec = set_recorder(Recorder(journal if armed else None, run_id="t"))
     try:
         with profiling.trace(str(tmp_path / "trace")):
-            with rec.span("sn.test.span", host=True, it=3, images=5, bytes=7):
-                pass
+            with rec.span("sn.test.span", host=True, it=3, images=5,
+                          bytes=7) as span:
+                span.set(alloc_bytes=9)  # known only once the work is done
     finally:
         rec.close()
         set_recorder(None)
     (span,) = named(read_spans(str(tmp_path / "trace")), "sn.test.span")
-    assert span["stats"] == {"it": 3, "images": 5, "bytes": 7}
+    assert span["stats"] == {"it": 3, "images": 5, "bytes": 7,
+                             "alloc_bytes": 9}
     if not armed:
         assert not os.path.exists(journal)
         return
@@ -186,7 +188,7 @@ def test_a_span_annotates_armed_or_not_and_journals_only_armed(
         events = [json.loads(line) for line in f]
     (ev,) = [e for e in events if e["event"] == "span"]
     assert ev["name"] == "sn.test.span" and ev["host"] is True
-    assert ev["note"] == "it=3 images=5 bytes=7"
+    assert ev["note"] == "it=3 images=5 bytes=7 alloc_bytes=9"
 
 
 def test_an_exception_inside_a_span_closes_its_annotation(tmp_path):
@@ -226,13 +228,22 @@ def test_the_solo_trace_holds(solo, name):
 def test_read_contains_decode_and_collate(solo):
     spans, _ = solo
     reads = named(spans, "sn.feed.read")
+    # the profiler drops a span already open when the session starts: the
+    # feed thread may be inside a read then, whose parts it still records
+    seen_from = min(r["start"] for r in reads)
     for kind in ("sn.feed.decode", "sn.feed.collate"):
-        parts = named(spans, kind)
+        parts = [p for p in named(spans, kind) if p["start"] >= seen_from]
         assert parts and all(
             any(inside(p, r) for r in reads) for p in parts)
-    for r in reads:  # each read holds one collate and its decode
-        assert sum(inside(c, r) for c in named(spans, "sn.feed.collate")) == 1
-        assert sum(inside(d, r) for d in named(spans, "sn.feed.decode")) >= 1
+    for r in reads:
+        # each read holds one collate (the hand-over) after its decodes
+        # (the per-record loop with its one copy; an epoch's end adds an
+        # empty one), and every record of the batch is counted once
+        (collate,) = [c for c in named(spans, "sn.feed.collate")
+                      if inside(c, r)]
+        decodes = [d for d in named(spans, "sn.feed.decode") if inside(d, r)]
+        assert decodes and all(d["end"] <= collate["start"] for d in decodes)
+        assert collate["stats"]["images"] == BATCH
 
 
 def test_the_feed_works_on_another_thread_than_the_wait(solo):
@@ -253,6 +264,9 @@ def test_one_read_per_batch_and_none_per_record(solo):
     assert 2 * STEPS - 3 <= len(reads) <= 2 * STEPS + 3
     for r in reads:
         assert r["stats"]["images"] == BATCH and "it" in r["stats"]
+        # on the CPU a placed batch may BE its host array, so the
+        # prefetcher hands out no ring slot: every batch is a fresh one
+        assert r["stats"]["alloc_bytes"] == BATCH * (3 * 16 * 16 + 4)
     its = [r["stats"]["it"] for r in reads]
     assert its == list(range(its[0], its[0] + len(its)))  # one a batch
     for p in named(spans, "sn.feed.put"):
@@ -301,18 +315,19 @@ def test_a_round_is_data_put_augment_dispatch_fence_in_that_order(rounds):
                   if inside(s, data)]
         reads = [r for r in named(rounds, "sn.feed.read") if inside(r, data)]
         assert len(reads) == TAU * WORKERS
-        # one concatenate a slot, after that slot's reads, then the stack
-        assert len(stacks) == TAU + 1
-        for t, stack in enumerate(stacks[:TAU]):
-            mine = reads[t * WORKERS:(t + 1) * WORKERS]
-            assert all(r["end"] <= stack["start"] for r in mine)
-            assert stack["stats"]["images"] == WORKERS * BATCH
+        # one span a slot, after that slot's reads, around what is left
+        # of the pack's own work: the reads already filled the buffer
+        assert len(stacks) == TAU
         images = TAU * WORKERS * BATCH
         nbytes = images * (3 * 16 * 16 + 4)
-        # every image counted once: the final np.stack carries bytes only
-        assert sum(s["stats"].get("images", 0) for s in stacks) == images
-        assert stacks[-1]["stats"] == {"it": rnd["stats"]["step_num"],
-                                       "bytes": nbytes}
+        for t, stack in enumerate(stacks):
+            mine = reads[t * WORKERS:(t + 1) * WORKERS]
+            assert all(r["end"] <= stack["start"] for r in mine)
+            # the buffer was made in the warm-up round: nothing allocated
+            assert stack["stats"] == {
+                "it": rnd["stats"]["step_num"], "images": WORKERS * BATCH,
+                "bytes": nbytes // TAU, "alloc_bytes": 0}
+        assert all(r["stats"]["alloc_bytes"] == 0 for r in reads)
         assert stages[1]["stats"]["images"] == images
         assert stages[1]["stats"]["bytes"] == nbytes
     assert outer[1]["stats"]["step_num"] == outer[0]["stats"]["step_num"] + TAU
