@@ -9,6 +9,10 @@ Data sources (the reference's in-net LMDB layers are host-plane inputs
 here): ``--data cifar:<dir>`` reads real CIFAR-10 binaries;
 ``--data db:<path>[,<test_path>]`` streams a record DB or Caffe LMDB
 (``{proc}`` expands to the process id — the per-worker-DB layout);
+``--data tokens:<file>[,<test_file>]`` feeds a language model windows of
+a flat ``uint16`` token file (the OLMo / Megatron on-disk convention;
+``data/text.py token_windows``): ``data`` and next-token ``label``
+``[batch, seq_len]``, through the same destination-passing feed as ``db:``;
 ``--data synthetic`` generates pixel-scale random batches (enough for
 ``time``/smoke runs, like ``caffe time``'s dummy forward/backward).
 """
@@ -208,17 +212,20 @@ def _carry_feed_attrs(fn, wrapped):
     return wrapped
 
 
-def _read_span(fn, images):
+def _read_span(fn, images, **counts):
     """``sn.feed.read`` around a host data fn: one span per host batch,
     from the cursor to the decoded, collated, cast and internalized
     batch, on whichever thread asks for it (the DevicePrefetcher's feed
     thread in the solo loop, the main thread inside ``_stack_tau``).
     ``alloc_bytes``: what of the batch lies in newly allocated arrays,
-    0 when it all went into the caller's ``out``."""
+    0 when it all went into the caller's ``out``.  ``images`` counts the
+    batch's records (sequences for a ``tokens:`` source, whose ``counts``
+    add ``tokens``)."""
     from sparknet_tpu.data.prefetch import fresh_bytes
 
     def wrapped(it, out=None):
-        with _host_span("sn.feed.read", it=it, images=images) as span:
+        with _host_span("sn.feed.read", it=it, images=images,
+                        **counts) as span:
             feeds = fn(it, out=out)
             span.set(alloc_bytes=fresh_bytes(feeds, out))
             return feeds
@@ -742,6 +749,32 @@ def _data_fns(args, net, test_net=None):
             train_fn.pipeline_factory = _db_pipeline_factory
         return (_read_span(_internalize(train_fn), batch),
                 _internalize(db_stream(test_path, train=False)))
+
+    if args.data.startswith("tokens:"):
+        # language-model training from a tokenised corpus: one flat
+        # uint16 token file (data/text.py token_windows), windows of
+        # seq_len + 1 -> data / label [batch, seq_len].
+        # "tokens:train[,test]"; {proc} and the shared-file batch
+        # interleave as for db:
+        from sparknet_tpu.data.text import token_windows
+
+        if len(data_shape) != 2:
+            raise SystemExit(
+                f"--data tokens: feeds a [batch, seq_len] data blob; the "
+                f"net's is {tuple(data_shape)}")
+        paths = args.data[7:].split(",")
+        shared = "{proc}" not in paths[0] and nproc > 1
+        try:
+            train_fn = token_windows(
+                paths[0].replace("{proc}", str(pid)), batch, data_shape[1],
+                stride=nproc if shared else 1, offset=pid if shared else 0)
+            # the eval stream is the same on every process (see cifar)
+            test_fn = token_windows(
+                paths[-1].replace("{proc}", "0"), batch, data_shape[1])
+        except (OSError, ValueError) as e:
+            raise SystemExit(f"--data tokens: {e}") from None
+        return (_read_span(train_fn, batch, tokens=batch * data_shape[1]),
+                test_fn)
 
     if args.data == "synthetic":
         rs = np.random.RandomState(pid)
@@ -2029,7 +2062,8 @@ def main(argv=None) -> int:
         sp.add_argument("--data", default="auto",
                         help="auto (default: the net's own data layers when "
                         "they declare a streamable source, else synthetic) | "
-                        "cifar:<dir> | db:<path>[,<test_path>] | proto "
+                        "cifar:<dir> | db:<path>[,<test_path>] | "
+                        "tokens:<uint16 file>[,<test_file>] | proto "
                         "(stream from the net's own Data/ImageData/WindowData/"
                         "HDF5Data layers — the caffe-train-from-solver flow) "
                         "| synthetic")

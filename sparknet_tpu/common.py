@@ -292,9 +292,10 @@ def require_chip(tool: str) -> dict:
 
 # the ``jax.named_scope`` names a trace reader may look for in this
 # program's executables (compiler/graph.py, solvers/solver.py,
-# data/device_transform.py): part of the compile-cache key.  Add a
-# scope, add it here
-CACHE_SCOPES = "scopes:L.<layer>,S.update,S.augment"
+# data/device_transform.py, ops/moe.py, ops/attention.py): part of the
+# compile-cache key.  Add a scope, add it here
+CACHE_SCOPES = ("scopes:L.<layer>,S.update,S.augment,"
+                "M.route,M.dispatch,M.experts,M.combine,A.core")
 
 
 def enable_compile_cache() -> str:

@@ -113,3 +113,53 @@ def char_lm_batches(
         data = np.stack([ids[s:s + seq_len] for s in starts])
         label = np.stack([ids[s + 1:s + seq_len + 1] for s in starts])
         yield {"data": data, "label": label}
+
+
+def token_windows(path: str, batch: int, seq_len: int, stride: int = 1,
+                  offset: int = 0):
+    """Host data fn over a flat token file: ``fn(it, out=None)``.
+
+    ``path`` holds ``uint16`` token ids back to back and nothing else
+    (the OLMo / Megatron ``.npy``-less on-disk convention: documents
+    already tokenised, concatenated with their end-of-text ids, no
+    header).  The file is memory-mapped.  Window ``j`` is the ``seq_len +
+    1`` tokens from ``j * (seq_len + 1)`` on: its first ``seq_len`` are
+    the row's ``data``, its last ``seq_len`` the row's ``label``
+    (next-token targets; attention crosses document boundaries, nothing
+    is padded or masked — OLMo's packing).  Batch ``b`` holds windows
+    ``b * batch .. b * batch + batch - 1`` modulo the file's whole
+    windows, and call ``n`` of the fn reads batch ``offset + n * stride``
+    (a multi-process job interleaves its batches by process id).
+
+    Like the ``db:`` cursor the fn takes a destination (``takes_out``):
+    ``out["data"]`` / ``out["label"]``, ``[batch, seq_len]`` of any
+    integer dtype, are filled in place, each token copied once from the
+    mapped file with the cast riding on the assignment, and come back as
+    the batch; without ``out`` the batch is two fresh int32 arrays."""
+    tokens = np.memmap(path, dtype=np.uint16, mode="r")
+    span = seq_len + 1
+    windows = tokens.size // span
+    if windows < 1:
+        raise ValueError(
+            f"{path}: {tokens.size} tokens, one window needs {span}")
+    state = {"n": 0}
+
+    def fn(_, out=None):
+        first = (offset + state["n"] * stride) * batch
+        state["n"] += 1
+        if out is None:
+            out = {"data": np.empty((batch, seq_len), np.int32),
+                   "label": np.empty((batch, seq_len), np.int32)}
+        elif out["data"].shape != (batch, seq_len) \
+                or out["label"].shape != (batch, seq_len):
+            raise ValueError(
+                f"destination is {out['data'].shape} / {out['label'].shape}"
+                f", the batch is {(batch, seq_len)}")
+        for row in range(batch):
+            lo = ((first + row) % windows) * span
+            out["data"][row] = tokens[lo:lo + seq_len]
+            out["label"][row] = tokens[lo + 1:lo + span]
+        return {"data": out["data"], "label": out["label"]}
+
+    fn.takes_out = True
+    return fn

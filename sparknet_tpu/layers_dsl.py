@@ -123,6 +123,7 @@ def InnerProductLayer(
     weight_filler: Message | None = None,
     bias_filler: Message | None = None,
     axis: int | None = None,
+    bias_term: bool = True,
 ) -> Message:
     """ref: Layers.scala:88-100.  ``axis`` flattens from that axis
     (Caffe default 1; axis=2 keeps a [B, S, E] sequence per-token)."""
@@ -130,7 +131,10 @@ def InnerProductLayer(
     p = Message()
     p.set("num_output", num_output)
     p.set("weight_filler", weight_filler or _filler("xavier"))
-    p.set("bias_filler", bias_filler or _filler("constant", value=0.0))
+    if bias_term:
+        p.set("bias_filler", bias_filler or _filler("constant", value=0.0))
+    else:
+        p.set("bias_term", False)
     if axis is not None:
         p.set("axis", axis)
     m.set("inner_product_param", p)
@@ -306,6 +310,7 @@ def EmbedLayer(
     num_output: int,
     weight_filler: Message | None = None,
     top: str | None = None,
+    bias_term: bool = True,
 ) -> Message:
     """Embedding lookup (ref: embed_layer.cpp; ops/blocks.py Embed)."""
     m = _layer(name, "Embed", bottoms, [top] if top else None)
@@ -313,7 +318,16 @@ def EmbedLayer(
     p.set("input_dim", input_dim)
     p.set("num_output", num_output)
     p.set("weight_filler", weight_filler or _filler("xavier"))
+    if not bias_term:
+        p.set("bias_term", False)
     return m.set("embed_param", p)
+
+
+def RMSNormLayer(name: str, bottoms: Sequence[str], eps: float = 1e-5,
+                 top: str | None = None) -> Message:
+    """RMSNorm over the last axis (ops/blocks.py RMSNorm)."""
+    m = _layer(name, "RMSNorm", bottoms, [top] if top else None)
+    return m.set("rms_norm_param", Message().set("eps", eps))
 
 
 def MultiHeadAttentionLayer(
@@ -323,15 +337,32 @@ def MultiHeadAttentionLayer(
     causal: bool = False,
     rope: bool = False,
     top: str | None = None,
+    bias_term: bool = True,
+    qk_norm: bool = False,
+    qk_norm_eps: float | None = None,
+    rope_theta: float | None = None,
+    weight_filler: Message | None = None,
 ) -> Message:
     """Sequence-model extra (no reference analog; ops/attention.py).
-    ``rope=True`` turns on parameter-free rotary position embeddings."""
+    ``rope=True`` turns on parameter-free rotary position embeddings
+    (base ``rope_theta``); ``qk_norm`` RMS-normalizes q and k;
+    ``bias_term=False`` drops the projection biases."""
     m = _layer(name, "MultiHeadAttention", bottoms, [top] if top else None)
     p = Message().set("num_heads", num_heads)
     if causal:
         p.set("causal", True)
     if rope:
         p.set("rope", True)
+    if rope_theta is not None:
+        p.set("rope_theta", rope_theta)
+    if not bias_term:
+        p.set("bias_term", False)
+    if qk_norm:
+        p.set("qk_norm", True)
+    if qk_norm_eps is not None:
+        p.set("qk_norm_eps", qk_norm_eps)
+    if weight_filler is not None:
+        p.set("weight_filler", weight_filler)
     return m.set("attention_param", p)
 
 
@@ -341,12 +372,35 @@ def MoELayer(
     num_experts: int,
     hidden_dim: int = 0,
     top: str | None = None,
+    top_k: int = 1,
+    expert_act: str = "relu",
+    norm_topk_prob: bool = False,
+    bias_term: bool = True,
+    loss_tops: Sequence[tuple[str, float]] = (),
+    weight_filler: Message | None = None,
 ) -> Message:
-    """Mixture-of-experts extra (no reference analog; ops/moe.py)."""
-    m = _layer(name, "MoE", bottoms, [top] if top else None)
+    """Mixture-of-experts extra (no reference analog; ops/moe.py).
+    ``loss_tops``: up to three further (top name, loss_weight) pairs, in
+    the layer's order: load-balancing loss, router z-loss, tokens per
+    expert."""
+    tops = [top or name, *(t for t, _ in loss_tops)]
+    m = _layer(name, "MoE", bottoms, tops)
+    if loss_tops:
+        for w in (0.0, *(w for _, w in loss_tops)):
+            m.add("loss_weight", w)
     p = Message().set("num_experts", num_experts)
     if hidden_dim:
         p.set("hidden_dim", hidden_dim)
+    if top_k != 1:
+        p.set("top_k", top_k)
+    if expert_act != "relu":
+        p.set("expert_act", expert_act)
+    if norm_topk_prob:
+        p.set("norm_topk_prob", True)
+    if not bias_term:
+        p.set("bias_term", False)
+    if weight_filler is not None:
+        p.set("weight_filler", weight_filler)
     return m.set("moe_param", p)
 
 

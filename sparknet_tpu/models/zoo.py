@@ -32,12 +32,14 @@ from sparknet_tpu.layers_dsl import (
     FlattenLayer,
     InnerProductLayer,
     LRNLayer,
+    MoELayer,
     MultiHeadAttentionLayer,
     NetParam,
     Pooling,
     PoolingLayer,
     RDDLayer,
     ReLULayer,
+    RMSNormLayer,
     ScaleLayer,
     SigmoidCrossEntropyLossLayer,
     SigmoidLayer,
@@ -1017,6 +1019,88 @@ def charlm_solver() -> SolverConfig:
 
 
 # ---------------------------------------------------------------------------
+# OLMoE — a real sparse decoder (Muennighoff et al. 2024, arXiv:2409.02060;
+# allenai/OLMoE-1B-7B-0125 config.json; no reference analog).  Per layer:
+# pre-norm RMSNorm -> fused q/k/v projection without biases -> RMSNorm over
+# the whole hidden-wide q and k (QK-norm) -> rotate-half RoPE -> causal
+# softmax attention -> output projection -> residual -> RMSNorm -> router
+# over the experts (f32 softmax, top-k, weights NOT renormalised) ->
+# dropless SwiGLU experts -> residual.  Final RMSNorm, untied head, token
+# cross-entropy + load-balancing + router z-loss (per layer, averaged over
+# the layers, as the model was trained).  Data side: ``--data tokens:<file>``
+# (`data/text.py` token_windows).  `transformer`/`charlm` above keep their
+# toy block; their one-definition merge with this one is ROADMAP D10.
+# ---------------------------------------------------------------------------
+def olmoe(
+    batch: int = 4,
+    seq_len: int = 4096,
+    vocab: int = 50304,
+    hidden: int = 2048,
+    heads: int = 16,
+    experts: int = 64,
+    top_k: int = 8,
+    expert_dim: int = 1024,
+    layers: int = 16,
+    rms_norm_eps: float = 1e-5,
+    rope_theta: float = 10000.0,
+    lb_weight: float = 0.01,
+    z_weight: float = 0.001,
+    init_std: float = 0.02,
+) -> Message:
+    """OLMoE-1B-7B at its published sizes by default: [batch, seq_len]
+    token ids -> per-token next-token logits.  ``loss`` is the mean
+    cross-entropy per token; each layer's ``lb<i>`` / ``z<i>`` tops carry
+    ``lb_weight`` / ``z_weight`` over the layer count as their
+    ``loss_weight``; ``load<i>`` is the layer's tokens per expert."""
+    init = _gauss(init_std)
+    net = [
+        RDDLayer("data", shape=[batch, seq_len]),
+        RDDLayer("label", shape=[batch, seq_len]),
+        EmbedLayer("embed", ["data"], input_dim=vocab, num_output=hidden,
+                   weight_filler=init, bias_term=False, top="embed"),
+    ]
+    x = "embed"
+    for i in range(1, layers + 1):
+        net += [
+            RMSNormLayer(f"norm{i}a", [x], eps=rms_norm_eps),
+            MultiHeadAttentionLayer(
+                f"attn{i}", [f"norm{i}a"], num_heads=heads, causal=True,
+                rope=True, rope_theta=rope_theta, bias_term=False,
+                qk_norm=True, qk_norm_eps=rms_norm_eps, weight_filler=init),
+            EltwiseLayer(f"res{i}a", [x, f"attn{i}"], top=f"res{i}a"),
+            RMSNormLayer(f"norm{i}b", [f"res{i}a"], eps=rms_norm_eps),
+            MoELayer(
+                f"moe{i}", [f"norm{i}b"], num_experts=experts,
+                hidden_dim=expert_dim, top_k=top_k, expert_act="swiglu",
+                weight_filler=init,
+                loss_tops=((f"lb{i}", lb_weight / layers),
+                           (f"z{i}", z_weight / layers), (f"load{i}", 0.0))),
+            EltwiseLayer(f"res{i}b", [f"res{i}a", f"moe{i}"], top=f"res{i}b"),
+        ]
+        x = f"res{i}b"
+    net += [
+        RMSNormLayer("norm_f", [x], eps=rms_norm_eps),
+        InnerProductLayer("lm_head", ["norm_f"], num_output=vocab, axis=2,
+                          weight_filler=init, bias_term=False),
+        SoftmaxWithLoss("loss", ["lm_head", "label"], axis=2),
+        AccuracyLayer("accuracy", ["lm_head", "label"], phase="TEST", axis=2),
+    ]
+    return NetParam("OLMoE", *net)
+
+
+def olmoe_solver() -> SolverConfig:
+    """The OLMoE paper's optimizer (arXiv:2409.02060, Table 12): AdamW,
+    peak lr 4e-4, betas 0.9 / 0.95, eps 1e-8, decoupled weight decay 0.1
+    on every parameter, gradient clipping at global norm 1.0.  The
+    warm-up and cosine schedule are left to the prototxt's lr_policy."""
+    return SolverConfig(
+        base_lr=4e-4, lr_policy="fixed", momentum=0.9, momentum2=0.95,
+        delta=1e-8, weight_decay=0.1, clip_gradients=1.0,
+        max_iter=10000, solver_type="AdamW", display=100,
+    )
+
+
+# ---------------------------------------------------------------------------
 # Cached per-token decode step (ISSUE 19, ROADMAP item 4).
 #
 # The rectangle decode path (serve/continuous.py) rebuilds the FULL
@@ -1090,6 +1174,10 @@ def decode_spec(network, end: str = "fc") -> DecodeSpec:
             if not layer.causal:
                 raise ValueError(
                     f"{layer.name}: cached decode needs causal attention")
+            if layer.qk_norm or not layer.bias_term:
+                raise ValueError(
+                    f"{layer.name}: the cached decode replays biased "
+                    "projections without QK-norm only")
             if heads is None:
                 heads = layer.num_heads
             elif heads != layer.num_heads:

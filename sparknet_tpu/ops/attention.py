@@ -16,9 +16,15 @@ Prototxt surface::
 
 Input/output blobs are [B, S, E].  Params follow Caffe blob order:
 [W_qkv (3E, E), b_qkv (3E), W_out (E, E), b_out (E)] — importable/
-exportable through every weight path (caffemodel, HDF5, orbax).  The
-attention core routes through :func:`flash_attention`, so
-``SPARKNET_ATTN_IMPL=pallas`` drops the blocked MXU kernel in unchanged.
+exportable through every weight path (caffemodel, HDF5, orbax).
+``bias_term: false`` drops the two biases; ``qk_norm: true`` appends
+[q_norm (E), k_norm (E)], RMSNorm weights applied to the whole E-wide q
+and k projections before the head split (the OLMoE / OLMo-2 QK-norm,
+``qk_norm_eps``); ``rope_theta`` is the rotary base.  The attention core
+(:func:`attention_core`) is chosen by shape and platform: long
+sequences on a TPU run jax's pallas flash kernels, the rest
+:func:`flash_attention` (XLA by default; ``SPARKNET_ATTN_IMPL`` still
+selects the repo's own pallas forward there).
 
 Sequence parallelism composes here: under an active
 :func:`sequence_parallel` context (a `ParallelTrainer` whose mesh has a
@@ -38,6 +44,7 @@ import jax.numpy as jnp
 
 from sparknet_tpu.common import get_config
 from sparknet_tpu.ops.base import Layer, LayerOutput
+from sparknet_tpu.ops.blocks import rms_norm
 from sparknet_tpu.ops.fillers import fill
 from sparknet_tpu.ops.pallas_kernels import flash_attention
 from sparknet_tpu.ops.registry import register
@@ -170,6 +177,38 @@ def rope_at(x: jax.Array, positions: jax.Array,
     ).astype(x.dtype)
 
 
+# device scope of the attention core (scores, softmax, weighted sum) inside
+# the layer's ``L.<name>`` scope; in common.CACHE_SCOPES
+CORE_SCOPE = "A.core"
+
+
+def attention_core(q, k, v, causal: bool):
+    """Softmax attention over [B, H, S, D], chosen by shape and platform.
+
+    From S = 2048 on a TPU (head dim a multiple of 128): jax's own
+    pallas ``flash_attention`` kernels, forward and backward, which
+    never hold the [B, H, S, S] scores (1 GB per 4k sequence of 16 heads
+    in f32).  Timed once on the v5e at 4 x 16 x 4096 x 128, causal,
+    forward + backward (PERF.md section 6): 14.2 ms at 1024-wide blocks
+    against 25.5 ms for an XLA loop over query blocks with remat.
+    Everything else takes :func:`flash_attention`'s XLA formulation,
+    which materializes the scores."""
+    S, D = q.shape[2], q.shape[3]
+    block = next((b for b in (1024, 512) if S % b == 0), 0)
+    if not (jax.default_backend() == "tpu" and S >= 2048 and block
+            and D % 128 == 0):
+        return flash_attention(q, k, v, causal=causal)
+    from jax.experimental.pallas.ops.tpu import flash_attention as fa
+
+    sizes = fa.BlockSizes(
+        block_q=block, block_k_major=block, block_k=block, block_b=1,
+        block_q_major_dkv=block, block_k_major_dkv=block,
+        block_k_dkv=block, block_q_dkv=block,
+        block_k_major_dq=block, block_k_dq=block, block_q_dq=block)
+    return fa.flash_attention(q, k, v, causal=causal, sm_scale=D ** -0.5,
+                              block_sizes=sizes)
+
+
 @register
 class MultiHeadAttentionLayer(Layer):
     TYPE = "MultiHeadAttention"
@@ -180,6 +219,10 @@ class MultiHeadAttentionLayer(Layer):
         self.num_heads = p.get_int("num_heads", 1)
         self.causal = p.get_bool("causal", False)
         self.rope = p.get_bool("rope", False)
+        self.rope_theta = p.get_float("rope_theta", 10000.0)
+        self.bias_term = p.get_bool("bias_term", True)
+        self.qk_norm = p.get_bool("qk_norm", False)
+        self.qk_norm_eps = p.get_float("qk_norm_eps", 1e-5)
         self.weight_filler = (
             p.get_msg("weight_filler")
             if p.has("weight_filler")
@@ -195,25 +238,40 @@ class MultiHeadAttentionLayer(Layer):
             )
         k1, k2 = jax.random.split(key)
         w_qkv = fill(self.weight_filler, k1, (3 * E, E))
-        b_qkv = jnp.zeros((3 * E,), jnp.float32)
         w_out = fill(self.weight_filler, k2, (E, E))
-        b_out = jnp.zeros((E,), jnp.float32)
-        return [w_qkv, b_qkv, w_out, b_out], {}
+        if self.bias_term:
+            params = [w_qkv, jnp.zeros((3 * E,), jnp.float32),
+                      w_out, jnp.zeros((E,), jnp.float32)]
+        else:
+            params = [w_qkv, w_out]
+        if self.qk_norm:
+            params += [jnp.ones((E,), jnp.float32), jnp.ones((E,), jnp.float32)]
+        return params, {}
 
     def apply(self, params, state, inputs, *, train, rng=None) -> LayerOutput:
         x = inputs[0]  # [B, S, E]
-        w_qkv, b_qkv, w_out, b_out = params
+        # blobs: [w_qkv, (b_qkv), w_out, (b_out), (q_norm, k_norm)]
+        if self.bias_term:
+            w_qkv, b_qkv, w_out, b_out = params[:4]
+        else:
+            w_qkv, w_out = params[:2]
         B, S, E = x.shape
         H = self.num_heads
         D = E // H
-        qkv = jnp.einsum("bse,fe->bsf", x, w_qkv) + b_qkv  # [B, S, 3E]
+        qkv = jnp.einsum("bse,fe->bsf", x, w_qkv)  # [B, S, 3E]
+        if self.bias_term:
+            qkv = qkv + b_qkv
         q, k, v = jnp.split(qkv, 3, axis=-1)
+        if self.qk_norm:
+            # over the whole E-wide projection, before the head split
+            q = rms_norm(q, params[-2], self.qk_norm_eps)
+            k = rms_norm(k, params[-1], self.qk_norm_eps)
         # [B, S, E] -> [B, H, S, D]
         split = lambda t: t.reshape(B, S, H, D).transpose(0, 2, 1, 3)
         q, k, v = split(q), split(k), split(v)
         if self.rope:
             # global positions — before any sequence-parallel split
-            q, k = rope(q), rope(k)
+            q, k = rope(q, self.rope_theta), rope(k, self.rope_theta)
         sp = active_sequence_parallel()
         if sp is not None and S % sp[0].shape[get_config().seq_axis] != 0:
             # ring/Ulysses need equal sequence blocks; an indivisible S
@@ -227,10 +285,13 @@ class MultiHeadAttentionLayer(Layer):
                 stacklevel=2,
             )
             sp = None
-        if sp is not None:
-            o = _sp_attention(sp[0], sp[1], q, k, v, self.causal)
-        else:
-            o = flash_attention(q, k, v, causal=self.causal)
+        with jax.named_scope(CORE_SCOPE):
+            if sp is not None:
+                o = _sp_attention(sp[0], sp[1], q, k, v, self.causal)
+            else:
+                o = attention_core(q, k, v, self.causal)
         o = o.transpose(0, 2, 1, 3).reshape(B, S, E)
-        y = jnp.einsum("bse,fe->bsf", o, w_out) + b_out
+        y = jnp.einsum("bse,fe->bsf", o, w_out)
+        if self.bias_term:
+            y = y + b_out
         return LayerOutput(outputs=[y])

@@ -554,6 +554,34 @@ class MVN(Layer):
 
 
 @register
+class RMSNorm(Layer):
+    """Root-mean-square normalization over the last axis (Zhang &
+    Sennrich 2019; the norm of the OLMo / Llama-style decoders):
+    ``y = w · x / sqrt(mean(x²) + eps)``.  One weight blob (D,), ones at
+    initialisation, no bias.  The statistics are taken in f32 whatever
+    the compute dtype; the normalized value returns to the input's dtype
+    before the weight multiplies it."""
+
+    TYPE = "RMSNorm"
+
+    def init(self, key, in_shapes):
+        return [jnp.ones((in_shapes[0][-1],), get_config().param_dtype)], {}
+
+    def apply(self, params, state, inputs, *, train, rng=None):
+        return LayerOutput([rms_norm(
+            inputs[0], params[0],
+            self.lp.get_msg("rms_norm_param").get_float("eps", 1e-5))])
+
+
+def rms_norm(x, weight, eps: float):
+    """The RMSNorm layer's arithmetic (also the q/k norm of
+    ``MultiHeadAttention``)."""
+    xf = x.astype(jnp.float32)
+    inv = jax.lax.rsqrt(jnp.mean(jnp.square(xf), axis=-1, keepdims=True) + eps)
+    return (xf * inv).astype(x.dtype) * weight.astype(x.dtype)
+
+
+@register
 class Silence(Layer):
     """Consumes bottoms, produces nothing (ref: silence_layer.cpp)."""
 

@@ -1,25 +1,46 @@
-"""In-graph mixture-of-experts FFN — the expert-parallel layer type.
+"""In-graph mixture-of-experts FFN — the expert layer type.
 
 The reference has no MoE or expert parallelism (ref: SURVEY §2.3.5 —
 its parallelism inventory ends at data parallelism); like
 `MultiHeadAttention`, this is a TPU-first-class extra wired through the
 ordinary prototxt/DSL -> compiler path so expert models build, train and
-snapshot like the CNN zoo.  The distributed dispatch lives in
-`parallel/expert.py` (tokens `all_to_all` over an ``expert`` mesh axis);
-this layer is the single-program dense form of the same math, and the
-two agree exactly when no token overflows capacity.
+snapshot like the CNN zoo.
 
 Prototxt surface::
 
     layer {
-      name: "moe" type: "MoE" bottom: "x" top: "y"
-      moe_param { num_experts: 8 hidden_dim: 256 }
+      name: "moe" type: "MoE" bottom: "x"
+      top: "y" top: "lb_loss" top: "z_loss" top: "load"
+      loss_weight: 0 loss_weight: 0.01 loss_weight: 0.001 loss_weight: 0
+      moe_param { num_experts: 64 hidden_dim: 1024 top_k: 8
+                  expert_act: "swiglu" bias_term: false }
     }
 
-Input/output blobs are [..., D].  Top-1 (switch) gating: each token is
-processed by its argmax expert, scaled by that expert's softmax gate
-probability.  Params in Caffe blob order:
-[W_gate (E, D), W1 (E, H, D), b1 (E, H), W2 (E, D, H), b2 (E, D)].
+Input/output blobs are [..., D].  Routing (Switch / OLMoE, public
+technique, PAPERS.md): router logits and their softmax over the experts
+in f32, the ``top_k`` largest probabilities are the token's experts and
+its combine weights (renormalised to sum 1 only with ``norm_topk_prob``).
+Dispatch is DROPLESS: the T·k (token, slot) pairs are sorted by expert,
+each expert runs on exactly its own rows through a grouped matmul over
+the ragged groups, and the rows are un-sorted and summed per token.  No
+capacity, no padding, no token dropped.
+
+Tops, as many as the prototxt declares (1 to 4): ``y``; the
+load-balancing loss ``E · Σ_e f_e · P_e`` (f_e: share of the T·k pairs
+routed to e per slot, P_e: mean router probability); the router z-loss
+``mean_t (logsumexp logits_t)²``; and the tokens per expert of this
+step, which the layer also keeps in its state (``load``) for a reader
+that looks only at fences.  The loss tops carry the prototxt's
+``loss_weight`` like any Caffe loss top.
+
+Params in Caffe blob order, every expert matrix ``[out, in]``:
+  relu   (default): [W_router (E, D), W1 (E, H, D), b1 (E, H),
+                     W2 (E, D, H), b2 (E, D)]   y = relu(x W1ᵀ+b1) W2ᵀ+b2
+  swiglu:           [W_router (E, D), W_gate (E, H, D), W_up (E, H, D),
+                     W_down (E, D, H)]  y = (silu(x W_gateᵀ) · x W_upᵀ) W_downᵀ
+``bias_term: false`` drops b1/b2 (swiglu never has them).  The defaults
+(top-1, relu, biases) are the switch layer `parallel/expert.py`
+distributes; ``moe_dense`` below is that layer's dense oracle.
 """
 
 from __future__ import annotations
@@ -31,6 +52,13 @@ from sparknet_tpu.ops.base import Layer, LayerOutput
 from sparknet_tpu.ops.fillers import fill
 from sparknet_tpu.ops.registry import register
 from sparknet_tpu.proto.text_format import Message
+
+# device scopes inside the layer's own ``L.<name>`` scope (a trace reader
+# finds the layer by ``L.``, the stage by these); in common.CACHE_SCOPES
+ROUTE_SCOPE = "M.route"
+DISPATCH_SCOPE = "M.dispatch"
+EXPERTS_SCOPE = "M.experts"
+COMBINE_SCOPE = "M.combine"
 
 
 def gate_top1(w_gate, x):
@@ -54,7 +82,8 @@ def expert_ffn(params_e, x):
 def moe_dense(params, x):
     """Dense top-1 MoE on [T, D] tokens: every expert computes every
     token, a one-hot combine keeps the chosen one.  The oracle for the
-    expert-parallel dispatch, and the in-graph layer's compute."""
+    expert-parallel dispatch (`parallel/expert.py`) and for the layer's
+    top-1 ReLU default; nothing on a training path calls it."""
     w_gate, w1, b1, w2, b2 = params
     idx, prob = gate_top1(w_gate, x)
     # [E, T, D]: expert-major dense compute (MXU-friendly batched matmuls)
@@ -62,6 +91,156 @@ def moe_dense(params, x):
     y_all = jnp.einsum("eth,edh->etd", h, w2) + b2[:, None, :]
     onehot = jax.nn.one_hot(idx, w1.shape[0], dtype=x.dtype)  # [T, E]
     return jnp.einsum("etd,te->td", y_all, onehot) * prob[:, None]
+
+
+# ---------------------------------------------------------------------------
+# routing
+# ---------------------------------------------------------------------------
+
+
+def route(w_router, x, top_k: int, norm_topk_prob: bool = False):
+    """Router on [T, D] tokens -> (logits [T, E] f32, probs [T, E] f32,
+    weights [T, k] f32, experts [T, k] int32).  The matmul takes the
+    operands as they come (bf16 under ``--dtype bf16``) and accumulates
+    in f32; everything after it is f32."""
+    logits = jnp.dot(x, w_router.T, preferred_element_type=jnp.float32)
+    probs = jax.nn.softmax(logits, axis=-1)
+    weights, experts = jax.lax.top_k(probs, top_k)
+    if norm_topk_prob:
+        weights = weights / jnp.sum(weights, axis=-1, keepdims=True)
+    return logits, probs, weights, experts.astype(jnp.int32)
+
+
+def load_balancing_loss(probs, experts, num_experts: int):
+    """``E · Σ_{slot, e} f_{slot,e} · P_e`` (Switch Transformer eq. 4 as
+    HF ``load_balancing_loss_func`` computes it for top-k: the share of
+    tokens whose slot s went to e, times the mean router probability of
+    e, summed over slots and experts)."""
+    mask = jax.nn.one_hot(experts, num_experts, dtype=jnp.float32)  # [T,k,E]
+    return num_experts * jnp.sum(
+        jnp.mean(mask, axis=0) * jnp.mean(probs, axis=0)[None, :])
+
+
+def router_z_loss(logits):
+    """``mean_t (log Σ_e exp logits_te)²`` (ST-MoE eq. 5)."""
+    return jnp.mean(jnp.square(jax.nn.logsumexp(logits, axis=-1)))
+
+
+# ---------------------------------------------------------------------------
+# dropless dispatch: sort the (token, slot) pairs by expert, grouped
+# matmul over the ragged groups, un-sort, weighted sum per token
+# ---------------------------------------------------------------------------
+
+
+@jax.custom_vjp
+def _take_rows(x, idx, inv):
+    """``x[idx]`` for a PERMUTATION ``idx`` of the rows with inverse
+    ``inv``: the cotangent is the inverse gather, never a scatter."""
+    return x[idx]
+
+
+def _take_rows_fwd(x, idx, inv):
+    return x[idx], inv
+
+
+def _take_rows_bwd(inv, g):
+    return g[inv], None, None
+
+
+_take_rows.defvjp(_take_rows_fwd, _take_rows_bwd)
+
+
+@jax.custom_vjp
+def _spread_rows(x, order, inv):
+    """Row r of the result is token ``order[r] // k`` of ``x`` [T, D]
+    (``order``: a permutation of the T·k pairs, ``inv`` its inverse).
+    The cotangent un-sorts and sums each token's k rows: a gather and a
+    reshape, never a scatter-add."""
+    return x[order // (order.shape[0] // x.shape[0])]
+
+
+def _spread_rows_fwd(x, order, inv):
+    return _spread_rows(x, order, inv), (inv, x.shape[0])
+
+
+def _spread_rows_bwd(res, g):
+    inv, tokens = res
+    return g[inv].reshape(tokens, -1, g.shape[-1]).sum(axis=1), None, None
+
+
+_spread_rows.defvjp(_spread_rows_fwd, _spread_rows_bwd)
+
+# every expert matrix is stored [out, in]: contract the rows' features
+# with the matrix's LAST axis, groups along its first
+_OUT_IN = jax.lax.RaggedDotDimensionNumbers(
+    dot_dimension_numbers=(([1], [2]), ([], [])),
+    lhs_ragged_dimensions=[0], rhs_group_dimensions=[0])
+
+
+def grouped_matmul(x, w, group_sizes):
+    """``x`` [M, K] rows sorted by group, ``w`` [G, N, K], ``group_sizes``
+    [G] int32 summing to M -> [M, N]: rows of group g times ``w[g]ᵀ``.
+
+    On a TPU, at lane-aligned widths: jax's own megablox ``gmm`` kernel
+    (forward, and ``gmm``/``tgmm`` backward).  Timed once on the v5e at
+    the OLMoE expert block's shapes (131,072 rows, 64 groups, 2048x1024,
+    forward + backward, PERF.md section 6): 39.2 ms against 51.7 ms for
+    XLA's ``ragged_dot`` with the weights transposed first and 78.4 ms
+    with them as stored.  Elsewhere (the CPU, odd widths) XLA's
+    ``ragged_dot_general``, which the kernel cannot replace there."""
+    (m, k), n = x.shape, w.shape[1]
+    if jax.default_backend() != "tpu" or k % 128 or n % 128:
+        return jax.lax.ragged_dot_general(
+            x, w, group_sizes, _OUT_IN, preferred_element_type=x.dtype)
+    from jax.experimental.pallas.ops.tpu.megablox import ops as megablox
+
+    pad = -m % 128  # the kernel tiles the rows; rows past the groups' sum are dead
+    if pad:
+        x = jnp.pad(x, ((0, pad), (0, 0)))
+    tm = next(t for t in (512, 256, 128) if (m + pad) % t == 0)
+    out = megablox.gmm(x, w, group_sizes, x.dtype,
+                       (tm, min(k, 1024), min(n, 1024)), None, None, True)
+    return out[:m] if pad else out
+
+
+def moe_dropless(params, x, *, top_k: int, expert_act: str,
+                 norm_topk_prob: bool = False):
+    """The layer's compute on [T, D] tokens -> (y [T, D], logits, probs,
+    experts [T, k], load [E] f32).  ``params`` as the layer holds them."""
+    w_router, rest = params[0], params[1:]
+    num_experts = w_router.shape[0]
+    tokens = x.shape[0]
+    with jax.named_scope(ROUTE_SCOPE):
+        logits, probs, weights, experts = route(
+            w_router, x, top_k, norm_topk_prob)
+    with jax.named_scope(DISPATCH_SCOPE):
+        flat = experts.reshape(-1)  # pair t*k + s -> its expert
+        order = jnp.argsort(flat, stable=True).astype(jnp.int32)
+        inv = jnp.zeros_like(order).at[order].set(
+            jnp.arange(order.shape[0], dtype=jnp.int32))
+        group_sizes = jnp.bincount(flat, length=num_experts).astype(jnp.int32)
+        rows = _spread_rows(x, order, inv)  # [T·k, D], expert-major
+    with jax.named_scope(EXPERTS_SCOPE):
+        if expert_act == "swiglu":
+            w_gate, w_up, w_down = rest
+            h = jax.nn.silu(grouped_matmul(rows, w_gate, group_sizes)) \
+                * grouped_matmul(rows, w_up, group_sizes)
+            out = grouped_matmul(h, w_down, group_sizes)
+        elif len(rest) == 2:
+            w1, w2 = rest
+            h = jax.nn.relu(grouped_matmul(rows, w1, group_sizes))
+            out = grouped_matmul(h, w2, group_sizes)
+        else:
+            w1, b1, w2, b2 = rest
+            of_row = flat[order]  # each sorted row's expert, for its bias
+            h = jax.nn.relu(
+                grouped_matmul(rows, w1, group_sizes) + b1[of_row])
+            out = grouped_matmul(h, w2, group_sizes) + b2[of_row]
+    with jax.named_scope(COMBINE_SCOPE):
+        per_pair = _take_rows(out, inv, order).reshape(tokens, top_k, -1)
+        y = jnp.sum(per_pair.astype(jnp.float32) * weights[..., None],
+                    axis=1).astype(x.dtype)
+    return y, logits, probs, experts, group_sizes.astype(jnp.float32)
 
 
 @register
@@ -73,6 +252,23 @@ class MoELayer(Layer):
         p = lp.get_msg("moe_param")
         self.num_experts = p.get_int("num_experts", 1)
         self.hidden_dim = p.get_int("hidden_dim", 0)
+        self.top_k = p.get_int("top_k", 1)
+        self.expert_act = p.get_str("expert_act", "relu")
+        self.norm_topk_prob = p.get_bool("norm_topk_prob", False)
+        if self.expert_act not in ("relu", "swiglu"):
+            raise ValueError(
+                f"{self.name}: unknown expert_act {self.expert_act!r} "
+                "(relu|swiglu)")
+        if not 1 <= self.top_k <= self.num_experts:
+            raise ValueError(
+                f"{self.name}: top_k {self.top_k} outside 1.."
+                f"{self.num_experts} experts")
+        # swiglu experts carry no biases (none of the published ones do)
+        self.bias_term = (self.expert_act == "relu"
+                          and p.get_bool("bias_term", True))
+        if len(self.tops) > 4:
+            raise ValueError(
+                f"{self.name}: at most 4 tops (y, lb_loss, z_loss, load)")
         self.weight_filler = (
             p.get_msg("weight_filler")
             if p.has("weight_filler")
@@ -83,16 +279,30 @@ class MoELayer(Layer):
         D = in_shapes[0][-1]
         H = self.hidden_dim or 4 * D
         E = self.num_experts
-        kg, k1, k2 = jax.random.split(key, 3)
-        w_gate = fill(self.weight_filler, kg, (E, D))
+        kg, k1, k2, k3 = jax.random.split(key, 4)
+        w_router = fill(self.weight_filler, kg, (E, D))
+        state = {"load": jnp.zeros((E,), jnp.float32)}
+        if self.expert_act == "swiglu":
+            return [w_router,
+                    fill(self.weight_filler, k1, (E, H, D)),
+                    fill(self.weight_filler, k3, (E, H, D)),
+                    fill(self.weight_filler, k2, (E, D, H))], state
         w1 = fill(self.weight_filler, k1, (E, H, D))
-        b1 = jnp.zeros((E, H), jnp.float32)
         w2 = fill(self.weight_filler, k2, (E, D, H))
-        b2 = jnp.zeros((E, D), jnp.float32)
-        return [w_gate, w1, b1, w2, b2], {}
+        if not self.bias_term:
+            return [w_router, w1, w2], state
+        return [w_router, w1, jnp.zeros((E, H), jnp.float32),
+                w2, jnp.zeros((E, D), jnp.float32)], state
 
     def apply(self, params, state, inputs, *, train, rng=None) -> LayerOutput:
         x = inputs[0]
-        tokens = x.reshape(-1, x.shape[-1])
-        y = moe_dense(params, tokens)
-        return LayerOutput(outputs=[y.reshape(x.shape)])
+        y, logits, probs, experts, load = moe_dropless(
+            params, x.reshape(-1, x.shape[-1]), top_k=self.top_k,
+            expert_act=self.expert_act, norm_topk_prob=self.norm_topk_prob)
+        outputs = [y.reshape(x.shape)]
+        if len(self.tops) > 1:
+            with jax.named_scope(ROUTE_SCOPE):
+                outputs += [
+                    load_balancing_loss(probs, experts, self.num_experts),
+                    router_z_loss(logits), load][:len(self.tops) - 1]
+        return LayerOutput(outputs=outputs, state={"load": load})

@@ -406,7 +406,7 @@ class UpdateStatics(NamedTuple):
 # rule name -> number of slot histories (mirrors updates.OPTIMIZERS)
 FUSED_RULE_SLOTS = {
     "SGD": 1, "Nesterov": 1, "AdaGrad": 1, "RMSProp": 1,
-    "AdaDelta": 2, "Adam": 2,
+    "AdaDelta": 2, "Adam": 2, "AdamW": 2,
 }
 
 
@@ -426,12 +426,16 @@ def _fused_prologue(st: UpdateStatics, w, g, clip_scale, decay):
     return g
 
 
-def _fused_rule_math(st: UpdateStatics, rule: str, w, g, slots, lr, corr):
-    """The six Caffe rules on f32 operands (ref: the per-rule solvers in
-    caffe/src/caffe/solvers/, rebuilt in solvers/updates.py) — op-for-op
-    the same sequence, so the f32 fused path is EXACT vs the unfused
-    chain for SGD/Nesterov and allclose for the sqrt/div rules.
-    Returns (delta_w, new_slots); W_new = w - delta_w."""
+def _fused_rule_math(st: UpdateStatics, rule: str, w, g, slots, lr, corr,
+                     corr2, decay):
+    """The six Caffe rules and AdamW on f32 operands (ref: the per-rule
+    solvers in caffe/src/caffe/solvers/, rebuilt in solvers/updates.py)
+    — op-for-op the same sequence, so the f32 fused path is EXACT vs the
+    unfused chain for SGD/Nesterov and allclose for the sqrt/div rules.
+    ``corr``/``corr2``: Adam's one correction factor, or AdamW's two
+    (1/(1-b1^t), 1/(1-b2^t)); ``decay``: the per-tile folded decay,
+    which AdamW takes here (decoupled) and every other rule in the
+    prologue.  Returns (delta_w, new_slots); W_new = w - delta_w."""
     if rule == "SGD":
         (h,) = slots
         h = st.momentum * h + lr * g
@@ -461,6 +465,13 @@ def _fused_rule_math(st: UpdateStatics, rule: str, w, g, slots, lr, corr):
         m = b1 * m + (1.0 - b1) * g
         v = b2 * v + (1.0 - b2) * g * g
         return (lr * corr) * m / (jnp.sqrt(v) + st.delta), [m, v]
+    if rule == "AdamW":
+        m, v = slots
+        b1, b2 = st.momentum, st.momentum2
+        m = b1 * m + (1.0 - b1) * g
+        v = b2 * v + (1.0 - b2) * g * g
+        step = (m * corr) / (jnp.sqrt(v * corr2) + st.delta) + decay * w
+        return lr * step, [m, v]
     raise ValueError(f"unknown fused update rule {rule!r}")
 
 
@@ -468,19 +479,21 @@ def _fused_kernel(st: UpdateStatics, rule: str, n_slots: int,
                   lr_ref, decay_ref, scal_ref, w_ref, g_ref, *refs):
     """One (tile,) grid cell: refs are [1, _ARENA_SUB, _ARENA_LANE]
     blocks; lr/decay are scalar-prefetched per-tile segment tables
-    (SMEM), scal = [rate, clip_scale, adam_correction].  Storage dtype
+    (SMEM), scal = [rate, clip_scale, adam_correction, adamw's second].
+    Storage dtype
     may be bf16; every operand upcasts to f32 in registers and casts
     back exactly once at the write."""
     i = pl.program_id(0)
     lr = scal_ref[0] * lr_ref[i]
     clip_scale = scal_ref[1]
-    corr = scal_ref[2]
+    corr, corr2 = scal_ref[2], scal_ref[3]
     decay = decay_ref[i]
     w = w_ref[...].astype(jnp.float32)
     g = g_ref[...].astype(jnp.float32)
     slots = [r[...].astype(jnp.float32) for r in refs[:n_slots]]
     g = _fused_prologue(st, w, g, clip_scale, decay)
-    dw, new_slots = _fused_rule_math(st, rule, w, g, slots, lr, corr)
+    dw, new_slots = _fused_rule_math(st, rule, w, g, slots, lr, corr,
+                                     corr2, decay)
     w_out = refs[n_slots]
     w_out[...] = (w - dw).astype(w_out.dtype)
     for r, h in zip(refs[n_slots + 1:], new_slots):
@@ -540,7 +553,7 @@ def _fused_update_xla(st: UpdateStatics, rule: str, w, g, slots,
     decay = decay_tiles[:, None]
     g32 = _fused_prologue(st, w32, g32, scalars[1], decay)
     dw, new_slots = _fused_rule_math(st, rule, w32, g32, s32, lr,
-                                     scalars[2])
+                                     scalars[2], scalars[3], decay)
     new_w = (w32 - dw).astype(w.dtype).reshape(w.shape)
     return new_w, [h.astype(s.dtype).reshape(s.shape)
                    for h, s in zip(new_slots, slots)]
@@ -554,7 +567,8 @@ def fused_update(rule: str, st: UpdateStatics, w, g, slots,
     ``ARENA_TILE``); ``slots``: list of [T] history arenas (1 or 2 per
     ``FUSED_RULE_SLOTS[rule]``); ``lr_tiles``/``decay_tiles``: [T/TILE]
     f32 segment tables (lr_mult and folded weight_decay*decay_mult per
-    tile); ``scalars``: [3] f32 = (rate, clip_scale, adam_correction).
+    tile); ``scalars``: [4] f32 = (rate, clip_scale, adam_correction,
+    adamw's second correction).
     Returns (new_w, new_slots), same dtypes as the inputs.
 
     ``force`` = 'pallas' | 'interpret' | 'xla' | 'auto' | None (None
@@ -633,7 +647,7 @@ def fused_update_tpu_custom_calls(rule: str = "SGD", n_slots: int = 1,
     slots = [jnp.zeros((T,), dtype) for _ in range(n_slots)]
     lr_tiles = jnp.ones((n_tiles,), jnp.float32)
     decay_tiles = jnp.zeros((n_tiles,), jnp.float32)
-    scalars = jnp.ones((3,), jnp.float32)
+    scalars = jnp.ones((4,), jnp.float32)
     fn = jax.jit(functools.partial(fused_update, rule, st, force="pallas"))
     exported = jexport.export(fn, platforms=["tpu"])(
         w, g, slots, lr_tiles, decay_tiles, scalars)

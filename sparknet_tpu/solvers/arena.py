@@ -237,7 +237,8 @@ def update_statics(cfg) -> UpdateStatics:
         rms_decay=float(cfg.rms_decay),
         delta=float(cfg.delta),
         iter_size=int(cfg.iter_size),
-        reg=("none" if cfg.weight_decay == 0.0
+        # AdamW's decay is decoupled: the rule takes it, not the gradient
+        reg=("none" if cfg.weight_decay == 0.0 or cfg.solver_type == "AdamW"
              else "l1" if cfg.regularization_type == "L1" else "l2"),
         clip=cfg.clip_gradients > 0,
     )
@@ -248,8 +249,9 @@ def arena_apply_update(cfg, layout: ArenaLayout, param_arena, grad_arena,
     """One full Caffe-ordered update over the arenas — the fused twin
     of ``updates.apply_update``.  The traced scalars the kernel cannot
     close over (lr for this iter, the global-norm clip scale computed
-    host-of-kernel from the grad arena, adam's bias correction) ride a
-    [3] f32 operand; everything else is trace-time static.  Returns
+    host-of-kernel from the grad arena, adam's bias correction, adamw's
+    second) ride a [4] f32 operand; everything else is trace-time
+    static.  Returns
     (new_param_arena, new_slot_arenas)."""
     if cfg.clip_gradients > 0:
         # ref: ClipGradients (sgd_solver.cpp:81-100) on raw accumulated
@@ -260,6 +262,7 @@ def arena_apply_update(cfg, layout: ArenaLayout, param_arena, grad_arena,
                                cfg.clip_gradients / norm, 1.0)
     else:
         clip_scale = jnp.float32(1.0)
+    corr2 = jnp.float32(1.0)
     if cfg.solver_type == "Adam":
         # ref: adam_solver.cpp correction with t = iter + 1 (the same
         # formula updates._adam traces; computed once per step here
@@ -267,11 +270,17 @@ def arena_apply_update(cfg, layout: ArenaLayout, param_arena, grad_arena,
         t = jnp.asarray(it, jnp.float32) + 1.0
         corr = (jnp.sqrt(1.0 - jnp.power(cfg.momentum2, t))
                 / (1.0 - jnp.power(cfg.momentum, t)))
+    elif cfg.solver_type == "AdamW":
+        # updates._adamw's two bias corrections, as factors
+        t = jnp.asarray(it, jnp.float32) + 1.0
+        corr = 1.0 / (1.0 - jnp.power(cfg.momentum, t))
+        corr2 = 1.0 / (1.0 - jnp.power(cfg.momentum2, t))
     else:
         corr = jnp.float32(1.0)
     scalars = jnp.stack([jnp.asarray(rate, jnp.float32),
                          jnp.asarray(clip_scale, jnp.float32),
-                         jnp.asarray(corr, jnp.float32)])
+                         jnp.asarray(corr, jnp.float32),
+                         jnp.asarray(corr2, jnp.float32)])
     return fused_update(
         cfg.solver_type, update_statics(cfg), param_arena, grad_arena,
         slot_arenas, jnp.asarray(layout.tile_lr),

@@ -50,6 +50,8 @@ _TYPE_ALIASES = {
     "RMSProp": "RMSProp",
     "AdaDelta": "AdaDelta",
     "Adam": "Adam",
+    "ADAMW": "AdamW",
+    "AdamW": "AdamW",
 }
 
 
@@ -785,7 +787,23 @@ class Solver:
         # round record that step() closes on this value is its line
         with Span(None, "sn.step.fence", it=self.iter) as sp:
             self.smoothed_loss = sp.fence_value(self._smoothed())
+            sp.set(**self._expert_load())
         return self.smoothed_loss
+
+    def _expert_load(self) -> dict:
+        """What the MoE layers counted in the last step (their ``load``
+        state: tokens per expert), for the fence's span: the fullest
+        expert's tokens over the layers, and the (token, slot) pairs and
+        experts of one layer, whose quotient is the mean.  Read after the
+        fence, so it costs no device sync of its own; empty for a net
+        without such a layer."""
+        loads = [np.asarray(st["load"]) for st in self.variables.state.values()
+                 if "load" in st]
+        if not loads:
+            return {}
+        return {"moe_load_max": int(max(a.max() for a in loads)),
+                "moe_pairs": int(loads[0].sum()),
+                "moe_experts": int(loads[0].size)}
 
     def _step_scanned(self, num_iters: int, data_fn: DataFn, callback,
                       scan_chunk: int) -> float:

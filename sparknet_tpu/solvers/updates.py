@@ -2,7 +2,8 @@
 
 Re-designs the 6-member solver family (ref:
 caffe/src/caffe/solvers/{sgd,nesterov,adagrad,rmsprop,adadelta,adam}_solver.cpp)
-as pure per-tensor update functions over pytrees — the optax shape, but with
+plus AdamW (decoupled decay; no reference analog) as pure per-tensor
+update functions over pytrees — the optax shape, but with
 Caffe's exact formulations (e.g. SGD's V = mu*V + lr*g; W -= V, which folds
 the LR *into* the momentum buffer, unlike optax's sgd).
 
@@ -32,6 +33,7 @@ class UpdateCtx(NamedTuple):
     rms_decay: float
     delta: float  # numerical epsilon (adagrad/rmsprop/adadelta/adam)
     it: jnp.ndarray  # iteration (adam bias correction)
+    decay: float = 0.0  # decoupled weight decay (adamw), decay_mult applied
 
 
 # Each rule: (ctx, w, g, slots) -> (delta_w, new_slots).  ``slots`` is the
@@ -87,6 +89,23 @@ def _adam(ctx, w, g, slots):
     return (ctx.rate * ctx.lr_mult) * correction * m / (jnp.sqrt(v) + ctx.delta), [m, v]
 
 
+def _adamw(ctx, w, g, slots):
+    """Adam with DECOUPLED weight decay (Loshchilov & Hutter 2019, as
+    torch.optim.AdamW applies it): the decay term never enters the
+    moments, ``w -= lr * (m_hat / (sqrt(v_hat) + eps) + wd * w)``.  Both
+    moments are bias-corrected before the division, so ``eps`` sits
+    beside sqrt(v_hat), not (as in Caffe's ``_adam``) beside sqrt(v)."""
+    m, v = slots
+    b1, b2 = ctx.momentum, ctx.momentum2
+    t = jnp.asarray(ctx.it, jnp.float32) + 1.0
+    m = b1 * m + (1.0 - b1) * g
+    v = b2 * v + (1.0 - b2) * g * g
+    m_hat = m / (1.0 - jnp.power(b1, t))
+    v_hat = v / (1.0 - jnp.power(b2, t))
+    step = m_hat / (jnp.sqrt(v_hat) + ctx.delta) + ctx.decay * w
+    return (ctx.rate * ctx.lr_mult) * step, [m, v]
+
+
 OPTIMIZERS: dict[str, tuple[Callable, int]] = {
     # name -> (rule, number of history slots)
     "SGD": (_sgd, 1),
@@ -95,6 +114,7 @@ OPTIMIZERS: dict[str, tuple[Callable, int]] = {
     "RMSProp": (_rmsprop, 1),
     "AdaDelta": (_adadelta, 2),
     "Adam": (_adam, 2),
+    "AdamW": (_adamw, 2),
 }
 
 
@@ -126,6 +146,9 @@ def apply_update(
     """One full Caffe-ordered update. cfg is a SolverConfig; specs maps
     layer -> [ParamSpec per blob]. Returns (new_params, new_slots)."""
     rule, _ = OPTIMIZERS[cfg.solver_type]
+    # AdamW takes its decay inside the rule, outside the moments; every
+    # other rule gets Caffe's Regularize (the decay added to the gradient)
+    decoupled = cfg.solver_type == "AdamW"
 
     # 1. clip on raw accumulated grads (ref: ClipGradients, sgd_solver.cpp:81-100)
     if cfg.clip_gradients > 0:
@@ -145,7 +168,7 @@ def apply_update(
                 g = g / cfg.iter_size
             # 3. regularize (ref: Regularize — L2: g += wd*W; L1: g += wd*sign(W))
             wd = cfg.weight_decay * spec.decay_mult
-            if wd != 0.0:
+            if wd != 0.0 and not decoupled:
                 if cfg.regularization_type == "L1":
                     g = g + wd * jnp.sign(w)
                 else:
@@ -158,6 +181,7 @@ def apply_update(
                 rms_decay=cfg.rms_decay,
                 delta=cfg.delta,
                 it=it,
+                decay=wd if decoupled else 0.0,
             )
             dw, s = rule(ctx, w, g, slots[lname][i])
             out_p.append(w - dw.astype(w.dtype))
