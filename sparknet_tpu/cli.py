@@ -21,9 +21,13 @@ from __future__ import annotations
 
 import argparse
 import contextlib
+import itertools
 import json
 import os
+import queue
 import sys
+import threading
+import weakref
 
 import numpy as np
 
@@ -216,7 +220,7 @@ def _read_span(fn, images, **counts):
     """``sn.feed.read`` around a host data fn: one span per host batch,
     from the cursor to the decoded, collated, cast and internalized
     batch, on whichever thread asks for it (the DevicePrefetcher's feed
-    thread in the solo loop, the main thread inside ``_stack_tau``).
+    thread in the solo loop, ``_stack_tau``'s in the trainer's).
     ``alloc_bytes``: what of the batch lies in newly allocated arrays,
     0 when it all went into the caller's ``out``.  ``images`` counts the
     batch's records (sequences for a ``tokens:`` source, whose ``counts``
@@ -1028,7 +1032,9 @@ def cmd_train(args) -> int:
             scan_n = max(getattr(args, "scan", 1), 1)
             wide_fn = _widen_batch(train_fn, trainer.num_local_workers,
                                    keep=scan_n)
-            with feed_ctx, SignalHandler() as sig:
+            # tau_fn's feed thread is joined when the loop ends or a
+            # signal stops it, before the process feed it reads is closed
+            with feed_ctx, SignalHandler() as sig, contextlib.closing(tau_fn):
                 o = 0
                 while o < outer:
                     if args.tau > 1 or elastic:
@@ -1117,6 +1123,45 @@ def cmd_train(args) -> int:
     return 0
 
 
+class _Turns:
+    """A lock that serves its waiters in the order they came.  A
+    ``threading.Lock`` goes to whoever asks first after a release, and
+    that is the thread that just released it: of two feeds over one data
+    fn the one with more reads to make starved the other (four reads in
+    five, PERF.md, PR 27)."""
+
+    def __init__(self):
+        self._cv = threading.Condition()
+        self._next = self._serving = 0  # tickets handed out, and served
+
+    def __enter__(self):
+        with self._cv:
+            mine, self._next = self._next, self._next + 1
+            self._cv.wait_for(lambda: self._serving == mine)
+
+    def __exit__(self, *exc):
+        with self._cv:
+            self._serving += 1
+            self._cv.notify_all()
+
+    def locked(self) -> bool:
+        return self._serving != self._next
+
+
+_DATA_FN_LOCKS = weakref.WeakKeyDictionary()  # data fn -> its lock
+_DATA_FN_LOCKS_GUARD = threading.Lock()
+
+
+def _data_fn_lock(train_fn) -> _Turns:
+    """The lock that belongs to ``train_fn``: one per data fn, whoever
+    asks.  A data fn drives one cursor (``db_stream``'s generator), and
+    two feeds over it may each have a thread inside it: the benchmark's
+    one-device feed is still reading its last round ahead when the mesh
+    feed starts.  Calls into it are made under this lock, in turn."""
+    with _DATA_FN_LOCKS_GUARD:
+        return _DATA_FN_LOCKS.setdefault(train_fn, _Turns())
+
+
 class _RoundBuffer:
     """ONE persistent host array ``[slots, workers * B, ...]`` per feed
     key, filled one per-worker batch at a time: batch (t, w) goes to the
@@ -1125,6 +1170,7 @@ class _RoundBuffer:
     batch is copied there.  Nothing is concatenated or stacked.  The
     arrays are made on the first read, from the first batch's shapes, and
     live as long as the buffer's owner (``_stack_tau`` / ``_widen_batch``).
+    The data fn is called under its own lock (``_data_fn_lock``).
 
     ``sn.feed.stack``, one per slot after the slot's reads, times what is
     left of the pack's own work and counts the slot's images;
@@ -1133,6 +1179,7 @@ class _RoundBuffer:
     def __init__(self, train_fn, slots, workers):
         self._fn, self._slots, self._workers = train_fn, slots, workers
         self._takes_out = getattr(train_fn, "takes_out", False)
+        self._lock = _data_fn_lock(train_fn)
         self.arrays: dict = {}
         self._batch = 0  # B, known with the first batch
         self._strays: list = []  # (views, batch) that missed their views
@@ -1143,8 +1190,9 @@ class _RoundBuffer:
         from sparknet_tpu.data.prefetch import fresh_bytes
 
         views = self._views(t, w)
-        got = (self._fn(index, out=views) if views and self._takes_out
-               else self._fn(index))
+        with self._lock:
+            got = (self._fn(index, out=views) if views and self._takes_out
+                   else self._fn(index))
         if not views:
             self._batch = len(next(iter(got.values())))
             self.arrays = {
@@ -1152,6 +1200,14 @@ class _RoundBuffer:
                              *v.shape[1:]), v.dtype)
                 for k, v in got.items()}
             self._alloc = sum(a.nbytes for a in self.arrays.values())
+            # the buffer's pages are touched here, not by the reads that
+            # fill it: those hold the data fn's lock, and on the chip's
+            # host a batch written into fresh memory takes 51 ms, into
+            # memory written once 25, from then on 4 (PERF.md, PR 27), so
+            # a second feed over the same data fn queued behind them
+            for a in self.arrays.values():
+                a.fill(0)
+                a.fill(0)
             views = self._views(t, w)
         if fresh_bytes(got, views):
             self._strays.append((views, got))
@@ -1180,22 +1236,65 @@ def _stack_tau(train_fn, tau, num_workers):
     Owns its own batch counter: each round consumes tau*num_workers fresh
     batches regardless of how the trainer advances its iteration count.
 
-    The arrays returned are ONE persistent buffer per feed key
-    (``_RoundBuffer``) that the next call overwrites: they are valid
-    until then.  ``ParallelTrainer.train_round`` fences on
-    ``float(loss)`` before it asks again, so the transfer and the round
-    that read them are done."""
-    buf = _RoundBuffer(train_fn, tau, num_workers)
-    counter = [0]
+    The feed is always exactly ONE round ahead.  It owns two persistent
+    buffers per feed key (``_RoundBuffer``) and, from the first call on,
+    one daemon thread (``prefetch.FeedThread``) that makes every call
+    into the data fn, in the order and with the indices a serial pack
+    would.  ``fn(it)`` hands out round n, waiting under ``sn.feed.wait``
+    where the thread has not filled it yet (``ready`` = 0), and only then
+    lets the thread start on round n+1, in the buffer round n-1 was read
+    from.  ``ParallelTrainer.train_round`` fences round n-1 on
+    ``float(loss)`` before it asks for round n, so that buffer's transfer
+    is complete (jax keeps a ``device_put``'s numpy source immutable
+    until then) and, on the CPU backend where a placed array may alias
+    its source, nothing reads it any more.  So the arrays ``fn`` returns
+    are valid, and not written, until the NEXT call returns; the thread
+    is never a second round ahead, which would write a buffer whose
+    transfer may still be in flight.
+
+    An error the data fn raises on the thread surfaces from ``fn``.
+    ``fn.close()`` stops and joins the thread; a feed nobody closes
+    cannot hold the process (a daemon).  The one round read past the
+    last one asked for is the price."""
+    from sparknet_tpu.data.prefetch import DONE, FeedThread
+
+    bufs = [_RoundBuffer(train_fn, tau, num_workers) for _ in range(2)]
+    asked: queue.SimpleQueue = queue.SimpleQueue()  # ``it`` of a round to fill
+    feed = None  # the thread, from the first call on
+
+    def fill(thread):
+        index = 0
+        for buf in itertools.cycle(bufs):
+            it = asked.get()
+            if it is None:  # close()
+                return
+            for t in range(tau):
+                for w in range(num_workers):
+                    if thread.stopped:
+                        return
+                    buf.read(index, t, w)
+                    index += 1
+                buf.slot(it, t)
+            if not thread.put(dict(buf.arrays), it):
+                return
 
     def fn(it):
-        for t in range(tau):
-            for w in range(num_workers):
-                buf.read(counter[0], t, w)
-                counter[0] += 1
-            buf.slot(it, t)
-        return dict(buf.arrays)
+        nonlocal feed
+        if feed is None:
+            feed = FeedThread(fill, depth=1)
+            asked.put(it)
+        arrays = feed.get(it)
+        if arrays is DONE:
+            raise RuntimeError("the tau-round feed was closed")
+        asked.put(it + tau)  # the trainer's next ``it``; names spans only
+        return arrays
 
+    def close():
+        if feed is not None:
+            asked.put(None)
+            feed.close()
+
+    fn.close = close
     return fn
 
 

@@ -94,66 +94,116 @@ class DevicePrefetcher:
         self._num = num_iters
         self._sharding = sharding
         self._device_fn = device_fn
-        self._q: queue.Queue = queue.Queue(maxsize=depth)
-        self._err: BaseException | None = None
         self._start = start_iter
-        self._stop = threading.Event()
-        self._finished = False
-        self._thread = threading.Thread(target=self._worker, daemon=True)
-        self._thread.start()
+        self._feed = FeedThread(self._worker, depth)
 
-    def _worker(self) -> None:
+    def _worker(self, feed: FeedThread) -> None:
         # the feed thread's spans (sn.feed.put / augment / full; the data
         # fn brings its own sn.feed.read) time the HOST side of each
         # stage: the transfer and the augment are dispatched, not awaited
-        try:
-            # the ring: host batches and what was last placed from each.
-            # None until the first batch (read into a fresh array) has
-            # told the shapes and where device_put lands; stays None for
-            # a data fn without ``takes_out`` and on an aliasing backend
-            ring: list[dict] | None = None
-            placed: list = [None] * RING
-            for it in range(self._start, self._start + self._num):
-                if self._stop.is_set():
-                    return
-                slot = (it - self._start) % RING
-                if ring is None:
-                    host = self._data_fn(it)
+        #
+        # the ring: host batches and what was last placed from each.
+        # None until the first batch (read into a fresh array) has told
+        # the shapes and where device_put lands; stays None for a data
+        # fn without ``takes_out`` and on an aliasing backend
+        ring: list[dict] | None = None
+        placed: list = [None] * RING
+        for it in range(self._start, self._start + self._num):
+            if feed.stopped:
+                return
+            slot = (it - self._start) % RING
+            if ring is None:
+                host = self._data_fn(it)
+            else:
+                if not _transferred(placed[slot]):
+                    ring[slot] = _empty_like(ring[slot])
+                placed[slot] = None
+                host = self._data_fn(it, out=ring[slot])
+            with get_recorder().span("sn.feed.put", host=True, it=it,
+                                     **feed_counts(host)):
+                if self._sharding is not None:
+                    feeds = {
+                        k: jax.device_put(v, self._sharding)
+                        for k, v in host.items()
+                    }
                 else:
-                    if not _transferred(placed[slot]):
-                        ring[slot] = _empty_like(ring[slot])
-                    placed[slot] = None
-                    host = self._data_fn(it, out=ring[slot])
-                with get_recorder().span("sn.feed.put", host=True, it=it,
-                                         **feed_counts(host)):
-                    if self._sharding is not None:
-                        feeds = {
-                            k: jax.device_put(v, self._sharding)
-                            for k, v in host.items()
-                        }
-                    else:
-                        feeds = jax.device_put(host)
-                if ring is not None:
-                    placed[slot] = feeds
-                elif (it == self._start
-                        and getattr(self._data_fn, "takes_out", False)
-                        and not _aliases_host(feeds)):
-                    ring = [_empty_like(host) for _ in range(RING)]
-                del host
-                if self._device_fn is not None:
-                    with get_recorder().span("sn.feed.augment", host=True,
-                                             it=it):
-                        feeds = self._device_fn(feeds, it)
-                if not self._put(feeds, it):
-                    return
-            self._put(_DONE)
+                    feeds = jax.device_put(host)
+            if ring is not None:
+                placed[slot] = feeds
+            elif (it == self._start
+                    and getattr(self._data_fn, "takes_out", False)
+                    and not _aliases_host(feeds)):
+                ring = [_empty_like(host) for _ in range(RING)]
+            del host
+            if self._device_fn is not None:
+                with get_recorder().span("sn.feed.augment", host=True,
+                                         it=it):
+                    feeds = self._device_fn(feeds, it)
+            if not feed.put(feeds, it):
+                return
+
+    def close(self) -> None:
+        """Stop the worker and release queued device batches."""
+        self._feed.close()
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc):
+        self.close()
+
+    def __iter__(self):
+        it = self._start
+        while True:
+            item = self._feed.get(it)
+            if item is DONE:
+                return
+            it += 1
+            yield item
+
+    def __len__(self) -> int:
+        return self._num
+
+
+class _Done:
+    pass
+
+
+DONE = _Done()  # what FeedThread.get gives once the stream has ended
+
+
+class FeedThread:
+    """A daemon thread that produces ahead of one consumer, and what the
+    two share: the bounded queue between them, the stop, the way an error
+    takes to the consumer's side, and the end.  ``produce(feed)`` runs on
+    the thread and hands over each item with ``feed.put(item, it)``; it
+    returns when it has no more, or when ``put`` says the feed was
+    closed.  ``DevicePrefetcher`` produces placed batches this way,
+    ``cli._stack_tau`` whole tau-rounds."""
+
+    def __init__(self, produce: Callable[["FeedThread"], None], depth: int):
+        self._q: queue.Queue = queue.Queue(maxsize=depth)
+        self._err: BaseException | None = None
+        self._stop = threading.Event()
+        self._ended = False
+        self.thread = threading.Thread(target=self._run, args=(produce,),
+                                       daemon=True)
+        self.thread.start()
+
+    def _run(self, produce) -> None:
+        try:
+            produce(self)
         except BaseException as e:  # surfaced on the consumer side
             self._err = e
-            self._put(_DONE)
+        self.put(DONE)
 
-    def _put(self, item, it: int = -1) -> bool:
+    @property
+    def stopped(self) -> bool:
+        return self._stop.is_set()
+
+    def put(self, item, it: int = -1) -> bool:
         """Bounded put that aborts on close() so an abandoned consumer
-        doesn't leave the worker pinning device batches forever.  The
+        doesn't leave the thread pinning what it produced forever.  The
         time it is blocked on a full queue (the feed is ahead of the
         device) is the ``sn.feed.full`` span."""
         if self._stop.is_set():
@@ -172,12 +222,30 @@ class DevicePrefetcher:
                     continue
         return False
 
+    def get(self, it: int):
+        """The next item, waited for under an ``sn.feed.wait`` span whose
+        ``ready`` stat is 1 when the item was there before it was asked
+        for, else 0.  ``DONE`` once the thread has ended or the feed was
+        closed: a single-use stream, so asking again never blocks on the
+        empty queue.  What ended the thread is raised here, every time."""
+        if not self._ended:
+            with get_recorder().span("sn.feed.wait", host=True, it=it,
+                                     ready=int(not self._q.empty())):
+                item = self._q.get()
+            if item is not DONE:
+                return item
+            self._ended = True
+        if self._err is not None:
+            raise self._err
+        return DONE
+
     def close(self) -> None:
-        """Stop the worker and release queued device batches."""
+        """Stop the thread and release what it had queued."""
         self._stop.set()
         self._drain()
-        self._thread.join(timeout=5.0)
-        self._drain()  # a racing _put may have landed one item mid-drain
+        self.thread.join(timeout=5.0)
+        self._drain()  # a racing put may have landed one item mid-drain
+        self._ended = True
 
     def _drain(self) -> None:
         while True:
@@ -185,38 +253,3 @@ class DevicePrefetcher:
                 self._q.get_nowait()
             except queue.Empty:
                 break
-
-    def __enter__(self):
-        return self
-
-    def __exit__(self, *exc):
-        self.close()
-
-    def __iter__(self):
-        if self._finished:
-            # single-use stream: a second iteration would block forever on
-            # the empty queue
-            if self._err is not None:
-                raise self._err
-            return
-        it = self._start
-        while True:
-            with get_recorder().span("sn.feed.wait", host=True, it=it):
-                item = self._q.get()
-            it += 1
-            if item is _DONE:
-                self._finished = True
-                if self._err is not None:
-                    raise self._err
-                return
-            yield item
-
-    def __len__(self) -> int:
-        return self._num
-
-
-class _Done:
-    pass
-
-
-_DONE = _Done()

@@ -229,8 +229,8 @@ def test_prefetcher_close_releases_worker():
     it = iter(pf)
     next(it)  # consume one, then abandon
     pf.close()
-    assert not pf._thread.is_alive()
-    assert pf._q.qsize() == 0
+    assert not pf._feed.thread.is_alive()
+    assert pf._feed._q.qsize() == 0
     # active threads back to baseline (no leaked workers)
     assert threading.active_count() < 20
 

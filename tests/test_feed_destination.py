@@ -1,8 +1,9 @@
 """Destination passing in the host feed: the cursor writes each record
 once, into the array its consumer hands it.
 
-``db_minibatches`` fresh and into a destination, ``cli._stack_tau`` /
-``_widen_batch`` over their one persistent buffer, and the
+``db_minibatches`` fresh and into a destination, ``cli._widen_batch``
+over its one persistent buffer, ``cli._stack_tau`` over its two (its feed
+thread fills round n+1 while round n is out), and the
 ``DevicePrefetcher``'s ring of host batches, all against the records the
 DB was written from.  Everything runs on the CPU, where ``device_put``
 may alias host memory: the ring is also forced on through a
@@ -11,6 +12,7 @@ may alias host memory: the ring is also forced on through a
 
 import itertools
 import json
+import threading
 
 import jax
 import numpy as np
@@ -232,8 +234,10 @@ def test_the_db_data_fn_takes_a_destination_and_says_what_it_allocated(
 @pytest.mark.parametrize("pack", ["stack_tau", "widen_batch"])
 def test_a_pack_fills_one_buffer_and_equals_the_plain_batches(
         job, tmp_path, pack, hook):
+    """``_widen_batch``: one buffer.  ``_stack_tau``: two, in turn."""
     flags, records = job
-    tau, workers, calls = (3, 2, 3) if pack == "stack_tau" else (1, 2, 4)
+    tau, workers, calls = (3, 2, 5) if pack == "stack_tau" else (1, 2, 4)
+    buffers = 2 if pack == "stack_tau" else 1
     journal = str(tmp_path / "journal.jsonl")
     got = []
 
@@ -251,6 +255,8 @@ def test_a_pack_fills_one_buffer_and_equals_the_plain_batches(
                 got.append(({k: v.copy() for k, v in feeds.items()},
                             {k: base_pointer(v) for k, v in feeds.items()}))
         finally:
+            if pack == "stack_tau":
+                fn.close()  # its thread journals its reads
             rec.close()
             set_recorder(None)
 
@@ -270,22 +276,353 @@ def test_a_pack_fills_one_buffer_and_equals_the_plain_batches(
                 want = want[0]
             np.testing.assert_array_equal(feeds[key], want)
             assert feeds[key].dtype == want.dtype
-    assert len({tuple(sorted(p.items())) for _, p in got}) == 1  # one buffer
+    where = [tuple(sorted(p.items())) for _, p in got]
+    assert len(set(where)) == buffers
+    assert where == (where[:buffers] * calls)[:calls]  # in turn
 
+    # the spans of the rounds handed out (a round feed reads one round
+    # more, or part of one, before it is closed)
     nbytes = BATCH * (3 * 16 * 16 + 4)
-    reads = journaled(journal, "sn.feed.read")
-    stacks = journaled(journal, "sn.feed.stack")
+    reads = journaled(journal, "sn.feed.read")[:calls * per_call]
+    stacks = journaled(journal, "sn.feed.stack")[:calls * tau]
     assert len(reads) == calls * per_call and len(stacks) == calls * tau
     assert all(s["images"] == workers * BATCH for s in stacks)
-    # the first call makes the buffer (and reads its first batch to learn
-    # the shapes); nothing batch-sized is allocated by the pack after that
-    assert [s["alloc_bytes"] for s in stacks] == (
-        [per_call * nbytes] + [0] * (len(stacks) - 1))
+    # the first call on a buffer makes it (and reads its first batch to
+    # learn the shapes); nothing batch-sized is allocated by the pack
+    # after that
+    made = [call * tau for call in range(buffers)]
+    assert [s["alloc_bytes"] for s in stacks] == [
+        per_call * nbytes if i in made else 0 for i in range(len(stacks))]
     if hook:
-        assert [r["alloc_bytes"] for r in reads] == (
-            [nbytes] + [0] * (len(reads) - 1))
+        first = [call * per_call for call in range(buffers)]
+        assert [r["alloc_bytes"] for r in reads] == [
+            nbytes if i in first else 0 for i in range(len(reads))]
     else:
         assert all(r["alloc_bytes"] == nbytes for r in reads)
+    waits = journaled(journal, "sn.feed.wait")
+    if pack == "stack_tau":  # one wait a round, each saying if it waited
+        assert [w["it"] for w in waits] == list(range(calls))
+        assert all(w["ready"] in (0, 1) for w in waits)
+        assert waits[0]["ready"] == 0  # nothing is read before it is asked
+    else:
+        assert not waits
+
+
+# ------------------------------------------------ the round feed's thread
+TAU, WORKERS = 3, 2
+ROUND = TAU * WORKERS  # batches a round
+
+
+def numbered(on_call=lambda index: None):
+    """A data fn whose batch ``index`` holds ``index`` everywhere, and the
+    indices it was asked for, in order.  ``on_call(index)`` runs inside
+    each call, after the index was noted."""
+    seen = []
+
+    def fn(index):
+        seen.append(index)
+        on_call(index)
+        return {"data": np.full((BATCH, 2), index, np.int32),
+                "label": np.full(BATCH, index, np.int32)}
+
+    return fn, seen
+
+
+def batches_of(feeds):
+    """The batch indices of one round of ``numbered``, in slot order."""
+    return feeds["label"][:, ::BATCH].ravel().tolist()
+
+
+def test_the_next_round_is_read_before_it_is_asked_for():
+    last_of = {n: threading.Event() for n in range(3)}
+
+    def on_call(index):
+        if index % ROUND == ROUND - 1 and index // ROUND in last_of:
+            last_of[index // ROUND].set()
+
+    data_fn, seen = numbered(on_call)
+    fn = cli._stack_tau(data_fn, TAU, WORKERS)
+    try:
+        assert seen == []  # a feed that is never asked reads nothing
+        first = fn(0)
+        assert batches_of(first) == list(range(ROUND))
+        # round 1 is read to its last batch with nobody asking for it
+        assert last_of[1].wait(timeout=30)
+        assert batches_of(first) == list(range(ROUND))
+    finally:
+        fn.close()
+    assert seen == list(range(2 * ROUND))  # and not one batch of round 2
+
+
+def test_the_feed_is_one_round_ahead_and_leaves_the_round_in_hand_alone():
+    filled = {n: threading.Event() for n in range(4)}
+    in_hand = {}
+
+    def on_call(index):
+        n, i = divmod(index, ROUND)
+        # while round n is read, round n-1 is the one in hand: untouched
+        if "feeds" in in_hand:
+            assert batches_of(in_hand["feeds"]) == list(
+                range((n - 1) * ROUND, n * ROUND))
+        if i == ROUND - 1:
+            filled[n].set()
+
+    data_fn, seen = numbered(on_call)
+    fn = cli._stack_tau(data_fn, TAU, WORKERS)
+    try:
+        for n in range(3):
+            in_hand["feeds"] = fn(n * TAU)
+            assert batches_of(in_hand["feeds"]) == list(
+                range(n * ROUND, (n + 1) * ROUND))
+            # round n+1 is read whole, and there the thread stops: it
+            # would write round n+2 into the buffer of the round in hand
+            assert filled[n + 1].wait(timeout=30)
+            assert max(seen) == (n + 2) * ROUND - 1
+            assert batches_of(in_hand["feeds"]) == list(
+                range(n * ROUND, (n + 1) * ROUND))
+    finally:
+        fn.close()  # joins: whatever the thread was going to read, it has
+    assert seen == list(range(4 * ROUND))  # every batch once, in order
+
+
+def test_two_feeds_over_one_data_fn_neither_raise_nor_lose_a_batch(job):
+    """The benchmark's order (jobs/tau_round.py): a one-device and a mesh
+    feed over the SAME data fn, a warm-up round each, the one-device
+    phase, then the mesh phase while the one-device feed's thread is
+    still a round ahead.  ``db_stream`` drives one generator: two threads
+    inside it raise ``ValueError: generator already executing``."""
+    flags, records = job
+    handed = {"one": [], "mesh": []}
+    returned = []  # the cursor's batches in the order it gave them
+
+    def body(new_train_fn):
+        inner = new_train_fn()
+
+        def train_fn(it, out=None):
+            assert cli._data_fn_lock(train_fn).locked()
+            feeds = inner(it, out=out)
+            feeds["label"][...] = len(returned)  # which of its batches
+            returned.append(feeds["data"].copy())
+            return feeds
+
+        train_fn.takes_out = True
+        one_fn = cli._stack_tau(train_fn, TAU, 1)
+        mesh_fn = cli._stack_tau(train_fn, TAU, WORKERS)
+        try:
+            for name, fn, rounds in (("one", one_fn, 1), ("mesh", mesh_fn, 1),
+                                     ("one", one_fn, 4), ("mesh", mesh_fn, 4)):
+                for _ in range(rounds):
+                    feeds = fn(len(handed[name]) * TAU)
+                    handed[name].append(
+                        {k: v.copy() for k, v in feeds.items()})
+        finally:
+            one_fn.close()
+            mesh_fn.close()
+
+    with_train_fn(flags, body)
+    # the cursor was read in its own order, batch after batch
+    for i, data in enumerate(returned):
+        np.testing.assert_array_equal(data, batch_of(records, i)[0])
+    # every round handed out is TAU x workers of those batches, whole; a
+    # feed's own come in the cursor's order, and none went to both
+    taken = {}
+    for name, workers in (("one", 1), ("mesh", WORKERS)):
+        assert len(handed[name]) == 5
+        taken[name] = []
+        for feeds in handed[name]:
+            data = feeds["data"].reshape(TAU * workers, BATCH, *IMAGE)
+            labels = feeds["label"].reshape(TAU * workers, BATCH)
+            for part, label in zip(data, labels):
+                assert len(set(label)) == 1
+                np.testing.assert_array_equal(part, returned[label[0]])
+                taken[name].append(int(label[0]))
+        assert taken[name] == sorted(set(taken[name]))
+    assert not set(taken["one"]) & set(taken["mesh"])
+    # what was read and not handed out is the two feeds' look-ahead
+    left = len(returned) - len(taken["one"]) - len(taken["mesh"])
+    assert 0 <= left <= TAU * (1 + WORKERS)
+
+
+def test_the_data_fns_lock_serves_its_waiters_in_turn():
+    """A feed with many reads to make does not starve one with few: the
+    thread that releases the lock and asks again at once goes behind the
+    one that was already waiting."""
+    lock = cli._data_fn_lock(lambda it: None)
+    order = []
+    asked = threading.Event()
+
+    def waiter():
+        asked.set()
+        with lock:
+            order.append("waiter")
+
+    with lock:
+        assert lock.locked()
+        thread = threading.Thread(target=waiter, daemon=True)
+        thread.start()
+        assert asked.wait(timeout=30)
+        while lock._next < 2:  # until the waiter has drawn its ticket
+            assert thread.is_alive()
+    with lock:  # asked for at once, and still served second
+        order.append("releaser")
+    thread.join(timeout=30)
+    assert order == ["waiter", "releaser"] and not lock.locked()
+
+
+def test_many_feeds_over_one_cursor_share_it_without_a_lost_batch():
+    """More feeds than cores over ONE generator, each drained by a thread
+    of its own under a short switch interval: a generator entered twice
+    raises, a batch counted twice or not at all breaks the census."""
+    import sys
+    import time
+
+    def cursor():
+        i = 0
+        while True:
+            time.sleep(0)  # gives the interpreter away inside the generator
+            yield {"data": np.full((BATCH, 2), i, np.int32),
+                   "label": np.full(BATCH, i, np.int32)}
+            i += 1
+
+    gen = cursor()
+    read = []
+
+    def data_fn(_):
+        feeds = next(gen)
+        read.append(int(feeds["label"][0]))
+        return feeds
+
+    feeds_n, rounds = 12, 25
+    got = [[] for _ in range(feeds_n)]
+    errors = []
+
+    def drain(k, fn):
+        try:
+            for n in range(rounds):
+                got[k].extend(batches_of(fn(n * TAU)))
+        except BaseException as e:  # told on the main thread, below
+            errors.append(e)
+
+    fns = [cli._stack_tau(data_fn, TAU, WORKERS) for _ in range(feeds_n)]
+    consumers = [threading.Thread(target=drain, args=(k, fn), daemon=True)
+                 for k, fn in enumerate(fns)]
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        for c in consumers:
+            c.start()
+        for c in consumers:
+            c.join(timeout=120)
+        assert not any(c.is_alive() for c in consumers)
+    finally:
+        sys.setswitchinterval(interval)
+        for fn in fns:
+            fn.close()
+    assert not errors, errors
+    assert read == list(range(len(read)))  # the cursor's order, no gap
+    for mine in got:
+        assert len(mine) == rounds * ROUND and mine == sorted(mine)
+    handed = sorted(i for mine in got for i in mine)
+    assert len(set(handed)) == len(handed) and set(handed) <= set(read)
+    # read and not handed out: at most a round ahead per feed
+    assert len(read) - len(handed) <= feeds_n * ROUND
+
+
+@pytest.mark.parametrize("error", [SystemExit, ValueError])
+def test_an_error_in_the_data_fn_surfaces_from_the_feed(error):
+    def on_call(index):
+        if index == ROUND + 2:
+            raise error("the cursor failed")
+
+    data_fn, _ = numbered(on_call)
+    fn = cli._stack_tau(data_fn, TAU, WORKERS)
+    try:
+        assert batches_of(fn(0)) == list(range(ROUND))
+        for _ in range(2):  # and again, however often it is asked
+            with pytest.raises(error, match="the cursor failed"):
+                fn(TAU)
+    finally:
+        fn.close()
+
+
+def test_close_joins_the_feed_thread():
+    before = set(threading.enumerate())
+    data_fn, seen = numbered()
+    fn = cli._stack_tau(data_fn, TAU, WORKERS)
+    fn.close()  # never asked: no thread yet, nothing to join
+    assert set(threading.enumerate()) == before and seen == []
+    fn(0)
+    (thread,) = set(threading.enumerate()) - before
+    assert thread.daemon  # a feed nobody closes cannot hold the process
+    fn.close()
+    assert not thread.is_alive()
+    assert len(seen) <= 2 * ROUND
+    with pytest.raises(RuntimeError, match="closed"):
+        fn(TAU)
+
+
+def test_rounds_over_the_feed_equal_rounds_over_batches_packed_by_hand(
+        job, tmp_path):
+    """Three tau-rounds on a CPU mesh of two, fed by ``_stack_tau``, and
+    three fed the same batches stacked and concatenated by hand: the
+    losses and every parameter bit for bit."""
+    from sparknet_tpu.parallel.mesh import data_parallel_mesh
+    from sparknet_tpu.parallel.trainer import ParallelTrainer
+
+    flags, records = job
+    # the job's net with an output per label of its records (0..6) and a
+    # rate their raw 0..255 pixels can take
+    (tmp_path / "net.prototxt").write_text(
+        NET.replace("num_output: 4", "num_output: 7"))
+    solver = tmp_path / "solver.prototxt"
+    solver.write_text(
+        f'net: "{tmp_path}/net.prototxt"\nbase_lr: 1e-6\nmax_iter: 100\n'
+        "display: 0\n")
+    flags = ["--solver", str(solver), *flags[2:]]
+    ends = {}
+
+    def by_hand(n):
+        plain = [batch_of(records, n * ROUND + i) for i in range(ROUND)]
+        return {key: np.stack([
+            np.concatenate([plain[t * WORKERS + w][part]
+                            for w in range(WORKERS)])
+            for t in range(TAU)]) for key, part in (("data", 0), ("label", 1))}
+
+    def as_train(args):
+        for feed in ("stack_tau", "by_hand"):
+            net_param, solver_cfg = cli._build_net_and_solver(args)
+            solver = cli._make_solver(solver_cfg, net_param, args)
+            train_fn, _ = cli._data_fns(args, solver.train_net,
+                                        test_net=solver.test_net)
+            trainer = ParallelTrainer(
+                solver, mesh=data_parallel_mesh(WORKERS), tau=args.tau)
+            trainer.feed_device_fn = train_fn.trainer_device_fn
+            if feed == "stack_tau":
+                fn = cli._stack_tau(train_fn, args.tau,
+                                    trainer.num_local_workers)
+            else:
+                fn = lambda it: by_hand(it // TAU)  # noqa: E731
+            losses = [trainer.train_round(fn) for _ in range(3)]
+            if feed == "stack_tau":
+                fn.close()
+            ends[feed] = (losses, jax.tree_util.tree_map(
+                np.asarray, trainer.variables))
+        return 0
+
+    orig = cli.cmd_train
+    cli.cmd_train = as_train
+    try:
+        assert cli.main(["train", *flags, "--tau", str(TAU)]) == 0
+    finally:
+        cli.cmd_train = orig
+    (losses, variables), (want_losses, want) = ends["stack_tau"], ends["by_hand"]
+    assert losses == want_losses and all(np.isfinite(losses))
+    assert len(set(losses)) == 3  # the rounds saw different batches
+    got_leaves, want_leaves = (jax.tree_util.tree_leaves(v)
+                               for v in (variables, want))
+    assert len(got_leaves) == len(want_leaves) > 0
+    for a, b in zip(got_leaves, want_leaves):
+        np.testing.assert_array_equal(a, b)
 
 
 def test_widen_batch_keeps_as_many_batches_as_it_is_asked_to(job):
@@ -311,11 +648,12 @@ def test_widen_batch_keeps_as_many_batches_as_it_is_asked_to(job):
 def force_the_ring_on(monkeypatch):
     """A ``device_put`` that copies, as a chip's does, and a prefetcher
     that believes it; the host arrays it was given, in order."""
-    sources = []
+    sources, kept = [], []
     real = jax.device_put
 
     def copying_put(x, *a, **k):
         sources.append(base_pointer(x["data"]))
+        kept.append(x["data"])  # so no later array gets a given-up one's address
         return real(jax.tree_util.tree_map(np.array, x), *a, **k)
 
     monkeypatch.setattr(prefetch.jax, "device_put", copying_put)

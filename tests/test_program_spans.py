@@ -7,7 +7,8 @@ back.  Two traces are taken once per module, through the front door's
 own functions (``cli.main`` with ``cmd_train`` swapped for the body, the
 way the benchmark drives it): two ``Solver.step`` chunks fed by a
 ``DevicePrefetcher`` from a tiny ``db:`` feed, and two tau=2
-``ParallelTrainer.train_round`` calls on two virtual devices.
+``ParallelTrainer.train_round`` calls on two virtual devices, fed by
+``cli._stack_tau``, whose thread reads a round ahead.
 """
 
 import glob
@@ -154,10 +155,11 @@ def rounds(job):
         trainer.feed_device_fn = train_fn.trainer_device_fn
         tau_fn = cli._stack_tau(train_fn, args.tau,
                                 trainer.num_local_workers)
-        trainer.train_round(tau_fn)  # compile
+        trainer.train_round(tau_fn)  # compile; the feed reads round 1
         with profiling.trace(str(tmp / "rounds")):
+            trainer.train_round(tau_fn)  # round 1; the feed reads round 2
             trainer.train_round(tau_fn)
-            trainer.train_round(tau_fn)
+            tau_fn.close()  # the feed's thread ends inside the trace
         return 0
 
     run_as_train([*flags, "--tau", str(TAU)], body)
@@ -292,8 +294,9 @@ def test_a_step_waits_inside_itself_and_fences_outside(solo):
 
 # ---------------------------------------------------------- the tau round
 @pytest.mark.parametrize("name", [
-    "sn.round", "sn.round.data", "sn.feed.read", "sn.feed.stack",
-    "sn.feed.put", "sn.feed.augment", "sn.round.dispatch", "sn.round.fence"])
+    "sn.round", "sn.round.data", "sn.feed.wait", "sn.feed.read",
+    "sn.feed.stack", "sn.feed.put", "sn.feed.augment", "sn.round.dispatch",
+    "sn.round.fence"])
 def test_the_round_trace_holds(rounds, name):
     assert named(rounds, name), sorted({s["name"] for s in rounds})
 
@@ -303,34 +306,58 @@ def test_a_round_is_data_put_augment_dispatch_fence_in_that_order(rounds):
              "sn.round.dispatch", "sn.round.fence"]
     outer = named(rounds, "sn.round")
     assert len(outer) == 2
-    assert len({s["thread"] for s in rounds
-                if s["name"].startswith("sn.")}) == 1  # all on one thread
+    # the round on the main thread, the reads and stacks on the feed's
+    (main,) = {s["thread"] for s in outer}
+    for name in [*order, "sn.feed.wait"]:
+        assert {s["thread"] for s in named(rounds, name)} == {main}, name
+    (feed,) = {s["thread"] for s in rounds
+               if s["name"] in ("sn.feed.read", "sn.feed.stack")}
+    assert feed != main
+    images = TAU * WORKERS * BATCH
+    nbytes = images * (3 * 16 * 16 + 4)
+    waits = []
     for rnd in outer:
         stages = [next(s for s in named(rounds, n) if inside(s, rnd))
                   for n in order]
         for a, b in zip(stages, stages[1:]):
             assert a["end"] <= b["start"], (a["name"], b["name"])
-        data = stages[0]
-        stacks = [s for s in named(rounds, "sn.feed.stack")
-                  if inside(s, data)]
-        reads = [r for r in named(rounds, "sn.feed.read") if inside(r, data)]
-        assert len(reads) == TAU * WORKERS
-        # one span a slot, after that slot's reads, around what is left
-        # of the pack's own work: the reads already filled the buffer
-        assert len(stacks) == TAU
-        images = TAU * WORKERS * BATCH
-        nbytes = images * (3 * 16 * 16 + 4)
-        for t, stack in enumerate(stacks):
-            mine = reads[t * WORKERS:(t + 1) * WORKERS]
-            assert all(r["end"] <= stack["start"] for r in mine)
-            # the buffer was made in the warm-up round: nothing allocated
-            assert stack["stats"] == {
-                "it": rnd["stats"]["step_num"], "images": WORKERS * BATCH,
-                "bytes": nbytes // TAU, "alloc_bytes": 0}
-        assert all(r["stats"]["alloc_bytes"] == 0 for r in reads)
+        # the round's data is a wait for the feed, which says whether the
+        # round was filled before it was asked for
+        (wait,) = [w for w in named(rounds, "sn.feed.wait")
+                   if inside(w, stages[0])]
+        assert wait["stats"]["it"] == rnd["stats"]["step_num"]
+        assert wait["stats"]["ready"] in (0, 1)
+        waits.append(wait)
         assert stages[1]["stats"]["images"] == images
         assert stages[1]["stats"]["bytes"] == nbytes
     assert outer[1]["stats"]["step_num"] == outer[0]["stats"]["step_num"] + TAU
+
+    # the feed reads round 2 (the warm-up was round 0) from the moment
+    # round 1 is handed out, and has it whole before it hands it out: TAU
+    # x WORKERS reads in the data fn's order, a stack after each slot's
+    per_round = TAU * WORKERS
+    reads = [r for r in named(rounds, "sn.feed.read")
+             if r["stats"]["it"] // per_round == 2]
+    assert [r["stats"]["it"] for r in reads] == list(
+        range(2 * per_round, 3 * per_round))
+    assert waits[0]["end"] <= reads[0]["start"]
+    stacks = [s for s in named(rounds, "sn.feed.stack")
+              if reads[0]["start"] <= s["start"] <= waits[1]["end"]]
+    assert len(stacks) == TAU and stacks[-1]["end"] <= waits[1]["end"]
+    for t, stack in enumerate(stacks):
+        mine = reads[t * WORKERS:(t + 1) * WORKERS]
+        assert all(r["end"] <= stack["start"] for r in mine)
+        assert all(stack["end"] <= r["start"]
+                   for r in reads[(t + 1) * WORKERS:])
+        # it names the round it is for (the trainer's next iteration);
+        # both buffers were made before the trace: nothing allocated
+        assert stack["stats"] == {
+            "it": outer[1]["stats"]["step_num"], "images": WORKERS * BATCH,
+            "bytes": nbytes // TAU, "alloc_bytes": 0}
+    assert all(r["stats"]["alloc_bytes"] == 0 for r in reads)
+    # and never a batch of the round after the next
+    assert all(r["stats"]["it"] < 4 * per_round
+               for r in named(rounds, "sn.feed.read"))
 
 
 # ------------------------------------------------------------ names, scopes
