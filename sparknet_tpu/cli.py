@@ -21,15 +21,15 @@ from __future__ import annotations
 
 import argparse
 import contextlib
-import itertools
 import json
 import os
-import queue
 import sys
-import threading
-import weakref
 
 import numpy as np
+
+# benchmarks/harness/front_door.py calls ``cli._stack_tau``; a ``benchmark``
+# issue repoints it at ``data.rounds`` (ROADMAP.md, D11)
+from sparknet_tpu.data.rounds import stack_tau as _stack_tau, widen_batch
 
 
 def _build_net_and_solver(args):
@@ -76,65 +76,23 @@ def _net_root(solver_msg, solver_path: str) -> str:
         d = parent
 
 
-def _feed_shapes(net, args=None):
-    shapes = net.feed_shapes()
-    if args is not None:
-        shapes.update(_db_peek_shapes(args, net))
-    if not shapes:
-        raise SystemExit(
-            "net declares no input shapes; use RDD/Input layers, keep the "
-            "DB at data_param.source on disk, or stream one with --data "
-            "db:<path> (a Data layer's geometry comes from its DB — ref: "
-            "data_layer.cpp DataLayerSetUp)"
-        )
-    return shapes
-
-
-def _db_peek_shapes(args, net) -> dict:
-    """Shapes for ``Data``-layer tops peeked from the user's ``--data db:``
-    path — Caffe parity (geometry comes from the DB, data_layer.cpp:40-48)
-    with the streamed DB standing in for a ``data_param.source`` that isn't
-    on this machine.  Empty dict when nothing needs peeking."""
-    data = getattr(args, "data", "") or ""
-    if not data.startswith("db:"):
-        return {}
-    known = net.feed_shapes()
-    missing = [
-        l for l in net.input_layers
-        if getattr(l, "TYPE", "") == "Data"
-        and any(t not in known for t in l.tops)
-    ]
-    if not missing:
-        return {}
-    import jax
-
-    from sparknet_tpu.data.createdb import peek_db_shape
-
-    # expand {proc} to THIS process: in the per-worker-DB layout a host
-    # may hold only its own shard (cmd_train initializes jax.distributed
-    # before any Solver is built, so the index is correct here)
-    path = data[3:].split(",")[0].replace("{proc}", str(jax.process_index()))
-    try:
-        chw = peek_db_shape(path)
-    except (OSError, ValueError) as e:
-        raise SystemExit(f"--data db: {path}: {e}") from None
-    out = {}
-    for l in missing:
-        shapes = l.shapes_for_chw(chw)
-        if shapes:
-            out.update(zip(l.tops, shapes))
-    return out
-
-
 def _peeked_feed_shapes(args, net_param):
     """--data db: shapes for a throwaway TRAIN-phase probe net (shared by
     every Solver/TPUNet construction site)."""
-    if not (getattr(args, "data", "") or "").startswith("db:"):
+    from sparknet_tpu.data import feed
+
+    spec = getattr(args, "data", "")
+    if feed.parse_spec(spec)[0] != "db:":
         return None  # the probe Network below would be wasted work
+    import jax
+
     from sparknet_tpu.common import Phase
     from sparknet_tpu.compiler.graph import Network
 
-    return _db_peek_shapes(args, Network(net_param, Phase.TRAIN)) or None
+    # cmd_train initializes jax.distributed before any Solver is built,
+    # so the process index is correct here
+    return feed.db_peek_shapes(spec, Network(net_param, Phase.TRAIN),
+                               jax.process_index()) or None
 
 
 def _make_solver(solver_cfg, net_param, args):
@@ -174,660 +132,29 @@ def _clean_shape_errors():
         ) from None
 
 
-def _internalize(fn):
-    """Wrap a data fn so canonical-NCHW host batches (cifar readers, DB
-    cursors, listfile sources — every real data plane emits blob order)
-    arrive in the INTERNAL layout (``Config.layout``, ops/layout.py).
-    A passthrough under nchw; preserves an attached ``device_fn``
-    (whose DeviceAugment already speaks the internal layout) and
-    ``pipeline_factory`` (whose sources produce the internal layout
-    NATIVELY — the process feed never pays this per-batch transpose,
-    which is the wire half of the nhwc zero-transpose contract).  A
-    destination (``takes_out``) is internal too: the cursor is handed
-    its canonical view and fills it through the strides."""
-    from sparknet_tpu.ops.layout import (
-        feeds_to_internal,
-        from_internal,
-        is_nhwc,
-    )
-
-    if fn is None or not is_nhwc():
-        return fn
-
-    def wrapped(it, out=None):
-        if out is None:
-            return feeds_to_internal(fn(it))
-        return feeds_to_internal(fn(it, out={
-            k: from_internal(v, "nhwc") for k, v in out.items()}))
-
-    return _carry_feed_attrs(fn, wrapped)
-
-
-def _carry_feed_attrs(fn, wrapped):
-    """The hooks a train data fn carries, kept across a wrapper.
-    ``takes_out``: the fn is ``fn(it, out=None)`` and writes the batch
-    into the arrays of ``out`` (one per feed key, ``[batch, ...]``) when
-    it can, so the consumer that owns them can reuse them; the batch it
-    returns says where the data landed."""
-    for attr in ("device_fn", "trainer_device_fn", "pipeline_factory",
-                 "takes_out"):
-        if hasattr(fn, attr):
-            setattr(wrapped, attr, getattr(fn, attr))
-    return wrapped
-
-
-def _read_span(fn, images, **counts):
-    """``sn.feed.read`` around a host data fn: one span per host batch,
-    from the cursor to the decoded, collated, cast and internalized
-    batch, on whichever thread asks for it (the DevicePrefetcher's feed
-    thread in the solo loop, ``_stack_tau``'s in the trainer's).
-    ``alloc_bytes``: what of the batch lies in newly allocated arrays,
-    0 when it all went into the caller's ``out``.  ``images`` counts the
-    batch's records (sequences for a ``tokens:`` source, whose ``counts``
-    add ``tokens``)."""
-    from sparknet_tpu.data.prefetch import fresh_bytes
-
-    def wrapped(it, out=None):
-        with _host_span("sn.feed.read", it=it, images=images,
-                        **counts) as span:
-            feeds = fn(it, out=out)
-            span.set(alloc_bytes=fresh_bytes(feeds, out))
-            return feeds
-
-    return _carry_feed_attrs(fn, wrapped)
-
-
-def _host_span(name, **counts):
-    """A host-only span of the program's one span type (obs/recorder.py
-    ``Span``): a profiler annotation always, a journal line when
-    ``SPARKNET_OBS`` is armed."""
-    from sparknet_tpu.obs import get_recorder
-
-    return get_recorder().span(name, host=True, **counts)
-
-
-def _attach_device_augment(train_fn, cfg, pid, seed=None):
-    """Attach the in-XLA transform as the async feed's ``device_fn`` —
-    the key policy lives in :meth:`DeviceAugment.device_fn`, shared by
-    the threaded prefetcher and the process pipeline's device stage —
-    plus the trainer-path twin (``trainer_device_fn``): the hook
-    ``ParallelTrainer``/``ElasticTrainer`` apply after their own feed
-    placement, so the uint8 wire reaches the chip on the tau path too."""
-    from sparknet_tpu.data import DeviceAugment
-
-    try:
-        aug = DeviceAugment(cfg)
-    except ValueError as e:
-        raise SystemExit(f"transform_param: {e}") from None
-    train_fn.device_fn = aug.device_fn(pid, seed)
-    train_fn.trainer_device_fn = aug.trainer_device_fn(pid, seed)
-    return train_fn
-
-
-def _feed_mode() -> str:
-    """The run's host feed architecture (``Config.feed``)."""
-    from sparknet_tpu.common import get_config
-
-    return get_config().feed
-
-
-def _device_augment_guards(args):
-    """Shared preconditions for --augment device (any source).
-
-    The distributed trainer path (tau > 1 / --distributed /
-    --elastic-alpha) needs NO async-feed precondition: the trainer owns
-    its own feed placement and applies the augment post-placement
-    (``trainer_device_fn`` -> ``ParallelTrainer.feed_device_fn``), so
-    uint8 wire batches work with the threaded AND process feeds alike.
-    Only the solo step loop requires an async device stage to dispatch
-    the augment on."""
-    if (getattr(args, "tau", 1) > 1
-            or getattr(args, "distributed", False)
-            or getattr(args, "elastic_alpha", 0.0) > 0):
-        return
-    if getattr(args, "prefetch", 0) <= 0 and _feed_mode() != "process":
-        raise SystemExit(
-            "--augment device rides the async feed: pass --prefetch N "
-            "or --feed process (the DeviceAugment dispatch belongs on "
-            "the feed's device stage, not the step loop)")
-
-
-def _auto_data(args, net) -> str:
-    """Resolve the ``--data auto`` sentinel (the default): a net whose
-    own data layers are self-describing streams them — ``caffe train
-    --solver=x`` semantics — otherwise synthetic batches (zoo/RDD nets,
-    where smoke runs feed random data by design).  Declaration check
-    only (cheap, no file I/O): the proto branch builds the source and
-    raises the loud cannot-stream error for unreadable declared sources.
-    Returns ``args.data`` unchanged when it isn't ``auto``."""
-    if args.data != "auto":
-        return args.data
-    from sparknet_tpu.data.listfile import _SOURCES
-
-    if any(l.type in _SOURCES for l in net.input_layers):
-        return "proto"
-    return "synthetic"
-
-
 def _data_fns(args, net, test_net=None):
-    """(train_fn, test_fn) from --data.
-
-    ``test_net``: when the caller holds a distinct TEST-phase net whose
-    own Data layer declares transform_param (crop/mean/scale), the test
-    stream honors THOSE params — the reference transforms each phase with
-    its own declaration (ref: data_transformer.cpp + net.cpp phase
-    filtering); without it the train net's params cover both phases.
-
-    Resolves the ``auto`` sentinel IN PLACE (``args.data`` holds the
-    concrete mode afterwards — cmd_train's TEST-net source hookup reads
-    it; callers that need the mode resolved earlier call ``_auto_data``
-    themselves).
-
-    In a multi-process job each process must stream DIFFERENT data (its
-    own partition, ref: CifarApp.scala:118-130 per-executor RDD
-    partitions): batch indices interleave by process id and the
-    synthetic stream seeds per process."""
+    """(train feed, test fn) from --data: the CLI's adapter to
+    ``data.feed.open_feeds``, which knows no flag names.  Resolves the
+    ``auto`` sentinel IN PLACE (``args.data`` holds the concrete mode
+    afterwards).  ``benchmarks/harness/front_door.py`` calls it by this
+    name; a ``benchmark`` issue repoints it at ``data.feed`` (ROADMAP.md,
+    D11)."""
     import jax
 
-    was_auto = args.data == "auto"
-    args.data = _auto_data(args, net)
+    from sparknet_tpu.data import feed
 
-    if (getattr(args, "augment", "host") == "device"
-            and not args.data.startswith(("cifar:", "db:"))):
-        raise SystemExit(
-            "--augment device is wired to the cifar: and db: sources "
-            "(other sources transform on the host)")
-
-    pid, nproc = jax.process_index(), jax.process_count()
-
-    if args.data == "proto":
-        # the net's OWN data-layer params drive the host stream — a
-        # reference Data/ImageData/WindowData/HDF5Data prototxt trains end
-        # to end with no surgery (ref: data_layer.cpp, image_data_layer.cpp,
-        # window_data_layer.cpp, hdf5_data_layer.cpp read these sources
-        # inside the layer; here the host reader replaces the layer's
-        # prefetch thread).  Handled before any feed-shape deref: these
-        # sources define their own geometry.
-        from sparknet_tpu.data.listfile import source_from_net
-
-        try:
-            train_src = source_from_net(
-                net, seed=1234 + pid + (getattr(args, "seed", 0) or 0),
-                anchor=getattr(args, "solver", ""))
-        except (OSError, ValueError, LookupError) as e:
-            mode = "auto" if was_auto else "proto"
-            # never silently substitute random data for a declared
-            # source — a garbage model trained without error is the
-            # worst outcome
-            raise SystemExit(
-                f"--data {mode}: the net's data layer declares a source "
-                f"that cannot stream ({e}); pass --data db:<path> / "
-                "cifar:<dir> to point at the data, or --data synthetic "
-                "to smoke-run on random batches"
-            ) from None
-
-        # Eval fallback: a SEPARATE lazily-built instance with a fixed
-        # seed so every process scores the identical stream (the cifar/db
-        # paths' sum-then-normalize invariant) and eval cadence can't
-        # advance the training stream.  Lazy because the usual train_val
-        # case replaces it with the TEST net's own source (cmd_train) —
-        # re-parsing a large window file for a throwaway would be waste.
-        eval_state: dict = {}
-
-        def eval_src(b):
-            if "src" not in eval_state:
-                try:
-                    eval_state["src"] = source_from_net(
-                        net, seed=4321, anchor=getattr(args, "solver", ""))
-                except (OSError, ValueError, LookupError) as e:
-                    raise SystemExit(f"--data proto (eval): {e}") from None
-            return eval_state["src"](b)
-        if nproc > 1:
-            # sequential (unshuffled) sources would otherwise stream the
-            # SAME lines on every process; interleave batches by process
-            # id like the shared-db path (every host decodes everything —
-            # correct, if not maximally efficient)
-            inner, state = train_src, {"started": False}
-
-            def train_src(it):  # noqa: F811 — deliberate shadowing wrapper
-                skip = pid if not state["started"] else nproc - 1
-                state["started"] = True
-                for _ in range(skip):
-                    inner(it)
-                return inner(it)
-
-        return _internalize(train_src), _internalize(eval_src)
-
-    shapes = _feed_shapes(net, args)
-    data_shape = shapes["data"]
-    batch = data_shape[0]
-
-    if args.data.startswith("cifar:"):
-        from sparknet_tpu.data import CifarLoader, DataTransformer, TransformConfig
-
-        loader = CifarLoader(args.data[6:])
-        xform_cfg = TransformConfig(mean_image=loader.mean_image)
-        xform = DataTransformer(xform_cfg)
-        xtr, ytr = loader.train_images, loader.train_labels
-        xte, yte = loader.test_images, loader.test_labels
-
-        if batch > len(ytr) or batch > len(yte):
-            raise SystemExit(
-                f"--batch {batch} exceeds dataset size {min(len(ytr), len(yte))}")
-
-        def _cifar_pipeline_factory(transform_cfg):
-            """Process-feed twin of the threaded cifar stream: raw batch
-            slices are index-pure (same modulo walk as the thread path),
-            the host transform — when any — runs IN the workers, and the
-            wire is reoriented ONCE at source build under nhwc (the
-            per-batch `_internalize` transpose never happens)."""
-
-            def factory(num_batches, start_index=0, workers=None):
-                from sparknet_tpu.data.pipeline import (
-                    DataFnSource,
-                    ProcessPipeline,
-                    TransformStage,
-                )
-                from sparknet_tpu.ops.layout import is_nhwc
-
-                lay = "nhwc" if is_nhwc() else "nchw"
-                xs = (np.ascontiguousarray(xtr.transpose(0, 2, 3, 1))
-                      if lay == "nhwc" else xtr)
-
-                def raw_fn(it):
-                    lo = ((it * nproc + pid) * batch) % (len(ytr) - batch + 1)
-                    return {
-                        "data": xs[lo : lo + batch],
-                        "label": ytr[lo : lo + batch].astype(np.int32),
-                    }
-
-                stage = None
-                if transform_cfg is not None:
-                    stage = TransformStage(transform_cfg, train=True,
-                                           layout=lay)
-                return ProcessPipeline(
-                    DataFnSource(raw_fn), stage, num_batches=num_batches,
-                    start_index=start_index, workers=workers,
-                    name="feed.cifar")
-
-            return factory
-
-        if getattr(args, "augment", "host") == "device":
-            # ship raw uint8 over the feed link; mean-subtract runs
-            # in-graph via DeviceAugment in the prefetcher's device_fn
-            # (4x fewer host->HBM bytes than f32 feeds)
-            _device_augment_guards(args)
-
-            def train_fn(it):
-                lo = ((it * nproc + pid) * batch) % (len(ytr) - batch + 1)
-                return {
-                    "data": xtr[lo : lo + batch],
-                    "label": ytr[lo : lo + batch].astype(np.int32),
-                }
-
-            _attach_device_augment(train_fn, xform_cfg, pid,
-                                   seed=getattr(args, "seed", None))
-            train_fn.pipeline_factory = _cifar_pipeline_factory(None)
-        else:
-            def train_fn(it):
-                lo = ((it * nproc + pid) * batch) % (len(ytr) - batch + 1)
-                return {
-                    "data": xform(xtr[lo : lo + batch], True),
-                    "label": ytr[lo : lo + batch].astype(np.int32),
-                }
-
-            train_fn.pipeline_factory = _cifar_pipeline_factory(xform_cfg)
-
-        def test_fn(b):
-            # eval streams stay IDENTICAL across processes (only training
-            # shards): every host then computes the same score, keeping
-            # the sum-then-normalize semantics well-defined
-            lo = (b * batch) % (len(yte) - batch + 1)
-            return {
-                "data": xform(xte[lo : lo + batch], False),
-                "label": yte[lo : lo + batch].astype(np.int32),
-            }
-
-        return _internalize(train_fn), _internalize(test_fn)
-
-    if args.data.startswith("db:"):
-        # DB-backed training — the CifarDBApp/ImageNetRunDBApp flow (ref:
-        # src/main/scala/apps/CifarDBApp.scala:96-131 reads per-worker
-        # LevelDBs through Caffe's DataLayer).  Accepts the native
-        # RecordDB or a real Caffe LMDB (auto-detected);
-        # "db:train[,test]" with "{proc}" substituted by process id for
-        # the reference's per-worker-DB layout.
-        from sparknet_tpu.data.createdb import db_minibatches
-
-        paths = args.data[3:].split(",")
-        train_path = paths[0].replace("{proc}", str(pid))
-        # eval stream stays identical on every process (see cifar note)
-        test_path = (paths[1] if len(paths) > 1 else paths[0]).replace(
-            "{proc}", "0"
-        )
-        # transform_param parity (ref: data_transformer.cpp: mean ->
-        # crop [random in TRAIN, center in TEST] -> mirror -> scale —
-        # the reference's DataLayer transforms every record).  Each
-        # phase net's own Data layer declares the params; --data-scale
-        # overrides the scale field (lenet_train_test.prototxt's
-        # 0.00390625 without a prototxt edit).
-        def _phase_tp(n):
-            """The first Data layer's transform_param of net ``n``."""
-            return next(
-                (l.lp.get_msg("transform_param") for l in n.input_layers
-                 if getattr(l, "TYPE", "") == "Data"),
-                None,
-            )
-
-        mean_cache: dict = {}
-
-        def _tp_params(tp):
-            mean_img = None
-            if tp:
-                mf = tp.get_str("mean_file")
-                if mf:
-                    # Caffe CHECK-fails on an unreadable mean_file;
-                    # silently training without mean subtraction would be
-                    # a wrong-result bug.  CWD-relative first (Caffe),
-                    # then walk-up from the solver file, like net: paths.
-                    # Cached per resolved path: the standard train_val
-                    # layout declares the SAME (ImageNet-scale) mean file
-                    # in both phases — load it once.
-                    from sparknet_tpu.data.transform import (
-                        load_mean_file,
-                        resolve_mean_file,
-                    )
-
-                    try:
-                        resolved = resolve_mean_file(
-                            mf, getattr(args, "solver", ""))
-                        if resolved not in mean_cache:
-                            mean_cache[resolved] = load_mean_file(resolved)
-                        mean_img = mean_cache[resolved]
-                    except ValueError as e:
-                        raise SystemExit(str(e)) from None
-            return {
-                "crop": tp.get_int("crop_size", 0) if tp else 0,
-                "mirror": tp.get_bool("mirror", False) if tp else False,
-                "mean_vals": (
-                    tuple(float(v) for v in tp.get_all("mean_value"))
-                    if tp else ()
-                ),
-                "mean_img": mean_img,
-                "scale": (
-                    getattr(args, "data_scale", 0.0)
-                    or (tp.get_float("scale", 1.0) if tp else 1.0)
-                ),
-            }
-
-        trainp = _tp_params(_phase_tp(net))
-        # Caffe semantics: each phase's Data layer carries its OWN
-        # transform_param — a TEST layer without one gets DEFAULTS (no
-        # crop/mean), it does NOT inherit the train declaration.  The
-        # train params cover the test stream only when the caller has no
-        # distinct test net or it declares no Data layer at all.
-        test_has_data = test_net is not None and any(
-            getattr(l, "TYPE", "") == "Data" for l in test_net.input_layers)
-        testp = _tp_params(_phase_tp(test_net)) if test_has_data else trainp
-        crop = trainp["crop"]
-        mirror = trainp["mirror"]
-        mean_vals = trainp["mean_vals"]
-        mean_img = trainp["mean_img"]
-        scale = trainp["scale"]
-        # one shared DB across a multi-process job: shard by batch
-        # interleave (process p takes batches p, p+n, ...) — correct but
-        # every host decodes everything; the {proc} per-worker layout is
-        # the efficient path
-        shared = "{proc}" not in paths[0] and nproc > 1
-
-        device_aug = getattr(args, "augment", "host") == "device"
-        if device_aug:
-            _device_augment_guards(args)
-
-        def db_stream(path, stride=1, offset=0, train=True):
-            """Lazy cursor: nothing opens until the first call, so
-            eval-only subcommands never touch the train DB; errors
-            surface as clean SystemExits at first use."""
-            state: dict = {}
-            p = trainp if train else testp  # phase-specific declaration
-            # with --augment device the TRAIN stream ships raw uint8 and
-            # the transform runs in XLA (device_fn below); eval batches
-            # stay host-transformed (off the hot loop, deterministic)
-            raw = device_aug and train
-            xform = None
-            if not raw and (p["crop"] or p["mirror"]
-                            or p["mean_img"] is not None or p["mean_vals"]):
-                from sparknet_tpu.data import DataTransformer, TransformConfig
-
-                try:
-                    xform = DataTransformer(TransformConfig(
-                        scale=p["scale"], mirror=p["mirror"],
-                        crop_size=p["crop"], mean_value=p["mean_vals"],
-                        mean_image=p["mean_img"],
-                        seed=1234 + pid + (getattr(args, "seed", 0) or 0),
-                    ))
-                except ValueError as e:  # e.g. mean_image AND mean_value
-                    raise SystemExit(f"transform_param: {e}") from None
-
-            def fn(_, out=None):
-                if "iter" not in state:
-                    try:
-                        state["iter"] = db_minibatches(
-                            path, batch, loop=True,
-                            dtype=np.uint8 if raw else np.float32,
-                        )
-                        b = next(state["iter"])
-                        for _ in range(offset):
-                            b = next(state["iter"])
-                    except (OSError, ValueError) as e:
-                        raise SystemExit(f"--data db: {path}: {e}") from None
-                else:
-                    for _ in range(stride - 1):
-                        next(state["iter"])
-                    # the cursor fills ``out`` (see ``takes_out``); its
-                    # first batch, above, is always a fresh array
-                    b = state["iter"].send(out)
-                if xform is not None:
-                    try:
-                        b = dict(b, data=xform(b["data"], train))
-                    except ValueError as e:  # e.g. crop > record size
-                        raise SystemExit(f"--data db: {path}: {e}") from None
-                elif not raw and p["scale"] != 1.0:
-                    b = dict(b, data=b["data"] * p["scale"])
-                if "checked" not in state:
-                    state["checked"] = True
-                    got = tuple(b["data"].shape[1:])
-                    # DB records are canonical (C, H, W); compare against
-                    # the canonical view of the net's (internal) blob
-                    from sparknet_tpu.ops.layout import canonical_shape
-
-                    want = tuple(canonical_shape(data_shape)[1:])
-                    if not train and test_net is not None:
-                        # the test stream feeds the TEST net: check
-                        # against ITS declared geometry (its own crop)
-                        try:
-                            want = tuple(canonical_shape(
-                                _feed_shapes(test_net, args)["data"])[1:])
-                        except (KeyError, SystemExit):
-                            pass  # fall back to the train net's blob
-                    if raw and p["crop"]:
-                        # device_fn crops later: records must be at least
-                        # net-sized with matching channels
-                        ok = (got[0] == want[0]
-                              and got[1] >= want[1] and got[2] >= want[2])
-                    else:
-                        # post-transform (or crop-free raw, where the
-                        # device augment leaves geometry unchanged): the
-                        # net sees this exact shape
-                        ok = got == want
-                    if not ok:
-                        raise SystemExit(
-                            f"{path}: db images {got} do not match the "
-                            f"net's data blob {want}"
-                        )
-                return b
-
-            # the batch is the cursor's own array unless a host transform
-            # or scale makes a new one from it
-            fn.takes_out = xform is None and (raw or p["scale"] == 1.0)
-            return fn
-
-        train_fn = db_stream(train_path,
-                             stride=nproc if shared else 1,
-                             offset=pid if shared else 0)
-        if device_aug:
-            from sparknet_tpu.data import TransformConfig
-
-            _attach_device_augment(train_fn, TransformConfig(
-                scale=scale, mirror=mirror, crop_size=crop,
-                mean_value=mean_vals, mean_image=mean_img,
-            ), pid, seed=getattr(args, "seed", None))
-
-        def _db_pipeline_factory(num_batches, start_index=0, workers=None):
-            """Process-feed twin of the threaded db cursor: a
-            RecordShardSource byte-offset index makes the DB epoch-
-            addressable (data/records.py), decode runs IN the ring
-            workers (the `decode` stage — the parallelizable host
-            work), and the wire is built in the internal layout
-            natively.  Host-transform arm composes a worker-side
-            TransformStage; the device arm ships raw uint8 and augments
-            post-placement in XLA."""
-            from sparknet_tpu.data.createdb import peek_db_shape
-            from sparknet_tpu.data.pipeline import (
-                ProcessPipeline,
-                TransformStage,
-            )
-            from sparknet_tpu.data.records import RecordShardSource
-            from sparknet_tpu.ops.layout import canonical_shape, is_nhwc
-
-            lay = "nhwc" if is_nhwc() else "nchw"
-            try:
-                src = RecordShardSource(
-                    train_path, batch, layout=lay,
-                    stride=nproc if shared else 1,
-                    offset=pid if shared else 0)
-            except (OSError, ValueError) as e:
-                raise SystemExit(
-                    f"--data db: {train_path}: {e}") from None
-            # DB records are canonical (C, H, W); compare against the
-            # canonical view of the net's (internal) blob.  With a crop
-            # declared, EITHER arm (worker TransformStage or device
-            # augment) crops records down to the net size — raw records
-            # just need matching channels and enough spatial extent.
-            got = tuple(peek_db_shape(train_path))
-            want = tuple(canonical_shape(data_shape)[1:])
-            if trainp["crop"]:
-                ok = (got[0] == want[0]
-                      and got[1] >= want[1] and got[2] >= want[2])
-            else:
-                ok = got == want
-            if not ok:
-                raise SystemExit(
-                    f"{train_path}: db images {got} do not match the "
-                    f"net's data blob {want}")
-            stage = None
-            if not device_aug:
-                from sparknet_tpu.data import TransformConfig
-
-                try:
-                    stage = TransformStage(TransformConfig(
-                        scale=trainp["scale"], mirror=trainp["mirror"],
-                        crop_size=trainp["crop"],
-                        mean_value=trainp["mean_vals"],
-                        mean_image=trainp["mean_img"],
-                        seed=1234 + pid + (getattr(args, "seed", 0) or 0),
-                    ), train=True, layout=lay)
-                except ValueError as e:
-                    raise SystemExit(f"transform_param: {e}") from None
-            return ProcessPipeline(
-                src, stage, num_batches=num_batches,
-                start_index=start_index, workers=workers,
-                name="feed.db")
-
-        from sparknet_tpu.data.records import probe_record_backend
-
-        if probe_record_backend(train_path) in ("record", "lmdb"):
-            # LevelDB keeps the threaded cursor: snappy blocks have no
-            # per-record byte offsets to index (RecordShardSource's
-            # refusal names convert_db as the migration)
-            train_fn.pipeline_factory = _db_pipeline_factory
-        return (_read_span(_internalize(train_fn), batch),
-                _internalize(db_stream(test_path, train=False)))
-
-    if args.data.startswith("tokens:"):
-        # language-model training from a tokenised corpus: one flat
-        # uint16 token file (data/text.py token_windows), windows of
-        # seq_len + 1 -> data / label [batch, seq_len].
-        # "tokens:train[,test]"; {proc} and the shared-file batch
-        # interleave as for db:
-        from sparknet_tpu.data.text import token_windows
-
-        if len(data_shape) != 2:
-            raise SystemExit(
-                f"--data tokens: feeds a [batch, seq_len] data blob; the "
-                f"net's is {tuple(data_shape)}")
-        paths = args.data[7:].split(",")
-        shared = "{proc}" not in paths[0] and nproc > 1
-        try:
-            train_fn = token_windows(
-                paths[0].replace("{proc}", str(pid)), batch, data_shape[1],
-                stride=nproc if shared else 1, offset=pid if shared else 0)
-            # the eval stream is the same on every process (see cifar)
-            test_fn = token_windows(
-                paths[-1].replace("{proc}", "0"), batch, data_shape[1])
-        except (OSError, ValueError) as e:
-            raise SystemExit(f"--data tokens: {e}") from None
-        return (_read_span(train_fn, batch, tokens=batch * data_shape[1]),
-                test_fn)
-
-    if args.data == "synthetic":
-        rs = np.random.RandomState(pid)
-        num_classes = 10
-
-        def synth_train(it):
-            return {
-                "data": (rs.randn(*data_shape) * 50).astype(np.float32),
-                "label": rs.randint(0, num_classes, batch).astype(np.int32),
-            }
-
-        def synth_test(b):
-            # stateless per-batch seed, identical on every process
-            rs2 = np.random.RandomState(100_000 + b)
-            return {
-                "data": (rs2.randn(*data_shape) * 50).astype(np.float32),
-                "label": rs2.randint(0, num_classes, batch).astype(np.int32),
-            }
-
-        def _synth_pipeline_factory(num_batches, start_index=0,
-                                    workers=None):
-            """Process-feed twin: per-INDEX stateless seeding (workers
-            cannot share synth_train's sequential RandomState; synthetic
-            batches carry no identity worth preserving, and determinism
-            per (pid, index) keeps the worker assignment pure).
-            ``data_shape`` is already the INTERNAL layout — synthesis IS
-            the wire, zero transposes in either orientation."""
-            from sparknet_tpu.data.pipeline import (
-                DataFnSource,
-                ProcessPipeline,
-            )
-
-            def indexed(it):
-                rs2 = np.random.RandomState(
-                    (pid * 1_000_003 + it) & 0x7FFFFFFF)
-                return {
-                    "data": (rs2.randn(*data_shape) * 50).astype(np.float32),
-                    "label": rs2.randint(0, num_classes, batch).astype(np.int32),
-                }
-
-            return ProcessPipeline(
-                DataFnSource(indexed), num_batches=num_batches,
-                start_index=start_index, workers=workers,
-                name="feed.synthetic")
-
-        synth_train.pipeline_factory = _synth_pipeline_factory
-        return synth_train, synth_test
-
-    raise SystemExit(f"unknown --data source {args.data!r}")
+    spec, args.data = args.data, feed.parse_spec(args.data, net)[1]
+    return feed.open_feeds(
+        spec, net, test_net,
+        pid=jax.process_index(), nproc=jax.process_count(),
+        seed=getattr(args, "seed", None),
+        augment=getattr(args, "augment", "host"),
+        solver_path=getattr(args, "solver", ""),
+        data_scale=getattr(args, "data_scale", 0.0),
+        prefetch=getattr(args, "prefetch", 0),
+        trainer=(getattr(args, "tau", 1) > 1
+                 or getattr(args, "distributed", False)
+                 or getattr(args, "elastic_alpha", 0.0) > 0))
 
 
 def _load_weights_into(
@@ -866,60 +193,13 @@ def _load_weights_into(
     return loaded
 
 
-# ---------------------------------------------------------------------------
-def _process_feed(train_fn, num_batches, start_index, args, log,
-                  device_stage=True):
-    """``Config.feed == "process"``: swap the thread feed for the
-    shared-memory pipeline (``data/pipeline.py``).  Returns
-    ``(context, data_fn)`` — the context owns the ring + (optionally)
-    the double-buffered device-put stage and must wrap the train loop;
-    the data_fn serves the solver's feed contract.
-
-    ``device_stage=False`` keeps feeds HOST-side (the ParallelTrainer
-    packs tau/global batches itself and owns its own device_put)."""
-    import contextlib
-
-    factory = getattr(train_fn, "pipeline_factory", None)
-    if factory is None:
-        raise SystemExit(
-            "--feed process needs an index-addressable source a worker "
-            "process can re-produce deterministically: synthetic, cifar:, "
-            "and db: record/LMDB files (RecordShardSource byte-offset "
-            "index, data/records.py) ride the ring; the remaining "
-            "stateful cursors (proto listfiles, LevelDB) keep --feed "
-            "threaded — convert LevelDB via data.createdb.convert_db to "
-            "join")
-    stack = contextlib.ExitStack()
-    pipe = stack.enter_context(factory(
-        num_batches=num_batches, start_index=start_index,
-        workers=getattr(args, "feed_workers", 0) or None))
-    if device_stage:
-        from sparknet_tpu.data.pipeline import device_feed
-
-        pf = stack.enter_context(device_feed(
-            pipe, depth=max(getattr(args, "prefetch", 0), 2),
-            device_fn=getattr(train_fn, "device_fn", None)))
-        it = iter(pf)
-        fn = lambda _it: next(it)  # noqa: E731 — the solver feed contract
-    else:
-        # trainer feeds stay host-side; _stack_tau/_widen_batch hold a
-        # slot's batches (one per worker) before copying them into their
-        # buffer, which outlives the ring's view-lifetime window — they
-        # need stable copies (cheap: the wire is uint8 under --augment
-        # device)
-        fn = pipe.as_data_fn(copy=True)
-    log(f"feed: process pipeline ({pipe.workers} worker(s), "
-        f"{pipe.slots} slots x {pipe.spec.slot_bytes:,} B"
-        f"{', device stage' if device_stage else ''})")
-    return stack, fn
-
-
 def cmd_train(args) -> int:
     """ref: caffe.cpp:153-218 train()."""
     import jax
 
+    from sparknet_tpu.common import get_config
+    from sparknet_tpu.data.feed import process_feed
     from sparknet_tpu.parallel.trainer import ParallelTrainer
-    from sparknet_tpu.solvers.solver import Solver
     from sparknet_tpu.utils import EventLogger, SignalHandler, SolverAction, agree_action
 
     if args.snapshot and getattr(args, "weights", ""):
@@ -976,23 +256,6 @@ def cmd_train(args) -> int:
                       prefix="tpunet_train")
     train_fn, test_fn = _data_fns(args, solver.train_net,
                                   test_net=solver.test_net)
-    if args.data == "proto":
-        # the TEST net's data layer names its own source file + phase; a
-        # train-only prototxt (no TEST-phase listfile layer) keeps the
-        # train stream for any eval
-        from sparknet_tpu.data.listfile import source_from_net
-
-        try:
-            test_fn = source_from_net(
-                solver.test_net, seed=4321,
-                anchor=getattr(args, "solver", ""))
-        except LookupError:
-            pass
-        except (OSError, ValueError) as e:
-            raise SystemExit(f"--data proto (test net): {e}") from None
-
-    import contextlib
-
     profile_ctx = contextlib.nullcontext()
     if args.profile:
         from sparknet_tpu.utils import profiling
@@ -1011,27 +274,25 @@ def cmd_train(args) -> int:
             )
             # --augment device on the trainer path: the wire stays uint8
             # all the way through _put_feeds; the augment runs post-
-            # placement, outside the jitted round program.  Capture the
-            # adapter BEFORE _process_feed swaps train_fn for the ring's
-            # attr-less as_data_fn.
-            aug_fn = getattr(train_fn, "trainer_device_fn", None)
-            if aug_fn is not None:
-                trainer.feed_device_fn = aug_fn
+            # placement, outside the jitted round program.
+            if train_fn.trainer_device_fn is not None:
+                trainer.feed_device_fn = train_fn.trainer_device_fn
                 log("augment: device (post-placement, tau wire uint8)")
             outer = -(-iters // max(args.tau, 1))  # ceil: run >= requested
             feed_ctx = contextlib.nullcontext()
-            if _feed_mode() == "process":
+            if get_config().feed == "process":
                 # one host-side pipeline feeds the whole tau round; the
                 # trainer keeps packing + device_put (its feeds carry
                 # the [tau, B*workers] contract, not per-batch puts)
-                feed_ctx, train_fn = _process_feed(
+                feed_ctx, train_fn = process_feed(
                     train_fn,
                     outer * max(args.tau, 1) * trainer.num_local_workers,
-                    0, args, log, device_stage=False)
+                    0, log, workers=getattr(args, "feed_workers", 0),
+                    device_stage=False)
             tau_fn = _stack_tau(train_fn, args.tau, trainer.num_local_workers)
             scan_n = max(getattr(args, "scan", 1), 1)
-            wide_fn = _widen_batch(train_fn, trainer.num_local_workers,
-                                   keep=scan_n)
+            wide_fn = widen_batch(train_fn, trainer.num_local_workers,
+                                  keep=scan_n)
             # tau_fn's feed thread is joined when the loop ends or a
             # signal stops it, before the process feed it reads is closed
             with feed_ctx, SignalHandler() as sig, contextlib.closing(tau_fn):
@@ -1067,15 +328,15 @@ def cmd_train(args) -> int:
                         break
             trainer.sync_to_solver()
         else:
-            import contextlib
-
             pf_ctx = contextlib.nullcontext()
-            if _feed_mode() == "process":
+            if get_config().feed == "process":
                 # multi-process shared-memory feed + double-buffered
                 # device stage (data/pipeline.py); streams from
                 # solver.iter so snapshot resume continues the sequence
-                pf_ctx, train_fn = _process_feed(
-                    train_fn, iters, solver.iter, args, log)
+                pf_ctx, train_fn = process_feed(
+                    train_fn, iters, solver.iter, log,
+                    workers=getattr(args, "feed_workers", 0),
+                    prefetch=getattr(args, "prefetch", 0))
             elif getattr(args, "prefetch", 0) > 0:
                 # async host->HBM feed (the BasePrefetchingDataLayer role):
                 # the worker thread transforms + device_puts ahead of the
@@ -1087,7 +348,7 @@ def cmd_train(args) -> int:
                 pf_ctx = DevicePrefetcher(
                     train_fn, iters, depth=args.prefetch,
                     start_iter=solver.iter,
-                    device_fn=getattr(train_fn, "device_fn", None),
+                    device_fn=train_fn.device_fn,
                 )
                 pf_iter = iter(pf_ctx)
 
@@ -1121,202 +382,6 @@ def cmd_train(args) -> int:
         out = solver.save(args.output or "tpunet_final")
         log(f"saved {out}")
     return 0
-
-
-class _Turns:
-    """A lock that serves its waiters in the order they came.  A
-    ``threading.Lock`` goes to whoever asks first after a release, and
-    that is the thread that just released it: of two feeds over one data
-    fn the one with more reads to make starved the other (four reads in
-    five, PERF.md, PR 27)."""
-
-    def __init__(self):
-        self._cv = threading.Condition()
-        self._next = self._serving = 0  # tickets handed out, and served
-
-    def __enter__(self):
-        with self._cv:
-            mine, self._next = self._next, self._next + 1
-            self._cv.wait_for(lambda: self._serving == mine)
-
-    def __exit__(self, *exc):
-        with self._cv:
-            self._serving += 1
-            self._cv.notify_all()
-
-    def locked(self) -> bool:
-        return self._serving != self._next
-
-
-_DATA_FN_LOCKS = weakref.WeakKeyDictionary()  # data fn -> its lock
-_DATA_FN_LOCKS_GUARD = threading.Lock()
-
-
-def _data_fn_lock(train_fn) -> _Turns:
-    """The lock that belongs to ``train_fn``: one per data fn, whoever
-    asks.  A data fn drives one cursor (``db_stream``'s generator), and
-    two feeds over it may each have a thread inside it: the benchmark's
-    one-device feed is still reading its last round ahead when the mesh
-    feed starts.  Calls into it are made under this lock, in turn."""
-    with _DATA_FN_LOCKS_GUARD:
-        return _DATA_FN_LOCKS.setdefault(train_fn, _Turns())
-
-
-class _RoundBuffer:
-    """ONE persistent host array ``[slots, workers * B, ...]`` per feed
-    key, filled one per-worker batch at a time: batch (t, w) goes to the
-    contiguous view ``buf[t, w*B:(w+1)*B]``.  A ``takes_out`` data fn is
-    handed that view and writes its records straight into it; any other
-    batch is copied there.  Nothing is concatenated or stacked.  The
-    arrays are made on the first read, from the first batch's shapes, and
-    live as long as the buffer's owner (``_stack_tau`` / ``_widen_batch``).
-    The data fn is called under its own lock (``_data_fn_lock``).
-
-    ``sn.feed.stack``, one per slot after the slot's reads, times what is
-    left of the pack's own work and counts the slot's images;
-    ``alloc_bytes`` is the buffer itself in the first one and 0 after."""
-
-    def __init__(self, train_fn, slots, workers):
-        self._fn, self._slots, self._workers = train_fn, slots, workers
-        self._takes_out = getattr(train_fn, "takes_out", False)
-        self._lock = _data_fn_lock(train_fn)
-        self.arrays: dict = {}
-        self._batch = 0  # B, known with the first batch
-        self._strays: list = []  # (views, batch) that missed their views
-        self._alloc = 0
-
-    def read(self, index, t, w):
-        """Batch ``index`` of the data fn into cell (t, w)."""
-        from sparknet_tpu.data.prefetch import fresh_bytes
-
-        views = self._views(t, w)
-        with self._lock:
-            got = (self._fn(index, out=views) if views and self._takes_out
-                   else self._fn(index))
-        if not views:
-            self._batch = len(next(iter(got.values())))
-            self.arrays = {
-                k: np.empty((self._slots, self._workers * self._batch,
-                             *v.shape[1:]), v.dtype)
-                for k, v in got.items()}
-            self._alloc = sum(a.nbytes for a in self.arrays.values())
-            # the buffer's pages are touched here, not by the reads that
-            # fill it: those hold the data fn's lock, and on the chip's
-            # host a batch written into fresh memory takes 51 ms, into
-            # memory written once 25, from then on 4 (PERF.md, PR 27), so
-            # a second feed over the same data fn queued behind them
-            for a in self.arrays.values():
-                a.fill(0)
-                a.fill(0)
-            views = self._views(t, w)
-        if fresh_bytes(got, views):
-            self._strays.append((views, got))
-
-    def _views(self, t, w):
-        lo = w * self._batch
-        return {k: a[t, lo:lo + self._batch] for k, a in self.arrays.items()}
-
-    def slot(self, it, t):
-        """Slot ``t``, whole: ``{key: [workers * B, ...]}``."""
-        from sparknet_tpu.obs.recorder import feed_counts
-
-        feeds = {k: a[t] for k, a in self.arrays.items()}
-        with _host_span("sn.feed.stack", it=it, alloc_bytes=self._alloc,
-                        **feed_counts(feeds)):
-            for views, got in self._strays:
-                for k, v in got.items():
-                    views[k][...] = v
-        self._strays, self._alloc = [], 0
-        return feeds
-
-
-def _stack_tau(train_fn, tau, num_workers):
-    """[tau, B*workers, ...] feeds: the net batch is per-worker; each tau
-    slot holds one batch per worker side by side (the global minibatch).
-    Owns its own batch counter: each round consumes tau*num_workers fresh
-    batches regardless of how the trainer advances its iteration count.
-
-    The feed is always exactly ONE round ahead.  It owns two persistent
-    buffers per feed key (``_RoundBuffer``) and, from the first call on,
-    one daemon thread (``prefetch.FeedThread``) that makes every call
-    into the data fn, in the order and with the indices a serial pack
-    would.  ``fn(it)`` hands out round n, waiting under ``sn.feed.wait``
-    where the thread has not filled it yet (``ready`` = 0), and only then
-    lets the thread start on round n+1, in the buffer round n-1 was read
-    from.  ``ParallelTrainer.train_round`` fences round n-1 on
-    ``float(loss)`` before it asks for round n, so that buffer's transfer
-    is complete (jax keeps a ``device_put``'s numpy source immutable
-    until then) and, on the CPU backend where a placed array may alias
-    its source, nothing reads it any more.  So the arrays ``fn`` returns
-    are valid, and not written, until the NEXT call returns; the thread
-    is never a second round ahead, which would write a buffer whose
-    transfer may still be in flight.
-
-    An error the data fn raises on the thread surfaces from ``fn``.
-    ``fn.close()`` stops and joins the thread; a feed nobody closes
-    cannot hold the process (a daemon).  The one round read past the
-    last one asked for is the price."""
-    from sparknet_tpu.data.prefetch import DONE, FeedThread
-
-    bufs = [_RoundBuffer(train_fn, tau, num_workers) for _ in range(2)]
-    asked: queue.SimpleQueue = queue.SimpleQueue()  # ``it`` of a round to fill
-    feed = None  # the thread, from the first call on
-
-    def fill(thread):
-        index = 0
-        for buf in itertools.cycle(bufs):
-            it = asked.get()
-            if it is None:  # close()
-                return
-            for t in range(tau):
-                for w in range(num_workers):
-                    if thread.stopped:
-                        return
-                    buf.read(index, t, w)
-                    index += 1
-                buf.slot(it, t)
-            if not thread.put(dict(buf.arrays), it):
-                return
-
-    def fn(it):
-        nonlocal feed
-        if feed is None:
-            feed = FeedThread(fill, depth=1)
-            asked.put(it)
-        arrays = feed.get(it)
-        if arrays is DONE:
-            raise RuntimeError("the tau-round feed was closed")
-        asked.put(it + tau)  # the trainer's next ``it``; names spans only
-        return arrays
-
-    def close():
-        if feed is not None:
-            asked.put(None)
-            feed.close()
-
-    fn.close = close
-    return fn
-
-
-def _widen_batch(train_fn, num_workers, keep=1):
-    """tau=1 global batch ``[B*workers, ...]``: one per-worker batch per
-    worker, side by side in a slot of a ``_RoundBuffer``.  A batch is
-    valid until ``keep`` calls later (``train_round`` fences before it
-    asks again; ``train_rounds`` holds a scan chunk's worth until it has
-    stacked them)."""
-    if num_workers == 1:
-        return train_fn
-    buf = _RoundBuffer(train_fn, keep, num_workers)
-    calls = [0]
-
-    def fn(it):
-        t = calls[0] % keep
-        calls[0] += 1
-        for w in range(num_workers):
-            buf.read(it * num_workers + w, t, w)
-        return buf.slot(it, t)
-
-    return fn
 
 
 def cmd_test(args) -> int:

@@ -12,12 +12,9 @@ to the reference's 3.
 A data fn that takes a destination (``takes_out``: ``data_fn(it, out=)``,
 the ``db:`` cursor) is handed one of ``RING`` host batches the prefetcher
 owns and refills, so the feed allocates nothing batch-sized per step.
-jax keeps the numpy source of a ``device_put`` immutable until the
-transfer completes, so a slot is refilled only once the device arrays
-placed from it are ready; with two slots that wait falls a whole read
-after the put and costs nothing.  Where ``device_put`` may alias host
-memory (the CPU backend), a placed batch IS its source for as long as it
-lives: there every batch stays a fresh array.
+When a slot may be refilled is the host-buffer rule (``data/rounds.py``);
+with two slots the wait falls a whole read after the put and costs
+nothing.
 
 The reference's ``InternalThread`` clones RNG/mode state into the child
 (ref: caffe/src/caffe/util/internal_thread.cpp:28-49); here the data_fn
@@ -179,7 +176,7 @@ class FeedThread:
     the thread and hands over each item with ``feed.put(item, it)``; it
     returns when it has no more, or when ``put`` says the feed was
     closed.  ``DevicePrefetcher`` produces placed batches this way,
-    ``cli._stack_tau`` whole tau-rounds."""
+    ``rounds.stack_tau`` whole tau-rounds."""
 
     def __init__(self, produce: Callable[["FeedThread"], None], depth: int):
         self._q: queue.Queue = queue.Queue(maxsize=depth)

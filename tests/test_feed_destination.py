@@ -1,8 +1,8 @@
 """Destination passing in the host feed: the cursor writes each record
 once, into the array its consumer hands it.
 
-``db_minibatches`` fresh and into a destination, ``cli._widen_batch``
-over its one persistent buffer, ``cli._stack_tau`` over its two (its feed
+``db_minibatches`` fresh and into a destination, ``rounds.widen_batch``
+over its one persistent buffer, ``rounds.stack_tau`` over its two (its feed
 thread fills round n+1 while round n is out), and the
 ``DevicePrefetcher``'s ring of host batches, all against the records the
 DB was written from.  Everything runs on the CPU, where ``device_put``
@@ -21,7 +21,9 @@ import pytest
 from sparknet_tpu import cli
 from sparknet_tpu.data import prefetch
 from sparknet_tpu.data.createdb import _open_reader, create_db, db_minibatches
+from sparknet_tpu.data.feed import Feed
 from sparknet_tpu.data.prefetch import DevicePrefetcher, fresh_bytes
+from sparknet_tpu.data.rounds import lock_of, stack_tau, widen_batch
 from sparknet_tpu.obs.recorder import Recorder, set_recorder
 
 BATCH = 6
@@ -234,7 +236,7 @@ def test_the_db_data_fn_takes_a_destination_and_says_what_it_allocated(
 @pytest.mark.parametrize("pack", ["stack_tau", "widen_batch"])
 def test_a_pack_fills_one_buffer_and_equals_the_plain_batches(
         job, tmp_path, pack, hook):
-    """``_widen_batch``: one buffer.  ``_stack_tau``: two, in turn."""
+    """``widen_batch``: one buffer.  ``stack_tau``: two, in turn."""
     flags, records = job
     tau, workers, calls = (3, 2, 5) if pack == "stack_tau" else (1, 2, 4)
     buffers = 2 if pack == "stack_tau" else 1
@@ -246,8 +248,8 @@ def test_a_pack_fills_one_buffer_and_equals_the_plain_batches(
         if not hook:  # a data fn that cannot take a destination
             inner = train_fn
             train_fn = lambda it: inner(it)  # noqa: E731
-        fn = (cli._stack_tau(train_fn, tau, workers) if pack == "stack_tau"
-              else cli._widen_batch(train_fn, workers))
+        fn = (stack_tau(train_fn, tau, workers) if pack == "stack_tau"
+              else widen_batch(train_fn, workers))
         rec = set_recorder(Recorder(journal, run_id="t"))
         try:
             for it in range(calls):
@@ -341,7 +343,7 @@ def test_the_next_round_is_read_before_it_is_asked_for():
             last_of[index // ROUND].set()
 
     data_fn, seen = numbered(on_call)
-    fn = cli._stack_tau(data_fn, TAU, WORKERS)
+    fn = stack_tau(data_fn, TAU, WORKERS)
     try:
         assert seen == []  # a feed that is never asked reads nothing
         first = fn(0)
@@ -368,7 +370,7 @@ def test_the_feed_is_one_round_ahead_and_leaves_the_round_in_hand_alone():
             filled[n].set()
 
     data_fn, seen = numbered(on_call)
-    fn = cli._stack_tau(data_fn, TAU, WORKERS)
+    fn = stack_tau(data_fn, TAU, WORKERS)
     try:
         for n in range(3):
             in_hand["feeds"] = fn(n * TAU)
@@ -399,15 +401,15 @@ def test_two_feeds_over_one_data_fn_neither_raise_nor_lose_a_batch(job):
         inner = new_train_fn()
 
         def train_fn(it, out=None):
-            assert cli._data_fn_lock(train_fn).locked()
+            assert lock_of(train_fn).locked()
             feeds = inner(it, out=out)
             feeds["label"][...] = len(returned)  # which of its batches
             returned.append(feeds["data"].copy())
             return feeds
 
         train_fn.takes_out = True
-        one_fn = cli._stack_tau(train_fn, TAU, 1)
-        mesh_fn = cli._stack_tau(train_fn, TAU, WORKERS)
+        one_fn = stack_tau(train_fn, TAU, 1)
+        mesh_fn = stack_tau(train_fn, TAU, WORKERS)
         try:
             for name, fn, rounds in (("one", one_fn, 1), ("mesh", mesh_fn, 1),
                                      ("one", one_fn, 4), ("mesh", mesh_fn, 4)):
@@ -447,7 +449,7 @@ def test_the_data_fns_lock_serves_its_waiters_in_turn():
     """A feed with many reads to make does not starve one with few: the
     thread that releases the lock and asks again at once goes behind the
     one that was already waiting."""
-    lock = cli._data_fn_lock(lambda it: None)
+    lock = lock_of(lambda it: None)
     order = []
     asked = threading.Event()
 
@@ -469,10 +471,12 @@ def test_the_data_fns_lock_serves_its_waiters_in_turn():
     assert order == ["waiter", "releaser"] and not lock.locked()
 
 
-def test_many_feeds_over_one_cursor_share_it_without_a_lost_batch():
+@pytest.mark.parametrize("kind", ["plain", "feed"])
+def test_many_feeds_over_one_cursor_share_it_without_a_lost_batch(kind):
     """More feeds than cores over ONE generator, each drained by a thread
     of its own under a short switch interval: a generator entered twice
-    raises, a batch counted twice or not at all breaks the census."""
+    raises, a batch counted twice or not at all breaks the census.  One
+    lock per data fn, a plain function's (``lock_of``) as a ``Feed``'s."""
     import sys
     import time
 
@@ -492,6 +496,9 @@ def test_many_feeds_over_one_cursor_share_it_without_a_lost_batch():
         read.append(int(feeds["label"][0]))
         return feeds
 
+    if kind == "feed":
+        data_fn = Feed(data_fn)
+
     feeds_n, rounds = 12, 25
     got = [[] for _ in range(feeds_n)]
     errors = []
@@ -503,7 +510,7 @@ def test_many_feeds_over_one_cursor_share_it_without_a_lost_batch():
         except BaseException as e:  # told on the main thread, below
             errors.append(e)
 
-    fns = [cli._stack_tau(data_fn, TAU, WORKERS) for _ in range(feeds_n)]
+    fns = [stack_tau(data_fn, TAU, WORKERS) for _ in range(feeds_n)]
     consumers = [threading.Thread(target=drain, args=(k, fn), daemon=True)
                  for k, fn in enumerate(fns)]
     interval = sys.getswitchinterval()
@@ -535,7 +542,7 @@ def test_an_error_in_the_data_fn_surfaces_from_the_feed(error):
             raise error("the cursor failed")
 
     data_fn, _ = numbered(on_call)
-    fn = cli._stack_tau(data_fn, TAU, WORKERS)
+    fn = stack_tau(data_fn, TAU, WORKERS)
     try:
         assert batches_of(fn(0)) == list(range(ROUND))
         for _ in range(2):  # and again, however often it is asked
@@ -548,7 +555,7 @@ def test_an_error_in_the_data_fn_surfaces_from_the_feed(error):
 def test_close_joins_the_feed_thread():
     before = set(threading.enumerate())
     data_fn, seen = numbered()
-    fn = cli._stack_tau(data_fn, TAU, WORKERS)
+    fn = stack_tau(data_fn, TAU, WORKERS)
     fn.close()  # never asked: no thread yet, nothing to join
     assert set(threading.enumerate()) == before and seen == []
     fn(0)
@@ -563,7 +570,7 @@ def test_close_joins_the_feed_thread():
 
 def test_rounds_over_the_feed_equal_rounds_over_batches_packed_by_hand(
         job, tmp_path):
-    """Three tau-rounds on a CPU mesh of two, fed by ``_stack_tau``, and
+    """Three tau-rounds on a CPU mesh of two, fed by ``stack_tau``, and
     three fed the same batches stacked and concatenated by hand: the
     losses and every parameter bit for bit."""
     from sparknet_tpu.parallel.mesh import data_parallel_mesh
@@ -598,7 +605,7 @@ def test_rounds_over_the_feed_equal_rounds_over_batches_packed_by_hand(
                 solver, mesh=data_parallel_mesh(WORKERS), tau=args.tau)
             trainer.feed_device_fn = train_fn.trainer_device_fn
             if feed == "stack_tau":
-                fn = cli._stack_tau(train_fn, args.tau,
+                fn = stack_tau(train_fn, args.tau,
                                     trainer.num_local_workers)
             else:
                 fn = lambda it: by_hand(it // TAU)  # noqa: E731
@@ -630,7 +637,7 @@ def test_widen_batch_keeps_as_many_batches_as_it_is_asked_to(job):
     held = []
 
     def body(new_train_fn):
-        fn = cli._widen_batch(new_train_fn(), 2, keep=3)
+        fn = widen_batch(new_train_fn(), 2, keep=3)
         held.extend(fn(it) for it in range(3))  # a scan chunk's worth
         held.append({k: v.copy() for k, v in fn(3).items()})
 

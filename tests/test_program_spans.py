@@ -8,7 +8,7 @@ own functions (``cli.main`` with ``cmd_train`` swapped for the body, the
 way the benchmark drives it): two ``Solver.step`` chunks fed by a
 ``DevicePrefetcher`` from a tiny ``db:`` feed, and two tau=2
 ``ParallelTrainer.train_round`` calls on two virtual devices, fed by
-``cli._stack_tau``, whose thread reads a round ahead.
+``rounds.stack_tau``, whose thread reads a round ahead.
 """
 
 import glob
@@ -25,6 +25,7 @@ from sparknet_tpu.data import DeviceAugment, TransformConfig
 from sparknet_tpu.data.createdb import create_db
 from sparknet_tpu.data.device_transform import AUGMENT_SCOPE
 from sparknet_tpu.data.prefetch import DevicePrefetcher
+from sparknet_tpu.data.rounds import stack_tau
 from sparknet_tpu.obs.recorder import Recorder, feed_counts, set_recorder
 from sparknet_tpu.parallel.mesh import data_parallel_mesh
 from sparknet_tpu.parallel.trainer import ParallelTrainer
@@ -153,8 +154,7 @@ def rounds(job):
         trainer = ParallelTrainer(solver, mesh=data_parallel_mesh(WORKERS),
                                   tau=args.tau)
         trainer.feed_device_fn = train_fn.trainer_device_fn
-        tau_fn = cli._stack_tau(train_fn, args.tau,
-                                trainer.num_local_workers)
+        tau_fn = stack_tau(train_fn, args.tau, trainer.num_local_workers)
         trainer.train_round(tau_fn)  # compile; the feed reads round 1
         with profiling.trace(str(tmp / "rounds")):
             trainer.train_round(tau_fn)  # round 1; the feed reads round 2

@@ -232,13 +232,13 @@ def test_tar_needs_decode_size_and_label_map(tmp_path):
 def test_process_feed_refusal_names_migration_path(tmp_path):
     """The remaining stateful sources' refusal tells the operator HOW to
     migrate (RecordShardSource / convert_db), not just no."""
-    from sparknet_tpu.cli import _process_feed
+    from sparknet_tpu.data.feed import process_feed
 
     def stateful(it):
         return {"x": np.zeros(2, np.float32)}
 
     with pytest.raises(SystemExit, match="RecordShardSource"):
-        _process_feed(stateful, 4, 0, object(), lambda *a, **k: None)
+        process_feed(stateful, 4, 0, lambda *a, **k: None)
 
 
 # ----------------------------------------------- through the process ring
@@ -402,7 +402,7 @@ def test_trainer_device_fn_key_policy_rank4_and_rank5():
 
 def test_cli_train_device_arm_tau_process_feed(tmp_path, monkeypatch):
     """End-to-end: db record source -> process ring (uint8 wire) ->
-    _stack_tau -> ParallelTrainer.feed_device_fn augment post-placement.
+    rounds.stack_tau -> ParallelTrainer.feed_device_fn augment post-placement.
     Threaded and process feeds must deliver the same training sequence
     (the ring reproduces the cursor order)."""
     from sparknet_tpu.cli import main
