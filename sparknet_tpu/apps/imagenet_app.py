@@ -96,28 +96,41 @@ class ImageNetApp:
         lab = np.concatenate(labels).reshape((self.tau, B_global))
         return {"data": data, "label": lab.astype(np.int32)}
 
+    def _round_feeds(self, it):
+        """The trainer's data fn, ONE for every round (so each round is
+        placed ahead, ``ParallelTrainer.train_round``): the streams' next
+        tau x workers minibatches, a new epoch's where they run out."""
+        try:
+            return self._tau_feeds(self._streams)
+        except StopIteration:
+            self._streams = [  # new epoch
+                self.minibatch_stream(w) for w in range(self.num_workers)
+            ]
+            try:
+                return self._tau_feeds(self._streams)
+            except StopIteration:
+                raise ValueError(
+                    f"dataset too small: tau={self.tau} x batch="
+                    f"{self.batch} x {self.num_workers} workers needs "
+                    f"{self.tau * self.batch * self.num_workers} decoded "
+                    "images per round (and every worker needs >=1 shard) "
+                    "— reduce tau/batch or add shards"
+                ) from None
+
     # ------------------------------------------------------------------
     def run(self, num_outer: int = 10) -> float:
-        streams = [self.minibatch_stream(w) for w in range(self.num_workers)]
+        self._streams = [
+            self.minibatch_stream(w) for w in range(self.num_workers)
+        ]
         loss = float("nan")
-        for outer in range(num_outer):
-            try:
-                feeds = self._tau_feeds(streams)
-            except StopIteration:
-                streams = [  # new epoch
-                    self.minibatch_stream(w) for w in range(self.num_workers)
-                ]
-                try:
-                    feeds = self._tau_feeds(streams)
-                except StopIteration:
-                    raise ValueError(
-                        f"dataset too small: tau={self.tau} x batch="
-                        f"{self.batch} x {self.num_workers} workers needs "
-                        f"{self.tau * self.batch * self.num_workers} decoded "
-                        "images per round (and every worker needs >=1 shard) "
-                        "— reduce tau/batch or add shards"
-                    ) from None
-            self.log("training", i=outer)
-            loss = self.trainer.train_round(lambda it: feeds)
-            self.log(f"loss: {loss:.5f}", i=outer)
+        try:
+            for outer in range(num_outer):
+                self.log("training", i=outer)
+                loss = self.trainer.train_round(self._round_feeds)
+                self.log(f"loss: {loss:.5f}", i=outer)
+        finally:
+            # the round placed past the last one is of these streams,
+            # which end here (and their decode pools with them)
+            self.trainer.close()
+            del self._streams
         return loss

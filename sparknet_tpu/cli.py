@@ -294,8 +294,10 @@ def cmd_train(args) -> int:
             wide_fn = widen_batch(train_fn, trainer.num_local_workers,
                                   keep=scan_n)
             # tau_fn's feed thread is joined when the loop ends or a
-            # signal stops it, before the process feed it reads is closed
-            with feed_ctx, SignalHandler() as sig, contextlib.closing(tau_fn):
+            # signal stops it, before the process feed it reads is closed;
+            # the round the trainer placed ahead is let go before that
+            with feed_ctx, SignalHandler() as sig, \
+                    contextlib.closing(tau_fn), contextlib.closing(trainer):
                 o = 0
                 while o < outer:
                     if args.tau > 1 or elastic:

@@ -13,12 +13,14 @@ each by its own means:
   thread owns.  A slot is refilled once the arrays placed from it a whole
   read ago are ready (``_transferred``); on an aliasing backend
   (``_aliases_host``) there is no ring and every batch is a fresh array.
-* ``stack_tau``'s two ``RoundBuffer``s: its feed thread owns them and is
-  exactly one round ahead.  ``ParallelTrainer.train_round`` fences round
-  n-1 on ``float(loss)`` before it asks for round n, so the buffer round
-  n+1 is written into (round n-1's) has been transferred and, where a
-  placed array aliases it, is read by nothing.  A second round ahead
-  would write a buffer whose transfer may still be in flight.
+* ``stack_tau``'s three ``RoundBuffer``s: its feed thread owns them and
+  is exactly one round ahead of what it was asked for.
+  ``ParallelTrainer.train_round`` asks for round n+1 and places it while
+  round n trains, and has fenced round n-1 on ``float(loss)`` before
+  round n began; so the buffer round n+2 is written into (round n-1's)
+  has been transferred and, where a placed array aliases it, is read by
+  nothing.  A fourth buffer would buy nothing, and two would let the
+  thread write the round that is training.
 * ``widen_batch``'s ``keep`` slots: the caller's thread owns them; a
   batch is rewritten ``keep`` calls later, after the same fence
   (``train_rounds`` holds a scan chunk's worth until it has stacked them).
@@ -31,15 +33,23 @@ each by its own means:
 from __future__ import annotations
 
 import itertools
+import os
 import queue
 import threading
 import weakref
+from concurrent.futures import ThreadPoolExecutor
 
 import numpy as np
 
 from sparknet_tpu.data.prefetch import DONE, FeedThread, fresh_bytes
 from sparknet_tpu.obs import get_recorder
 from sparknet_tpu.obs.recorder import feed_counts
+
+
+# threads that touch a new buffer's pages (``RoundBuffer._make``): the
+# host's cores, and no more than eight (past that the memory is the limit:
+# a fresh 2 GB written twice takes 8 threads 0.72 s, 12 0.63; PERF.md, PR 29)
+TOUCHERS = min(8, os.cpu_count() or 1)
 
 
 class Turns:
@@ -88,8 +98,9 @@ class RoundBuffer:
     contiguous view ``buf[t, w*B:(w+1)*B]``.  A ``takes_out`` data fn is
     handed that view and writes its records straight into it; any other
     batch is copied there.  Nothing is concatenated or stacked.  The
-    arrays are made on the first read, from the first batch's shapes, and
-    live as long as the buffer's owner (``stack_tau`` / ``widen_batch``;
+    arrays are made on the first read, from the first batch's shapes (or
+    by ``make_like``, from another buffer's), and live as long as
+    the buffer's owner (``stack_tau`` / ``widen_batch``;
     the module docstring says when they may be rewritten).  The data fn
     is called under its own lock (``lock_of``).
 
@@ -113,23 +124,35 @@ class RoundBuffer:
             got = (self._fn(index, out=views) if views and self._takes_out
                    else self._fn(index))
         if not views:
-            self._batch = len(next(iter(got.values())))
-            self.arrays = {
-                k: np.empty((self._slots, self._workers * self._batch,
-                             *v.shape[1:]), v.dtype)
-                for k, v in got.items()}
-            self._alloc = sum(a.nbytes for a in self.arrays.values())
-            # the buffer's pages are touched here, not by the reads that
-            # fill it: those hold the data fn's lock, and on the chip's
-            # host a batch written into fresh memory takes 51 ms, into
-            # memory written once 25, from then on 4 (PERF.md, PR 27), so
-            # a second feed over the same data fn queued behind them
-            for a in self.arrays.values():
-                a.fill(0)
-                a.fill(0)
+            self._make(got)
             views = self._views(t, w)
         if fresh_bytes(got, views):
             self._strays.append((views, got))
+
+    def make_like(self, other):
+        """The arrays now, for the batches ``other`` holds, and not on
+        the first read."""
+        self._make(other._views(0, 0))
+
+    def _make(self, batch):
+        self._batch = len(next(iter(batch.values())))
+        self.arrays = {
+            k: np.empty((self._slots, self._workers * self._batch,
+                         *v.shape[1:]), v.dtype)
+            for k, v in batch.items()}
+        self._alloc = sum(a.nbytes for a in self.arrays.values())
+        # the buffer's pages are touched here, not by the reads that
+        # fill it: those hold the data fn's lock, and on the chip's
+        # host a batch written into fresh memory takes 51 ms, into
+        # memory written once 25, from then on 4 (PERF.md, PR 27), so
+        # a second feed over the same data fn queued behind them.  From
+        # a few threads at once: one writes a fresh 2 GB twice in 3.0 s,
+        # eight in 0.7 (PERF.md, PR 29), and this is set-up time
+        parts = [part for a in self.arrays.values()
+                 for part in np.array_split(a.reshape(-1), TOUCHERS)]
+        with ThreadPoolExecutor(TOUCHERS) as pool:
+            for _ in range(2):
+                list(pool.map(lambda part: part.fill(0), parts))
 
     def _views(self, t, w):
         lo = w * self._batch
@@ -153,21 +176,24 @@ def stack_tau(train_fn, tau, num_workers):
     Owns its own batch counter: each round consumes tau*num_workers fresh
     batches regardless of how the trainer advances its iteration count.
 
-    The feed is always exactly ONE round ahead.  It owns two persistent
-    buffers per feed key (``RoundBuffer``) and, from the first call on,
-    one daemon thread (``prefetch.FeedThread``) that makes every call
-    into the data fn, in the order and with the indices a serial pack
-    would.  ``fn(it)`` hands out round n, waiting under ``sn.feed.wait``
-    where the thread has not filled it yet (``ready`` = 0), and only then
-    lets the thread start on round n+1, in the buffer round n-1 was read
-    from (the host-buffer rule, module docstring).  So the arrays ``fn``
-    returns are valid, and not written, until the NEXT call returns.
+    The feed is always exactly ONE round ahead.  It owns three
+    persistent buffers per feed key (``RoundBuffer``; all made and
+    touched with the first batch, so none of that lands in a later
+    round) and, from the first call on, one daemon thread
+    (``prefetch.FeedThread``) that makes every call into the data fn, in
+    the order and with the indices a serial pack would.  ``fn(it)`` hands
+    out round n, waiting under ``sn.feed.wait`` where the thread has not
+    filled it yet (``ready`` = 0), and only then lets the thread start on
+    round n+1, in the buffer round n-2 was read from (the host-buffer
+    rule, module docstring).  So the arrays ``fn`` returns are valid, and
+    not written, until the call AFTER the next returns: a trainer may
+    ask for round n+1 while round n still trains from its buffer.
 
     An error the data fn raises on the thread surfaces from ``fn``.
     ``fn.close()`` stops and joins the thread; a feed nobody closes
     cannot hold the process (a daemon).  The one round read past the
     last one asked for is the price."""
-    bufs = [RoundBuffer(train_fn, tau, num_workers) for _ in range(2)]
+    bufs = [RoundBuffer(train_fn, tau, num_workers) for _ in range(3)]
     asked: queue.SimpleQueue = queue.SimpleQueue()  # ``it`` of a round to fill
     feed = None  # the thread, from the first call on
 
@@ -182,6 +208,9 @@ def stack_tau(train_fn, tau, num_workers):
                     if thread.stopped:
                         return
                     buf.read(index, t, w)
+                    if index == 0:  # the shapes are known: the other two
+                        for other in bufs[1:]:
+                            other.make_like(buf)
                     index += 1
                 buf.slot(it, t)
             if not thread.put(dict(buf.arrays), it):

@@ -529,9 +529,13 @@ def dryrun_main(argv: list[str]) -> int:
     trainer = ParallelTrainer(
         Solver(family.solver(), family.net(per_device)), mesh=mesh,
         tau=args.tau)
+    # one data fn for every round: the trainer places a round ahead
+    def tau_feeds(it):
+        return _feeds_for(family, batch, rs, tau=args.tau)
+
     for _ in range(args.rounds):
-        trainer.train_round(
-            lambda it: _feeds_for(family, batch, rs, tau=args.tau))
+        trainer.train_round(tau_feeds)
+    trainer.close()
 
     if args.elastic:
         from sparknet_tpu.parallel.elastic import (

@@ -99,6 +99,9 @@ class ParallelTrainer:
         # round program itself (banked graph/mem manifests stay
         # byte-identical whether or not the hook is armed).
         self.feed_device_fn = None
+        # the round placed ahead (``_place_ahead``): (it, data fn, what
+        # staging it gave or raised), or None
+        self._ahead = None
         self._step_fn = solver._make_train_step(debug=False)
         self._rules = rules or ShardingRules()
         self._pshard = param_shardings(
@@ -378,6 +381,14 @@ class ParallelTrainer:
         _put_feeds).  Returns mean loss (device value materialized — call
         sites that care about overlap should batch rounds).
 
+        The tau-shaped contract is placed ONE round ahead: between this
+        round's dispatch and its fence the next round is asked of
+        ``data_fn``, placed and augmented (``_place_ahead``), so pass the
+        same ``data_fn`` every round (a new function a round has every
+        round staged twice, ``_take_ahead``), let it leave a call's arrays
+        alone until the call after the next returns, and ``close()`` the
+        trainer after the last round.
+
         With ``SPARKNET_OBS`` armed each round emits one obs record
         (wall fence-stamped on the loss VALUE, comm_model-predicted
         collective bytes attached); disabled, the body is untouched —
@@ -387,13 +398,14 @@ class ParallelTrainer:
         rec = get_recorder()
         t0 = time.perf_counter() if rec else 0.0
         it0 = self.iter
-        # the round's serial order, each stage with its own wall on the
-        # profiler's clock: data -> put -> augment -> dispatch -> fence
+        stacked = self._elastic or self.tau > 1
+        # dispatch -> data, put, augment of the NEXT round -> fence, each
+        # stage with its own wall on the profiler's clock; a round nothing
+        # was placed ahead for begins with its own data, put, augment
         with step_span("sn.round", it0):
-            with rec.span("sn.round.data", host=True, it=it0):
-                raw = data_fn(it0)
-            feeds = self._stage_feeds(
-                raw, with_tau_axis=self._elastic or self.tau > 1)
+            batch, feeds = (
+                self._take_ahead(data_fn, it0)
+                or self._stage_round(data_fn, it0, stacked, staged=0))
             with rec.span("sn.round.dispatch", host=True, it=it0):
                 if self._elastic:
                     (self.variables, self.slots, self.center,
@@ -413,28 +425,91 @@ class ParallelTrainer:
                         self.solver._key
                     )
             self.iter += self.tau
+            if stacked:
+                self._place_ahead(data_fn)
             # on the profiler's clock only (no Recorder): in the journal
             # the round record closed on this value is the fence's line
             with Span(None, "sn.round.fence", it=it0) as sp:
                 if rec:
-                    loss_val = self._emit_obs_round(rec, raw, t0, loss)
+                    loss_val = self._emit_obs_round(rec, batch, t0, loss)
                 else:
                     loss_val = float(loss)
                 sp.fence_value(loss_val)
         return loss_val
 
-    def _stage_feeds(self, raw, with_tau_axis: bool):
+    def _stage_round(self, data_fn: DataFn, it: int, stacked: bool,
+                     staged: int):
+        """Round ``it``'s feeds from the data fn to the device: (images a
+        local step, placed feeds).  ``staged`` = 1 on ``sn.round.data``
+        when this is done before the round before it is fenced."""
+        with get_recorder().span("sn.round.data", host=True, it=it,
+                                 staged=staged):
+            raw = data_fn(it)
+        batch = 0
+        for v in raw.values():
+            shp = np.shape(v)
+            if shp:
+                batch = int(shp[1]) if stacked and len(shp) > 1 \
+                    else int(shp[0])
+                break
+        return batch, self._stage_feeds(raw, it, with_tau_axis=stacked)
+
+    def _place_ahead(self, data_fn: DataFn) -> None:
+        """The tau-shaped contract's look-ahead, ONE round deep: between
+        round n's dispatch and its fence, round n+1 is asked of the data
+        fn, placed and handed to the device hook under its OWN ``it``, so
+        its transfer and its augment run beside round n's steps and every
+        random draw is the serial order's.  An error of the early call
+        belongs to round n+1 and is raised by the ``train_round`` that
+        would have trained it.  The data fn must leave the arrays of a
+        call alone until the call after the next returns (``rounds.
+        stack_tau`` does: the host-buffer rule, ``data/rounds.py``).
+        tau == 1 (``widen_batch`` / ``train_rounds``) has another rule
+        and no look-ahead."""
+        it = self.iter
+        try:
+            got = self._stage_round(data_fn, it, True, staged=1)
+        except (Exception, SystemExit) as e:
+            got = e
+        self._ahead = (it, data_fn, got)
+
+    def _take_ahead(self, data_fn: DataFn, it: int):
+        """What ``_place_ahead`` staged, if it was staged for ``it`` and
+        asked of ``data_fn``.  Any other round is dropped, its batches
+        with it: its augment was keyed by another ``it``, or its records
+        are another feed's (a caller that hands ``train_round`` a new
+        function every round gets the serial order, and pays for a round
+        placed in vain)."""
+        ahead, self._ahead = self._ahead, None
+        if ahead is None:
+            return None
+        placed_for, asked_of, got = ahead
+        if placed_for != it or asked_of != data_fn:
+            return None
+        if isinstance(got, BaseException):
+            raise got
+        return got
+
+    def close(self) -> None:
+        """Lets go of the round placed ahead, its shards with it: call it
+        when the last round is trained.  The trainer can train on (its
+        next round stages its own feeds)."""
+        self._ahead = None
+
+    def _stage_feeds(self, raw, it: int, with_tau_axis: bool):
         """Host feeds -> their shards (``_put_feeds``), then the
-        post-placement device hook.  ``sn.feed.put`` / ``sn.feed.augment``
-        time the HOST side: both are dispatched, not awaited."""
+        post-placement device hook, for the round that begins at ``it``.
+        ``sn.feed.put`` / ``sn.feed.augment`` time the HOST side: both
+        are dispatched, not awaited.  The placed wire is let go as soon
+        as the hook has what it made of it."""
         rec = get_recorder()
         counts = feed_counts(raw, 2 if with_tau_axis else 1)
-        with rec.span("sn.feed.put", host=True, it=self.iter, **counts):
+        with rec.span("sn.feed.put", host=True, it=it, **counts):
             feeds = self._put_feeds(raw, with_tau_axis=with_tau_axis)
         if self.feed_device_fn is not None:
-            with rec.span("sn.feed.augment", host=True, it=self.iter,
+            with rec.span("sn.feed.augment", host=True, it=it,
                           images=counts["images"]):
-                feeds = self.feed_device_fn(feeds, self.iter)
+                feeds = self.feed_device_fn(feeds, it)
         return feeds
 
     def train(self, num_outer: int, data_fn: DataFn, callback=None) -> float:
@@ -489,7 +564,7 @@ class ParallelTrainer:
         self._obs_comm_cache = comm
         return comm
 
-    def _emit_obs_round(self, rec, raw, t0: float, loss) -> float:
+    def _emit_obs_round(self, rec, batch: int, t0: float, loss) -> float:
         """Journal one round record; returns the fenced loss VALUE —
         the same number ``float(loss)`` yields (``value_fence`` on the
         scalar loss IS the value fetch), so obs-on and obs-off return
@@ -499,13 +574,6 @@ class ParallelTrainer:
         loss_val = value_fence(loss)
         wall = time.perf_counter() - t0
         stacked = self.tau > 1 or self._elastic
-        batch = 0
-        for v in raw.values():
-            shp = getattr(v, "shape", None) or np.shape(v)
-            if shp:
-                batch = int(shp[1]) if stacked and len(shp) > 1 \
-                    else int(shp[0])
-                break
         from sparknet_tpu.obs import lineage as obs_lineage
 
         it_consumed = self.tau if stacked else 1
@@ -557,7 +625,7 @@ class ParallelTrainer:
         # xs layout
         # (the device hook's rank-5 arm: [n, B, ...] scanned rounds take
         # per-slot keys exactly like a [tau, B, ...] round)
-        feeds = self._stage_feeds(stacked, with_tau_axis=True)
+        feeds = self._stage_feeds(stacked, self.iter, with_tau_axis=True)
         with self._sp_context():
             self.variables, self.slots, losses = self._round_scan_fns[n](
                 self.variables, self.slots, self.iter, feeds,
@@ -631,6 +699,7 @@ class ParallelTrainer:
         return variables_to_collection(self._averaged_variables())
 
     def set_weights(self, wc: WeightCollection) -> None:
+        self._ahead = None  # a new model begins with a round of its own
         v = collection_to_variables(wc, self.solver.variables)
         if self.tau == 1 and not self._elastic:
             self.variables = place(v, self._pshard)
@@ -656,6 +725,7 @@ class ParallelTrainer:
         from sparknet_tpu.solvers.orbax_io import restore_trainer_orbax
 
         restore_trainer_orbax(self, path)
+        self._ahead = None  # placed for the iteration before the restore
 
     def sync_to_solver(self) -> None:
         """Pull the averaged model AND optimizer history back into the

@@ -2,7 +2,7 @@
 once, into the array its consumer hands it.
 
 ``db_minibatches`` fresh and into a destination, ``rounds.widen_batch``
-over its one persistent buffer, ``rounds.stack_tau`` over its two (its feed
+over its one persistent buffer, ``rounds.stack_tau`` over its three (its feed
 thread fills round n+1 while round n is out), and the
 ``DevicePrefetcher``'s ring of host batches, all against the records the
 DB was written from.  Everything runs on the CPU, where ``device_put``
@@ -236,10 +236,10 @@ def test_the_db_data_fn_takes_a_destination_and_says_what_it_allocated(
 @pytest.mark.parametrize("pack", ["stack_tau", "widen_batch"])
 def test_a_pack_fills_one_buffer_and_equals_the_plain_batches(
         job, tmp_path, pack, hook):
-    """``widen_batch``: one buffer.  ``stack_tau``: two, in turn."""
+    """``widen_batch``: one buffer.  ``stack_tau``: three, in turn."""
     flags, records = job
-    tau, workers, calls = (3, 2, 5) if pack == "stack_tau" else (1, 2, 4)
-    buffers = 2 if pack == "stack_tau" else 1
+    tau, workers, calls = (3, 2, 7) if pack == "stack_tau" else (1, 2, 4)
+    buffers = 3 if pack == "stack_tau" else 1
     journal = str(tmp_path / "journal.jsonl")
     got = []
 
@@ -289,16 +289,15 @@ def test_a_pack_fills_one_buffer_and_equals_the_plain_batches(
     stacks = journaled(journal, "sn.feed.stack")[:calls * tau]
     assert len(reads) == calls * per_call and len(stacks) == calls * tau
     assert all(s["images"] == workers * BATCH for s in stacks)
-    # the first call on a buffer makes it (and reads its first batch to
-    # learn the shapes); nothing batch-sized is allocated by the pack
-    # after that
+    # the very first read learns the shapes and every buffer is made
+    # then (a buffer says so in the first slot it hands out); nothing
+    # batch-sized is allocated by the pack after that
     made = [call * tau for call in range(buffers)]
     assert [s["alloc_bytes"] for s in stacks] == [
         per_call * nbytes if i in made else 0 for i in range(len(stacks))]
     if hook:
-        first = [call * per_call for call in range(buffers)]
         assert [r["alloc_bytes"] for r in reads] == [
-            nbytes if i in first else 0 for i in range(len(reads))]
+            nbytes if i == 0 else 0 for i in range(len(reads))]
     else:
         assert all(r["alloc_bytes"] == nbytes for r in reads)
     waits = journaled(journal, "sn.feed.wait")
@@ -356,35 +355,39 @@ def test_the_next_round_is_read_before_it_is_asked_for():
     assert seen == list(range(2 * ROUND))  # and not one batch of round 2
 
 
-def test_the_feed_is_one_round_ahead_and_leaves_the_round_in_hand_alone():
-    filled = {n: threading.Event() for n in range(4)}
-    in_hand = {}
+def test_the_feed_is_one_round_ahead_and_leaves_the_two_rounds_in_hand_alone():
+    rounds = 6
+    filled = {n: threading.Event() for n in range(rounds + 1)}
+    in_hand = {}  # the last two rounds handed out, by their number
+
+    def untouched():
+        for m, feeds in list(in_hand.items()):
+            assert batches_of(feeds) == list(range(m * ROUND, (m + 1) * ROUND))
 
     def on_call(index):
         n, i = divmod(index, ROUND)
-        # while round n is read, round n-1 is the one in hand: untouched
-        if "feeds" in in_hand:
-            assert batches_of(in_hand["feeds"]) == list(
-                range((n - 1) * ROUND, n * ROUND))
+        # while round n is read, rounds n-1 and n-2 are in hand (the one
+        # being placed and the one that trains): untouched
+        untouched()
         if i == ROUND - 1:
             filled[n].set()
 
     data_fn, seen = numbered(on_call)
     fn = stack_tau(data_fn, TAU, WORKERS)
     try:
-        for n in range(3):
-            in_hand["feeds"] = fn(n * TAU)
-            assert batches_of(in_hand["feeds"]) == list(
-                range(n * ROUND, (n + 1) * ROUND))
+        for n in range(rounds):
+            in_hand.pop(n - 2, None)  # fenced: its buffer is the feed's
+            in_hand[n] = fn(n * TAU)
+            untouched()
             # round n+1 is read whole, and there the thread stops: it
-            # would write round n+2 into the buffer of the round in hand
+            # would write round n+2 into the buffer of round n-1, which
+            # is valid until the call after this one returns
             assert filled[n + 1].wait(timeout=30)
             assert max(seen) == (n + 2) * ROUND - 1
-            assert batches_of(in_hand["feeds"]) == list(
-                range(n * ROUND, (n + 1) * ROUND))
+            untouched()
     finally:
         fn.close()  # joins: whatever the thread was going to read, it has
-    assert seen == list(range(4 * ROUND))  # every batch once, in order
+    assert seen == list(range((rounds + 1) * ROUND))  # every batch once
 
 
 def test_two_feeds_over_one_data_fn_neither_raise_nor_lose_a_batch(job):

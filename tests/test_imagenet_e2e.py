@@ -50,12 +50,14 @@ def shard_dir(tmp_path_factory):
     return str(root), label_file
 
 
-def test_imagenet_app_end_to_end(shard_dir, tmp_path):
+@pytest.fixture(scope="module")
+def app_and_logs(shard_dir, tmp_path_factory):
     from sparknet_tpu.apps.imagenet_app import ImageNetApp
     from sparknet_tpu.parallel.mesh import data_parallel_mesh
 
     root, label_file = shard_dir
-    app = ImageNetApp(
+    logs = tmp_path_factory.mktemp("imagenet_logs")
+    return ImageNetApp(
         root,
         label_file,
         mesh=data_parallel_mesh(2),  # 2 workers, one shard each
@@ -63,8 +65,12 @@ def test_imagenet_app_end_to_end(shard_dir, tmp_path):
         batch=3,
         model="caffenet",
         num_classes=4,
-        log_dir=str(tmp_path),
-    )
+        log_dir=str(logs),
+    ), logs
+
+
+def test_imagenet_app_end_to_end(app_and_logs):
+    app, tmp_path = app_and_logs
     assert app.num_workers == 2
     assert app.mean_image.shape == (3, 256, 256)
     # mean of raw pixels: strictly inside (0, 255)
@@ -76,6 +82,33 @@ def test_imagenet_app_end_to_end(shard_dir, tmp_path):
     # consume 12 of 24 per shard without re-epoching
     logs = [f for f in os.listdir(tmp_path) if f.startswith("imagenet_training_log")]
     assert logs, "event log missing"
+
+
+def test_imagenet_app_places_every_round_once(app_and_logs):
+    """The app hands the trainer ONE data fn, so a round is asked for,
+    put and trained once, the next one while this one trains (three rounds
+    and the one placed past the last, which ``run`` lets go of): a new
+    function a round would stage each round twice."""
+    app, _ = app_and_logs
+    events = []
+    put, feeds = app.trainer._put_feeds, app._tau_feeds
+
+    def noting_put(raw, **kw):
+        events.append("put")
+        return put(raw, **kw)
+
+    def noting_feeds(streams):
+        events.append("data")
+        return feeds(streams)
+
+    app.trainer._put_feeds, app._tau_feeds = noting_put, noting_feeds
+    try:
+        # 3 + 1 rounds of 6 per worker: the 24 of a shard, no new epoch
+        assert np.isfinite(app.run(num_outer=3))
+    finally:
+        del app.trainer._put_feeds, app._tau_feeds
+    assert events == ["data", "put"] * 4
+    assert app.trainer._ahead is None
 
 
 def test_imagenet_app_dataset_too_small(shard_dir, tmp_path):

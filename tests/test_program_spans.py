@@ -8,7 +8,8 @@ own functions (``cli.main`` with ``cmd_train`` swapped for the body, the
 way the benchmark drives it): two ``Solver.step`` chunks fed by a
 ``DevicePrefetcher`` from a tiny ``db:`` feed, and two tau=2
 ``ParallelTrainer.train_round`` calls on two virtual devices, fed by
-``rounds.stack_tau``, whose thread reads a round ahead.
+``rounds.stack_tau``, whose thread reads a round ahead of the round the
+trainer places ahead.
 """
 
 import glob
@@ -155,9 +156,9 @@ def rounds(job):
                                   tau=args.tau)
         trainer.feed_device_fn = train_fn.trainer_device_fn
         tau_fn = stack_tau(train_fn, args.tau, trainer.num_local_workers)
-        trainer.train_round(tau_fn)  # compile; the feed reads round 1
+        trainer.train_round(tau_fn)  # compile; round 1 is placed ahead
         with profiling.trace(str(tmp / "rounds")):
-            trainer.train_round(tau_fn)  # round 1; the feed reads round 2
+            trainer.train_round(tau_fn)  # round 1; places 2, the feed reads 3
             trainer.train_round(tau_fn)
             tau_fn.close()  # the feed's thread ends inside the trace
         return 0
@@ -230,13 +231,21 @@ def test_the_solo_trace_holds(solo, name):
 def test_read_contains_decode_and_collate(solo):
     spans, _ = solo
     reads = named(spans, "sn.feed.read")
-    # the profiler drops a span already open when the session starts: the
-    # feed thread may be inside a read then, whose parts it still records
+    # the profiler drops a span open when the session starts or stops:
+    # the feed thread may be inside a read then, whose parts it still
+    # records.  Before the first read on record and after the last one
+    # lie the parts of those two reads, and of no other
     seen_from = min(r["start"] for r in reads)
-    for kind in ("sn.feed.decode", "sn.feed.collate"):
-        parts = [p for p in named(spans, kind) if p["start"] >= seen_from]
+    seen_to = max(r["end"] for r in reads)
+    # (a read has one collate and one decode, two at an epoch's end)
+    for kind, a_read in (("sn.feed.decode", 2), ("sn.feed.collate", 1)):
+        parts = [p for p in named(spans, kind)
+                 if seen_from <= p["start"] < seen_to]
         assert parts and all(
             any(inside(p, r) for r in reads) for p in parts)
+        early = [p for p in named(spans, kind) if p["start"] < seen_from]
+        late = [p for p in named(spans, kind) if p["start"] >= seen_to]
+        assert len(early) <= a_read and len(late) <= a_read, (early, late)
     for r in reads:
         # each read holds one collate (the hand-over) after its decodes
         # (the per-record loop with its one copy; an epoch's end adds an
@@ -301,9 +310,10 @@ def test_the_round_trace_holds(rounds, name):
     assert named(rounds, name), sorted({s["name"] for s in rounds})
 
 
-def test_a_round_is_data_put_augment_dispatch_fence_in_that_order(rounds):
-    order = ["sn.round.data", "sn.feed.put", "sn.feed.augment",
-             "sn.round.dispatch", "sn.round.fence"]
+def test_a_round_is_dispatch_the_next_rounds_data_put_augment_and_its_fence(
+        rounds):
+    order = ["sn.round.dispatch", "sn.round.data", "sn.feed.put",
+             "sn.feed.augment", "sn.round.fence"]
     outer = named(rounds, "sn.round")
     assert len(outer) == 2
     # the round on the main thread, the reads and stacks on the feed's
@@ -317,29 +327,36 @@ def test_a_round_is_data_put_augment_dispatch_fence_in_that_order(rounds):
     nbytes = images * (3 * 16 * 16 + 4)
     waits = []
     for rnd in outer:
+        it = rnd["stats"]["step_num"]
         stages = [next(s for s in named(rounds, n) if inside(s, rnd))
                   for n in order]
         for a, b in zip(stages, stages[1:]):
             assert a["end"] <= b["start"], (a["name"], b["name"])
-        # the round's data is a wait for the feed, which says whether the
-        # round was filled before it was asked for
+        # the dispatch and the fence are the round's own; what lies
+        # between them is for the NEXT round and carries its ``it``
+        assert stages[0]["stats"]["it"] == stages[4]["stats"]["it"] == it
+        assert [s["stats"]["it"] for s in stages[1:4]] == [it + TAU] * 3
+        assert stages[1]["stats"]["staged"] == 1
+        # the data is a wait for the feed, which says whether the round
+        # was filled before it was asked for
         (wait,) = [w for w in named(rounds, "sn.feed.wait")
-                   if inside(w, stages[0])]
-        assert wait["stats"]["it"] == rnd["stats"]["step_num"]
+                   if inside(w, stages[1])]
+        assert wait["stats"]["it"] == it + TAU
         assert wait["stats"]["ready"] in (0, 1)
         waits.append(wait)
-        assert stages[1]["stats"]["images"] == images
-        assert stages[1]["stats"]["bytes"] == nbytes
+        assert stages[2]["stats"]["images"] == images
+        assert stages[2]["stats"]["bytes"] == nbytes
     assert outer[1]["stats"]["step_num"] == outer[0]["stats"]["step_num"] + TAU
 
-    # the feed reads round 2 (the warm-up was round 0) from the moment
-    # round 1 is handed out, and has it whole before it hands it out: TAU
-    # x WORKERS reads in the data fn's order, a stack after each slot's
+    # the feed reads round 3 (the warm-up was round 0 and placed round 1)
+    # from the moment round 2 is handed out, inside round 1, and has it
+    # whole before it hands it out: TAU x WORKERS reads in the data fn's
+    # order, a stack after each slot's
     per_round = TAU * WORKERS
     reads = [r for r in named(rounds, "sn.feed.read")
-             if r["stats"]["it"] // per_round == 2]
+             if r["stats"]["it"] // per_round == 3]
     assert [r["stats"]["it"] for r in reads] == list(
-        range(2 * per_round, 3 * per_round))
+        range(3 * per_round, 4 * per_round))
     assert waits[0]["end"] <= reads[0]["start"]
     stacks = [s for s in named(rounds, "sn.feed.stack")
               if reads[0]["start"] <= s["start"] <= waits[1]["end"]]
@@ -349,14 +366,16 @@ def test_a_round_is_data_put_augment_dispatch_fence_in_that_order(rounds):
         assert all(r["end"] <= stack["start"] for r in mine)
         assert all(stack["end"] <= r["start"]
                    for r in reads[(t + 1) * WORKERS:])
-        # it names the round it is for (the trainer's next iteration);
-        # both buffers were made before the trace: nothing allocated
+        # it names the round it is for (the one placed inside the second
+        # traced round); all three buffers were made before the trace:
+        # nothing allocated
         assert stack["stats"] == {
-            "it": outer[1]["stats"]["step_num"], "images": WORKERS * BATCH,
+            "it": outer[1]["stats"]["step_num"] + TAU,
+            "images": WORKERS * BATCH,
             "bytes": nbytes // TAU, "alloc_bytes": 0}
     assert all(r["stats"]["alloc_bytes"] == 0 for r in reads)
-    # and never a batch of the round after the next
-    assert all(r["stats"]["it"] < 4 * per_round
+    # and never a batch of the round after the one read ahead
+    assert all(r["stats"]["it"] < 5 * per_round
                for r in named(rounds, "sn.feed.read"))
 
 
