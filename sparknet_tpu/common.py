@@ -295,7 +295,8 @@ def require_chip(tool: str) -> dict:
 # data/device_transform.py, ops/moe.py, ops/attention.py): part of the
 # compile-cache key.  Add a scope, add it here
 CACHE_SCOPES = ("scopes:L.<layer>,S.update,S.augment,"
-                "M.route,M.dispatch,M.experts,M.combine,A.core")
+                "M.route,M.dispatch,M.experts,M.combine,M.shared,"
+                "A.core,A.latent")
 
 
 def enable_compile_cache() -> str:

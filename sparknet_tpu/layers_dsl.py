@@ -124,10 +124,15 @@ def InnerProductLayer(
     bias_filler: Message | None = None,
     axis: int | None = None,
     bias_term: bool = True,
+    param_name: str | None = None,
 ) -> Message:
     """ref: Layers.scala:88-100.  ``axis`` flattens from that axis
-    (Caffe default 1; axis=2 keeps a [B, S, E] sequence per-token)."""
+    (Caffe default 1; axis=2 keeps a [B, S, E] sequence per-token).
+    ``param_name`` names the weight blob: layers that give one name share
+    the array (Caffe's ``param { name }``)."""
     m = _layer(name, "InnerProduct", bottoms)
+    if param_name:
+        m.add("param", Message().set("name", param_name))
     p = Message()
     p.set("num_output", num_output)
     p.set("weight_filler", weight_filler or _filler("xavier"))
@@ -268,6 +273,7 @@ def SoftmaxLayer(name: str, bottoms: Sequence[str]) -> Message:
 def SoftmaxWithLoss(
     name: str, bottoms: Sequence[str], loss_weight: float | None = None,
     top: str | None = None, axis: int | None = None,
+    keep_value: bool = False,
 ) -> Message:
     """ref: Layers.scala:115-128 (bottoms = [scores, label]).  ``loss_weight``
     scales this loss term in the total objective — the GoogLeNet auxiliary
@@ -277,6 +283,8 @@ def SoftmaxWithLoss(
     m = _loss_layer(name, "SoftmaxWithLoss", bottoms, loss_weight, top)
     if axis is not None:
         m.set("softmax_param", Message().set("axis", axis))
+    if keep_value:  # the value stays in the layer's state, for the fence
+        m.set("loss_param", Message().set("keep_value", True))
     return m
 
 
@@ -311,9 +319,13 @@ def EmbedLayer(
     weight_filler: Message | None = None,
     top: str | None = None,
     bias_term: bool = True,
+    param_name: str | None = None,
 ) -> Message:
-    """Embedding lookup (ref: embed_layer.cpp; ops/blocks.py Embed)."""
+    """Embedding lookup (ref: embed_layer.cpp; ops/blocks.py Embed).
+    ``param_name``: as ``InnerProductLayer``'s."""
     m = _layer(name, "Embed", bottoms, [top] if top else None)
+    if param_name:
+        m.add("param", Message().set("name", param_name))
     p = Message()
     p.set("input_dim", input_dim)
     p.set("num_output", num_output)
@@ -328,6 +340,62 @@ def RMSNormLayer(name: str, bottoms: Sequence[str], eps: float = 1e-5,
     """RMSNorm over the last axis (ops/blocks.py RMSNorm)."""
     m = _layer(name, "RMSNorm", bottoms, [top] if top else None)
     return m.set("rms_norm_param", Message().set("eps", eps))
+
+
+def SliceLayer(name: str, bottoms: Sequence[str], tops: Sequence[str],
+               axis: int = 1, slice_points: Sequence[int] = ()) -> Message:
+    """ref: slice_layer.cpp (one top per piece)."""
+    m = _layer(name, "Slice", bottoms, tops)
+    p = Message().set("axis", axis)
+    for pt in slice_points:
+        p.add("slice_point", pt)
+    return m.set("slice_param", p)
+
+
+def GatedMLPLayer(name: str, bottoms: Sequence[str], hidden_dim: int,
+                  weight_filler: Message | None = None,
+                  top: str | None = None) -> Message:
+    """Gated SiLU feed-forward over the last axis (ops/blocks.py
+    GatedMLP): ``(silu(x W_g) * x W_u) W_d``, no biases."""
+    m = _layer(name, "GatedMLP", bottoms, [top] if top else None)
+    p = Message().set("hidden_dim", hidden_dim)
+    if weight_filler is not None:
+        p.set("weight_filler", weight_filler)
+    return m.set("gated_mlp_param", p)
+
+
+def LatentAttentionLayer(
+    name: str,
+    bottoms: Sequence[str],
+    num_heads: int,
+    q_lora_rank: int,
+    kv_lora_rank: int,
+    qk_nope_head_dim: int,
+    qk_rope_head_dim: int,
+    v_head_dim: int,
+    rope_theta: float | None = None,
+    rope_interleave: bool = False,
+    norm_eps: float | None = None,
+    weight_filler: Message | None = None,
+    top: str | None = None,
+) -> Message:
+    """Causal multi-head latent attention (ops/attention.py
+    LatentAttentionLayer): low-rank query and key/value paths with a norm
+    inside each, one rotary key shared by the heads."""
+    m = _layer(name, "LatentAttention", bottoms, [top] if top else None)
+    p = Message().set("num_heads", num_heads)
+    p.set("q_lora_rank", q_lora_rank).set("kv_lora_rank", kv_lora_rank)
+    p.set("qk_nope_head_dim", qk_nope_head_dim)
+    p.set("qk_rope_head_dim", qk_rope_head_dim).set("v_head_dim", v_head_dim)
+    if rope_theta is not None:
+        p.set("rope_theta", rope_theta)
+    if rope_interleave:
+        p.set("rope_interleave", True)
+    if norm_eps is not None:
+        p.set("norm_eps", norm_eps)
+    if weight_filler is not None:
+        p.set("weight_filler", weight_filler)
+    return m.set("attention_param", p)
 
 
 def MultiHeadAttentionLayer(
@@ -378,11 +446,21 @@ def MoELayer(
     bias_term: bool = True,
     loss_tops: Sequence[tuple[str, float]] = (),
     weight_filler: Message | None = None,
+    scoring_func: str = "softmax",
+    routed_scaling_factor: float = 1.0,
+    bias_update_rate: float = 0.0,
+    shared_hidden_dim: int = 0,
+    experts_held: int | None = None,
+    first_expert: int = 0,
 ) -> Message:
     """Mixture-of-experts extra (no reference analog; ops/moe.py).
     ``loss_tops``: up to three further (top name, loss_weight) pairs, in
     the layer's order: load-balancing loss, router z-loss, tokens per
-    expert."""
+    expert.  ``scoring_func`` / ``routed_scaling_factor`` /
+    ``bias_update_rate`` / ``shared_hidden_dim``: the DeepSeek-V3
+    family's router and shared expert; ``experts_held`` /
+    ``first_expert``: the experts of the router's ``num_experts`` this
+    layer holds (all by default)."""
     tops = [top or name, *(t for t, _ in loss_tops)]
     m = _layer(name, "MoE", bottoms, tops)
     if loss_tops:
@@ -399,6 +477,17 @@ def MoELayer(
         p.set("norm_topk_prob", True)
     if not bias_term:
         p.set("bias_term", False)
+    if scoring_func != "softmax":
+        p.set("scoring_func", scoring_func)
+    if routed_scaling_factor != 1.0:
+        p.set("routed_scaling_factor", routed_scaling_factor)
+    if bias_update_rate:
+        p.set("bias_update_rate", bias_update_rate)
+    if shared_hidden_dim:
+        p.set("shared_hidden_dim", shared_hidden_dim)
+    if experts_held is not None and (experts_held, first_expert) != (
+            num_experts, 0):
+        p.set("experts_held", experts_held).set("first_expert", first_expert)
     if weight_filler is not None:
         p.set("weight_filler", weight_filler)
     return m.set("moe_param", p)

@@ -37,6 +37,8 @@ from sparknet_tpu.models.zoo import (  # noqa: F401
     mnist_autoencoder_solver,
     mnist_siamese,
     mnist_siamese_solver,
+    joyai_flash,
+    joyai_flash_solver,
     olmoe,
     olmoe_solver,
     resnet50,

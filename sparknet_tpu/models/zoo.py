@@ -30,7 +30,9 @@ from sparknet_tpu.layers_dsl import (
     EmbedLayer,
     EuclideanLossLayer,
     FlattenLayer,
+    GatedMLPLayer,
     InnerProductLayer,
+    LatentAttentionLayer,
     LRNLayer,
     MoELayer,
     MultiHeadAttentionLayer,
@@ -43,6 +45,7 @@ from sparknet_tpu.layers_dsl import (
     ScaleLayer,
     SigmoidCrossEntropyLossLayer,
     SigmoidLayer,
+    SliceLayer,
     SoftmaxWithLoss,
     _filler,
 )
@@ -1095,6 +1098,141 @@ def olmoe_solver() -> SolverConfig:
     warm-up and cosine schedule are left to the prototxt's lr_policy."""
     return SolverConfig(
         base_lr=4e-4, lr_policy="fixed", momentum=0.9, momentum2=0.95,
+        delta=1e-8, weight_decay=0.1, clip_gradients=1.0,
+        max_iter=10000, solver_type="AdamW", display=100,
+    )
+
+
+def joyai_flash(
+    batch: int = 1,
+    seq_len: int = 4096,
+    vocab: int = 129280,
+    hidden: int = 2048,
+    heads: int = 32,
+    q_lora_rank: int = 1536,
+    kv_lora_rank: int = 512,
+    qk_nope_head_dim: int = 128,
+    qk_rope_head_dim: int = 64,
+    v_head_dim: int = 128,
+    dense_dim: int = 7168,
+    dense_layers: int = 1,
+    experts: int = 256,
+    top_k: int = 8,
+    expert_dim: int = 768,
+    shared_dim: int = 768,
+    layers: int = 40,
+    experts_held: int | None = None,
+    first_expert: int = 0,
+    rms_norm_eps: float = 1e-6,
+    rope_theta: float = 32e6,
+    routed_scaling_factor: float = 2.5,
+    bias_update_rate: float = 0.001,
+    mtp_weight: float = 0.3,
+    init_std: float = 0.006,
+) -> Message:
+    """JoyAI-LLM-Flash (jdopensource, config.json; the DeepSeek-V3 block)
+    at its published sizes by default: [batch, seq_len] token ids ->
+    per-token next-token logits, and one multi-token-prediction module.
+
+    ``layers`` blocks of latent attention and a feed-forward: a dense
+    gated MLP in the first ``dense_layers``, then sigmoid-routed experts
+    beside one shared expert, selected with a balancing bias.
+    ``experts_held`` / ``first_expert`` give this chip's share of every
+    expert layer (all ``experts`` by default), ``vocab`` the rows of the
+    embedding and the head it holds.
+
+    The MTP module (DeepSeek-V3, arXiv:2412.19437 section 2.2) takes the
+    residual stream before the final norm and the embedding of the NEXT
+    token (``label``), through the main model's embedding and head (shared
+    by ``param { name }``), one more whole block, and predicts the token
+    after next: ``mtp_loss`` is the mean cross-entropy of positions
+    0..S-2 against ``label`` shifted by one, weighted ``mtp_weight``."""
+    init = _gauss(init_std)
+
+    def attention(name, bottom):
+        return LatentAttentionLayer(
+            name, [bottom], num_heads=heads, q_lora_rank=q_lora_rank,
+            kv_lora_rank=kv_lora_rank, qk_nope_head_dim=qk_nope_head_dim,
+            qk_rope_head_dim=qk_rope_head_dim, v_head_dim=v_head_dim,
+            rope_theta=rope_theta, rope_interleave=True,
+            norm_eps=rms_norm_eps, weight_filler=init)
+
+    def expert_layer(name, bottom):
+        return MoELayer(
+            name, [bottom], num_experts=experts, hidden_dim=expert_dim,
+            top_k=top_k, expert_act="swiglu", norm_topk_prob=True,
+            scoring_func="sigmoid",
+            routed_scaling_factor=routed_scaling_factor,
+            bias_update_rate=bias_update_rate, shared_hidden_dim=shared_dim,
+            experts_held=experts_held, first_expert=first_expert,
+            weight_filler=init)
+
+    def block(x, norm_a, attn, res_a, norm_b, ffn, res_b, dense=False):
+        return [
+            RMSNormLayer(norm_a, [x], eps=rms_norm_eps),
+            attention(attn, norm_a),
+            EltwiseLayer(res_a, [x, attn], top=res_a),
+            RMSNormLayer(norm_b, [res_a], eps=rms_norm_eps),
+            GatedMLPLayer(ffn, [norm_b], dense_dim, weight_filler=init)
+            if dense else expert_layer(ffn, norm_b),
+            EltwiseLayer(res_b, [res_a, ffn], top=res_b),
+        ]
+
+    net = [
+        RDDLayer("data", shape=[batch, seq_len]),
+        RDDLayer("label", shape=[batch, seq_len]),
+        EmbedLayer("embed", ["data"], input_dim=vocab, num_output=hidden,
+                   weight_filler=init, bias_term=False, top="embed",
+                   param_name="embed_w"),
+    ]
+    x = "embed"
+    for i in range(1, layers + 1):
+        dense = i <= dense_layers
+        net += block(x, f"norm{i}a", f"attn{i}", f"res{i}a", f"norm{i}b",
+                     f"mlp{i}" if dense else f"moe{i}", f"res{i}b", dense)
+        x = f"res{i}b"
+    net += [
+        RMSNormLayer("norm_f", [x], eps=rms_norm_eps),
+        InnerProductLayer("lm_head", ["norm_f"], num_output=vocab, axis=2,
+                          weight_filler=init, bias_term=False,
+                          param_name="head_w"),
+        SoftmaxWithLoss("loss", ["lm_head", "label"], axis=2),
+        AccuracyLayer("accuracy", ["lm_head", "label"], phase="TEST", axis=2),
+        # multi-token prediction: h_i and Emb(t_{i+1}) -> t_{i+2}
+        EmbedLayer("mtp_embed", ["label"], input_dim=vocab, num_output=hidden,
+                   weight_filler=init, bias_term=False, top="mtp_embed",
+                   param_name="embed_w"),
+        RMSNormLayer("mtp_norm_h", [x], eps=rms_norm_eps),
+        RMSNormLayer("mtp_norm_e", ["mtp_embed"], eps=rms_norm_eps),
+        ConcatLayer("mtp_cat", ["mtp_norm_h", "mtp_norm_e"], axis=2),
+        InnerProductLayer("mtp_proj", ["mtp_cat"], num_output=hidden, axis=2,
+                          weight_filler=init, bias_term=False),
+        *block("mtp_proj", "mtp_norm_a", "mtp_attn", "mtp_res_a",
+               "mtp_norm_b", "mtp_moe", "mtp_res_b"),
+        RMSNormLayer("mtp_norm_f", ["mtp_res_b"], eps=rms_norm_eps),
+        # the last position has no token after next: positions 0..S-2
+        # against label_1..label_{S-1}
+        SliceLayer("mtp_positions", ["mtp_norm_f"],
+                   ["mtp_hidden", "mtp_hidden_last"], axis=1,
+                   slice_points=[seq_len - 1]),
+        SliceLayer("mtp_targets", ["label"], ["label_first", "label_next"],
+                   axis=1, slice_points=[1]),
+        InnerProductLayer("mtp_head", ["mtp_hidden"], num_output=vocab,
+                          axis=2, weight_filler=init, bias_term=False,
+                          param_name="head_w"),
+        SoftmaxWithLoss("mtp_loss", ["mtp_head", "label_next"],
+                        loss_weight=mtp_weight, axis=2, keep_value=True),
+    ]
+    return NetParam("JoyAI-LLM-Flash", *net)
+
+
+def joyai_flash_solver() -> SolverConfig:
+    """AdamW as DeepSeek-V3 trained its block (arXiv:2412.19437 section
+    4.2: betas 0.9 / 0.95, decoupled weight decay 0.1, gradient clipping
+    at global norm 1.0, peak lr 2.2e-4); eps 1e-8.  The warm-up and the
+    decay schedule are left to the prototxt's lr_policy."""
+    return SolverConfig(
+        base_lr=2.2e-4, lr_policy="fixed", momentum=0.9, momentum2=0.95,
         delta=1e-8, weight_decay=0.1, clip_gradients=1.0,
         max_iter=10000, solver_type="AdamW", display=100,
     )

@@ -46,7 +46,7 @@ from sparknet_tpu.common import get_config
 from sparknet_tpu.ops.base import Layer, LayerOutput
 from sparknet_tpu.ops.blocks import rms_norm
 from sparknet_tpu.ops.fillers import fill
-from sparknet_tpu.ops.pallas_kernels import flash_attention
+from sparknet_tpu.ops.pallas_kernels import attention_xla, flash_attention
 from sparknet_tpu.ops.registry import register
 from sparknet_tpu.proto.text_format import Message
 
@@ -128,7 +128,8 @@ def _sp_attention(mesh, impl, q, k, v, causal):
     )(q, k, v)
 
 
-def rope(x: jax.Array, base: float = 10000.0) -> jax.Array:
+def rope(x: jax.Array, base: float = 10000.0,
+         interleave: bool = False) -> jax.Array:
     """Rotary position embedding over ``x`` [B, H, S, D] (D even).
 
     Parameter-free absolute-position encoding with the relative-position
@@ -138,6 +139,11 @@ def rope(x: jax.Array, base: float = 10000.0) -> jax.Array:
     scores then depend on t_q − t_k.  No new weight blobs, so every
     wire format (caffemodel/HDF5/orbax) is untouched.  Must run BEFORE
     any sequence-parallel split: positions here are global.
+
+    The pair that angle θ_i turns is features (i, i + D/2) by default
+    (rotate-half, the Llama / OLMoE weight layout) and features
+    (2i, 2i + 1) with ``interleave`` (the DeepSeek-V3 family's published
+    layout, ``rope_interleave``); each feature stays where it was.
     """
     B, H, S, D = x.shape
     if D % 2:
@@ -146,6 +152,11 @@ def rope(x: jax.Array, base: float = 10000.0) -> jax.Array:
     theta = base ** (-jnp.arange(half, dtype=jnp.float32) / half)  # [half]
     ang = jnp.arange(S, dtype=jnp.float32)[:, None] * theta[None, :]  # [S,half]
     cos, sin = jnp.cos(ang), jnp.sin(ang)
+    if interleave:
+        x1, x2 = x[..., 0::2], x[..., 1::2]
+        return jnp.stack(
+            [x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1
+        ).reshape(x.shape).astype(x.dtype)
     x1, x2 = x[..., :half], x[..., half:]  # rotate-half convention
     return jnp.concatenate(
         [x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1
@@ -183,20 +194,34 @@ CORE_SCOPE = "A.core"
 
 
 def attention_core(q, k, v, causal: bool):
-    """Softmax attention over [B, H, S, D], chosen by shape and platform.
+    """Softmax attention over q, k [B, H, S, D] and v [B, H, S, Dv] ->
+    [B, H, S, Dv], chosen by shape and platform; the scores are scaled by
+    ``D ** -0.5``, D the key width.
 
-    From S = 2048 on a TPU (head dim a multiple of 128): jax's own
-    pallas ``flash_attention`` kernels, forward and backward, which
-    never hold the [B, H, S, S] scores (1 GB per 4k sequence of 16 heads
-    in f32).  Timed once on the v5e at 4 x 16 x 4096 x 128, causal,
-    forward + backward (PERF.md section 6): 14.2 ms at 1024-wide blocks
-    against 25.5 ms for an XLA loop over query blocks with remat.
-    Everything else takes :func:`flash_attention`'s XLA formulation,
-    which materializes the scores."""
-    S, D = q.shape[2], q.shape[3]
+    From S = 2048 on a TPU, kernels that never hold the [B, H, S, S]
+    scores (1 GB per 4k sequence of 16 heads in f32), forward and
+    backward.  Equal widths (a multiple of 128): jax's own pallas
+    ``flash_attention``.  Timed once on the v5e at 4 x 16 x 4096 x 128,
+    causal, forward + backward (PERF.md section 6): 14.2 ms at 1024-wide
+    blocks against 25.5 ms for an XLA loop over query blocks with remat.
+    A value width that differs from the key width (latent attention: keys
+    of 192, values of 128): jax's own pallas splash attention, the one
+    kernel here that takes them as they are.  Timed once on the v5e at
+    1 x 32 x 4096, keys 192, values 128, causal, forward + backward
+    (PERF.md section 6): 7.41 ms at 1024-wide blocks with the fused
+    backward kernel (8.35 ms with separate dq and dkv kernels) against
+    12.82 ms for the flash kernels with all three padded to 256 and
+    49.9 ms for an XLA loop over query blocks with remat.
+    Everything else takes the XLA formulation, which materializes the
+    scores: through :func:`flash_attention` at equal widths."""
+    S, D, Dv = q.shape[2], q.shape[3], v.shape[3]
     block = next((b for b in (1024, 512) if S % b == 0), 0)
-    if not (jax.default_backend() == "tpu" and S >= 2048 and block
-            and D % 128 == 0):
+    long_on_tpu = jax.default_backend() == "tpu" and S >= 2048 and block
+    if D != Dv:
+        if not (long_on_tpu and causal and D % 64 == 0 and Dv % 128 == 0):
+            return attention_xla(q, k, v, causal)
+        return _splash_causal(q, k, v, block)
+    if not (long_on_tpu and D % 128 == 0):
         return flash_attention(q, k, v, causal=causal)
     from jax.experimental.pallas.ops.tpu import flash_attention as fa
 
@@ -207,6 +232,23 @@ def attention_core(q, k, v, causal: bool):
         block_k_major_dq=block, block_k_dq=block, block_q_dq=block)
     return fa.flash_attention(q, k, v, causal=causal, sm_scale=D ** -0.5,
                               block_sizes=sizes)
+
+
+def _splash_causal(q, k, v, block: int):
+    """Causal attention with unequal key and value widths through jax's
+    splash attention kernels (forward, dq and dkv)."""
+    from jax.experimental.pallas.ops.tpu.splash_attention import (
+        splash_attention_kernel as sk, splash_attention_mask as sm)
+
+    H, S, D = q.shape[1:]
+    mask = sm.MultiHeadMask([sm.CausalMask((S, S))] * H)
+    sizes = sk.BlockSizes(
+        block_q=block, block_kv=block, block_kv_compute=block,
+        block_q_dkv=block, block_kv_dkv=block, block_kv_dkv_compute=block,
+        use_fused_bwd_kernel=True)
+    kernel = sk.make_splash_mha_single_device(mask, block_sizes=sizes)
+    # the kernel takes [H, S, D] and scores q kᵀ as given: scale q first
+    return jax.vmap(kernel)((q * D ** -0.5).astype(q.dtype), k, v)
 
 
 @register
@@ -294,4 +336,98 @@ class MultiHeadAttentionLayer(Layer):
         y = jnp.einsum("bse,fe->bsf", o, w_out)
         if self.bias_term:
             y = y + b_out
+        return LayerOutput(outputs=[y])
+
+
+# device scope of latent attention's projections, inner norms, RoPE and
+# the assembly of k (everything of the layer outside ``A.core``); in
+# common.CACHE_SCOPES
+LATENT_SCOPE = "A.latent"
+
+
+@register
+class LatentAttentionLayer(Layer):
+    """Multi-head latent attention (MLA; DeepSeek-V2 arXiv:2405.04434
+    section 2.1, as the DeepSeek-V3 family's ``config.json`` sizes it), the
+    training form: the compressed latents are expanded to full heads and
+    the core is ordinary causal attention with keys of
+    ``qk_nope_head_dim + qk_rope_head_dim`` and values of ``v_head_dim``.
+
+    ``attention_param { num_heads q_lora_rank kv_lora_rank
+    qk_nope_head_dim qk_rope_head_dim v_head_dim rope_theta
+    rope_interleave norm_eps causal }``.  [B, S, E] -> [B, S, E]; blobs,
+    every matrix ``[out, in]``, no biases:
+
+      W_dq (q_lora_rank, E), q_norm (q_lora_rank),
+      W_uq (H·(nope + rope), q_lora_rank)        per head [nope ; rope]
+      W_dkv (kv_lora_rank + rope, E)             [latent ; the ONE rotary key]
+      kv_norm (kv_lora_rank),
+      W_ukv (H·(nope + v), kv_lora_rank)         per head [k_nope ; v]
+      W_o (E, H·v)
+
+    RoPE (global positions) turns the rope part of every query head and
+    the one rotary key all heads share; the scores are scaled by
+    ``(nope + rope) ** -0.5``."""
+
+    TYPE = "LatentAttention"
+
+    def __init__(self, lp, phase):
+        super().__init__(lp, phase)
+        p = lp.get_msg("attention_param")
+        self.num_heads = p.get_int("num_heads", 1)
+        self.q_rank = p.get_int("q_lora_rank")
+        self.kv_rank = p.get_int("kv_lora_rank")
+        self.nope = p.get_int("qk_nope_head_dim")
+        self.rope_dim = p.get_int("qk_rope_head_dim")
+        self.v_dim = p.get_int("v_head_dim")
+        self.causal = p.get_bool("causal", True)
+        self.rope_theta = p.get_float("rope_theta", 10000.0)
+        self.rope_interleave = p.get_bool("rope_interleave", False)
+        self.norm_eps = p.get_float("norm_eps", 1e-6)
+        self.weight_filler = (
+            p.get_msg("weight_filler")
+            if p.has("weight_filler")
+            else Message().set("type", "xavier")
+        )
+
+    def init(self, key, in_shapes):
+        E = in_shapes[0][-1]
+        H, qk = self.num_heads, self.nope + self.rope_dim
+        keys = jax.random.split(key, 5)
+        shapes = [(self.q_rank, E), (H * qk, self.q_rank),
+                  (self.kv_rank + self.rope_dim, E),
+                  (H * (self.nope + self.v_dim), self.kv_rank),
+                  (E, H * self.v_dim)]
+        w_dq, w_uq, w_dkv, w_ukv, w_o = (
+            fill(self.weight_filler, k, s) for k, s in zip(keys, shapes))
+        return [w_dq, jnp.ones((self.q_rank,), jnp.float32), w_uq, w_dkv,
+                jnp.ones((self.kv_rank,), jnp.float32), w_ukv, w_o], {}
+
+    def apply(self, params, state, inputs, *, train, rng=None) -> LayerOutput:
+        if active_sequence_parallel() is not None:
+            raise NotImplementedError(
+                f"{self.name}: latent attention has no sequence-parallel "
+                "core (ring / Ulysses take one head width)")
+        x = inputs[0]  # [B, S, E]
+        w_dq, q_norm, w_uq, w_dkv, kv_norm, w_ukv, w_o = params
+        B, S, _ = x.shape
+        H, nope, rd, vd = self.num_heads, self.nope, self.rope_dim, self.v_dim
+        heads = lambda t: t.reshape(B, S, H, -1).transpose(0, 2, 1, 3)
+        turn = lambda t: rope(t, self.rope_theta, self.rope_interleave)
+        with jax.named_scope(LATENT_SCOPE):
+            c_q = rms_norm(x @ w_dq.T, q_norm, self.norm_eps)
+            q = heads(c_q @ w_uq.T)  # [B, H, S, nope + rope]
+            q = jnp.concatenate([q[..., :nope], turn(q[..., nope:])], -1)
+            dkv = x @ w_dkv.T
+            c_kv = rms_norm(dkv[..., :self.kv_rank], kv_norm, self.norm_eps)
+            k_rope = turn(dkv[:, None, :, self.kv_rank:])  # [B, 1, S, rope]
+            kv = heads(c_kv @ w_ukv.T)  # [B, H, S, nope + v]
+            k = jnp.concatenate(
+                [kv[..., :nope], jnp.broadcast_to(k_rope, (B, H, S, rd))], -1)
+            v = kv[..., nope:]
+        with jax.named_scope(CORE_SCOPE):
+            # scaled by (nope + rd) ** -0.5, the whole key's width
+            o = attention_core(q, k, v, self.causal)
+        with jax.named_scope(LATENT_SCOPE):
+            y = o.transpose(0, 2, 1, 3).reshape(B, S, H * vd) @ w_o.T
         return LayerOutput(outputs=[y])

@@ -14,6 +14,7 @@ from sparknet_tpu.common import get_config
 from sparknet_tpu.ops import fillers, layout
 from sparknet_tpu.ops.base import Layer, LayerOutput
 from sparknet_tpu.ops.registry import register
+from sparknet_tpu.proto.text_format import Message
 
 
 def _canon_axis(axis: int, ndim: int) -> int:
@@ -579,6 +580,38 @@ def rms_norm(x, weight, eps: float):
     xf = x.astype(jnp.float32)
     inv = jax.lax.rsqrt(jnp.mean(jnp.square(xf), axis=-1, keepdims=True) + eps)
     return (xf * inv).astype(x.dtype) * weight.astype(x.dtype)
+
+
+def gated_mlp(x, w_gate, w_up, w_down):
+    """``(silu(x W_gateᵀ) · x W_upᵀ) W_downᵀ`` over the last axis, every
+    matrix ``[out, in]``: the dense SwiGLU feed-forward of the
+    DeepSeek / Llama-style decoders.  The ``GatedMLP`` layer and the MoE
+    layer's shared expert (ops/moe.py) are both this function."""
+    h = jax.nn.silu(x @ w_gate.T) * (x @ w_up.T)
+    return h @ w_down.T
+
+
+@register
+class GatedMLP(Layer):
+    """Gated SiLU feed-forward over the last axis (:func:`gated_mlp`).
+    ``gated_mlp_param { hidden_dim: 7168 }``; blobs [W_gate (H, D),
+    W_up (H, D), W_down (D, H)], no biases."""
+
+    TYPE = "GatedMLP"
+
+    def init(self, key, in_shapes):
+        p = self.lp.get_msg("gated_mlp_param")
+        d, h = in_shapes[0][-1], p.get_int("hidden_dim")
+        wf = (p.get_msg("weight_filler") if p.has("weight_filler")
+              else Message().set("type", "xavier"))
+        dtype = get_config().param_dtype
+        kg, ku, kd = jax.random.split(key, 3)
+        return [fillers.fill(wf, kg, (h, d), dtype),
+                fillers.fill(wf, ku, (h, d), dtype),
+                fillers.fill(wf, kd, (d, h), dtype)], {}
+
+    def apply(self, params, state, inputs, *, train, rng=None):
+        return LayerOutput([gated_mlp(inputs[0], *params)])
 
 
 @register

@@ -44,6 +44,13 @@ class _LossBase(Layer):
         normalize = lp.get_bool("normalize", True)
         return ignore, normalize
 
+    def _keeps_value(self) -> bool:
+        """``loss_param { keep_value: true }``: the layer keeps its last
+        value in its state (``value``), for a reader that looks only at
+        fences (``Solver._fence_stats``): one term of a total that the
+        step returns only summed."""
+        return self.lp.get_msg("loss_param").get_bool("keep_value", False)
+
 
 @register
 class SoftmaxWithLoss(_LossBase):
@@ -52,6 +59,10 @@ class SoftmaxWithLoss(_LossBase):
     by the count of non-ignored positions, else by outer_num (batch)."""
 
     TYPE = "SoftmaxWithLoss"
+
+    def init(self, key, in_shapes):
+        keeps = self._keeps_value()
+        return [], {"value": jnp.zeros((), jnp.float32)} if keeps else {}
 
     def apply(self, params, state, inputs, *, train, rng=None):
         x, label = inputs[0], inputs[1]
@@ -87,7 +98,8 @@ class SoftmaxWithLoss(_LossBase):
         outs = [loss]
         if len(self.tops) > 1:
             outs.append(prob)
-        return LayerOutput(outs)
+        return LayerOutput(
+            outs, {"value": loss} if self._keeps_value() else {})
 
 
 @register

@@ -41,6 +41,23 @@ Params in Caffe blob order, every expert matrix ``[out, in]``:
 ``bias_term: false`` drops b1/b2 (swiglu never has them).  The defaults
 (top-1, relu, biases) are the switch layer `parallel/expert.py`
 distributes; ``moe_dense`` below is that layer's dense oracle.
+
+The DeepSeek-V3 family's layer (arXiv:2412.19437) is the same path with
+further ``moe_param`` fields: ``scoring_func: "sigmoid"`` (independent
+scores; default softmax), ``routed_scaling_factor`` (times the weights,
+after ``norm_topk_prob``), ``bias_update_rate: γ`` (a per-expert bias
+[E] f32 in the layer's state ``bias`` joins the scores for the SELECTION
+only; after each training step ``b_e += γ · sign(mean(load) − load_e)``,
+no gradient) and ``shared_hidden_dim`` (one shared SwiGLU expert every
+token passes: three more blobs [Ws_gate (Hs, D), Ws_up (Hs, D),
+Ws_down (D, Hs)] at the end, scope ``M.shared``).
+
+The chip's SHARE of an expert-parallel layer: ``experts_held: n`` and
+``first_expert: e0`` say that this layer holds experts [e0, e0 + n) of
+the ``num_experts`` the router scores.  The router keeps its width, the
+expert blobs' leading axis is n, and a (token, slot) pair whose expert
+is not held adds nothing to ``y``; ``load`` still counts every router
+output.  Nothing stands in for the absent chips or their exchange.
 """
 
 from __future__ import annotations
@@ -49,6 +66,7 @@ import jax
 import jax.numpy as jnp
 
 from sparknet_tpu.ops.base import Layer, LayerOutput
+from sparknet_tpu.ops.blocks import gated_mlp
 from sparknet_tpu.ops.fillers import fill
 from sparknet_tpu.ops.registry import register
 from sparknet_tpu.proto.text_format import Message
@@ -59,6 +77,7 @@ ROUTE_SCOPE = "M.route"
 DISPATCH_SCOPE = "M.dispatch"
 EXPERTS_SCOPE = "M.experts"
 COMBINE_SCOPE = "M.combine"
+SHARED_SCOPE = "M.shared"  # the shared expert, which every token passes
 
 
 def gate_top1(w_gate, x):
@@ -98,17 +117,33 @@ def moe_dense(params, x):
 # ---------------------------------------------------------------------------
 
 
-def route(w_router, x, top_k: int, norm_topk_prob: bool = False):
-    """Router on [T, D] tokens -> (logits [T, E] f32, probs [T, E] f32,
+def route(w_router, x, top_k: int, norm_topk_prob: bool = False, *,
+          scoring: str = "softmax", select_bias=None, scale: float = 1.0):
+    """Router on [T, D] tokens -> (logits [T, E] f32, scores [T, E] f32,
     weights [T, k] f32, experts [T, k] int32).  The matmul takes the
     operands as they come (bf16 under ``--dtype bf16``) and accumulates
-    in f32; everything after it is f32."""
+    in f32; everything after it is f32.
+
+    ``scoring``: ``softmax`` over the experts (Switch / OLMoE) or an
+    independent ``sigmoid`` per expert (DeepSeek-V3).  ``select_bias``
+    [E] f32 is added to the scores for the SELECTION only (the
+    auxiliary-loss-free balancing of arXiv:2408.15664: no gradient
+    reaches it, the weights are the unbiased scores of the chosen);
+    ``scale`` multiplies the weights after the renormalisation."""
     logits = jnp.dot(x, w_router.T, preferred_element_type=jnp.float32)
-    probs = jax.nn.softmax(logits, axis=-1)
-    weights, experts = jax.lax.top_k(probs, top_k)
+    scores = (jax.nn.sigmoid(logits) if scoring == "sigmoid"
+              else jax.nn.softmax(logits, axis=-1))
+    if select_bias is None:
+        weights, experts = jax.lax.top_k(scores, top_k)
+    else:
+        _, experts = jax.lax.top_k(
+            scores + jax.lax.stop_gradient(select_bias), top_k)
+        weights = jnp.take_along_axis(scores, experts, axis=-1)
     if norm_topk_prob:
         weights = weights / jnp.sum(weights, axis=-1, keepdims=True)
-    return logits, probs, weights, experts.astype(jnp.int32)
+    if scale != 1.0:
+        weights = weights * scale
+    return logits, scores, weights, experts.astype(jnp.int32)
 
 
 def load_balancing_loss(probs, experts, num_experts: int):
@@ -204,22 +239,45 @@ def grouped_matmul(x, w, group_sizes):
 
 
 def moe_dropless(params, x, *, top_k: int, expert_act: str,
-                 norm_topk_prob: bool = False):
-    """The layer's compute on [T, D] tokens -> (y [T, D], logits, probs,
-    experts [T, k], load [E] f32).  ``params`` as the layer holds them."""
+                 norm_topk_prob: bool = False, scoring: str = "softmax",
+                 select_bias=None, scale: float = 1.0, first_expert: int = 0):
+    """The layer's compute on [T, D] tokens -> (y [T, D], logits, scores,
+    experts [T, k], load [E] f32).  ``params`` as the layer holds them:
+    the router over all E experts, then the matrices of the experts HELD
+    here, ``[first_expert, first_expert + n)`` with n their leading axis
+    (all E by default).  A pair routed to an expert that is not held adds
+    nothing: its rows sort behind the last held group, where the grouped
+    matmuls skip them, and its weight is dropped from the sum.  ``load``
+    counts the pairs of every router output, held or not."""
     w_router, rest = params[0], params[1:]
-    num_experts = w_router.shape[0]
+    num_experts, held_n = w_router.shape[0], rest[0].shape[0]
+    whole = held_n == num_experts  # every expert lives here: no pair is dead
     tokens = x.shape[0]
     with jax.named_scope(ROUTE_SCOPE):
         logits, probs, weights, experts = route(
-            w_router, x, top_k, norm_topk_prob)
+            w_router, x, top_k, norm_topk_prob, scoring=scoring,
+            select_bias=select_bias, scale=scale)
     with jax.named_scope(DISPATCH_SCOPE):
         flat = experts.reshape(-1)  # pair t*k + s -> its expert
+        load = jnp.bincount(flat, length=num_experts).astype(jnp.int32)
+        if whole:
+            group_sizes = load
+        else:
+            local = flat - first_expert
+            held = (local >= 0) & (local < held_n)
+            flat = jnp.where(held, local, held_n)  # not held: sorted last
+            group_sizes = jax.lax.dynamic_slice_in_dim(
+                load, first_expert, held_n)
         order = jnp.argsort(flat, stable=True).astype(jnp.int32)
         inv = jnp.zeros_like(order).at[order].set(
             jnp.arange(order.shape[0], dtype=jnp.int32))
-        group_sizes = jnp.bincount(flat, length=num_experts).astype(jnp.int32)
         rows = _spread_rows(x, order, inv)  # [T·k, D], expert-major
+        if not whole:
+            # rows past the held groups are never computed: what the
+            # kernels leave there must not reach a sum, forward or backward
+            live = (jnp.arange(order.shape[0], dtype=jnp.int32)
+                    < jnp.sum(group_sizes))[:, None]
+            rows = jnp.where(live, rows, 0)
     with jax.named_scope(EXPERTS_SCOPE):
         if expert_act == "swiglu":
             w_gate, w_up, w_down = rest
@@ -233,14 +291,18 @@ def moe_dropless(params, x, *, top_k: int, expert_act: str,
         else:
             w1, b1, w2, b2 = rest
             of_row = flat[order]  # each sorted row's expert, for its bias
+            if not whole:
+                of_row = jnp.minimum(of_row, held_n - 1)  # a dead row
             h = jax.nn.relu(
                 grouped_matmul(rows, w1, group_sizes) + b1[of_row])
             out = grouped_matmul(h, w2, group_sizes) + b2[of_row]
+        if not whole:
+            out = jnp.where(live, out, 0)
     with jax.named_scope(COMBINE_SCOPE):
         per_pair = _take_rows(out, inv, order).reshape(tokens, top_k, -1)
         y = jnp.sum(per_pair.astype(jnp.float32) * weights[..., None],
                     axis=1).astype(x.dtype)
-    return y, logits, probs, experts, group_sizes.astype(jnp.float32)
+    return y, logits, probs, experts, load.astype(jnp.float32)
 
 
 @register
@@ -255,14 +317,33 @@ class MoELayer(Layer):
         self.top_k = p.get_int("top_k", 1)
         self.expert_act = p.get_str("expert_act", "relu")
         self.norm_topk_prob = p.get_bool("norm_topk_prob", False)
+        self.scoring = p.get_str("scoring_func", "softmax")
+        self.scale = p.get_float("routed_scaling_factor", 1.0)
+        # the balancing bias of the selection: state, moved by its own rule
+        self.bias_rate = p.get_float("bias_update_rate", 0.0)
+        self.select_bias = self.bias_rate > 0
+        self.shared_dim = p.get_int("shared_hidden_dim", 0)
+        # this chip's share: experts [first_expert, first_expert + held)
+        self.first_expert = p.get_int("first_expert", 0)
+        self.experts_held = p.get_int("experts_held", self.num_experts)
         if self.expert_act not in ("relu", "swiglu"):
             raise ValueError(
                 f"{self.name}: unknown expert_act {self.expert_act!r} "
                 "(relu|swiglu)")
+        if self.scoring not in ("softmax", "sigmoid"):
+            raise ValueError(
+                f"{self.name}: unknown scoring_func {self.scoring!r} "
+                "(softmax|sigmoid)")
         if not 1 <= self.top_k <= self.num_experts:
             raise ValueError(
                 f"{self.name}: top_k {self.top_k} outside 1.."
                 f"{self.num_experts} experts")
+        if not (self.experts_held >= 1 and 0 <= self.first_expert
+                <= self.num_experts - self.experts_held):
+            raise ValueError(
+                f"{self.name}: experts [{self.first_expert}, "
+                f"{self.first_expert + self.experts_held}) are not among "
+                f"the router's {self.num_experts}")
         # swiglu experts carry no biases (none of the published ones do)
         self.bias_term = (self.expert_act == "relu"
                           and p.get_bool("bias_term", True))
@@ -278,31 +359,55 @@ class MoELayer(Layer):
     def init(self, key, in_shapes):
         D = in_shapes[0][-1]
         H = self.hidden_dim or 4 * D
-        E = self.num_experts
+        E, N = self.num_experts, self.experts_held
         kg, k1, k2, k3 = jax.random.split(key, 4)
         w_router = fill(self.weight_filler, kg, (E, D))
         state = {"load": jnp.zeros((E,), jnp.float32)}
+        if self.select_bias:
+            state["bias"] = jnp.zeros((E,), jnp.float32)
         if self.expert_act == "swiglu":
-            return [w_router,
-                    fill(self.weight_filler, k1, (E, H, D)),
-                    fill(self.weight_filler, k3, (E, H, D)),
-                    fill(self.weight_filler, k2, (E, D, H))], state
-        w1 = fill(self.weight_filler, k1, (E, H, D))
-        w2 = fill(self.weight_filler, k2, (E, D, H))
-        if not self.bias_term:
-            return [w_router, w1, w2], state
-        return [w_router, w1, jnp.zeros((E, H), jnp.float32),
-                w2, jnp.zeros((E, D), jnp.float32)], state
+            params = [w_router,
+                      fill(self.weight_filler, k1, (N, H, D)),
+                      fill(self.weight_filler, k3, (N, H, D)),
+                      fill(self.weight_filler, k2, (N, D, H))]
+        else:
+            w1 = fill(self.weight_filler, k1, (N, H, D))
+            w2 = fill(self.weight_filler, k2, (N, D, H))
+            params = ([w_router, w1, jnp.zeros((N, H), jnp.float32),
+                       w2, jnp.zeros((N, D), jnp.float32)]
+                      if self.bias_term else [w_router, w1, w2])
+        if self.shared_dim:
+            ks = jax.random.split(jax.random.fold_in(key, 1), 3)
+            params += [fill(self.weight_filler, ks[0], (self.shared_dim, D)),
+                       fill(self.weight_filler, ks[1], (self.shared_dim, D)),
+                       fill(self.weight_filler, ks[2], (D, self.shared_dim))]
+        return params, state
 
     def apply(self, params, state, inputs, *, train, rng=None) -> LayerOutput:
         x = inputs[0]
+        flat = x.reshape(-1, x.shape[-1])
+        routed = params[:-3] if self.shared_dim else params
+        bias = state["bias"] if self.select_bias else None
         y, logits, probs, experts, load = moe_dropless(
-            params, x.reshape(-1, x.shape[-1]), top_k=self.top_k,
-            expert_act=self.expert_act, norm_topk_prob=self.norm_topk_prob)
+            routed, flat, top_k=self.top_k, expert_act=self.expert_act,
+            norm_topk_prob=self.norm_topk_prob, scoring=self.scoring,
+            select_bias=bias, scale=self.scale,
+            first_expert=self.first_expert)
+        if self.shared_dim:
+            with jax.named_scope(SHARED_SCOPE):
+                y = y + gated_mlp(flat, *params[-3:])
         outputs = [y.reshape(x.shape)]
         if len(self.tops) > 1:
             with jax.named_scope(ROUTE_SCOPE):
                 outputs += [
                     load_balancing_loss(probs, experts, self.num_experts),
                     router_z_loss(logits), load][:len(self.tops) - 1]
-        return LayerOutput(outputs=outputs, state={"load": load})
+        new_state = {"load": load}
+        if self.select_bias:
+            # after the step, no gradient: an expert that saw fewer pairs
+            # than the mean is raised, a fuller one lowered (DeepSeek-V3,
+            # arXiv:2412.19437 section 2.1.2; the mean is T·k / E)
+            new_state["bias"] = (
+                bias + self.bias_rate * jnp.sign(jnp.mean(load) - load)
+                if train else bias)
+        return LayerOutput(outputs=outputs, state=new_state)

@@ -787,23 +787,43 @@ class Solver:
         # round record that step() closes on this value is its line
         with Span(None, "sn.step.fence", it=self.iter) as sp:
             self.smoothed_loss = sp.fence_value(self._smoothed())
-            sp.set(**self._expert_load())
+            sp.set(**self._fence_stats())
         return self.smoothed_loss
 
-    def _expert_load(self) -> dict:
-        """What the MoE layers counted in the last step (their ``load``
-        state: tokens per expert), for the fence's span: the fullest
+    def _fence_stats(self) -> dict:
+        """What the net's layers kept of the last step in their state, for
+        the fence's span; read after the fence, so it costs no device
+        sync of its own, and empty for a net without such a layer.
+
+        The MoE layers' ``load`` (tokens per expert): the fullest
         expert's tokens over the layers, and the (token, slot) pairs and
-        experts of one layer, whose quotient is the mean.  Read after the
-        fence, so it costs no device sync of its own; empty for a net
-        without such a layer."""
-        loads = [np.asarray(st["load"]) for st in self.variables.state.values()
-                 if "load" in st]
+        experts of one layer, whose quotient is the mean.  Where a layer
+        holds a share of its experts, or selects with a balancing bias:
+        the layers counted, the pairs that landed on held experts over
+        all of them, and the bias's extremes.  A loss layer that keeps
+        its ``value`` (``loss_param { keep_value: true }``) gives it under
+        the layer's name."""
+        state = self.variables.state
+        stats = {name: float(st["value"]) for name, st in state.items()
+                 if "value" in st}
+        loads = {name: np.asarray(st["load"]) for name, st in state.items()
+                 if "load" in st}
         if not loads:
-            return {}
-        return {"moe_load_max": int(max(a.max() for a in loads)),
-                "moe_pairs": int(loads[0].sum()),
-                "moe_experts": int(loads[0].size)}
+            return stats
+        first = next(iter(loads.values()))
+        stats.update(moe_load_max=int(max(a.max() for a in loads.values())),
+                     moe_pairs=int(first.sum()), moe_experts=int(first.size))
+        layers = [l for l in self.train_net.layers if l.name in loads]
+        if any(l.experts_held < l.num_experts for l in layers):
+            stats.update(moe_layers=len(layers), moe_pairs_held=int(sum(
+                loads[l.name][l.first_expert:][:l.experts_held].sum()
+                for l in layers)))
+        biases = [np.asarray(st["bias"]) for st in state.values()
+                  if "bias" in st]
+        if biases:
+            stats.update(moe_bias_min=float(min(b.min() for b in biases)),
+                         moe_bias_max=float(max(b.max() for b in biases)))
+        return stats
 
     def _step_scanned(self, num_iters: int, data_fn: DataFn, callback,
                       scan_chunk: int) -> float:
