@@ -62,6 +62,8 @@ output.  Nothing stands in for the absent chips or their exchange.
 
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 
@@ -212,30 +214,83 @@ _OUT_IN = jax.lax.RaggedDotDimensionNumbers(
     lhs_ragged_dimensions=[0], rhs_group_dimensions=[0])
 
 
-def grouped_matmul(x, w, group_sizes):
-    """``x`` [M, K] rows sorted by group, ``w`` [G, N, K], ``group_sizes``
-    [G] int32 summing to M -> [M, N]: rows of group g times ``w[g]ᵀ``.
+def _gmm_tiles(x, w):
+    """The megablox tile triple (rows, in, out) for ``x`` [M, K] times
+    ``w`` [G, N, K]; M is a multiple of 128."""
+    tm = next(t for t in (512, 256, 128) if x.shape[0] % t == 0)
+    return tm, min(x.shape[1], 1024), min(w.shape[1], 1024)
 
-    On a TPU, at lane-aligned widths: jax's own megablox ``gmm`` kernel
-    (forward, and ``gmm``/``tgmm`` backward).  Timed once on the v5e at
-    the OLMoE expert block's shapes (131,072 rows, 64 groups, 2048x1024,
-    forward + backward, PERF.md section 6): 39.2 ms against 51.7 ms for
-    XLA's ``ragged_dot`` with the weights transposed first and 78.4 ms
-    with them as stored.  Elsewhere (the CPU, odd widths) XLA's
-    ``ragged_dot_general``, which the kernel cannot replace there."""
-    (m, k), n = x.shape, w.shape[1]
-    if jax.default_backend() != "tpu" or k % 128 or n % 128:
-        return jax.lax.ragged_dot_general(
-            x, w, group_sizes, _OUT_IN, preferred_element_type=x.dtype)
-    from jax.experimental.pallas.ops.tpu.megablox import ops as megablox
 
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _gmm_out_in(x, w, group_sizes, interpret):
+    """``x`` [M, K] with M a multiple of 128, ``w`` [G, N, K] -> [M, N]
+    through jax's megablox kernels, with the backward ``grouped_matmul``
+    describes."""
+    from jax.experimental.pallas.ops.tpu.megablox.gmm import gmm
+
+    return gmm(x, w, group_sizes, x.dtype, _gmm_tiles(x, w),
+               transpose_rhs=True, interpret=interpret)
+
+
+def _gmm_out_in_fwd(x, w, group_sizes, interpret):
+    return _gmm_out_in(x, w, group_sizes, interpret), (x, w, group_sizes)
+
+
+def _gmm_out_in_bwd(interpret, res, dy):
+    from jax.experimental.pallas.ops.tpu.megablox.gmm import gmm, tgmm
+
+    x, w, group_sizes = res
+    tm, tk, tn = _gmm_tiles(x, w)
+    tiles = (tm, tn, tk)  # both calls contract what the forward wrote
+    dx = gmm(dy, w, group_sizes, x.dtype, tiles, interpret=interpret)
+    # [G, N, K] as ``w`` is stored: dy is the kernel's LEFT operand
+    dw = tgmm(dy.swapaxes(0, 1), x, group_sizes, w.dtype, tiles,
+              num_actual_groups=w.shape[0], interpret=interpret)
+    return dx, dw, None
+
+
+_gmm_out_in.defvjp(_gmm_out_in_fwd, _gmm_out_in_bwd)
+
+
+def _megablox_matmul(x, w, group_sizes, interpret=False):
+    """``grouped_matmul``'s TPU branch.  ``interpret`` is for the tests,
+    which run the kernels on the CPU."""
+    m = x.shape[0]
     pad = -m % 128  # the kernel tiles the rows; rows past the groups' sum are dead
     if pad:
         x = jnp.pad(x, ((0, pad), (0, 0)))
-    tm = next(t for t in (512, 256, 128) if (m + pad) % t == 0)
-    out = megablox.gmm(x, w, group_sizes, x.dtype,
-                       (tm, min(k, 1024), min(n, 1024)), None, None, True)
+    out = _gmm_out_in(x, w, group_sizes, interpret)
     return out[:m] if pad else out
+
+
+def grouped_matmul(x, w, group_sizes):
+    """``x`` [M, K] rows sorted by group, ``w`` [G, N, K], ``group_sizes``
+    [G] int32 summing to at most M -> [M, N]: rows of group g times
+    ``w[g]ᵀ``.
+
+    On a TPU, at lane-aligned widths: jax's own megablox kernels, ``gmm``
+    forward and for ``dx``, ``tgmm`` for ``dw``.  The kernels' time, timed
+    once on the v5e at the OLMoE expert block's shapes (131,072 rows, 64
+    groups, 2048x1024, forward + backward, PERF.md section 6): 39.2 ms
+    against 51.7 ms for XLA's ``ragged_dot`` with the weights transposed
+    first and 78.4 ms with them as stored.  Elsewhere (the CPU, odd
+    widths) XLA's ``ragged_dot_general``, which the kernel cannot replace
+    there.
+
+    The backward is ours, not ``megablox.ops.gmm``'s.  With
+    ``transpose_rhs`` that one computes ``tgmm(xᵀ, dy)`` -> [G, K, N] and
+    then ``swapaxes(1, 2)``.  The kernel's output layout is fixed, so XLA
+    folded the transpose into the layout of the AdamW fusion that takes
+    the gradient, and converted the f32 matrix and both its moments into
+    that layout and back in every step: 18 copies of 537 MB in the OLMoE
+    step, 1.71 ms each (ledger, PR 30), to save one of 268 MB.
+    ``tgmm(dyᵀ, x)`` IS [G, N, K]: the same products, the same f32
+    accumulation, nothing transposed between the kernel and the update
+    (``tools/expert_copies.py`` counts such copies in a compiled step)."""
+    if jax.default_backend() != "tpu" or x.shape[1] % 128 or w.shape[1] % 128:
+        return jax.lax.ragged_dot_general(
+            x, w, group_sizes, _OUT_IN, preferred_element_type=x.dtype)
+    return _megablox_matmul(x, w, group_sizes)
 
 
 def moe_dropless(params, x, *, top_k: int, expert_act: str,

@@ -1,0 +1,135 @@
+"""The grouped matmul's TPU branch hands its weight gradient over as the
+weights are stored, ``[experts, out, in]`` (PR 31).
+
+Two proofs, both without a chip.  (1) The branch itself
+(`ops/moe._megablox_matmul`: jax's megablox kernels under a backward of
+our own) in Pallas interpret mode on the CPU against ``jax.vjp`` of the
+``ragged_dot_general`` branch, f32, at lane-aligned toy sizes: 500 rows
+(the kernel needs 512: the pad), 4 groups of which one is EMPTY, 50 rows
+past the groups' sum (a held share's dead rows).  Same products in both,
+summed in another order: measured 0 to 3e-7 relative, limit 1e-4.
+(2) A toy OLMoE train step compiled for a described v5e
+(`tools/expert_copies.py`): no ``copy`` in the ENTRY computation has the
+shape of an f32 expert matrix.  With megablox's own backward the same toy
+step has 18 (each matrix and both AdamW moments, into the transposed
+layout and back).  Both proofs live in this one file: the process that
+describes the topology holds libtpu, and a second file could land on
+another worker.
+"""
+
+import dataclasses
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from sparknet_tpu import models
+from sparknet_tpu.common import Phase, get_config, set_config
+from sparknet_tpu.compiler.graph import Network
+from sparknet_tpu.ops import moe
+from tools import expert_copies
+
+ROWS, LIVE, SIZES = 500, 450, (200, 0, 150, 100)
+G, D, H = len(SIZES), 128, 256
+# SwiGLU's three matrices as the layer stores them: [G, out, in]
+MATRICES = {"w_gate": (G, H, D), "w_up": (G, H, D), "w_down": (G, D, H)}
+TOL = 1e-4
+
+
+def rel(a, b):
+    a, b = np.asarray(a, np.float64), np.asarray(b, np.float64)
+    return float(np.linalg.norm(a - b) / np.linalg.norm(b))
+
+
+def masked(matmul):
+    """``matmul`` as ``moe_dropless`` calls it for a held share: nothing
+    goes into a dead row and nothing comes out of one."""
+    live = (jnp.arange(ROWS) < LIVE)[:, None]
+    sizes = jnp.asarray(SIZES, jnp.int32)
+    return lambda x, w: jnp.where(
+        live, matmul(jnp.where(live, x, 0), w, sizes), 0)
+
+
+@pytest.mark.parametrize("matrix", sorted(MATRICES))
+def test_tpu_branch_matches_ragged_dot(matrix):
+    shape = MATRICES[matrix]
+    rng = np.random.default_rng(sorted(MATRICES).index(matrix))
+    x = jnp.asarray(rng.standard_normal((ROWS, shape[2])), jnp.float32)
+    w = jnp.asarray(rng.standard_normal(shape), jnp.float32)
+    dy = jnp.asarray(rng.standard_normal((ROWS, shape[1])), jnp.float32)
+    want, want_vjp = jax.vjp(masked(
+        lambda x, w, s: jax.lax.ragged_dot_general(
+            x, w, s, moe._OUT_IN, preferred_element_type=x.dtype)), x, w)
+    got, got_vjp = jax.vjp(masked(
+        lambda x, w, s: moe._megablox_matmul(x, w, s, interpret=True)), x, w)
+    (want_dx, want_dw), (got_dx, got_dw) = want_vjp(dy), got_vjp(dy)
+    assert got_dw.shape == w.shape and got_dw.dtype == w.dtype
+    assert rel(got, want) <= TOL
+    assert rel(got_dx, want_dx) <= TOL
+    assert rel(got_dw, want_dw) <= TOL
+    assert not np.asarray(got_dw[SIZES.index(0)]).any()  # the empty group
+
+
+def test_backward_transposes_no_expert_matrix():
+    """Between the kernel and whoever takes ``dw`` there is no transpose
+    of a [G, ., .] array: that one was what XLA folded into the update's
+    layout."""
+    x = jax.ShapeDtypeStruct((512, D), jnp.bfloat16)
+    w = jax.ShapeDtypeStruct((G, H, D), jnp.bfloat16)
+    sizes = jnp.asarray((212, 0, 150, 150), jnp.int32)
+
+    def dw(x, w):
+        return jax.grad(lambda w: moe._megablox_matmul(
+            x, w, sizes, interpret=True).astype(jnp.float32).sum())(w)
+
+    jaxpr = jax.make_jaxpr(dw)(x, w)
+    assert jaxpr.out_avals[0].shape == w.shape
+    rank3 = [e for e in jaxpr.eqns if e.primitive.name == "transpose"
+             and len(e.invars[0].aval.shape) == 3]
+    assert not rank3
+
+
+# --- the compiled program, for a described v5e ------------------------------
+
+
+@pytest.fixture(scope="module")
+def v5e():
+    try:
+        return expert_copies.v5e_chip()
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture
+def bf16_compute():
+    before = get_config().compute_dtype
+    set_config(compute_dtype=jnp.bfloat16)
+    yield
+    set_config(compute_dtype=before)
+
+
+@pytest.fixture
+def no_compile_cache():
+    """A program compiled for a described chip is written to the
+    persistent cache but cannot be read back without one."""
+    before = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    yield
+    jax.config.update("jax_enable_compilation_cache", before)
+
+
+def test_compiled_step_copies_no_expert_matrix(v5e, bf16_compute,
+                                               no_compile_cache):
+    toy = dict(batch=2, seq_len=256, vocab=512, hidden=D, heads=1, experts=G,
+               top_k=2, expert_dim=H, layers=1)
+    net = Network(models.olmoe(**toy), Phase.TRAIN)
+    cfg = dataclasses.replace(models.olmoe_solver(), display=0)
+    compiled, variables = expert_copies.compile_step(
+        cfg, net, (toy["batch"], toy["seq_len"]), v5e)
+    shapes = expert_copies.expert_shapes(net, variables)
+    assert shapes == {(G, H, D), (G, D, H)}
+    text = compiled.as_text()
+    # the step did take the kernels: 3 matmuls x (gmm, gmm, tgmm)
+    assert text.count("tpu_custom_call") >= 9
+    assert expert_copies.expert_copies(text, shapes) == []
