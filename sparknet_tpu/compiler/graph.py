@@ -471,7 +471,8 @@ class Network:
                     ins = [_cast(x, jnp.float32) for x in ins]
                 else:
                     if mixed:
-                        p = [_cast(x, cdt) for x in p]
+                        p = [x if i in layer.F32_BLOBS else _cast(x, cdt)
+                             for i, x in enumerate(p)]
                     if act_policy:
                         # upcast stored-bf16 inputs back to the compute
                         # dtype: storage is the only thing that narrows

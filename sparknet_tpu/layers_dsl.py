@@ -364,6 +364,70 @@ def GatedMLPLayer(name: str, bottoms: Sequence[str], hidden_dim: int,
     return m.set("gated_mlp_param", p)
 
 
+def LayerNormLayer(name: str, bottoms: Sequence[str], eps: float = 1e-5,
+                   top: str | None = None) -> Message:
+    """LayerNorm over the last axis, weight and bias (ops/blocks.py
+    LayerNorm)."""
+    m = _layer(name, "LayerNorm", bottoms, [top] if top else None)
+    return m.set("layer_norm_param", Message().set("eps", eps))
+
+
+def MambaLayer(name: str, bottoms: Sequence[str], d_state: int = 16,
+               d_conv: int = 4, expand: int = 2, dt_rank: int | None = None,
+               weight_filler: Message | None = None,
+               memory_top: str | None = None) -> Message:
+    """Selective state-space layer (ops/ssm.py MambaLayer).
+    ``memory_top`` names a second top: the scan's output before the gate,
+    for ``GatedMemoryUnitLayer``s to read."""
+    m = _layer(name, "Mamba", bottoms,
+               [name, memory_top] if memory_top else None)
+    p = Message().set("d_state", d_state).set("d_conv", d_conv)
+    p.set("expand", expand)
+    if dt_rank is not None:
+        p.set("dt_rank", dt_rank)
+    if weight_filler is not None:
+        p.set("weight_filler", weight_filler)
+    return m.set("mamba_param", p)
+
+
+def GatedMemoryUnitLayer(name: str, bottoms: Sequence[str],
+                         weight_filler: Message | None = None) -> Message:
+    """``W_2 (silu(W_1 x) * m)``; bottoms [x, m] (ops/blocks.py
+    GatedMemoryUnit)."""
+    m = _layer(name, "GatedMemoryUnit", bottoms)
+    p = Message()
+    if weight_filler is not None:
+        p.set("weight_filler", weight_filler)
+    return m.set("gmu_param", p)
+
+
+def DifferentialAttentionLayer(
+    name: str,
+    bottoms: Sequence[str],
+    num_heads: int,
+    num_kv_heads: int,
+    lambda_init: float,
+    window: int = 0,
+    norm_eps: float | None = None,
+    weight_filler: Message | None = None,
+    kv_tops: Sequence[str] = (),
+) -> Message:
+    """Causal differential attention with grouped heads (ops/attention.py
+    DifferentialAttentionLayer).  Bottoms [x], or [x, k, v] for a layer
+    that reads another's keys and values; ``kv_tops`` names two further
+    tops under which this layer hands its own on."""
+    m = _layer(name, "DifferentialAttention", bottoms, [name, *kv_tops])
+    p = Message().set("num_heads", num_heads)
+    p.set("num_kv_heads", num_kv_heads).set("lambda_init", lambda_init)
+    if window:
+        p.set("window", window)
+    if norm_eps is not None:
+        p.set("norm_eps", norm_eps)
+    if weight_filler is not None:
+        p.set("weight_filler", weight_filler)
+    return m.set("attention_param", p)
+
+
 def LatentAttentionLayer(
     name: str,
     bottoms: Sequence[str],

@@ -41,6 +41,10 @@ from sparknet_tpu.models.zoo import (  # noqa: F401
     joyai_flash_solver,
     olmoe,
     olmoe_solver,
+    phi4_flash,
+    phi4_flash_lambda_init,
+    phi4_flash_role,
+    phi4_flash_solver,
     resnet50,
     resnet50_solver,
     squeezenet,

@@ -18,6 +18,7 @@ host input pipeline; batch is a builder argument, not baked into the file.
 from __future__ import annotations
 
 import dataclasses
+import math
 from typing import Any
 
 from sparknet_tpu.layers_dsl import (
@@ -25,15 +26,19 @@ from sparknet_tpu.layers_dsl import (
     BatchNormLayer,
     ConcatLayer,
     ConvolutionLayer,
+    DifferentialAttentionLayer,
     DropoutLayer,
     EltwiseLayer,
     EmbedLayer,
     EuclideanLossLayer,
     FlattenLayer,
+    GatedMemoryUnitLayer,
     GatedMLPLayer,
     InnerProductLayer,
     LatentAttentionLayer,
+    LayerNormLayer,
     LRNLayer,
+    MambaLayer,
     MoELayer,
     MultiHeadAttentionLayer,
     NetParam,
@@ -1233,6 +1238,159 @@ def joyai_flash_solver() -> SolverConfig:
     decay schedule are left to the prototxt's lr_policy."""
     return SolverConfig(
         base_lr=2.2e-4, lr_policy="fixed", momentum=0.9, momentum2=0.95,
+        delta=1e-8, weight_decay=0.1, clip_gradients=1.0,
+        max_iter=10000, solver_type="AdamW", display=100,
+    )
+
+
+# ---------------------------------------------------------------------------
+# Phi-4-mini-flash-reasoning — the SambaY decoder-hybrid-decoder (Ren et al.
+# 2025, arXiv:2507.06607; microsoft/Phi-4-mini-flash-reasoning config.json,
+# ``model_type: phi4flash``; no reference analog).  Every layer is
+# u = x + Mix(LayerNorm(x)); x' = u + SwiGLU(LayerNorm(u)), and the token
+# mixer is set by the layer's PUBLISHED index (``phi4_flash_role``): a
+# self-decoder of Mamba layers and window differential attention, the
+# Mamba layer whose scan output is kept as the MEMORY, the one full
+# attention layer whose keys and values are kept, and a cross-decoder of
+# gated memory units and cross-attention that read those two blobs.  No
+# positional encoding; the head is the embedding (``param { name }``).
+# ---------------------------------------------------------------------------
+def phi4_flash_role(i: int, layers: int, mb_per_layer: int = 2) -> str:
+    """The token mixer of published layer ``i`` of ``layers``, as the
+    published modeling code assigns it: ``i % mb_per_layer == 0`` is a
+    Mamba-side layer, else an attention-side one; below ``layers / 2`` the
+    self-decoder ("mamba" / "window"), at ``layers / 2`` the memory's
+    Mamba layer ("memory"), the layer after it the full attention layer
+    whose keys and values are kept ("full"), then the cross-decoder
+    ("gmu" / "cross")."""
+    half = layers // 2
+    if layers % 2 or half % mb_per_layer:
+        raise ValueError(f"{layers} layers: half of them must be a multiple "
+                         f"of mb_per_layer ({mb_per_layer})")
+    if not 0 <= i < layers:
+        raise ValueError(f"layer {i} of {layers}")
+    mamba_side = i % mb_per_layer == 0
+    if i < half:
+        return "mamba" if mamba_side else "window"
+    if i == half:
+        return "memory"
+    if i == half + 1:
+        return "full"
+    return "gmu" if mamba_side else "cross"
+
+
+def phi4_flash_lambda_init(i: int) -> float:
+    """Differential attention's lambda_init at published depth ``i``
+    (arXiv:2410.05258 section 2.1): 0.8 - 0.6 exp(-0.3 i)."""
+    return 0.8 - 0.6 * math.exp(-0.3 * i)
+
+
+def phi4_flash(
+    batch: int = 1,
+    seq_len: int = 2048,
+    vocab: int = 200064,
+    hidden: int = 2560,
+    heads: int = 40,
+    kv_heads: int = 20,
+    mlp_dim: int = 10240,
+    layers: int = 32,
+    mb_per_layer: int = 2,
+    window: int = 512,
+    d_state: int = 16,
+    d_conv: int = 4,
+    expand: int = 2,
+    dt_rank: int | None = None,
+    layer_norm_eps: float = 1e-5,
+    init_std: float = 0.02,
+    kept_layers: tuple[int, ...] | None = None,
+) -> Message:
+    """Phi-4-mini-flash-reasoning at its published sizes by default (32
+    layers, 3.85 B parameters): [batch, seq_len] token ids -> per-token
+    next-token logits over ``vocab`` rows; ``loss`` is the mean
+    cross-entropy over every position.
+
+    ``kept_layers`` (published indices, ascending; all by default) builds
+    a cut: every kept layer keeps the role and the lambda_init of its
+    published index, and a layer that reads the memory or the kept keys
+    and values needs its producer (``layers / 2`` / ``layers / 2 + 1``)
+    kept too.  Layer i's prototxt layers are ``norm<i>a``, the mixer
+    (``mamba<i>`` / ``attn<i>`` / ``gmu<i>`` / ``xattn<i>``), ``res<i>a``,
+    ``norm<i>b``, ``mlp<i>``, ``res<i>b``; the memory is the blob
+    ``memory``, the keys and values ``yoco_k`` / ``yoco_v``."""
+    init = _gauss(init_std)
+    kept = tuple(range(layers)) if kept_layers is None else tuple(kept_layers)
+    if list(kept) != sorted(set(kept)):
+        raise ValueError(f"kept_layers must ascend: {kept}")
+    roles = {i: phi4_flash_role(i, layers, mb_per_layer) for i in kept}
+    half = layers // 2
+    for role, producer in (("gmu", half), ("cross", half + 1)):
+        if role in roles.values() and producer not in roles:
+            raise ValueError(
+                f"a {role!r} layer is kept without layer {producer}, whose "
+                "output it reads")
+
+    def mixer(i, bottom):
+        role = roles[i]
+        if role in ("mamba", "memory"):
+            return MambaLayer(
+                f"mamba{i}", [bottom], d_state=d_state, d_conv=d_conv,
+                expand=expand, dt_rank=dt_rank, weight_filler=init,
+                memory_top="memory" if role == "memory" else None)
+        if role == "gmu":
+            return GatedMemoryUnitLayer(f"gmu{i}", [bottom, "memory"],
+                                        weight_filler=init)
+        cross = role == "cross"
+        return DifferentialAttentionLayer(
+            f"xattn{i}" if cross else f"attn{i}",
+            [bottom, "yoco_k", "yoco_v"] if cross else [bottom],
+            num_heads=heads, num_kv_heads=kv_heads,
+            lambda_init=phi4_flash_lambda_init(i),
+            window=window if role == "window" else 0,
+            norm_eps=layer_norm_eps, weight_filler=init,
+            kv_tops=("yoco_k", "yoco_v") if role == "full" else ())
+
+    net = [
+        RDDLayer("data", shape=[batch, seq_len]),
+        RDDLayer("label", shape=[batch, seq_len]),
+        EmbedLayer("embed", ["data"], input_dim=vocab, num_output=hidden,
+                   weight_filler=init, bias_term=False, top="embed",
+                   param_name="embed_w"),
+    ]
+    x = "embed"
+    for i in kept:
+        mix = mixer(i, f"norm{i}a")
+        name = mix.get_str("name")
+        net += [
+            LayerNormLayer(f"norm{i}a", [x], eps=layer_norm_eps),
+            mix,
+            EltwiseLayer(f"res{i}a", [x, name], top=f"res{i}a"),
+            LayerNormLayer(f"norm{i}b", [f"res{i}a"], eps=layer_norm_eps),
+            GatedMLPLayer(f"mlp{i}", [f"norm{i}b"], mlp_dim,
+                          weight_filler=init),
+            EltwiseLayer(f"res{i}b", [f"res{i}a", f"mlp{i}"], top=f"res{i}b"),
+        ]
+        x = f"res{i}b"
+    net += [
+        LayerNormLayer("norm_f", [x], eps=layer_norm_eps),
+        # tie_word_embeddings: the head IS the embedding
+        InnerProductLayer("lm_head", ["norm_f"], num_output=vocab, axis=2,
+                          weight_filler=init, bias_term=False,
+                          param_name="embed_w"),
+        SoftmaxWithLoss("loss", ["lm_head", "label"], axis=2),
+        AccuracyLayer("accuracy", ["lm_head", "label"], phase="TEST", axis=2),
+    ]
+    return NetParam("Phi-4-mini-flash-reasoning", *net)
+
+
+def phi4_flash_solver() -> SolverConfig:
+    """AdamW, betas 0.9 / 0.95, eps 1e-8, decoupled weight decay 0.1 on
+    every parameter, gradient clipping at global norm 1.0, peak lr 4e-4:
+    the recipe of this repo's other decoders (OLMoE's Table 12); the
+    Phi-4-mini-flash card and config.json state none, so each value is an
+    assumption the benchmark's configuration file lists.  The schedule is
+    left to the prototxt's lr_policy."""
+    return SolverConfig(
+        base_lr=4e-4, lr_policy="fixed", momentum=0.9, momentum2=0.95,
         delta=1e-8, weight_decay=0.1, clip_gradients=1.0,
         max_iter=10000, solver_type="AdamW", display=100,
     )

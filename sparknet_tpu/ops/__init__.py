@@ -17,3 +17,4 @@ from sparknet_tpu.ops import loss  # noqa: F401
 from sparknet_tpu.ops import python_layer  # noqa: F401
 from sparknet_tpu.ops import attention  # noqa: F401
 from sparknet_tpu.ops import moe  # noqa: F401
+from sparknet_tpu.ops import ssm  # noqa: F401

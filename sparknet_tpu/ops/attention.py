@@ -193,17 +193,21 @@ def rope_at(x: jax.Array, positions: jax.Array,
 CORE_SCOPE = "A.core"
 
 
-def attention_core(q, k, v, causal: bool):
-    """Softmax attention over q, k [B, H, S, D] and v [B, H, S, Dv] ->
-    [B, H, S, Dv], chosen by shape and platform; the scores are scaled by
-    ``D ** -0.5``, D the key width.
+def attention_core(q, k, v, causal: bool, window: int = 0):
+    """Softmax attention over q [B, H, S, D], k [B, Hk, S, D] and v
+    [B, Hv, S, Dv] -> [B, H, S, Dv], chosen by shape and platform; the
+    scores are scaled by ``D ** -0.5``, D the key width.  Grouped heads:
+    Hk divides H and Hv divides Hk, query head h reads key head
+    ``h // (H / Hk)`` and value head ``h // (H / Hv)``.  ``window`` > 0
+    (causal only): query t sees keys t - window + 1 .. t.
 
     From S = 2048 on a TPU, kernels that never hold the [B, H, S, S]
     scores (1 GB per 4k sequence of 16 heads in f32), forward and
-    backward.  Equal widths (a multiple of 128): jax's own pallas
-    ``flash_attention``.  Timed once on the v5e at 4 x 16 x 4096 x 128,
-    causal, forward + backward (PERF.md section 6): 14.2 ms at 1024-wide
-    blocks against 25.5 ms for an XLA loop over query blocks with remat.
+    backward.  Equal widths (a multiple of 128), equal head counts, no
+    window: jax's own pallas ``flash_attention``.  Timed once on the v5e
+    at 4 x 16 x 4096 x 128, causal, forward + backward (PERF.md section
+    6): 14.2 ms at 1024-wide blocks against 25.5 ms for an XLA loop over
+    query blocks with remat.
     A value width that differs from the key width (latent attention: keys
     of 192, values of 128): jax's own pallas splash attention, the one
     kernel here that takes them as they are.  Timed once on the v5e at
@@ -212,15 +216,38 @@ def attention_core(q, k, v, causal: bool):
     backward kernel (8.35 ms with separate dq and dkv kernels) against
     12.82 ms for the flash kernels with all three padded to 256 and
     49.9 ms for an XLA loop over query blocks with remat.
+    Keys of 64 under values of 128 with grouped heads and, in some
+    layers, a window (differential attention: 40 query heads on 20 key
+    and 10 value heads): the same splash kernels in their grouped form,
+    one key head and the query heads that read it a call, so that no key
+    head is repeated in HBM (a value head is written out once per key
+    head that reads it: the kernel takes one value head a key head); the
+    window is a ``LocalMask`` and 512-wide blocks, so that the blocks the
+    window never reaches are skipped.  Timed once on the v5e at
+    1 x 40 x 2048, keys 64, values 128, forward + backward (PERF.md
+    section 6, PR 32): full causal 1.75 ms at 1024-wide blocks (1.79 at
+    512) against 9.59 ms for the XLA formulation; under a window of 512
+    1.45 ms at 512-wide blocks (1.75 at 1024) against 9.58 ms.
     Everything else takes the XLA formulation, which materializes the
-    scores: through :func:`flash_attention` at equal widths."""
-    S, D, Dv = q.shape[2], q.shape[3], v.shape[3]
+    scores: through :func:`flash_attention` at equal widths and head
+    counts without a window."""
+    H, S, D = q.shape[1:]
+    Dv = v.shape[3]
+    if window and not causal:
+        raise ValueError("a window is causal here: keys t - window + 1 .. t")
+    if H % k.shape[1] or k.shape[1] % v.shape[1]:
+        raise ValueError(f"{H} query heads over {k.shape[1]} key heads over "
+                         f"{v.shape[1]} value heads: each must divide the "
+                         "one before")
+    window = 0 if window >= S else window
     block = next((b for b in (1024, 512) if S % b == 0), 0)
     long_on_tpu = jax.default_backend() == "tpu" and S >= 2048 and block
-    if D != Dv:
+    plain = not window and k.shape[1] == H and v.shape[1] == H
+    if D != Dv or not plain:
         if not (long_on_tpu and causal and D % 64 == 0 and Dv % 128 == 0):
-            return attention_xla(q, k, v, causal)
-        return _splash_causal(q, k, v, block)
+            return _attention_xla(q, k, v, causal, window)
+        return _splash_causal(q, k, v, min(block, 512) if window else block,
+                              window)
     if not (long_on_tpu and D % 128 == 0):
         return flash_attention(q, k, v, causal=causal)
     from jax.experimental.pallas.ops.tpu import flash_attention as fa
@@ -234,21 +261,53 @@ def attention_core(q, k, v, causal: bool):
                               block_sizes=sizes)
 
 
-def _splash_causal(q, k, v, block: int):
+def _attention_xla(q, k, v, causal: bool, window: int):
+    """The XLA formulation with grouped heads and a window:
+    :func:`attention_xla` where there is neither."""
+    H, S = q.shape[1:3]
+    if not window and k.shape[1] == H and v.shape[1] == H:
+        return attention_xla(q, k, v, causal)
+    k = jnp.repeat(k, H // k.shape[1], axis=1)
+    v = jnp.repeat(v, H // v.shape[1], axis=1)
+    s = jnp.einsum("bhqd,bhkd->bhqk", q.astype(jnp.float32),
+                   k.astype(jnp.float32)) * q.shape[-1] ** -0.5
+    ahead = jnp.arange(S)[:, None] - jnp.arange(S)[None, :]  # query - key
+    seen = ahead >= 0 if causal else jnp.ones((S, S), bool)
+    if window:
+        seen &= ahead < window
+    s = jnp.where(seen, s, -1e30)
+    return jnp.einsum("bhqk,bhkd->bhqd", jax.nn.softmax(s, axis=-1),
+                      v.astype(jnp.float32)).astype(q.dtype)
+
+
+def _splash_causal(q, k, v, block: int, window: int = 0):
     """Causal attention with unequal key and value widths through jax's
-    splash attention kernels (forward, dq and dkv)."""
+    splash attention kernels (forward, dq and dkv); with fewer key heads
+    than query heads, through their grouped form."""
     from jax.experimental.pallas.ops.tpu.splash_attention import (
         splash_attention_kernel as sk, splash_attention_mask as sm)
 
     H, S, D = q.shape[1:]
-    mask = sm.MultiHeadMask([sm.CausalMask((S, S))] * H)
+    Hk = k.shape[1]
+    seen = (sm.LocalMask((S, S), (window - 1, 0), 0) if window
+            else sm.CausalMask((S, S)))
     sizes = sk.BlockSizes(
         block_q=block, block_kv=block, block_kv_compute=block,
         block_q_dkv=block, block_kv_dkv=block, block_kv_dkv_compute=block,
         use_fused_bwd_kernel=True)
-    kernel = sk.make_splash_mha_single_device(mask, block_sizes=sizes)
     # the kernel takes [H, S, D] and scores q kᵀ as given: scale q first
-    return jax.vmap(kernel)((q * D ** -0.5).astype(q.dtype), k, v)
+    q = (q * D ** -0.5).astype(q.dtype)
+    if Hk == H and v.shape[1] == H:
+        kernel = sk.make_splash_mha_single_device(
+            sm.MultiHeadMask([seen] * H), block_sizes=sizes)
+        return jax.vmap(kernel)(q, k, v)
+    # one key head and its H / Hk query heads a call
+    kernel = sk.make_splash_mqa_single_device(
+        sm.MultiHeadMask([seen] * (H // Hk)), block_sizes=sizes)
+    v = jnp.repeat(v, Hk // v.shape[1], axis=1)
+    grouped = q.reshape((q.shape[0], Hk, H // Hk, S, D))
+    o = jax.vmap(jax.vmap(kernel))(grouped, k, v)
+    return o.reshape((q.shape[0], H, S, v.shape[3]))
 
 
 @register
@@ -431,3 +490,102 @@ class LatentAttentionLayer(Layer):
         with jax.named_scope(LATENT_SCOPE):
             y = o.transpose(0, 2, 1, 3).reshape(B, S, H * vd) @ w_o.T
         return LayerOutput(outputs=[y])
+
+
+@register
+class DifferentialAttentionLayer(Layer):
+    """Differential attention with grouped heads (Ye et al. 2024,
+    arXiv:2410.05258, in its two-maps-over-doubled-values form, as the
+    SambaY decoder uses it, arXiv:2507.06607), self or cross.
+
+    ``attention_param { num_heads num_kv_heads window lambda_init
+    norm_eps }``; ``num_heads`` H query heads and ``num_kv_heads`` Hk key
+    and value heads of D = E / H; ``window`` > 0: query t sees keys
+    t - window + 1 .. t, else every key up to t.  No positional encoding,
+    no biases.
+
+    Self (one bottom [B, S, E]): blobs W_qkv ((H + 2 Hk) D, E) [q ; k ; v],
+    W_o (E, H D), lambda_q1, lambda_k1, lambda_q2, lambda_k2 (D each),
+    subln (2 D).  Tops [y] or [y, k, v]: k [B, Hk, S, D] and the paired
+    values v [B, Hk / 2, S, 2 D], for a layer far down the net to read.
+    Cross (bottoms [x, k, v]): blobs W_q (H D, E), W_o and the same five
+    vectors; it projects no key and no value.
+
+    Heads pair: query heads (2j, 2j + 1) are (q1_j, q2_j), key heads
+    (2g, 2g + 1) are (k1_g, k2_g), value heads (2g, 2g + 1) side by side
+    are v_g of width 2 D; query pair j reads pair g = j // (H / Hk).
+    a^r_j = softmax(q^r_j k^r_gᵀ / sqrt(D) + mask) v_g for r = 1, 2;
+    lambda = exp(lambda_q1 · lambda_k1) - exp(lambda_q2 · lambda_k2) +
+    lambda_init; o_j = (1 - lambda_init) · RMSNorm_{2D}(a^1_j - lambda a^2_j)
+    with weight subln; y = W_o [o_1 .. o_{H/2}].  Both maps of every pair
+    go through ONE :func:`attention_core` call: H query heads of D over
+    Hk key heads of D and Hk / 2 value heads of 2 D."""
+
+    TYPE = "DifferentialAttention"
+    F32_BLOBS = (2, 3, 4, 5)  # the four lambda vectors
+
+    def __init__(self, lp, phase):
+        super().__init__(lp, phase)
+        p = lp.get_msg("attention_param")
+        self.num_heads = p.get_int("num_heads")
+        self.num_kv_heads = p.get_int("num_kv_heads", self.num_heads)
+        self.window = p.get_int("window", 0)
+        self.lambda_init = p.get_float("lambda_init", 0.8)
+        self.norm_eps = p.get_float("norm_eps", 1e-5)
+        self.cross = len(self.bottoms) == 3
+        if len(self.bottoms) not in (1, 3) or len(self.tops) not in (1, 3):
+            raise ValueError(
+                f"{self.name}: bottoms are [x] or [x, k, v], tops [y] or "
+                "[y, k, v]")
+        self.weight_filler = (
+            p.get_msg("weight_filler") if p.has("weight_filler")
+            else Message().set("type", "xavier"))
+
+    def init(self, key, in_shapes):
+        E = in_shapes[0][-1]
+        H, Hk = self.num_heads, self.num_kv_heads
+        if E % H or H % Hk or Hk % 2:
+            raise ValueError(
+                f"{self.name}: {H} query heads must divide the width {E}, "
+                f"{Hk} key heads must divide them and come in pairs")
+        D = E // H
+        k_in, k_out, k_lam = jax.random.split(key, 3)
+        rows = H * D if self.cross else (H + 2 * Hk) * D
+        lam = 0.1 * jax.random.normal(k_lam, (4, D), jnp.float32)
+        return [fill(self.weight_filler, k_in, (rows, E)),
+                fill(self.weight_filler, k_out, (E, H * D)),
+                *lam, jnp.ones((2 * D,), jnp.float32)], {}
+
+    def apply(self, params, state, inputs, *, train, rng=None) -> LayerOutput:
+        if active_sequence_parallel() is not None:
+            raise NotImplementedError(
+                f"{self.name}: differential attention has no "
+                "sequence-parallel core (ring / Ulysses take one head "
+                "width and one head count)")
+        w_in, w_o, lq1, lk1, lq2, lk2, subln = params
+        x = inputs[0]
+        B, S, E = x.shape
+        H, Hk = self.num_heads, self.num_kv_heads
+        D, rep = E // H, H // Hk
+        proj = x @ w_in.T
+        if self.cross:
+            k, v = inputs[1], inputs[2]
+        else:
+            heads = lambda t, n, d: t.reshape(B, S, n, d).transpose(0, 2, 1, 3)
+            k = heads(proj[..., H * D:(H + Hk) * D], Hk, D)
+            # value heads (2g, 2g + 1) side by side: one head of 2 D
+            v = heads(proj[..., (H + Hk) * D:], Hk // 2, 2 * D)
+        # query head 2j + r of pair j = g rep + i, half r -> core head
+        # (g, r, i), which reads key head 2g + r and value head g
+        q = proj[..., :H * D].reshape(B, S, Hk // 2, rep, 2, D)
+        q = q.transpose(0, 2, 4, 3, 1, 5).reshape(B, H, S, D)
+        with jax.named_scope(CORE_SCOPE):
+            a = attention_core(q, k, v, True, self.window)
+        a = a.reshape(B, Hk // 2, 2, rep, S, 2 * D).astype(jnp.float32)
+        lam = (jnp.exp(jnp.sum(lq1 * lk1)) - jnp.exp(jnp.sum(lq2 * lk2))
+               + self.lambda_init).astype(jnp.float32)
+        o = rms_norm(a[:, :, 0] - lam * a[:, :, 1], subln, self.norm_eps)
+        o = (o * (1.0 - self.lambda_init)).astype(x.dtype)
+        # [B, g, i, S, 2D] -> [B, S, pairs (g, i), 2D]
+        y = o.transpose(0, 3, 1, 2, 4).reshape(B, S, H * D) @ w_o.T
+        return LayerOutput(outputs=[y, k, v][:len(self.tops)])

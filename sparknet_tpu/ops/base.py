@@ -52,6 +52,10 @@ class Layer:
     # Layers whose type name ends in "Loss" produce a loss top with default
     # weight 1 (ref: layer.hpp SetLossWeights / caffe.proto loss_weight).
     IS_LOSS: bool = False
+    # Blob positions that keep the parameter dtype when the compute dtype
+    # is narrower (compiler/graph.py casts the others at the layer's
+    # door): values a layer's own f32 arithmetic reads.
+    F32_BLOBS: tuple[int, ...] = ()
 
     def __init__(self, lp: Message, phase: Phase):
         self.lp = lp

@@ -582,6 +582,63 @@ def rms_norm(x, weight, eps: float):
     return (xf * inv).astype(x.dtype) * weight.astype(x.dtype)
 
 
+@register
+class LayerNorm(Layer):
+    """Layer normalization over the last axis (Ba et al. 2016):
+    ``y = w · (x - mean) / sqrt(var + eps) + b``.  Blobs [w (D), b (D)],
+    ones and zeros at initialisation.  The statistics are taken in f32
+    whatever the compute dtype; the normalized value returns to the
+    input's dtype before the weight multiplies it."""
+
+    TYPE = "LayerNorm"
+
+    def init(self, key, in_shapes):
+        d, dtype = in_shapes[0][-1], get_config().param_dtype
+        return [jnp.ones((d,), dtype), jnp.zeros((d,), dtype)], {}
+
+    def apply(self, params, state, inputs, *, train, rng=None):
+        x = inputs[0]
+        eps = self.lp.get_msg("layer_norm_param").get_float("eps", 1e-5)
+        xf = x.astype(jnp.float32)
+        mean = jnp.mean(xf, axis=-1, keepdims=True)
+        var = jnp.mean(jnp.square(xf - mean), axis=-1, keepdims=True)
+        y = ((xf - mean) * jax.lax.rsqrt(var + eps)).astype(x.dtype)
+        return LayerOutput([y * params[0].astype(x.dtype)
+                            + params[1].astype(x.dtype)])
+
+
+# device scope of the gated memory unit's product; in common.CACHE_SCOPES
+GATE_SCOPE = "R.gate"
+
+
+@register
+class GatedMemoryUnit(Layer):
+    """The SambaY cross-decoder's gated memory unit (arXiv:2507.06607
+    section 2): ``y = W_2 (silu(W_1 x) ⊙ m)`` over the last axis, x the
+    layer's input [B, S, E] and m [B, S, d] a MEMORY another layer wrote
+    (a ``Mamba`` layer's second top).  Bottoms [x, m]; blobs W_1 (d, E),
+    W_2 (E, d), no biases; d is the memory's width."""
+
+    TYPE = "GatedMemoryUnit"
+
+    def init(self, key, in_shapes):
+        e, d = in_shapes[0][-1], in_shapes[1][-1]
+        p = self.lp.get_msg("gmu_param")
+        wf = (p.get_msg("weight_filler") if p.has("weight_filler")
+              else Message().set("type", "xavier"))
+        dtype = get_config().param_dtype
+        k1, k2 = jax.random.split(key)
+        return [fillers.fill(wf, k1, (d, e), dtype),
+                fillers.fill(wf, k2, (e, d), dtype)], {}
+
+    def apply(self, params, state, inputs, *, train, rng=None):
+        x, memory = inputs
+        gate = x @ params[0].T
+        with jax.named_scope(GATE_SCOPE):
+            gated = jax.nn.silu(gate) * memory.astype(gate.dtype)
+        return LayerOutput([gated @ params[1].T])
+
+
 def gated_mlp(x, w_gate, w_up, w_down):
     """``(silu(x W_gateᵀ) · x W_upᵀ) W_downᵀ`` over the last axis, every
     matrix ``[out, in]``: the dense SwiGLU feed-forward of the

@@ -802,10 +802,17 @@ class Solver:
         the layers counted, the pairs that landed on held experts over
         all of them, and the bias's extremes.  A loss layer that keeps
         its ``value`` (``loss_param { keep_value: true }``) gives it under
-        the layer's name."""
+        the layer's name.  The selective-scan layers (``ops/ssm.py``)
+        keep no state between steps: the fence names how many there are,
+        the steps between two of the states their forward keeps for the
+        backward, and the bytes of those states over the layers."""
         state = self.variables.state
         stats = {name: float(st["value"]) for name, st in state.items()
                  if "value" in st}
+        scans = [l for l in self.train_net.layers if l.type == "Mamba"]
+        if scans:
+            stats.update(ssm_layers=len(scans), ssm_chunk=scans[0].chunk,
+                         ssm_saved_bytes=sum(l.saved_bytes for l in scans))
         loads = {name: np.asarray(st["load"]) for name, st in state.items()
                  if "load" in st}
         if not loads:
