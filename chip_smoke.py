@@ -15,7 +15,8 @@ One process drives, at the full width of AlexNet (``models.alexnet(256)``,
               sync-DP round, on every visible chip;
 4. snapshot — ``save`` -> ``restore`` -> ``Solver.step`` -> ``test()``;
 5. kernels  — each Pallas kernel compiled (not interpreted) at its caller's
-              full-width shape against its XLA twin.
+              full-width shape against its XLA twin (the selective scan at
+              a small shape that tiles, against its ``lax.scan`` loop form).
 
 Every phase runs even when an earlier one failed, so one call shows all
 that is broken; any failure makes the exit code 1 and withholds the result
@@ -54,11 +55,15 @@ FULL = dict(batch=256, crop=227, classes=1000, warm=3, steps=5,
             # (B, T, H, D, num_blocks, blocks_per_slot): charlm decode
             # geometry, then the planning point
             paged=[(8, 16, 4, 16, 64, 8), (8, 64, 8, 64, 128, 16)],
+            # (B, S, d_inner, d_state): the selective scan, 4 time blocks
+            # of 2 d-blocks
+            scan=[(2, 256, 1024, 16)],
             arena="alexnet", kernel_impl="pallas")
 REHEARSAL = dict(batch=4, crop=67, classes=10, warm=1, steps=5,
                  lrn=[("norm1", (2, 16, 9, 9))],
                  flash=[((1, 2, 160, 16), "float32")],
                  paged=[(3, 8, 2, 8, 16, 3)],
+                 scan=[(1, 24, 256, 8)],
                  # the interpreter walks the tile grid one cell at a time
                  arena="cifar10_quick", kernel_impl="interpret")
 
@@ -457,6 +462,27 @@ def phase_kernels(size: dict) -> None:
         pg = lambda f: (lambda *a: pk.paged_attention(*a, force=f))  # noqa: E731
         compare(f"paged B{B} T{T} H{H} D{D} ({MB} blocks/slot)",
                 pg(impl), pg("xla"), (q, kp, vp, tables, pos), 1e-5)
+
+    # the selective scan: the kernels' forward against the loop form
+    # (ops/ssm.py; the state, Δ and the exponential are f32 in both)
+    from sparknet_tpu.ops import ssm
+
+    for B, S, d, n in size["scan"]:
+        args = (normal((B, S, d), jnp.bfloat16), normal((B, S, d)) - 4.0,
+                normal((B, S, n), jnp.bfloat16),
+                normal((B, S, n), jnp.bfloat16),
+                jnp.log(jax.random.uniform(next(keys), (d, n), jnp.float32,
+                                           1.0, n)), normal((d,)))
+        kernel = lambda *a: ssm.selective_scan_kernel(  # noqa: E731
+            *a, interpret=impl == "interpret")
+        d_pre = lambda f: jax.grad(lambda *a: jnp.sum(  # noqa: E731
+            f(*a).astype(jnp.float32) ** 2), argnums=1)
+        compare(f"scan B{B} S{S} d{d} N{n}", kernel,
+                lambda *a: ssm._selective_scan(*a, None), args, 1e-2)
+        # the gradient against the time-step oracle: the loop form's own
+        # lies 2e-3 from it on the chip (PERF.md section 6, PR 33)
+        compare(f"scan B{B} S{S} d{d} N{n} fwd+bwd d(dt_pre) vs oracle",
+                d_pre(kernel), d_pre(ssm.selective_scan_steps), args, 1e-4)
 
     check(not failures, f"{len(failures)} kernel check(s) failed: {failures}")
 

@@ -804,14 +804,19 @@ class Solver:
         its ``value`` (``loss_param { keep_value: true }``) gives it under
         the layer's name.  The selective-scan layers (``ops/ssm.py``)
         keep no state between steps: the fence names how many there are,
-        the steps between two of the states their forward keeps for the
-        backward, and the bytes of those states over the layers."""
+        how many of them run their scan as the Pallas kernels (the others
+        as the ``lax.scan`` loop form: on the CPU all of them), and, of
+        the path that runs, the steps between two of the states the
+        forward keeps for the backward and the bytes of those states
+        over the layers."""
         state = self.variables.state
         stats = {name: float(st["value"]) for name, st in state.items()
                  if "value" in st}
         scans = [l for l in self.train_net.layers if l.type == "Mamba"]
         if scans:
-            stats.update(ssm_layers=len(scans), ssm_chunk=scans[0].chunk,
+            stats.update(ssm_layers=len(scans),
+                         ssm_kernel_layers=sum(l.kernel for l in scans),
+                         ssm_chunk=scans[0].chunk,
                          ssm_saved_bytes=sum(l.saved_bytes for l in scans))
         loads = {name: np.asarray(st["load"]) for name, st in state.items()
                  if "load" in st}

@@ -14,7 +14,9 @@ shape of an f32 expert matrix.  With megablox's own backward the same toy
 step has 18 (each matrix and both AdamW moments, into the transposed
 layout and back).  Both proofs live in this one file: the process that
 describes the topology holds libtpu, and a second file could land on
-another worker.
+another worker.  For the same reason the selective scan's kernels
+(``ops/ssm.py``, PR 33) are compiled for that v5e here, at the hybrid
+cell's widths: Mosaic refuses here what it would refuse on the chip.
 """
 
 import dataclasses
@@ -28,7 +30,7 @@ from sparknet_tpu import models
 from sparknet_tpu.common import Phase, get_config, set_config
 from sparknet_tpu.compiler.graph import Network
 from sparknet_tpu.ops import moe
-from tools import expert_copies
+from tools import expert_copies, scan_kernel
 
 ROWS, LIVE, SIZES = 500, 450, (200, 0, 150, 100)
 G, D, H = len(SIZES), 128, 256
@@ -133,3 +135,27 @@ def test_compiled_step_copies_no_expert_matrix(v5e, bf16_compute,
     # the step did take the kernels: 3 matmuls x (gmm, gmm, tgmm)
     assert text.count("tpu_custom_call") >= 9
     assert expert_copies.expert_copies(text, shapes) == []
+
+
+@pytest.mark.parametrize("seq", [2048, 2000], ids=["whole", "padded"])
+def test_the_scan_kernels_compile_at_the_cell_widths(v5e, no_compile_cache,
+                                                     seq):
+    """1 x 2,048 x 5120, state 16, bf16 c / B / C beside f32 Δ: forward and
+    backward are one Mosaic kernel each, and between them they keep the
+    state at every time block's start and no whole sequence of states."""
+    from jax.sharding import SingleDeviceSharding
+
+    from sparknet_tpu.ops import ssm
+
+    args = [jax.ShapeDtypeStruct(shape, dtype,
+                                 sharding=SingleDeviceSharding(v5e))
+            for shape, dtype in scan_kernel.shapes(seq)]
+    compiled = scan_kernel.both(ssm.selective_scan_kernel).lower(
+        *args).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 2
+    assert "while(" not in text
+    d, n = scan_kernel.D_INNER, scan_kernel.D_STATE
+    kept = ssm.saved_state_bytes(1, seq, d, n, ssm.TIME_BLOCK)
+    temps = compiled.memory_analysis().temp_size_in_bytes
+    assert kept <= temps < seq * d * n * 4 // 4

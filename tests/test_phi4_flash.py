@@ -503,12 +503,13 @@ def test_training_lowers_the_loss():
 
 
 def test_the_fence_carries_the_new_counters():
-    """After ``Solver.step``: the scan layers, the steps between two kept
-    states and the bytes of those states (f32 [chunks, B, N, d_inner])."""
+    """After ``Solver.step``: the scan layers, how many of them run the
+    kernels (none on the CPU), the steps between two kept states and the
+    bytes of those states (f32 [chunks, B, N, d_inner])."""
     solver = make_solver()
     solver.step(2, lambda it: batch_of(it))
     stats = solver._fence_stats()
-    assert stats == {"ssm_layers": 2, "ssm_chunk": 32,
+    assert stats == {"ssm_layers": 2, "ssm_kernel_layers": 0, "ssm_chunk": 32,
                      "ssm_saved_bytes": 2 * 1 * 2 * 4 * 128 * 4}
     assert ssm.chunking(2048) == (64, 32)
     assert ssm.saved_state_bytes(1, 2048, 5120, 16) == 32 * 16 * 5120 * 4
