@@ -34,19 +34,23 @@ from sparknet_tpu.data.rounds import stack_tau as _stack_tau, widen_batch
 
 def _build_net_and_solver(args):
     from sparknet_tpu import models
+    from sparknet_tpu.obs.recorder import Span
     from sparknet_tpu.proto.text_format import parse_file
     from sparknet_tpu.solvers.solver import SolverConfig, load_solver_net
 
     if not args.solver:
         raise SystemExit("--solver is required (prototxt path or zoo:<name>)")
-    if args.solver.startswith("zoo:"):
-        name = args.solver[4:]
-        net_param = getattr(models, name)(args.batch or 100)
-        solver_cfg = getattr(models, f"{name}_solver")()
-        return net_param, solver_cfg
-    solver_msg = parse_file(args.solver)
-    net_param = load_solver_net(solver_msg, root=_net_root(solver_msg, args.solver))
-    return net_param, SolverConfig.from_proto(solver_msg)
+    # sn.setup.net: the net and solver messages, parsed or built from the zoo
+    with Span(None, "sn.setup.net", host=True, compile_stats=True):
+        if args.solver.startswith("zoo:"):
+            name = args.solver[4:]
+            net_param = getattr(models, name)(args.batch or 100)
+            solver_cfg = getattr(models, f"{name}_solver")()
+            return net_param, solver_cfg
+        solver_msg = parse_file(args.solver)
+        net_param = load_solver_net(
+            solver_msg, root=_net_root(solver_msg, args.solver))
+        return net_param, SolverConfig.from_proto(solver_msg)
 
 
 def _net_root(solver_msg, solver_path: str) -> str:
@@ -157,6 +161,59 @@ def _data_fns(args, net, test_net=None):
                  or getattr(args, "elastic_alpha", 0.0) > 0))
 
 
+def _setup_line() -> str | None:
+    """Where this job's set-up went, from the program's own record
+    (``obs.recorder.flight`` reduced by ``obs.recorder.stages``): the
+    front door to the first fenced step or round, by stage, with what
+    each built and compiled, and the main thread's compile seconds by
+    jax's own events.  ``setup_s`` as a user of ``tpunet train`` feels it
+    (docs/OBSERVABILITY.md, "The record")."""
+    import threading
+
+    from sparknet_tpu.obs.recorder import flight, stages
+    from sparknet_tpu.obs.sentinel import EVENT_LABELS, get_sentinel
+
+    me = threading.get_ident()
+    mine = [s for s in flight()[0] if s[1] == me]
+    front = next((s for s in reversed(mine) if s[0] == "sn.main"), None)
+    if front is None:
+        return None
+    mine = [s for s in mine if s[2] >= front[2]]
+    fence = next((s for s in mine
+                  if s[0] in ("sn.step.fence", "sn.round.fence")), None)
+    if fence is None:
+        return None
+    first = [s for s in mine if s[0] in ("sn.step", "sn.round")][:1]
+    labels = {"sn.main": "front door", "sn.setup.net": "net",
+              "sn.solver.build": "solver build",
+              "sn.solver.nets": "of it nets", "sn.solver.init": "init",
+              "sn.trainer.build": "trainer build",
+              "sn.feed.open": "feed open", "sn.step": "first step",
+              "sn.round": "first step", fence[0]: "its fence"}
+    parts = []
+    for row in (*stages(mine), *stages(first, ("sn.step", "sn.round")),
+                *stages([fence], (fence[0],))):
+        text = f"{labels[row['name']]} {row['wall_s']:.1f}s"
+        if row["stats"]:
+            text += " (" + ", ".join(
+                f"{k} {'/'.join(map(str, v))}"
+                for k, v in row["stats"].items()) + ")"
+        if row["compiles"]:
+            text += (f" [{row['compiles']} compiles {row['compile_s']:.1f}s"
+                     + (f", {row['cache_hits']} from the cache"
+                        if row["cache_hits"] else "") + "]")
+        parts.append(text)
+    seconds = get_sentinel().thread_seconds()
+    split = ", ".join(f"{label} {seconds[event]:.1f}s"
+                      for event, label in EVENT_LABELS.items()
+                      if event in seconds)
+    total = (fence[2] + fence[3] - front[2]) / 1e9
+    return (f"set-up: {total:.1f}s from the front door to the first fenced "
+            "step: " + ", ".join(parts)
+            + (f"; this thread's compile seconds by event: {split}"
+               if split else ""))
+
+
 def _load_weights_into(
     solver, path: str, strict_shapes: bool, require_match: bool
 ) -> list[str]:
@@ -264,6 +321,16 @@ def cmd_train(args) -> int:
         log(f"profiling -> {args.profile}")
 
     iters = args.iterations or solver_cfg.max_iter
+    setup_pending = [True]
+
+    def log_setup():
+        """One line, once the first step or round has fenced."""
+        if setup_pending:
+            setup_pending.clear()
+            line = _setup_line()
+            if line:
+                log(line)
+
     with profile_ctx:
         elastic = args.elastic_alpha > 0
         if args.tau > 1 or args.distributed or elastic:
@@ -318,6 +385,7 @@ def cmd_train(args) -> int:
                             loss = trainer.train_round(wide_fn)
                             o += 1
                     log(f"loss: {loss:.5f}", i=trainer.iter)
+                    log_setup()
                     action = agree_action(sig.check())
                     if action is SolverAction.SNAPSHOT:
                         trainer.sync_to_solver()
@@ -361,6 +429,7 @@ def cmd_train(args) -> int:
             display = solver_cfg.display
             with pf_ctx, SignalHandler() as sig:
                 def hook(it, loss):
+                    log_setup()
                     # mirror the solver's display cadence into the event log
                     # so parse_log gets train-table rows (the reference's
                     # single glog stream carries both)
@@ -1208,6 +1277,27 @@ def cmd_device_query(args) -> int:
 
 # ---------------------------------------------------------------------------
 def main(argv=None) -> int:
+    from sparknet_tpu.common import get_config, set_config
+    from sparknet_tpu.obs.recorder import Span
+
+    # sn.main: the front door, up to the hand-over to the sub-command.
+    # Its start is where a job's set-up clocks in (the record:
+    # obs/recorder).  No Recorder yet: --obs arms it in here
+    with Span(None, "sn.main", host=True, compile_stats=True):
+        args, overrides = _front_door(argv)
+    if not overrides:
+        return args.fn(args)
+    prev = {k: getattr(get_config(), k) for k in overrides}
+    set_config(**overrides)
+    try:
+        return args.fn(args)
+    finally:
+        set_config(**prev)
+
+
+def _front_door(argv):
+    """``(args, config overrides)``: the parser, the platform, the compile
+    cache's placement and the scoped config a sub-command runs under."""
     p = argparse.ArgumentParser(prog="tpunet", description=__doc__)
     p.add_argument(
         "--platform",
@@ -1551,16 +1641,7 @@ def main(argv=None) -> int:
     if getattr(args, "feed", ""):
         # host feed architecture (data/pipeline.py) — scoped like layout
         overrides["feed"] = args.feed
-    if overrides:
-        from sparknet_tpu.common import get_config, set_config
-
-        prev = {k: getattr(get_config(), k) for k in overrides}
-        set_config(**overrides)
-        try:
-            return args.fn(args)
-        finally:
-            set_config(**prev)
-    return args.fn(args)
+    return args, overrides
 
 
 if __name__ == "__main__":

@@ -21,6 +21,7 @@ import numpy as np
 from sparknet_tpu.data.prefetch import fresh_bytes
 from sparknet_tpu.data.rounds import Turns
 from sparknet_tpu.obs import get_recorder
+from sparknet_tpu.obs.recorder import Span
 
 
 class Feed:
@@ -256,16 +257,21 @@ def open_feeds(spec, net, test_net=None, *, pid=0, nproc=1, seed=None,
         raise SystemExit(
             "--augment device is wired to the cifar: and db: sources "
             "(other sources transform on the host)")
-    data_shape = ()
-    if kind != "proto":
-        data_shape = feed_shapes(net, text, pid)["data"]
-    if not kind:
-        raise SystemExit(f"unknown --data source {text!r}")
-    return OPENERS[kind](
-        text=text, net=net, test_net=test_net, data_shape=data_shape,
-        pid=pid, nproc=nproc, seed=seed, host_seed=1234 + pid + (seed or 0),
-        was_auto=spec == "auto", augment=augment, solver_path=solver_path,
-        data_scale=data_scale, prefetch=prefetch, trainer=trainer)
+    # sn.feed.open: one per spec opened (files, indexes, the augment's
+    # programs); the batches it then reads are sn.feed.read's
+    with Span(None, "sn.feed.open", host=True, compile_stats=True,
+              source=kind.rstrip(":") or "unknown"):
+        data_shape = ()
+        if kind != "proto":
+            data_shape = feed_shapes(net, text, pid)["data"]
+        if not kind:
+            raise SystemExit(f"unknown --data source {text!r}")
+        return OPENERS[kind](
+            text=text, net=net, test_net=test_net, data_shape=data_shape,
+            pid=pid, nproc=nproc, seed=seed,
+            host_seed=1234 + pid + (seed or 0), was_auto=spec == "auto",
+            augment=augment, solver_path=solver_path, data_scale=data_scale,
+            prefetch=prefetch, trainer=trainer)
 
 
 def _open_proto(net, test_net, pid, nproc, host_seed, solver_path, was_auto,

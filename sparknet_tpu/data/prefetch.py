@@ -134,7 +134,7 @@ class DevicePrefetcher:
             del host
             if self._device_fn is not None:
                 with get_recorder().span("sn.feed.augment", host=True,
-                                         it=it):
+                                         compile_stats=True, it=it):
                     feeds = self._device_fn(feeds, it)
             if not feed.put(feeds, it):
                 return

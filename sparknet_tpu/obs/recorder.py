@@ -13,9 +13,20 @@ clock: entering it enters a ``jax.profiler.TraceAnnotation`` of the same
 name, armed or not, so the ``sn.*`` spans of the feed, the step and the
 round lie beside the device ops in any ``tpunet train --profile`` trace
 (docs/OBSERVABILITY.md, "Spans on the profiler's clock").  With no
-profiler session open a span costs about two microseconds (PERF.md,
-PR 24) and records nothing; it dispatches nothing either way.  Only the
-journal line is what ``SPARKNET_OBS`` arms.
+profiler session open the annotation records nothing; it dispatches
+nothing either way.  Only the journal line is what ``SPARKNET_OBS``
+arms.
+
+And a span keeps what it measured, armed or not, profiler or not: on
+exit it appends ``(name, thread ident, start_ns, wall_ns, counts)`` to a
+bounded in-memory record that :func:`flight` reads (``start_ns`` is
+``time.time_ns()``, the wall clock: a profiler's xplane counts from its
+session's start, and a reader anchors one on the other by the step
+spans' ``it``; ``wall_ns`` the monotonic duration; ``counts`` the span's
+own dict, so what :meth:`Span.set` added rides along).  Set-up and the untraced
+window are in it, which no profiler session covers.  It adds two clock
+reads and a deque append to a span, no device work and no dispatch
+(docs/OBSERVABILITY.md, "The record").
 
 Walls are only evidence when they are FENCE-STAMPED.  A span that
 encloses device work must close through :meth:`Span.fence`, which fetches
@@ -30,9 +41,11 @@ rule machine-checks call sites for the same contract.
 
 from __future__ import annotations
 
+import collections
 import json
 import os
 import sys
+import threading
 import time
 
 from sparknet_tpu._chaoslock import named_lock
@@ -40,14 +53,71 @@ from sparknet_tpu.obs import schema
 from sparknet_tpu.obs.metrics import MetricsHub
 from sparknet_tpu.obs.sentinel import get_sentinel
 
-__all__ = ["Recorder", "Span", "feed_counts", "get_recorder",
-           "set_recorder"]
+__all__ = ["Recorder", "Span", "feed_counts", "flight", "get_recorder",
+           "set_recorder", "stages"]
 
 _ENV = "SPARKNET_OBS"
 
 # loss EMA decay for per-round records: ~"average of the last 10 rounds",
 # the observability analog of SolverParameter.average_loss
 _EMA_DECAY = 0.9
+
+
+# the record: the newest FLIGHT_MAX spans of the process, oldest first.
+# A 30 s window of the busiest benchmark cell is ~7 k spans.  An append
+# is atomic under the interpreter lock; the count beside it is not, and
+# two threads that exit a span at once may count one: it only says how
+# many spans the bound has dropped
+FLIGHT_MAX = 65536
+_flight: collections.deque = collections.deque(maxlen=FLIGHT_MAX)
+_flight_count = 0
+
+# the stages of a job's set-up, in the order they run, and the stats a
+# span opened with ``compile_stats`` carries
+SETUP_STAGES = ("sn.main", "sn.setup.net", "sn.solver.build",
+                "sn.solver.nets", "sn.solver.init", "sn.trainer.build",
+                "sn.feed.open")
+COMPILE_STATS = ("compiles", "compile_s", "cache_hits")
+# a trace that ends in a jit-cache hit costs ~0.1 ms and compiles
+# nothing: under this many seconds, with no compile, a span stays as a
+# warm span was
+_COMPILE_FLOOR_S = 1e-3
+
+
+def flight() -> tuple[list, int]:
+    """``(spans, dropped)``: a snapshot of the record, oldest first, each
+    ``(name, thread ident, start_ns, wall_ns, counts)``, and how many
+    older spans the bound has pushed out."""
+    count = _flight_count
+    spans = list(_flight)
+    return spans, max(0, count - len(spans))
+
+
+def stages(spans, names=SETUP_STAGES) -> list[dict]:
+    """The record reduced by stage: for each of ``names`` that ``spans``
+    (rows of :func:`flight`, or of its JSON form) holds, in that order,
+    ``{"name", "count", "wall_s", "compiles", "compile_s", "cache_hits",
+    "stats"}``: the sums over its spans, and under ``stats`` every other
+    stat they carry (``params``, ``layers``, ``nets``, ``devices``,
+    ``source``) with its values in the spans' order.  The one reduction
+    behind ``tpunet train``'s ``set-up:`` line and the benchmark's set-up
+    table."""
+    rows = []
+    for name in names:
+        mine = [s for s in spans if s[0] == name]
+        if not mine:
+            continue
+        stats: dict = {}
+        for s in mine:
+            for k, v in s[4].items():
+                if k not in COMPILE_STATS and k != "it":
+                    stats.setdefault(k, []).append(v)
+        rows.append({
+            "name": name, "count": len(mine),
+            "wall_s": sum(s[3] for s in mine) / 1e9,
+            **{k: sum(s[4].get(k, 0) for s in mine) for k in COMPILE_STATS},
+            "stats": stats})
+    return rows
 
 
 _TraceAnnotation = None
@@ -81,8 +151,8 @@ def feed_counts(feeds, lead_axes: int = 1) -> dict:
 
 
 class Span:
-    """One wall, on two clocks.  Use as a context manager off
-    :meth:`Recorder.span`.
+    """One wall, on two clocks and in the record.  Use as a context
+    manager off :meth:`Recorder.span`.
 
     On the profiler's clock, always: a ``TraceAnnotation(name,
     **counts)`` is open for as long as the span is (``counts``: ``it``,
@@ -91,27 +161,48 @@ class Span:
     what only the work itself can tell).  One span per batch or round —
     never per record, layer or parameter leaf.
 
+    In the record (:func:`flight`), always, with the same counts.
+
     In the journal, only when the Recorder is armed: close device-work
     spans with :meth:`fence` (or :meth:`fence_value` when the caller
     already materialized the producing program's own output)."""
 
     __slots__ = ("_rec", "name", "host", "note", "counts", "_ann", "_t0",
-                 "_fenced", "_fence_value")
+                 "_t0_ns", "_compiled0", "_fenced", "_fence_value")
 
     def __init__(self, rec: "Recorder | None", name: str,
-                 host: bool = False, note: str | None = None, **counts):
+                 host: bool = False, note: str | None = None,
+                 step: int | None = None, compile_stats: bool = False,
+                 **counts):
+        """``step``: the span is one training step or round — a
+        ``StepTraceAnnotation`` (``step_num``) on the profiler's clock,
+        ``it`` in the record.  ``compile_stats``: the span carries what
+        its thread compiled while it was open (``compiles``,
+        ``compile_s``, ``cache_hits``: obs/sentinel.py), each only when
+        not zero, and installs the compile listener if nothing has."""
         self._rec = rec if (rec is not None and rec.enabled) else None
         self.name = name
         self.host = bool(host)
         self.note = note
         self.counts = counts
-        self._ann = _trace_annotation()(name, **counts)
+        if step is None:
+            self._ann = _trace_annotation()(name, **counts)
+        else:
+            # what jax.profiler.StepTraceAnnotation builds
+            self._ann = _trace_annotation()(name, _r=1, step_num=step)
+            counts["it"] = step
         self._t0 = 0.0
+        self._t0_ns = 0
+        # None: not asked for; else the thread's totals at entry
+        self._compiled0 = () if compile_stats else None
         self._fenced = False
         self._fence_value: float | None = None
 
     def __enter__(self) -> "Span":
         self._ann.__enter__()
+        if self._compiled0 is not None:
+            self._compiled0 = get_sentinel().install().thread_compile()
+        self._t0_ns = time.time_ns()
         self._t0 = time.perf_counter()
         return self
 
@@ -144,10 +235,21 @@ class Span:
         return self._fence_value
 
     def __exit__(self, exc_type, exc, tb) -> None:
+        wall = time.perf_counter() - self._t0
+        if self._compiled0 is not None:
+            now = get_sentinel().thread_compile()
+            delta = {k: b - a for k, a, b in zip(
+                COMPILE_STATS, self._compiled0, now) if b - a}
+            if "compiles" in delta or \
+                    delta.get("compile_s", 0.0) >= _COMPILE_FLOOR_S:
+                self.set(**delta)
         self._ann.__exit__(exc_type, exc, tb)
+        global _flight_count
+        _flight.append((self.name, threading.get_ident(), self._t0_ns,
+                        int(wall * 1e9), self.counts))
+        _flight_count += 1
         if self._rec is None:
             return
-        wall = time.perf_counter() - self._t0
         fields: dict = {
             "name": self.name,
             "wall_s": round(wall, 6),

@@ -72,121 +72,126 @@ class ParallelTrainer:
         rules: ShardingRules | None = None,
         elastic_alpha: float = 0.0,
     ):
-        cfg = get_config()
-        if solver.config.iter_size > 1:
-            raise ValueError(
-                "ParallelTrainer does not support iter_size > 1: the feed "
-                "layout [iter_size, B, ...] conflicts with the trainer's "
-                "batch/tau axis contract. Use a larger per-device batch or "
-                "tau-step accumulation instead."
-            )
-        self.solver = solver
-        self.mesh = mesh if mesh is not None else data_parallel_mesh()
-        self.tau = int(tau)
-        self.data_axis = cfg.data_axis
-        self.num_workers = self.mesh.shape.get(cfg.data_axis, 1)
-        # processes the mesh spans: >1 switches _put_feeds to per-process
-        # shard assembly; a process-local sub-mesh stays single-host
-        self._mesh_procs = len({d.process_index for d in self.mesh.devices.flat})
-        # data-axis width THIS process feeds (the per-host worker count a
-        # driver loop should build batches for)
-        self.num_local_workers = max(self.num_workers // self._mesh_procs, 1)
-        self.iter = 0
-        # Optional post-placement feed hook (``fn(feeds, it) -> feeds``,
-        # e.g. DeviceAugment.trainer_device_fn): applied AFTER _put_feeds
-        # and BEFORE the jitted round program, so the uint8 wire's
-        # device-resident augment runs on-device without touching the
-        # round program itself (banked graph/mem manifests stay
-        # byte-identical whether or not the hook is armed).
-        self.feed_device_fn = None
-        # the round placed ahead (``_place_ahead``): (it, data fn, what
-        # staging it gave or raised), or None
-        self._ahead = None
-        self._step_fn = solver._make_train_step(debug=False)
-        self._rules = rules or ShardingRules()
-        self._pshard = param_shardings(
-            solver.train_net, solver.variables, self.mesh, self._rules
-        )
-
-        # Sequence parallelism: a 'seq' mesh axis + rules.sequence_parallel
-        # shards feed axis 1 over it and routes MultiHeadAttention layers
-        # through ring/Ulysses at trace time (ops.attention context).
-        from sparknet_tpu.parallel.mesh import mesh_seq_size
-
-        self._seq_size = (
-            mesh_seq_size(self.mesh) if self._rules.sequence_parallel else 1
-        )
-        if self._seq_size > 1 and (self.tau > 1 or elastic_alpha > 0):
-            raise ValueError(
-                "sequence parallelism (a 'seq' mesh axis) composes with "
-                "tau=1 synchronous DP only: the tau>1/EASGD rounds are "
-                "already a manual shard_map over 'data' and cannot nest "
-                "the seq-axis attention shard_map. Use tau=1, or a mesh "
-                "without a 'seq' axis."
-            )
-
-        self.elastic_alpha = float(elastic_alpha)
-        self._elastic = elastic_alpha > 0.0
-        if elastic_alpha and not (
-            0.0 < elastic_alpha * self.num_workers <= 1.0
-        ):
-            # EASGD stability: the center's moving rate is beta = p*alpha
-            # and must stay in (0, 1] (Zhang et al. 2015 use beta = 0.9)
-            raise ValueError(
-                f"elastic_alpha={elastic_alpha} violates the stability "
-                f"bound alpha*num_workers <= 1 with "
-                f"{self.num_workers} workers; use ~0.9/{self.num_workers}"
-            )
-
-        if self.tau == 1 and not self._elastic:
-            self.variables = place(solver.variables, self._pshard)
-            self.slots = self._place_slots(solver.slots)
-            # Pin the carry's OUTPUT shardings to its input shardings:
-            # with TP/SP axes live, GSPMD otherwise propagates activation
-            # shardings into updated params (graphcheck caught ip-style
-            # weights returning P(None,'model') after entering P()), so
-            # every round paid an entry reshard and the changed layout
-            # broke the donation aliasing for those leaves.
-            out_shards = (
-                self._pshard,
-                {
-                    lname: [
-                        [self._pshard.params[lname][i]] * len(hl)
-                        for i, hl in enumerate(per_param)
-                    ]
-                    for lname, per_param in solver.slots.items()
-                },
-                NamedSharding(self.mesh, P()),  # scalar loss
-            )
-            self._train = jax.jit(self._step_fn, donate_argnums=(0, 1),
-                                  out_shardings=out_shards)
-        else:
-            # stack a worker axis: leaf [R, ...] sharded over 'data' — each
-            # device owns its own (initially identical) model replica
-            self.variables = self._stack_replicas(solver.variables)
-            self.slots = self._stack_replicas(solver.slots)
-            if self._elastic:
-                # EASGD (Zhang, Choromanska, LeCun 2015 — the reference's
-                # unrealized ROADMAP.md:11 item): workers couple to a
-                # replicated CENTER variable instead of hard-averaging
-                rep = NamedSharding(self.mesh, P())
-                self.center = jax.tree_util.tree_map(
-                    lambda x: jax.device_put(x, rep), solver.variables.params
+        # sn.trainer.build: the mesh, and the variables and slots
+        # replicated and placed on it; unfenced, like sn.solver.build
+        with Span(None, "sn.trainer.build", host=True,
+                  compile_stats=True) as sp:
+            cfg = get_config()
+            if solver.config.iter_size > 1:
+                raise ValueError(
+                    "ParallelTrainer does not support iter_size > 1: the feed "
+                    "layout [iter_size, B, ...] conflicts with the trainer's "
+                    "batch/tau axis contract. Use a larger per-device batch or "
+                    "tau-step accumulation instead."
                 )
-                self._train = jax.jit(
-                    self._make_elastic_round(), donate_argnums=(0, 1, 2)
+            self.solver = solver
+            self.mesh = mesh if mesh is not None else data_parallel_mesh()
+            sp.set(devices=int(self.mesh.devices.size))
+            self.tau = int(tau)
+            self.data_axis = cfg.data_axis
+            self.num_workers = self.mesh.shape.get(cfg.data_axis, 1)
+            # processes the mesh spans: >1 switches _put_feeds to per-process
+            # shard assembly; a process-local sub-mesh stays single-host
+            self._mesh_procs = len({d.process_index for d in self.mesh.devices.flat})
+            # data-axis width THIS process feeds (the per-host worker count a
+            # driver loop should build batches for)
+            self.num_local_workers = max(self.num_workers // self._mesh_procs, 1)
+            self.iter = 0
+            # Optional post-placement feed hook (``fn(feeds, it) -> feeds``,
+            # e.g. DeviceAugment.trainer_device_fn): applied AFTER _put_feeds
+            # and BEFORE the jitted round program, so the uint8 wire's
+            # device-resident augment runs on-device without touching the
+            # round program itself (banked graph/mem manifests stay
+            # byte-identical whether or not the hook is armed).
+            self.feed_device_fn = None
+            # the round placed ahead (``_place_ahead``): (it, data fn, what
+            # staging it gave or raised), or None
+            self._ahead = None
+            self._step_fn = solver._make_train_step(debug=False)
+            self._rules = rules or ShardingRules()
+            self._pshard = param_shardings(
+                solver.train_net, solver.variables, self.mesh, self._rules
+            )
+
+            # Sequence parallelism: a 'seq' mesh axis + rules.sequence_parallel
+            # shards feed axis 1 over it and routes MultiHeadAttention layers
+            # through ring/Ulysses at trace time (ops.attention context).
+            from sparknet_tpu.parallel.mesh import mesh_seq_size
+
+            self._seq_size = (
+                mesh_seq_size(self.mesh) if self._rules.sequence_parallel else 1
+            )
+            if self._seq_size > 1 and (self.tau > 1 or elastic_alpha > 0):
+                raise ValueError(
+                    "sequence parallelism (a 'seq' mesh axis) composes with "
+                    "tau=1 synchronous DP only: the tau>1/EASGD rounds are "
+                    "already a manual shard_map over 'data' and cannot nest "
+                    "the seq-axis attention shard_map. Use tau=1, or a mesh "
+                    "without a 'seq' axis."
                 )
+
+            self.elastic_alpha = float(elastic_alpha)
+            self._elastic = elastic_alpha > 0.0
+            if elastic_alpha and not (
+                0.0 < elastic_alpha * self.num_workers <= 1.0
+            ):
+                # EASGD stability: the center's moving rate is beta = p*alpha
+                # and must stay in (0, 1] (Zhang et al. 2015 use beta = 0.9)
+                raise ValueError(
+                    f"elastic_alpha={elastic_alpha} violates the stability "
+                    f"bound alpha*num_workers <= 1 with "
+                    f"{self.num_workers} workers; use ~0.9/{self.num_workers}"
+                )
+
+            if self.tau == 1 and not self._elastic:
+                self.variables = place(solver.variables, self._pshard)
+                self.slots = self._place_slots(solver.slots)
+                # Pin the carry's OUTPUT shardings to its input shardings:
+                # with TP/SP axes live, GSPMD otherwise propagates activation
+                # shardings into updated params (graphcheck caught ip-style
+                # weights returning P(None,'model') after entering P()), so
+                # every round paid an entry reshard and the changed layout
+                # broke the donation aliasing for those leaves.
+                out_shards = (
+                    self._pshard,
+                    {
+                        lname: [
+                            [self._pshard.params[lname][i]] * len(hl)
+                            for i, hl in enumerate(per_param)
+                        ]
+                        for lname, per_param in solver.slots.items()
+                    },
+                    NamedSharding(self.mesh, P()),  # scalar loss
+                )
+                self._train = jax.jit(self._step_fn, donate_argnums=(0, 1),
+                                      out_shardings=out_shards)
             else:
-                self._train = jax.jit(
-                    self._make_tau_round(), donate_argnums=(0, 1)
-                )
+                # stack a worker axis: leaf [R, ...] sharded over 'data' — each
+                # device owns its own (initially identical) model replica
+                self.variables = self._stack_replicas(solver.variables)
+                self.slots = self._stack_replicas(solver.slots)
+                if self._elastic:
+                    # EASGD (Zhang, Choromanska, LeCun 2015 — the reference's
+                    # unrealized ROADMAP.md:11 item): workers couple to a
+                    # replicated CENTER variable instead of hard-averaging
+                    rep = NamedSharding(self.mesh, P())
+                    self.center = jax.tree_util.tree_map(
+                        lambda x: jax.device_put(x, rep), solver.variables.params
+                    )
+                    self._train = jax.jit(
+                        self._make_elastic_round(), donate_argnums=(0, 1, 2)
+                    )
+                else:
+                    self._train = jax.jit(
+                        self._make_tau_round(), donate_argnums=(0, 1)
+                    )
 
-        # tau>1 keeps per-replica params; average once per test() call (not
-        # per batch) and feed the solver's own jitted eval step — one shared
-        # implementation of the TestAndStoreResult semantics.
-        self._average = jax.jit(
-            lambda v: jax.tree_util.tree_map(lambda x: x.mean(0), v)
-        )
+            # tau>1 keeps per-replica params; average once per test() call (not
+            # per batch) and feed the solver's own jitted eval step — one shared
+            # implementation of the TestAndStoreResult semantics.
+            self._average = jax.jit(
+                lambda v: jax.tree_util.tree_map(lambda x: x.mean(0), v)
+            )
 
     # ------------------------------------------------------------------
     def _stack_replicas(self, tree):
@@ -507,8 +512,8 @@ class ParallelTrainer:
         with rec.span("sn.feed.put", host=True, it=it, **counts):
             feeds = self._put_feeds(raw, with_tau_axis=with_tau_axis)
         if self.feed_device_fn is not None:
-            with rec.span("sn.feed.augment", host=True, it=it,
-                          images=counts["images"]):
+            with rec.span("sn.feed.augment", host=True, compile_stats=True,
+                          it=it, images=counts["images"]):
                 feeds = self.feed_device_fn(feeds, it)
         return feeds
 

@@ -404,84 +404,102 @@ class Solver:
         feed_dtypes: dict[str, Any] | None = None,
         batch_override: int | None = None,
     ):
-        self.config = (
-            solver if isinstance(solver, SolverConfig) else SolverConfig.from_proto(solver)
-        )
-        fmt = self.config.snapshot_format.upper()
-        if fmt not in ("", "BINARYPROTO", "HDF5"):
-            # fail at construction, not hours later at the first snapshot
-            raise ValueError(
-                f"unknown snapshot_format {self.config.snapshot_format!r} "
-                "(BINARYPROTO|HDF5|'')"
+        # sn.solver.build: the whole construction, unfenced (a sync here
+        # would be one the program does not have): what init left running
+        # on the device is booked to whatever blocks next.  Like the
+        # fences, the set-up spans are no journal lines (no Recorder)
+        with Span(None, "sn.solver.build", host=True, compile_stats=True):
+            self.config = (
+                solver if isinstance(solver, SolverConfig) else SolverConfig.from_proto(solver)
             )
-        if fmt == "HDF5":
-            try:
-                import h5py  # noqa: F401
-            except ImportError as e:
+            fmt = self.config.snapshot_format.upper()
+            if fmt not in ("", "BINARYPROTO", "HDF5"):
+                # fail at construction, not hours later at the first snapshot
                 raise ValueError(
-                    "snapshot_format=HDF5 needs h5py (pip install "
-                    "sparknet-tpu[hdf5])"
-                ) from e
-        self.net_param = net_param
-        self.train_net = Network(net_param, Phase.TRAIN, batch_override)
-        # one TEST net per test_state (ref: Solver::InitTestNets
-        # solver.cpp:135-190: NetState per test net, merged stages);
-        # no test_state = the single default test net
-        states = self.config.test_states or ((),)
-        levels = self.config.test_levels or (0,) * len(states)
-        self.test_nets = [
-            Network(net_param, Phase.TEST, batch_override,
-                    stages=set(st), level=lv)
-            for st, lv in zip(states, levels)
-        ]
-        self.test_net = self.test_nets[0]
-        # ref: Solver::InitTestNets CHECK_EQ(test_iter size, num test nets)
-        if self.config.test_iter and len(self.config.test_iter) != len(
-            self.test_nets
-        ):
-            raise ValueError(
-                f"test_iter specifies {len(self.config.test_iter)} counts "
-                f"but there are {len(self.test_nets)} test nets "
-                "(one test_iter per test net, ref: solver.cpp:113-118)"
-            )
-        seed = self.config.random_seed if self.config.random_seed >= 0 else None
-        self._key = root_key(seed)
-        self.variables = self.train_net.init(self._key, feed_shapes, feed_dtypes)
-        self.slots = init_slots(self.config.solver_type, self.variables.params)
-        self.iter = 0
-        self.smoothed_loss = 0.0
-        self._loss_window: list[float] = []
-        # obs bookkeeping (sparknet_tpu/obs): both stay inert — and the
-        # jitted programs bit-identical — while SPARKNET_OBS is off
-        self._obs_in_step = False
-        self._obs_images_per_iter = 0
-        self._specs = self.train_net.param_specs_for(self.variables)
-        # One-pass fused update (Config.fused_update, read at
-        # construction like every trace-time knob): build the flat-
-        # arena geometry once — per-blob spans padded to the kernel
-        # tile, per-tile lr_mult/decay segment tables (solvers/
-        # arena.py).  Off (default): self._arena stays None and every
-        # traced program below is byte-identical to the banked
-        # manifests.
-        self._fused = bool(get_config().fused_update)
-        self._arena = None
-        if self._fused:
-            from sparknet_tpu.solvers.arena import build_layout
+                    f"unknown snapshot_format {self.config.snapshot_format!r} "
+                    "(BINARYPROTO|HDF5|'')"
+                )
+            if fmt == "HDF5":
+                try:
+                    import h5py  # noqa: F401
+                except ImportError as e:
+                    raise ValueError(
+                        "snapshot_format=HDF5 needs h5py (pip install "
+                        "sparknet-tpu[hdf5])"
+                    ) from e
+            self.net_param = net_param
+            # sn.solver.nets: layer set-up of the train net and every test net
+            with Span(None, "sn.solver.nets", host=True,
+                      compile_stats=True) as sp:
+                self.train_net = Network(net_param, Phase.TRAIN, batch_override)
+                # one TEST net per test_state (ref: Solver::InitTestNets
+                # solver.cpp:135-190: NetState per test net, merged stages);
+                # no test_state = the single default test net
+                states = self.config.test_states or ((),)
+                levels = self.config.test_levels or (0,) * len(states)
+                self.test_nets = [
+                    Network(net_param, Phase.TEST, batch_override,
+                            stages=set(st), level=lv)
+                    for st, lv in zip(states, levels)
+                ]
+                self.test_net = self.test_nets[0]
+                nets = [self.train_net, *self.test_nets]
+                sp.set(nets=len(nets),
+                       layers=sum(len(n.layers) for n in nets))
+            # ref: Solver::InitTestNets CHECK_EQ(test_iter size, num test nets)
+            if self.config.test_iter and len(self.config.test_iter) != len(
+                self.test_nets
+            ):
+                raise ValueError(
+                    f"test_iter specifies {len(self.config.test_iter)} counts "
+                    f"but there are {len(self.test_nets)} test nets "
+                    "(one test_iter per test net, ref: solver.cpp:113-118)"
+                )
+            seed = self.config.random_seed if self.config.random_seed >= 0 else None
+            self._key = root_key(seed)
+            # sn.solver.init: shape inference and the fillers, the
+            # optimizer's slots, the per-parameter specs, the arena
+            with Span(None, "sn.solver.init", host=True,
+                      compile_stats=True) as sp:
+                self.variables = self.train_net.init(self._key, feed_shapes, feed_dtypes)
+                self.slots = init_slots(self.config.solver_type, self.variables.params)
+                self.iter = 0
+                self.smoothed_loss = 0.0
+                self._loss_window: list[float] = []
+                # obs bookkeeping (sparknet_tpu/obs): both stay inert — and the
+                # jitted programs bit-identical — while SPARKNET_OBS is off
+                self._obs_in_step = False
+                self._obs_images_per_iter = 0
+                self._specs = self.train_net.param_specs_for(self.variables)
+                # One-pass fused update (Config.fused_update, read at
+                # construction like every trace-time knob): build the flat-
+                # arena geometry once — per-blob spans padded to the kernel
+                # tile, per-tile lr_mult/decay segment tables (solvers/
+                # arena.py).  Off (default): self._arena stays None and every
+                # traced program below is byte-identical to the banked
+                # manifests.
+                self._fused = bool(get_config().fused_update)
+                self._arena = None
+                if self._fused:
+                    from sparknet_tpu.solvers.arena import build_layout
 
-            self._arena = build_layout(
-                self.variables.params, self._specs, self.config)
-        # Donate the (variables, slots) carry: step() rebinds both from
-        # the outputs every iteration, so keeping the inputs alive just
-        # holds a second copy of params+slots in device memory (the
-        # graphcheck donation audit flagged exactly this; the trainer
-        # and jitted_train_step paths already donated).  Callers that
-        # need the pre-step buffers use jitted_train_step(donate=False).
-        self._train_step = jax.jit(self._make_train_step(),
-                                   donate_argnums=(0, 1))
-        self._eval_steps = [
-            jax.jit(self._make_eval_step(net)) for net in self.test_nets
-        ]
-        self._eval_step = self._eval_steps[0]
+                    self._arena = build_layout(
+                        self.variables.params, self._specs, self.config)
+                sp.set(params=sum(
+                    int(p.size) for ps in self.variables.params.values()
+                    for p in ps))
+            # Donate the (variables, slots) carry: step() rebinds both from
+            # the outputs every iteration, so keeping the inputs alive just
+            # holds a second copy of params+slots in device memory (the
+            # graphcheck donation audit flagged exactly this; the trainer
+            # and jitted_train_step paths already donated).  Callers that
+            # need the pre-step buffers use jitted_train_step(donate=False).
+            self._train_step = jax.jit(self._make_train_step(),
+                                       donate_argnums=(0, 1))
+            self._eval_steps = [
+                jax.jit(self._make_eval_step(net)) for net in self.test_nets
+            ]
+            self._eval_step = self._eval_steps[0]
 
     # ------------------------------------------------------------------
     def _make_train_step(self, debug: bool | None = None):

@@ -32,8 +32,12 @@ def trace(log_dir: str):
 
 
 def step_span(name: str, step: int):
-    """Named span for one training round (shows as a block in the trace)."""
-    return jax.profiler.StepTraceAnnotation(name, step_num=step)
+    """Named span for one training step or round: a block in the trace
+    (a ``StepTraceAnnotation``) and a line of the program's record
+    (``obs.recorder.flight``), with what the step compiled, if anything."""
+    from sparknet_tpu.obs.recorder import Span
+
+    return Span(None, name, host=True, step=step, compile_stats=True)
 
 
 def device_memory_stats() -> dict:

@@ -27,7 +27,9 @@ from sparknet_tpu.data.createdb import create_db
 from sparknet_tpu.data.device_transform import AUGMENT_SCOPE
 from sparknet_tpu.data.prefetch import DevicePrefetcher
 from sparknet_tpu.data.rounds import stack_tau
-from sparknet_tpu.obs.recorder import Recorder, feed_counts, set_recorder
+from sparknet_tpu.obs import recorder
+from sparknet_tpu.obs.recorder import (
+    Recorder, Span, feed_counts, set_recorder)
 from sparknet_tpu.parallel.mesh import data_parallel_mesh
 from sparknet_tpu.parallel.trainer import ParallelTrainer
 from sparknet_tpu.solvers.solver import UPDATE_SCOPE
@@ -420,3 +422,345 @@ def test_the_jitted_augment_names_itself(rank):
         fn = jax.jit(lambda x, k: jax.vmap(aug)(x, jax.random.split(k, 2)))
     assert AUGMENT_SCOPE in fn.lower(
         x, jax.random.key(0)).as_text(debug_info=True)
+
+
+# ------------------------------------------------------------- the record
+# What a span measured is kept in memory, with no profiler session and no
+# SPARKNET_OBS: set-up and every step of a run, not a traced window of it
+# (docs/OBSERVABILITY.md, "The record").
+def flight_since(mark):
+    """The spans recorded since ``mark`` (``flight_mark()``), as dicts."""
+    spans, dropped = recorder.flight()
+    new = len(spans) + dropped - mark
+    assert 0 <= new <= len(spans)
+    return [{"name": n, "thread": t, "start": s, "end": s + w, "stats": c}
+            for n, t, s, w, c in spans[len(spans) - new:]]
+
+
+def flight_mark():
+    spans, dropped = recorder.flight()
+    return len(spans) + dropped
+
+
+@pytest.fixture(scope="module")
+def solo_flight(job):
+    """The record of set-up and two untraced step chunks of a fresh job."""
+    _, flags = job
+    assert not os.environ.get("SPARKNET_OBS")
+    mark = flight_mark()
+
+    def body(args):
+        solver, train_fn = build(args)
+        pf = DevicePrefetcher(train_fn, 1 << 30, depth=args.prefetch,
+                              start_iter=solver.iter,
+                              device_fn=train_fn.device_fn)
+        batches = iter(pf)
+        with pf:
+            for _ in range(2):
+                solver.step(STEPS, lambda it: next(batches))
+        return 0
+
+    run_as_train(flags, body)
+    return flight_since(mark)
+
+
+@pytest.fixture(scope="module")
+def rounds_flight(job):
+    """The record of set-up and three untraced tau=2 rounds."""
+    _, flags = job
+    mark = flight_mark()
+
+    def body(args):
+        solver, train_fn = build(args)
+        trainer = ParallelTrainer(solver, mesh=data_parallel_mesh(WORKERS),
+                                  tau=args.tau)
+        trainer.feed_device_fn = train_fn.trainer_device_fn
+        tau_fn = stack_tau(train_fn, args.tau, trainer.num_local_workers)
+        for _ in range(3):
+            trainer.train_round(tau_fn)
+        tau_fn.close()
+        return 0
+
+    run_as_train([*flags, "--tau", str(TAU)], body)
+    return flight_since(mark)
+
+
+SETUP_SPANS = ["sn.main", "sn.setup.net", "sn.solver.build",
+               "sn.solver.nets", "sn.solver.init", "sn.feed.open"]
+
+
+@pytest.mark.parametrize("name", [
+    *SETUP_SPANS, "sn.feed.read", "sn.feed.decode", "sn.feed.collate",
+    "sn.feed.put", "sn.feed.augment", "sn.feed.wait", "sn.step",
+    "sn.step.fence"])
+def test_the_solo_record_holds(solo_flight, name):
+    assert named(solo_flight, name), sorted({s["name"] for s in solo_flight})
+
+
+@pytest.mark.parametrize("name", [
+    *SETUP_SPANS, "sn.trainer.build", "sn.round", "sn.round.data",
+    "sn.feed.wait", "sn.feed.read", "sn.feed.stack", "sn.feed.put",
+    "sn.feed.augment", "sn.round.dispatch", "sn.round.fence"])
+def test_the_round_record_holds(rounds_flight, name):
+    assert named(rounds_flight, name), sorted(
+        {s["name"] for s in rounds_flight})
+
+
+def test_the_solo_record_has_every_step_with_its_it_thread_and_stats(
+        solo_flight):
+    steps = named(solo_flight, "sn.step")
+    assert [s["stats"]["it"] for s in steps] == list(range(2 * STEPS))
+    (main,) = {s["thread"] for s in steps}
+    assert {s["thread"] for s in named(solo_flight, "sn.main")} == {main}
+    assert {s["thread"] for s in named(solo_flight, "sn.step.fence")} == {main}
+    # what Span.set added once the work was done rides along
+    waits = named(solo_flight, "sn.feed.wait")
+    assert len(waits) == 2 * STEPS
+    assert all(w["thread"] == main and w["stats"]["ready"] in (0, 1)
+               and any(inside(w, s) for s in steps) for w in waits)
+    reads = named(solo_flight, "sn.feed.read")
+    assert all(r["thread"] != main and r["stats"]["images"] == BATCH
+               and "alloc_bytes" in r["stats"] for r in reads)
+    # appended as each closes: a thread's spans end in the record's order,
+    # and the wall clock they start on does not run backwards
+    for thread in {s["thread"] for s in solo_flight}:
+        ends = [s["end"] for s in solo_flight if s["thread"] == thread]
+        assert ends == sorted(ends)
+    starts = [s["start"] for s in steps]
+    assert starts == sorted(starts) and starts[0] > 1.6e18  # ns since 1970
+
+
+def test_the_round_record_has_every_round_and_the_feeds_readiness(
+        rounds_flight):
+    rounds_ = named(rounds_flight, "sn.round")
+    assert [r["stats"]["it"] for r in rounds_] == [0, TAU, 2 * TAU]
+    (main,) = {r["thread"] for r in rounds_}
+    for rnd in rounds_:
+        fence, = [f for f in named(rounds_flight, "sn.round.fence")
+                  if inside(f, rnd)]
+        assert fence["stats"]["it"] == rnd["stats"]["it"]
+    (build_,) = named(rounds_flight, "sn.trainer.build")
+    assert build_["stats"]["devices"] == WORKERS and build_["thread"] == main
+    waits = [w for w in named(rounds_flight, "sn.feed.wait")]
+    assert waits and all(w["stats"]["ready"] in (0, 1) for w in waits)
+    staged = [d["stats"]["staged"] for d in named(rounds_flight,
+                                                 "sn.round.data")]
+    assert staged[0] == 0 and set(staged[1:]) == {1}
+
+
+@pytest.mark.parametrize("which", ["solo", "rounds"])
+def test_only_the_first_step_compiles_and_says_for_how_long(
+        solo_flight, rounds_flight, which):
+    spans = solo_flight if which == "solo" else rounds_flight
+    first, *later = named(spans, "sn.step" if which == "solo" else "sn.round")
+    assert first["stats"]["compiles"] >= 1
+    assert 0 < first["stats"]["compile_s"] <= (
+        first["end"] - first["start"]) / 1e9
+    for s in later:  # a warm step's span is what it was
+        assert not {"compiles", "compile_s", "cache_hits"} & set(s["stats"])
+
+
+@pytest.mark.parametrize("which", ["solo", "rounds"])
+def test_one_solver_build_contains_its_nets_and_its_init(
+        solo_flight, rounds_flight, which):
+    spans = solo_flight if which == "solo" else rounds_flight
+    (build_,) = named(spans, "sn.solver.build")
+    (nets,) = named(spans, "sn.solver.nets")
+    (init,) = named(spans, "sn.solver.init")
+    assert inside(nets, build_) and inside(init, build_)
+    assert nets["end"] <= init["start"]
+    assert nets["stats"]["nets"] == 2 and nets["stats"]["layers"] == 6
+    assert init["stats"]["params"] == 3 * 12 * 12 * 4 + 4
+    # what the eager init compiled (nothing, where an earlier test's
+    # solver already did) the build that contains it compiled too
+    assert build_["stats"].get("compiles", 0) >= init["stats"].get(
+        "compiles", 0)
+    # set-up in the front door's order, each stage after the one before
+    order = [named(spans, n)[0] for n in (
+        "sn.main", "sn.setup.net", "sn.solver.build", "sn.feed.open")]
+    for a, b in zip(order, order[1:]):
+        assert a["end"] <= b["start"], (a["name"], b["name"])
+    (opened,) = named(spans, "sn.feed.open")
+    assert opened["stats"]["source"] == "db"
+
+
+def test_a_zoo_solver_build_is_one_span_with_its_parameter_count():
+    from sparknet_tpu import models
+    from sparknet_tpu.solvers.solver import Solver
+
+    mark = flight_mark()
+    solver = Solver(models.lenet_solver(), models.lenet(4))
+    spans = flight_since(mark)
+    (build_,) = named(spans, "sn.solver.build")
+    (nets,) = named(spans, "sn.solver.nets")
+    (init,) = named(spans, "sn.solver.init")
+    assert inside(nets, build_) and inside(init, build_)
+    assert nets["stats"]["layers"] == len(solver.train_net.layers) + len(
+        solver.test_net.layers)
+    assert init["stats"]["params"] == sum(
+        int(p.size) for ps in solver.variables.params.values() for p in ps)
+    assert {s["name"] for s in spans} == {
+        "sn.solver.build", "sn.solver.nets", "sn.solver.init"}
+
+
+def test_a_compile_on_a_feed_thread_is_booked_to_that_threads_span():
+    import threading
+
+    fn = jax.jit(lambda x: x * 3 + 1)  # never compiled before
+    mark = flight_mark()
+
+    def feed():
+        with Span(None, "sn.test.feed", host=True, compile_stats=True):
+            fn(jnp.ones(7)).block_until_ready()
+
+    with Span(None, "sn.test.main", host=True, compile_stats=True):
+        t = threading.Thread(target=feed)
+        t.start()
+        t.join(60)
+        assert not t.is_alive()
+    spans = flight_since(mark)
+    (on_feed,), (on_main,) = (named(spans, "sn.test.feed"),
+                              named(spans, "sn.test.main"))
+    assert on_feed["thread"] != on_main["thread"]
+    assert on_feed["stats"]["compiles"] >= 1
+    assert on_feed["stats"]["compile_s"] > 0
+    assert on_main["stats"] == {}  # the main thread compiled nothing
+
+
+def test_the_record_is_bounded_and_counts_what_it_dropped(monkeypatch):
+    import collections
+
+    monkeypatch.setattr(recorder, "_flight", collections.deque(maxlen=8))
+    monkeypatch.setattr(recorder, "_flight_count", 0)
+    for i in range(5):
+        with Span(None, "sn.test.bound", host=True, it=i):
+            pass
+    spans, dropped = recorder.flight()
+    assert [s[4]["it"] for s in spans] == list(range(5)) and dropped == 0
+    for i in range(5, 20):
+        with Span(None, "sn.test.bound", host=True, it=i):
+            pass
+    spans, dropped = recorder.flight()
+    assert [s[4]["it"] for s in spans] == list(range(12, 20))
+    assert dropped == 12
+    assert recorder.FLIGHT_MAX == 65536
+
+
+def test_a_trace_that_compiles_nothing_leaves_the_span_as_it_was():
+    """The solo feed's eager ``device_fn`` re-traces on every batch and
+    ends in jit's cache: ~0.1 ms of trace events, no compile."""
+    fn = jax.jit(lambda x: x * 11 - 4)
+    fn(jnp.ones(5)).block_until_ready()
+    mark = flight_mark()
+    with Span(None, "sn.test.warm", host=True, compile_stats=True, it=2):
+        jax.jit(lambda x: x)  # built, never called
+        fn(jnp.ones(5)).block_until_ready()
+    (warm,) = flight_since(mark)
+    assert warm["stats"] == {"it": 2}
+
+
+ROWS = [("sn.solver.build", 1, 100, 4 * 10**9,
+         {"compiles": 3, "compile_s": 2.5, "cache_hits": 1}),
+        ("sn.trainer.build", 1, 200, 10**9, {"devices": 4}),
+        ("sn.round", 1, 250, 10**9, {"it": 0, "compiles": 1,
+                                     "compile_s": 0.5}),
+        ["sn.trainer.build", 1, 300, 2 * 10**9,
+         {"devices": 1, "compiles": 2, "compile_s": 0.25}]]  # its JSON form
+
+
+@pytest.mark.parametrize("names,expected", [
+    (recorder.SETUP_STAGES, [
+        {"name": "sn.solver.build", "count": 1, "wall_s": 4.0, "compiles": 3,
+         "compile_s": 2.5, "cache_hits": 1, "stats": {}},
+        {"name": "sn.trainer.build", "count": 2, "wall_s": 3.0, "compiles": 2,
+         "compile_s": 0.25, "cache_hits": 0, "stats": {"devices": [4, 1]}}]),
+    (("sn.round",), [
+        {"name": "sn.round", "count": 1, "wall_s": 1.0, "compiles": 1,
+         "compile_s": 0.5, "cache_hits": 0, "stats": {}}]),
+    (("sn.feed.open",), [])])
+def test_the_record_reduces_by_stage(names, expected):
+    assert recorder.stages(ROWS, names) == expected
+
+
+def test_the_record_keeps_a_span_that_raised_and_a_steps_it():
+    mark = flight_mark()
+    with pytest.raises(RuntimeError):
+        with Span(None, "sn.test.raises", host=True, it=1) as sp:
+            sp.set(ready=0)
+            raise RuntimeError("inside")
+    with profiling.step_span("sn.test.step", 41):
+        pass
+    raised, step = flight_since(mark)
+    assert raised["name"] == "sn.test.raises"
+    assert raised["stats"] == {"it": 1, "ready": 0}
+    assert step["stats"] == {"it": 41} and step["end"] >= step["start"]
+
+
+def test_a_step_span_is_still_a_step_annotation_in_the_trace(tmp_path):
+    with profiling.trace(str(tmp_path / "trace")):
+        with profiling.step_span("sn.test.step", 7):
+            pass
+    (span,) = named(read_spans(str(tmp_path / "trace")), "sn.test.step")
+    assert span["stats"]["step_num"] == 7
+
+
+# -------------------------------------------------- the compile listener
+def test_the_sentinel_keeps_the_seconds_it_is_given_by_event_and_thread():
+    from sparknet_tpu.obs.sentinel import get_sentinel
+
+    sentinel = get_sentinel().install()
+    before = sentinel.thread_seconds()
+    n0, s0, _ = sentinel.thread_compile()
+    last0 = sentinel.last_compile_ns
+    jax.jit(lambda x: x * 5 - 2)(jnp.ones(3)).block_until_ready()
+    after = sentinel.thread_seconds()
+    for stage in ("jaxpr_trace_duration", "jaxpr_to_mlir_module_duration",
+                  "backend_compile_duration"):
+        key = f"/jax/core/compile/{stage}"
+        assert after[key] > before.get(key, 0.0), stage
+    n1, s1, _ = sentinel.thread_compile()
+    assert n1 - n0 >= 1 and s1 > s0
+    # the stages of one compilation do not overlap: union == sum here
+    assert s1 - s0 <= sum(after.values()) - sum(before.values()) + 1e-6
+    assert sentinel.last_compile_ns > last0
+
+
+@pytest.mark.parametrize("events,union_s", [
+    # two traces inside a third: the outer one's seconds, once
+    ([(1.0, 2.0), (3.0, 4.0), (0.5, 5.0)], 4.5),
+    # disjoint stages add up
+    ([(0.0, 1.0), (1.0, 1.5), (2.0, 4.0)], 3.5),
+    # a later event that starts inside an earlier one adds only its rest
+    ([(0.0, 2.0), (1.0, 3.0)], 3.0)])
+def test_nested_compile_stages_are_counted_once(events, union_s):
+    from sparknet_tpu.obs.sentinel import RecompileSentinel
+
+    sentinel = RecompileSentinel()
+    for start, end in events:
+        sentinel._cover(1, int(start * 1e9), int(end * 1e9))
+    assert sentinel._busy[1][0] == int(union_s * 1e9)
+
+
+@pytest.mark.parametrize("extra,stages", [
+    ([], ["solver build", "init", "feed open", "first step", "its fence"]),
+    (["--tau", str(TAU)], ["solver build", "trainer build", "feed open",
+                           "first step", "its fence"])])
+def test_train_logs_one_set_up_line_from_the_record(job, capsys, extra,
+                                                    stages):
+    tmp, flags = job
+    assert cli.main(["train", *flags, *extra, "--iterations", "4",
+                     "--output", str(tmp / "out")]) == 0
+    lines = [l for l in capsys.readouterr().out.splitlines()
+             if "set-up:" in l]
+    assert len(lines) == 1, lines
+    assert "from the front door to the first fenced step" in lines[0]
+    for stage in stages:
+        assert stage in lines[0], (stage, lines[0])
+    assert "compiles" in lines[0]  # the first step's program, at least
+    # what each stage built, and the listener's seconds by event
+    built = ["(nets 2, layers 6)", f"(params {3 * 12 * 12 * 4 + 4})",
+             "(source db)"] + (["(devices "] if extra else [])
+    for stat in built:
+        assert stat in lines[0], (stat, lines[0])
+    assert "this thread's compile seconds by event: trace " in lines[0]
+    assert "compile or load " in lines[0]
