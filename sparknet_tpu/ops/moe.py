@@ -58,11 +58,30 @@ the ``num_experts`` the router scores.  The router keeps its width, the
 expert blobs' leading axis is n, and a (token, slot) pair whose expert
 is not held adds nothing to ``y``; ``load`` still counts every router
 output.  Nothing stands in for the absent chips or their exchange.
+
+What a share-holding layer moves (PR 35).  A deployment's exchange would
+hand this chip only its own rows, so the layer touches only those where
+it can: the pairs are sorted with the held groups first, and when the
+step's held pairs fit the layer's ``capacity`` (``CAPACITY_FACTOR`` = 6
+times the level share n / E of the T·k pairs, in whole tiles of 512
+rows: 6,144 of 32,768 at 8 of 256 experts; a function of the shapes
+alone) it gathers that many rows of ``x``, runs the grouped matmuls, the
+activation and the masks at M = capacity, and adds the weighted rows to
+their tokens in f32 (a scatter-add of the capacity's rows): no [T·k, ·]
+array exists in either pass.  When they do not fit, the path over all
+T·k rows runs, as before: the layer is dropless at ANY routing, all
+pairs on held experts included.  One ``lax.cond`` a pass picks the path
+(``_held_rows``, a ``custom_vjp`` whose backward branches again, so that
+neither path's residuals are written by the other); ``takes_compact`` is
+the predicate, on the device and for the fence's ``moe_compact_layers``
+count alike.  A layer that holds every expert, or whose capacity would
+be all its pairs, has the one path and lowers as it did.
 """
 
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -293,46 +312,74 @@ def grouped_matmul(x, w, group_sizes):
     return _megablox_matmul(x, w, group_sizes)
 
 
-def moe_dropless(params, x, *, top_k: int, expert_act: str,
-                 norm_topk_prob: bool = False, scoring: str = "softmax",
-                 select_bias=None, scale: float = 1.0, first_expert: int = 0):
-    """The layer's compute on [T, D] tokens -> (y [T, D], logits, scores,
-    experts [T, k], load [E] f32).  ``params`` as the layer holds them:
-    the router over all E experts, then the matrices of the experts HELD
-    here, ``[first_expert, first_expert + n)`` with n their leading axis
-    (all E by default).  A pair routed to an expert that is not held adds
-    nothing: its rows sort behind the last held group, where the grouped
-    matmuls skip them, and its weight is dropped from the sum.  ``load``
-    counts the pairs of every router output, held or not."""
-    w_router, rest = params[0], params[1:]
-    num_experts, held_n = w_router.shape[0], rest[0].shape[0]
-    whole = held_n == num_experts  # every expert lives here: no pair is dead
-    tokens = x.shape[0]
-    with jax.named_scope(ROUTE_SCOPE):
-        logits, probs, weights, experts = route(
-            w_router, x, top_k, norm_topk_prob, scoring=scoring,
-            select_bias=select_bias, scale=scale)
-    with jax.named_scope(DISPATCH_SCOPE):
-        flat = experts.reshape(-1)  # pair t*k + s -> its expert
-        load = jnp.bincount(flat, length=num_experts).astype(jnp.int32)
-        if whole:
-            group_sizes = load
-        else:
-            local = flat - first_expert
-            held = (local >= 0) & (local < held_n)
-            flat = jnp.where(held, local, held_n)  # not held: sorted last
-            group_sizes = jax.lax.dynamic_slice_in_dim(
-                load, first_expert, held_n)
-        order = jnp.argsort(flat, stable=True).astype(jnp.int32)
-        inv = jnp.zeros_like(order).at[order].set(
-            jnp.arange(order.shape[0], dtype=jnp.int32))
-        rows = _spread_rows(x, order, inv)  # [T·k, D], expert-major
-        if not whole:
-            # rows past the held groups are never computed: what the
-            # kernels leave there must not reach a sum, forward or backward
-            live = (jnp.arange(order.shape[0], dtype=jnp.int32)
-                    < jnp.sum(group_sizes))[:, None]
-            rows = jnp.where(live, rows, 0)
+# ---------------------------------------------------------------------------
+# a layer that holds a SHARE of its experts: the held pairs' rows only
+# ---------------------------------------------------------------------------
+
+# A chip that holds n of E experts is sent, under a level router, n / E
+# of the (token, slot) pairs.  The share-holding layer gathers, computes
+# and combines at a static CAPACITY of sorted rows, this many times that
+# level share, whenever the step's held pairs fit it, and at all T·k rows
+# (the exact path, dropless at any routing) when they do not.  One
+# constant for every layer: 6 x is 6,144 of 32,768 pairs at 8 of 256
+# experts.  Read off the held counts of the JoyAI cell's layer-steps and
+# one layer's times (PERF.md section 6, PR 35): a step at the capacity
+# costs in proportion to it (2.6 ms a layer at 4 x, 3.5 at 8 x, 7.5 over
+# all rows), a step OVER it costs more than the layer did before (its
+# forward runs twice), and of 500 layer-steps the four decoder blocks'
+# layers never held more than 5.3 x (two thirds of them under 1 x).
+CAPACITY_FACTOR = 6
+CAPACITY_TILE = 512  # rows: whole megablox row tiles at any capacity
+
+
+def capacity(pairs: int, held_n: int, num_experts: int) -> int:
+    """Sorted rows a layer of ``pairs`` (token, slot) pairs that holds
+    ``held_n`` of ``num_experts`` experts dispatches when its held pairs
+    fit: ``CAPACITY_FACTOR`` times the level share, in whole tiles.  0
+    where that is no fewer than all the pairs (a layer that holds every
+    expert, or a large share of few): such a layer has one path."""
+    rows = -(-CAPACITY_FACTOR * pairs * held_n
+             // (num_experts * CAPACITY_TILE)) * CAPACITY_TILE
+    return rows if held_n < num_experts and rows < pairs else 0
+
+
+def takes_compact(held_pairs, cap: int):
+    """Whether a step whose held experts got ``held_pairs`` pairs runs at
+    the capacity ``cap``: the ONE predicate behind the device's branch
+    (``held_pairs`` traced) and the host's count of it
+    (``Solver._fence_stats``, from the same ``load``)."""
+    return (cap > 0) & (held_pairs <= cap)
+
+
+def _add_rows(v, idx, rows: int):
+    """[rows, D] f32 whose row i is the sum of the rows ``v[r]`` with
+    ``idx[r] == i``: a scatter-add of ``len(idx)`` rows, nothing of T·k."""
+    return jnp.zeros((rows, v.shape[1]), jnp.float32).at[idx].add(v)
+
+
+@jax.custom_vjp
+def _gather_rows(x, idx):
+    """``x[idx]`` for row indices that may repeat; the cotangent adds the
+    rows of one index in f32 before it is rounded to ``x``'s type."""
+    return x[idx]
+
+
+def _gather_rows_fwd(x, idx):
+    return x[idx], (idx, x.shape[0])
+
+
+def _gather_rows_bwd(res, g):
+    idx, rows = res
+    return _add_rows(g.astype(jnp.float32), idx, rows).astype(g.dtype), None
+
+
+_gather_rows.defvjp(_gather_rows_fwd, _gather_rows_bwd)
+
+
+def _experts(rows, rest, group_sizes, flat, order, live, expert_act: str):
+    """The held experts on their sorted ``rows`` -> [M, D].  ``flat[order]``
+    is each row's expert, for the experts' biases; ``live`` [M, 1] marks
+    the rows inside the groups where some are not (a share), else None."""
     with jax.named_scope(EXPERTS_SCOPE):
         if expert_act == "swiglu":
             w_gate, w_up, w_down = rest
@@ -346,17 +393,169 @@ def moe_dropless(params, x, *, top_k: int, expert_act: str,
         else:
             w1, b1, w2, b2 = rest
             of_row = flat[order]  # each sorted row's expert, for its bias
-            if not whole:
-                of_row = jnp.minimum(of_row, held_n - 1)  # a dead row
+            if live is not None:
+                of_row = jnp.minimum(of_row, w1.shape[0] - 1)  # a dead row
             h = jax.nn.relu(
                 grouped_matmul(rows, w1, group_sizes) + b1[of_row])
             out = grouped_matmul(h, w2, group_sizes) + b2[of_row]
-        if not whole:
+        if live is not None:
             out = jnp.where(live, out, 0)
+    return out
+
+
+def _all_rows(x, weights, rest, flat, order, group_sizes, *, live: bool,
+              expert_act: str):
+    """Dispatch, experts and combine over ALL T·k sorted rows -> y [T, D].
+    ``live``: rows past the groups' sum exist (a share) and must reach no
+    sum, forward or backward: the kernels never compute them."""
+    tokens, top_k = weights.shape
+    with jax.named_scope(DISPATCH_SCOPE):
+        inv = jnp.zeros_like(order).at[order].set(
+            jnp.arange(order.shape[0], dtype=jnp.int32))
+        rows = _spread_rows(x, order, inv)  # [T·k, D], expert-major
+        mask = None
+        if live:
+            mask = (jnp.arange(order.shape[0], dtype=jnp.int32)
+                    < jnp.sum(group_sizes))[:, None]
+            rows = jnp.where(mask, rows, 0)
+    out = _experts(rows, rest, group_sizes, flat, order, mask, expert_act)
     with jax.named_scope(COMBINE_SCOPE):
         per_pair = _take_rows(out, inv, order).reshape(tokens, top_k, -1)
-        y = jnp.sum(per_pair.astype(jnp.float32) * weights[..., None],
-                    axis=1).astype(x.dtype)
+        return jnp.sum(per_pair.astype(jnp.float32) * weights[..., None],
+                       axis=1).astype(x.dtype)
+
+
+def _capacity_rows(x, weights, rest, flat, order, group_sizes, *, cap: int,
+                   expert_act: str):
+    """The same sum over the first ``cap`` sorted rows, which hold every
+    held pair of a step that ``takes_compact``: ``cap`` rows of ``x`` are
+    gathered, the experts and the masks run at M = ``cap``, and the
+    weighted rows are added to their tokens in f32.  No array of T·k rows
+    is made, forward or backward."""
+    tokens, top_k = weights.shape
+    with jax.named_scope(DISPATCH_SCOPE):
+        order = order[:cap]
+        token = order // top_k
+        live = (jnp.arange(cap, dtype=jnp.int32)
+                < jnp.sum(group_sizes))[:, None]
+        rows = jnp.where(live, _gather_rows(x, token), 0)
+    out = _experts(rows, rest, group_sizes, flat, order, live, expert_act)
+    with jax.named_scope(COMBINE_SCOPE):
+        weighted = out.astype(jnp.float32) * weights.reshape(-1)[order, None]
+        return _add_rows(weighted, token, tokens).astype(x.dtype)
+
+
+def _held_paths(cap, expert_act):
+    """(at the capacity, over all rows): the two paths of a share."""
+    return (functools.partial(_capacity_rows, cap=cap, expert_act=expert_act),
+            functools.partial(_all_rows, live=True, expert_act=expert_act))
+
+
+# jitted: a net's share-holding layers of one shape are traced once, both
+# paths and their vjps, not once a layer (set-up time: PERF.md, PR 35)
+@functools.partial(jax.jit, static_argnames=("cap", "expert_act"))
+def _held_forward(x, weights, rest, flat, order, group_sizes, *, cap,
+                  expert_act):
+    compact, full = _held_paths(cap, expert_act)
+    return jax.lax.cond(
+        takes_compact(jnp.sum(group_sizes), cap),
+        lambda *ops: compact(*ops), lambda *ops: full(*ops),
+        x, weights, rest, flat, order, group_sizes)
+
+
+@functools.partial(jax.jit, static_argnames=("cap", "expert_act"))
+def _held_backward(x, weights, rest, flat, order, group_sizes, dy, *, cap,
+                   expert_act):
+    def pull(path):
+        return lambda x, weights, rest, dy: jax.vjp(
+            lambda *diff: path(*diff, flat, order, group_sizes),
+            x, weights, rest)[1](dy)
+
+    compact, full = _held_paths(cap, expert_act)
+    return jax.lax.cond(takes_compact(jnp.sum(group_sizes), cap),
+                        pull(compact), pull(full), x, weights, rest, dy)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7))
+def _held_rows(x, weights, rest, flat, order, group_sizes, cap, expert_act):
+    """y [T, D] of a layer that holds a share of its experts: at the
+    capacity when the held pairs fit it, else over all rows.
+
+    A ``lax.cond`` that autodiff differentiates hands BOTH branches'
+    residuals out of the forward, the untaken ones as zeros: a step at the
+    capacity would still write the other branch's [T·k, ·] arrays.  So the
+    forward keeps only its operands and the backward branches again, each
+    branch the vjp of its own path."""
+    return _held_forward(x, weights, rest, flat, order, group_sizes,
+                         cap=cap, expert_act=expert_act)
+
+
+def _held_rows_fwd(x, weights, rest, flat, order, group_sizes, cap,
+                   expert_act):
+    ops = (x, weights, rest, flat, order, group_sizes)
+    return _held_forward(*ops, cap=cap, expert_act=expert_act), ops
+
+
+def _held_rows_bwd(cap, expert_act, ops, dy):
+    grads = _held_backward(*ops, dy, cap=cap, expert_act=expert_act)
+    return (*grads, None, None, None)
+
+
+_held_rows.defvjp(_held_rows_fwd, _held_rows_bwd)
+
+
+def sort_pairs(experts, num_experts: int, held_n: int, first_expert: int):
+    """The T·k (token, slot) pairs of ``experts`` [T, k] sorted by expert
+    -> (flat [T·k]: pair t·k + s -> its held expert's position, ``held_n``
+    where it is not held; order [T·k] int32: the stable sort, held groups
+    first; group_sizes [held_n]: rows of each held expert; load [E] int32:
+    pairs of every router output)."""
+    with jax.named_scope(DISPATCH_SCOPE):
+        flat = experts.reshape(-1)  # pair t*k + s -> its expert
+        load = jnp.bincount(flat, length=num_experts).astype(jnp.int32)
+        if held_n == num_experts:
+            group_sizes = load
+        else:
+            local = flat - first_expert
+            held = (local >= 0) & (local < held_n)
+            flat = jnp.where(held, local, held_n)  # not held: sorted last
+            group_sizes = jax.lax.dynamic_slice_in_dim(
+                load, first_expert, held_n)
+        order = jnp.argsort(flat, stable=True).astype(jnp.int32)
+    return flat, order, group_sizes, load
+
+
+def moe_dropless(params, x, *, top_k: int, expert_act: str,
+                 norm_topk_prob: bool = False, scoring: str = "softmax",
+                 select_bias=None, scale: float = 1.0, first_expert: int = 0):
+    """The layer's compute on [T, D] tokens -> (y [T, D], logits, scores,
+    experts [T, k], load [E] f32).  ``params`` as the layer holds them:
+    the router over all E experts, then the matrices of the experts HELD
+    here, ``[first_expert, first_expert + n)`` with n their leading axis
+    (all E by default).  A pair routed to an expert that is not held adds
+    nothing: its rows sort behind the last held group and its weight
+    reaches no sum.  Where the layer has a ``capacity`` and the step's
+    held pairs fit it, only that many sorted rows are gathered, computed
+    and combined (``_capacity_rows``); otherwise all T·k are, and the
+    grouped matmuls skip the dead ones (``_all_rows``).  Either way no
+    pair is dropped.  ``load`` counts the pairs of every router output,
+    held or not."""
+    w_router, rest = params[0], tuple(params[1:])
+    num_experts, held_n = w_router.shape[0], rest[0].shape[0]
+    whole = held_n == num_experts  # every expert lives here: no pair is dead
+    with jax.named_scope(ROUTE_SCOPE):
+        logits, probs, weights, experts = route(
+            w_router, x, top_k, norm_topk_prob, scoring=scoring,
+            select_bias=select_bias, scale=scale)
+    flat, order, group_sizes, load = sort_pairs(
+        experts, num_experts, held_n, first_expert)
+    cap = capacity(order.shape[0], held_n, num_experts)
+    if cap:
+        y = _held_rows(x, weights, rest, flat, order, group_sizes, cap,
+                       expert_act)
+    else:
+        y = _all_rows(x, weights, rest, flat, order, group_sizes,
+                      live=not whole, expert_act=expert_act)
     return y, logits, probs, experts, load.astype(jnp.float32)
 
 
@@ -381,6 +580,10 @@ class MoELayer(Layer):
         # this chip's share: experts [first_expert, first_expert + held)
         self.first_expert = p.get_int("first_expert", 0)
         self.experts_held = p.get_int("experts_held", self.num_experts)
+        # sorted rows a share dispatches when its held pairs fit (0: the
+        # layer has one path); what Solver._fence_stats counts against,
+        # known once shapes are (init)
+        self.capacity = 0
         if self.expert_act not in ("relu", "swiglu"):
             raise ValueError(
                 f"{self.name}: unknown expert_act {self.expert_act!r} "
@@ -415,6 +618,8 @@ class MoELayer(Layer):
         D = in_shapes[0][-1]
         H = self.hidden_dim or 4 * D
         E, N = self.num_experts, self.experts_held
+        self.capacity = capacity(
+            math.prod(in_shapes[0][:-1]) * self.top_k, N, E)
         kg, k1, k2, k3 = jax.random.split(key, 4)
         w_router = fill(self.weight_filler, kg, (E, D))
         state = {"load": jnp.zeros((E,), jnp.float32)}

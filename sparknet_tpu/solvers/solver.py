@@ -28,6 +28,7 @@ from sparknet_tpu.common import Phase, get_config, root_key, step_key
 from sparknet_tpu.compiler.graph import Network, NetVars
 from sparknet_tpu.obs import get_recorder
 from sparknet_tpu.obs.recorder import Span
+from sparknet_tpu.ops.moe import takes_compact
 from sparknet_tpu.proto.text_format import Message, parse_file
 from sparknet_tpu.solvers.lr_policy import learning_rate
 from sparknet_tpu.solvers.updates import apply_update, init_slots
@@ -818,7 +819,10 @@ class Solver:
         experts of one layer, whose quotient is the mean.  Where a layer
         holds a share of its experts, or selects with a balancing bias:
         the layers counted, the pairs that landed on held experts over
-        all of them, and the bias's extremes.  A loss layer that keeps
+        all of them, how many of the layers ran that step at their
+        capacity (``ops/moe.py takes_compact``, the predicate the device
+        branched on, asked of the same ``load``), and the bias's
+        extremes.  A loss layer that keeps
         its ``value`` (``loss_param { keep_value: true }``) gives it under
         the layer's name.  The selective-scan layers (``ops/ssm.py``)
         keep no state between steps: the fence names how many there are,
@@ -845,9 +849,12 @@ class Solver:
                      moe_pairs=int(first.sum()), moe_experts=int(first.size))
         layers = [l for l in self.train_net.layers if l.name in loads]
         if any(l.experts_held < l.num_experts for l in layers):
-            stats.update(moe_layers=len(layers), moe_pairs_held=int(sum(
-                loads[l.name][l.first_expert:][:l.experts_held].sum()
-                for l in layers)))
+            held = [int(loads[l.name][l.first_expert:][:l.experts_held].sum())
+                    for l in layers]
+            stats.update(moe_layers=len(layers), moe_pairs_held=sum(held),
+                         moe_compact_layers=sum(
+                             bool(takes_compact(n, l.capacity))
+                             for n, l in zip(held, layers)))
         biases = [np.asarray(st["bias"]) for st in state.values()
                   if "bias" in st]
         if biases:

@@ -428,7 +428,8 @@ def test_tpunet_train_trains_joyai_from_prototxt_and_a_token_file(tmp_path):
 
 def test_the_fence_carries_the_new_counters():
     """After ``Solver.step``: pairs on held experts over the layers, the
-    bias's extremes, the MTP term of the loss (kept in its layer's state)."""
+    layers that ran at their capacity, the bias's extremes, the MTP term
+    of the loss (kept in its layer's state)."""
     solver = make_solver()
     solver.step(2, lambda it: batch_of(it))
     stats = solver._fence_stats()
@@ -439,6 +440,26 @@ def test_the_fence_carries_the_new_counters():
     assert stats["moe_bias_min"] == pytest.approx(-0.002)
     assert stats["moe_bias_max"] == pytest.approx(0.002)
     assert 3.0 < stats["mtp_loss"] < 6.0  # ~ln(97) at initialisation
+    # 4 of 16 experts: 6 x the level share is every pair, so no layer has
+    # a capacity and none is counted at one
+    assert [l.capacity for l in solver.train_net.layers
+            if l.type == "MoE"] == [0, 0, 0]
+    assert stats["moe_compact_layers"] == 0
+    # 4 of 128 on 1,024 pairs: a capacity of 512 rows, and the count is
+    # what the device's own predicate says of the fence's loads
+    wide = Solver(models.joyai_flash_solver(), models.joyai_flash(**dict(
+        TINY, seq_len=128, experts=128)))
+    wide.step(1, lambda it: {k: np.tile(v, (1, 4)) for k, v in
+                             batch_of(it).items()})
+    layers = [l for l in wide.train_net.layers if l.type == "MoE"]
+    assert [l.capacity for l in layers] == [512, 512, 512]
+    held = [int(np.asarray(wide.variables.state[l.name]["load"])[4:8].sum())
+            for l in layers]
+    stats = wide._fence_stats()
+    assert stats["moe_pairs_held"] == sum(held)
+    assert stats["moe_compact_layers"] == sum(
+        bool(moe.takes_compact(n, 512)) for n in held)
+    assert stats["moe_compact_layers"] >= 1
     # a whole layer without a bias keeps to the counters it had
     plain = Solver(models.olmoe_solver(), models.olmoe(
         batch=2, seq_len=32, vocab=97, hidden=64, heads=4, experts=8,

@@ -14,7 +14,8 @@ shape of an f32 expert matrix.  With megablox's own backward the same toy
 step has 18 (each matrix and both AdamW moments, into the transposed
 layout and back).  Both proofs live in this one file: the process that
 describes the topology holds libtpu, and a second file could land on
-another worker.  For the same reason the selective scan's kernels
+another worker.  For the same reason the share-holding layer's two
+compiled branches are counted here (PR 35), and the selective scan's kernels
 (``ops/ssm.py``, PR 33) are compiled for that v5e here, at the hybrid
 cell's widths: Mosaic refuses here what it would refuse on the chip.
 """
@@ -134,6 +135,35 @@ def test_compiled_step_copies_no_expert_matrix(v5e, bf16_compute,
     text = compiled.as_text()
     # the step did take the kernels: 3 matmuls x (gmm, gmm, tgmm)
     assert text.count("tpu_custom_call") >= 9
+    assert expert_copies.expert_copies(text, shapes) == []
+
+
+def test_compiled_share_step_branches_to_a_path_with_no_array_of_all_pairs(
+        v5e, bf16_compute, no_compile_cache):
+    """A toy JoyAI step (4 of 128 experts held, 2,048 pairs a layer, a
+    capacity of 512 rows) compiled for the v5e: each share-holding layer
+    is one ``conditional`` a pass, whose branch at the capacity holds no
+    [T·k, ·] and no [T, k, ·] array, while the branch over all rows does
+    (PR 35; ``tools/expert_copies.py pair_arrays``).  Its expert matrices
+    are copied as little as OLMoE's."""
+    toy = dict(batch=2, seq_len=256, vocab=512, hidden=D, heads=2,
+               q_lora_rank=64, kv_lora_rank=64, qk_nope_head_dim=32,
+               qk_rope_head_dim=16, v_head_dim=32, dense_dim=H, experts=128,
+               top_k=4, expert_dim=D, shared_dim=D, layers=2, experts_held=4,
+               first_expert=8)
+    net = Network(models.joyai_flash(**toy), Phase.TRAIN)
+    cfg = dataclasses.replace(models.joyai_flash_solver(), display=0)
+    compiled, variables = expert_copies.compile_step(
+        cfg, net, (toy["batch"], toy["seq_len"]), v5e)
+    assert [l.capacity for l in net.layers if l.TYPE == "MoE"] == [512, 512]
+    text = compiled.as_text()
+    pairs = toy["batch"] * toy["seq_len"] * toy["top_k"]
+    branches = expert_copies.pair_arrays(text, pairs, toy["top_k"])
+    assert len(branches) == 4  # two layers, forward and backward
+    for over_all_rows, at_capacity in branches:
+        assert at_capacity == 0 and over_all_rows >= 8
+    shapes = expert_copies.expert_shapes(net, variables)
+    assert shapes == {(4, D, D)}
     assert expert_copies.expert_copies(text, shapes) == []
 
 

@@ -9,7 +9,11 @@ Compiles the solo step program of a decoder configuration under
 nothing is materialized), and prints ``memory_analysis()`` and the
 ``copy`` instructions of the ENTRY computation whose result has the shape
 of an f32 expert matrix (a rank-3 blob of an ``MoE`` layer: the matrix
-itself or one of its AdamW moments); exit code 1 if there is one.  PR 31 found 18 such copies of
+itself or one of its AdamW moments); exit code 1 if there is one.  Since
+PR 35 it also counts, in each branch of the ``conditional``s a
+share-holding layer compiles to (one a pass), the arrays over ALL T·k
+(token, slot) pairs (``pair_arrays``): the branch at the layer's capacity
+must hold none (exit code 1 otherwise).  PR 31 found 18 such copies of
 537 MB in the OLMoE step and 90 of 50 MB in JoyAI's: XLA had folded the
 transpose megablox put behind its weight gradient into the layout of the
 update, and converted the matrix and both moments there and back in
@@ -128,6 +132,68 @@ def expert_copies(hlo_text: str, shapes) -> list[dict]:
     return found
 
 
+_COMPUTATION = re.compile(r"^(?:ENTRY )?%(?P<name>[\w.\-]+) \(.*\{$")
+_RESULT = re.compile(r"^\s*(?:ROOT\s+)?%[\w.\-]+ = \w+\[(?P<dims>[\d,]+)\]")
+_CALLED = re.compile(
+    r"(?:calls|to_apply|body|condition|true_computation|false_computation)="
+    r"%([\w.\-]+)|branch_computations=\{([^}]*)\}")
+
+
+def computations(hlo_text: str) -> dict[str, list[str]]:
+    """{computation: its instruction lines} of ``compiled.as_text()``."""
+    found, name = {}, None
+    for line in hlo_text.splitlines():
+        m = _COMPUTATION.match(line)
+        if m:
+            name = m["name"]
+            found[name] = []
+        elif line.rstrip() == "}":
+            name = None
+        elif name:
+            found[name].append(line)
+    return found
+
+
+def _callees(line: str) -> list[str]:
+    return [n.strip().lstrip("%") for one, many in _CALLED.findall(line)
+            for n in ([one] if one else many.split(","))]
+
+
+def pair_arrays(hlo_text: str, pairs: int, top_k: int) -> list[list[int]]:
+    """For each ``conditional`` of the compiled program whose branches
+    differ in it: per branch, the instructions (in the branch and in what
+    it calls) whose result is an array over ALL (token, slot) pairs:
+    [pairs, ·] or [pairs / top_k, top_k, ·].  ``ops/moe.py``'s share-holding
+    layer branches between a path at its capacity (branch 1) and the path
+    over all rows (branch 0), once a pass: the first must read 0."""
+    comps = computations(hlo_text)
+
+    def over_pairs(line):
+        m = _RESULT.match(line)
+        dims = [int(d) for d in m["dims"].split(",")] if m else []
+        return len(dims) >= 2 and (dims[0] == pairs or (
+            len(dims) == 3 and dims[:2] == [pairs // top_k, top_k]))
+
+    def count(name, seen):
+        if name in seen or name not in comps:
+            return 0
+        seen.add(name)
+        return sum(over_pairs(l) + sum(count(c, seen) for c in _callees(l))
+                   for l in comps[name])
+
+    found = []
+    for lines in comps.values():
+        for line in lines:
+            m = re.search(r" conditional\(.*branch_computations=\{([^}]*)\}",
+                          line)
+            if m:
+                counts = [count(n.strip().lstrip("%"), set())
+                          for n in m[1].split(",")]
+                if len(set(counts)) > 1:
+                    found.append(counts)
+    return found
+
+
 def report(label, compiled, hlo_text, copies, seconds) -> dict:
     m = compiled.memory_analysis()
     gb = lambda b: round(b / 1e9, 3)
@@ -171,12 +237,17 @@ def main() -> int:
     compiled, variables = compile_step(cfg, net, batch, v5e_chip())
     text = compiled.as_text()
     copies = expert_copies(text, expert_shapes(net, variables))
-    print(json.dumps(report(f"{a.config} solo step, {batch[0]} sequences",
-                            compiled, text, copies, time.time() - t0)),
-          flush=True)
+    row = report(f"{a.config} solo step, {batch[0]} sequences", compiled,
+                 text, copies, time.time() - t0)
+    # a share-holding layer's two paths: [all rows, at the capacity] a cond
+    shares = [l for l in net.layers if l.TYPE == "MoE" and l.capacity]
+    branches = pair_arrays(text, math.prod(batch) * shares[0].top_k,
+                           shares[0].top_k) if shares else []
+    row["pair_arrays_by_branch"] = branches
+    print(json.dumps(row), flush=True)
     for c in copies:
         print(json.dumps(c))
-    return 1 if copies else 0
+    return 1 if copies or any(b[1:] != [0] for b in branches) else 0
 
 
 if __name__ == "__main__":
