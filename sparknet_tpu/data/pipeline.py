@@ -951,6 +951,8 @@ def device_feed(pipeline: ProcessPipeline, sharding=None, depth: int = 2,
             feeds = device_fn(feeds, it_)
         return feeds
 
+    # the augment span's ``fused`` stat, where the device_fn has one
+    confirm.fused = getattr(device_fn, "fused", None)
     return DevicePrefetcher(
         data_fn, num_iters=pipeline.num_batches, sharding=sharding,
         depth=depth, start_iter=pipeline.start_index, device_fn=confirm)

@@ -133,8 +133,13 @@ class DevicePrefetcher:
                 ring = [_empty_like(host) for _ in range(RING)]
             del host
             if self._device_fn is not None:
+                # ``fused``: 1 where the batch takes the augment's one
+                # pass, 0 where it falls back (DeviceAugment.device_fn
+                # says; another device_fn carries no such stat)
+                fused = getattr(self._device_fn, "fused", None)
+                stat = {} if fused is None else {"fused": fused(feeds)}
                 with get_recorder().span("sn.feed.augment", host=True,
-                                         compile_stats=True, it=it):
+                                         compile_stats=True, it=it, **stat):
                     feeds = self._device_fn(feeds, it)
             if not feed.put(feeds, it):
                 return

@@ -17,7 +17,9 @@ describes the topology holds libtpu, and a second file could land on
 another worker.  For the same reason the share-holding layer's two
 compiled branches are counted here (PR 35), and the selective scan's kernels
 (``ops/ssm.py``, PR 33) are compiled for that v5e here, at the hybrid
-cell's widths: Mosaic refuses here what it would refuse on the chip.
+cell's widths: Mosaic refuses here what it would refuse on the chip; and
+the solo feed's augment (``data/device_transform.py``, PR 39) at the two
+CNN solo cells' shapes.
 """
 
 import dataclasses
@@ -189,3 +191,34 @@ def test_the_scan_kernels_compile_at_the_cell_widths(v5e, no_compile_cache,
     kept = ssm.saved_state_bytes(1, seq, d, n, ssm.TIME_BLOCK)
     temps = compiled.memory_analysis().temp_size_in_bytes
     assert kept <= temps < seq * d * n * 4 // 4
+
+
+@pytest.mark.parametrize("batch, crop", [(1024, 227), (256, 224)],
+                         ids=["alexnet-solo", "resnet50-solo"])
+def test_the_augment_is_one_kernel_and_no_copy(v5e, no_compile_cache, batch,
+                                               crop):
+    """The solo feed's one pass (``data/device_transform.py``, PR 39) at
+    the two CNN solo cells' shapes: Mosaic takes the kernel, and because
+    it writes the crop as the chip stores it (batch-minor) the program
+    is that kernel and nothing that moves an array the crop's size: no
+    ``copy``, under 1 MB of temporaries."""
+    from jax.sharding import SingleDeviceSharding
+
+    from sparknet_tpu.data.device_transform import DeviceAugment, crop_tiles
+    from sparknet_tpu.data.transform import TransformConfig
+
+    aug = DeviceAugment(TransformConfig(crop_size=crop, mirror=True,
+                                        mean_value=(104.0, 117.0, 123.0)),
+                        layout="nchw")
+    assert crop_tiles(batch, 256, 256, crop)
+    chip = SingleDeviceSharding(v5e)
+    compiled = jax.jit(lambda x, it: aug.fused(
+        x, jax.random.fold_in(jax.random.key(1234), it))).lower(
+        jax.ShapeDtypeStruct((batch, 3, 256, 256), jnp.uint8, sharding=chip),
+        jax.ShapeDtypeStruct((), jnp.int32, sharding=chip)).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 1
+    big = f"f32[{batch},3,{crop},{crop}]"
+    assert not [line for line in text.splitlines()
+                if " copy(" in line and big in line]
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20
