@@ -296,7 +296,7 @@ def require_chip(tool: str) -> dict:
 # compile-cache key.  Add a scope, add it here
 CACHE_SCOPES = ("scopes:L.<layer>,S.update,S.augment,"
                 "M.route,M.dispatch,M.experts,M.combine,M.shared,"
-                "A.core,A.latent,R.scan,R.gate")
+                "A.core,A.latent,R.scan,R.gate,LOOP.<region>")
 
 
 def enable_compile_cache() -> str:

@@ -119,6 +119,34 @@ class BlobInfo:
     dtype: Any
 
 
+# The device scope around a looped region, all passes: ``LOOP.<name>``
+# (the region's layers keep their own ``L.<name>`` scopes inside it, one
+# scope a layer with every pass under it); in common.CACHE_SCOPES
+LOOP_SCOPE = "LOOP."
+
+
+@dataclasses.dataclass(frozen=True)
+class LoopRegion:
+    """A net-level ``loop { name count first last carry_in carry_out
+    collect { blob top } }``: the consecutive layers ``first`` .. ``last``
+    (indices into ``Network.layers``) run ``count`` times on ONE set of
+    parameters.  Pass 1 reads the blob ``carry_in`` as the net made it;
+    pass t + 1 reads, under that name, what pass t left in ``carry_out``.
+    After the region ``carry_out`` holds the last pass's value and each
+    ``collect`` top every pass's value of its blob, pass-major along
+    axis 0 ([count * N, ...]: pass t's rows are [t N, (t + 1) N)), so the
+    layers behind the region see an ordinary [N, ...] blob.  The
+    region's other tops do not leave it."""
+
+    name: str
+    count: int
+    first: int
+    last: int
+    carry_in: str
+    carry_out: str
+    collect: tuple[tuple[str, str], ...]
+
+
 class Network:
     """A phase-specific compiled view of a NetParameter.
 
@@ -186,6 +214,57 @@ class Network:
                         self._shared_names[(lname, i)] = pname
                 else:
                     owners[pname] = (lname, i)
+        self.loops: list[LoopRegion] = self._loop_regions()
+        self._loop_of = {i: r for r in self.loops
+                         for i in range(r.first, r.last + 1)}
+
+    # -- looped regions (``loop { ... }``, no Caffe analog) -----------------
+    def _loop_regions(self) -> list[LoopRegion]:
+        """The net's ``loop`` messages against this phase's layers.  A
+        region none of whose layers are in the phase is not in it either."""
+        names = [l.name for l in self.layers]
+        out: list[LoopRegion] = []
+        for lm in self.net_param.get_all("loop"):
+            name = lm.get_str("name", "loop")
+            ends = [lm.get_str("first"), lm.get_str("last")]
+            if not any(e in names for e in ends):
+                continue
+            if not all(names.count(e) == 1 for e in ends):
+                raise ValueError(
+                    f"loop {name!r}: first / last {ends} must each name one "
+                    f"layer of the {self.phase.name} net")
+            first, last = (names.index(e) for e in ends)
+            count = lm.get_int("count", 1)
+            if first > last or count < 1:
+                raise ValueError(
+                    f"loop {name!r}: wants first <= last and count >= 1, "
+                    f"got layers #{first} .. #{last}, count {count}")
+            if out and first <= out[-1].last:
+                raise ValueError(
+                    f"loop {name!r} overlaps loop {out[-1].name!r} (regions "
+                    "are listed in the layers' order and do not nest)")
+            inside = self.layers[first:last + 1]
+            bad = [l.name for l in inside if isinstance(l, InputLayer)
+                   or l.IS_LOSS or any(w != 0.0 for w in l.loss_weights())]
+            if bad:
+                raise ValueError(
+                    f"loop {name!r}: input and loss layers stay outside a "
+                    f"looped region: {bad}")
+            region = LoopRegion(
+                name, count, first, last, lm.get_str("carry_in"),
+                lm.get_str("carry_out"),
+                tuple((c.get_str("blob"), c.get_str("top"))
+                      for c in lm.get_all("collect")))
+            tops = {t for l in inside for t in l.tops}
+            lost = [b for b in (region.carry_out,
+                                *(b for b, _ in region.collect))
+                    if b not in tops]
+            if lost or not region.carry_in:
+                raise ValueError(
+                    f"loop {name!r}: carry_in must be named, and carry_out "
+                    f"and every collected blob a top of the region: {lost}")
+            out.append(region)
+        return out
 
     # -- legacy net-level inputs (ref: net.cpp AppendTop "deprecated 4D input
     # dimensions" / input_shape) ------------------------------------------
@@ -247,6 +326,7 @@ class Network:
             blob[name] = jax.ShapeDtypeStruct(shapes[name], dtypes.get(name, jnp.float32))
         params: Params = {}
         state: State = {}
+        region_of = self._loop_of
         for idx, layer in enumerate(self.layers):
             sub = layer_key(key, idx)
             if isinstance(layer, InputLayer):
@@ -309,6 +389,11 @@ class Network:
                     raise ValueError(
                         f"two stateful layers share the name {layer.name!r}"
                     )
+                if idx in region_of:
+                    raise ValueError(
+                        f"layer {layer.name!r} keeps state and lies in the "
+                        f"looped region {region_of[idx].name!r}: a region's "
+                        "passes share parameters, not state")
                 state[layer.name] = s
             outs = self._abstract_apply(
                 layer,
@@ -318,6 +403,19 @@ class Network:
             )
             for top, o in zip(layer.tops, outs):
                 blob[top] = jax.ShapeDtypeStruct(o.shape, o.dtype)
+            region = region_of.get(idx)
+            if region is not None and idx == region.last:
+                # the shapes of one pass are the shapes of every pass
+                cin, cout = blob[region.carry_in], blob[region.carry_out]
+                if cin.shape != cout.shape:
+                    raise ValueError(
+                        f"loop {region.name!r}: carry_out {region.carry_out!r}"
+                        f" {cout.shape} must have the shape of carry_in "
+                        f"{region.carry_in!r} {cin.shape}")
+                for b, top in region.collect:
+                    blob[top] = jax.ShapeDtypeStruct(
+                        (region.count * blob[b].shape[0], *blob[b].shape[1:]),
+                        blob[b].dtype)
         self._blob_info = {k: BlobInfo(v.shape, v.dtype) for k, v in blob.items()}
         return NetVars(params=params, state=state)
 
@@ -446,15 +544,10 @@ class Network:
                     blob[name] = _cast(blob[name], jnp.bfloat16)
         new_state: State = {}
         total_loss = jnp.zeros((), jnp.float32)
-        for idx, layer in enumerate(self.layers):
-            if idx < si or idx > ei:
-                continue
-            sub = layer_key(rng, idx) if rng is not None else None
-            if isinstance(layer, InputLayer):
-                if getattr(layer, "SELF_FEEDING", False):
-                    for top, val in zip(layer.tops, layer.constant_values()):
-                        blob[top] = val
-                continue
+
+        def run(layer, blob, sub, sink):
+            """One layer on ``blob``: its tops land there, its state in
+            ``new_state``; returns its (weight, top) loss terms."""
             p = self._resolve_shared(
                 layer, variables.params.get(layer.name, []), variables.params
             )
@@ -507,15 +600,71 @@ class Network:
                 new_state[layer.name] = out_state
             for top, o in zip(layer.tops, out.outputs):
                 blob[top] = o
-                if debug_sink is not None and o.size:
-                    debug_sink[(layer.name, top)] = jnp.mean(jnp.abs(o))
-            for w, o in zip(layer.loss_weights(), out.outputs):
-                if w != 0.0:
-                    total_loss = total_loss + w * jnp.sum(o).astype(jnp.float32)
+                if sink is not None and o.size:
+                    sink[(layer.name, top)] = jnp.mean(jnp.abs(o))
+            return [(w, o) for w, o in zip(layer.loss_weights(), out.outputs)
+                    if w != 0.0]
+
+        for idx, layer in enumerate(self.layers):
+            if idx < si or idx > ei:
+                continue
+            region = self._loop_of.get(idx)
+            if region is not None:  # the whole region runs at its first layer
+                if (idx == si and idx != region.first) or ei < region.last:
+                    raise ValueError(
+                        f"a partial run starts or ends at {layer.name!r}, "
+                        f"inside the looped region {region.name!r}")
+                if idx == region.first:
+                    self._apply_loop(region, blob, run, rng, debug_sink)
+                continue
+            sub = layer_key(rng, idx) if rng is not None else None
+            if isinstance(layer, InputLayer):
+                if getattr(layer, "SELF_FEEDING", False):
+                    for top, val in zip(layer.tops, layer.constant_values()):
+                        blob[top] = val
+                continue
+            for w, o in run(layer, blob, sub, debug_sink):
+                total_loss = total_loss + w * jnp.sum(o).astype(jnp.float32)
         # carry forward unmodified state so the pytree structure is stable
         for lname, s in variables.state.items():
             new_state.setdefault(lname, s)
         return blob, new_state, total_loss
+
+    def _apply_loop(self, region: LoopRegion, blob, run, rng, sink) -> None:
+        """The region's layers, ``count`` times, EXPANDED here: declared
+        once (one set of parameters in the prototxt, ``NetVars.params``, a
+        snapshot and the optimizer), traced once a pass, so a looped
+        blob's gradient is autodiff's sum over the passes.  ``run`` is
+        ``apply``'s one-layer step; every pass sees the blobs made before
+        the region.
+
+        One ``lax.scan`` over the passes (each block ONCE in the program)
+        was built first and measured (PERF.md section 6, PR 41): scan
+        keeps every residual of its body stacked [count, ...], also those
+        XLA recomputes for nothing in the straight-line program (the f32
+        copy of each norm's input, SiLU's factors): at the benchmark's
+        looped decoder 13.3 GB of temporaries against 7.1, which does
+        not fit the chip, and at half the length a step 22 % slower."""
+        layers = list(enumerate(self.layers))[region.first:region.last + 1]
+        if region.carry_in not in blob:
+            raise ValueError(
+                f"loop {region.name!r} needs blob {region.carry_in!r}")
+        carry = blob[region.carry_in]
+        kept: list[list] = [[] for _ in region.collect]
+        with jax.named_scope(LOOP_SCOPE + region.name.replace("/", ".")):
+            for t in range(region.count):
+                local = dict(blob)
+                local[region.carry_in] = carry
+                for idx, layer in layers:
+                    sub = None if rng is None else jax.random.fold_in(
+                        layer_key(rng, idx), t)
+                    run(layer, local, sub, sink)
+                carry = local[region.carry_out]
+                for rows, (b, _) in zip(kept, region.collect):
+                    rows.append(local[b])
+            for rows, (_, top) in zip(kept, region.collect):
+                blob[top] = jnp.concatenate(rows, axis=0)
+        blob[region.carry_out] = carry
 
     # ------------------------------------------------------------------
     def param_specs_for(self, variables: NetVars) -> dict[str, list[ParamSpec]]:

@@ -288,6 +288,20 @@ def SoftmaxWithLoss(
     return m
 
 
+def ExitWeightedLossLayer(
+    name: str, bottoms: Sequence[str], steps: int,
+    entropy_weight: float = 0.0,
+    tops: Sequence[str] = ("loss", "step_loss", "exit_mean_step"),
+) -> Message:
+    """The exit-weighted loss of a looped model (ops/loss.py
+    ExitWeightedLoss): bottoms = [the ``steps`` passes' logits, label,
+    the exit gate's logits]; the first top is the loss, the others are
+    read-outs at weight 0."""
+    m = _layer(name, "ExitWeightedLoss", bottoms, tops)
+    return m.set("exit_loss_param", Message().set("steps", steps).set(
+        "entropy_weight", entropy_weight))
+
+
 def AccuracyLayer(
     name: str,
     bottoms: Sequence[str],
@@ -557,9 +571,28 @@ def MoELayer(
     return m.set("moe_param", p)
 
 
-def NetParam(name: str, *layers: Message) -> Message:
-    """Aggregate layers into a NetParameter (ref: Layers.scala:130-137)."""
+def LoopRegion(name: str, count: int, first: str, last: str,
+               carry_in: str, carry_out: str,
+               collect: Sequence[tuple[str, str]] = ()) -> Message:
+    """A net-level ``loop`` (compiler/graph.py LoopRegion): the layers
+    ``first`` .. ``last`` run ``count`` times on one set of parameters,
+    ``carry_out`` of a pass is ``carry_in`` of the next, and each
+    ``(blob, top)`` of ``collect`` leaves the region as the blob's value
+    in every pass, pass-major along axis 0."""
+    m = Message().set("name", name).set("count", count)
+    m.set("first", first).set("last", last)
+    m.set("carry_in", carry_in).set("carry_out", carry_out)
+    for blob, top in collect:
+        m.add("collect", Message().set("blob", blob).set("top", top))
+    return m
+
+
+def NetParam(name: str, *layers: Message, loops: Sequence[Message] = ()) -> Message:
+    """Aggregate layers (and looped regions) into a NetParameter
+    (ref: Layers.scala:130-137)."""
     net = Message().set("name", name)
     for l in layers:
         net.add("layer", l)
+    for r in loops:
+        net.add("loop", r)
     return net

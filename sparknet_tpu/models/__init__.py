@@ -41,6 +41,8 @@ from sparknet_tpu.models.zoo import (  # noqa: F401
     joyai_flash_solver,
     olmoe,
     olmoe_solver,
+    ouro,
+    ouro_solver,
     phi4_flash,
     phi4_flash_lambda_init,
     phi4_flash_role,

@@ -31,12 +31,14 @@ from sparknet_tpu.layers_dsl import (
     EltwiseLayer,
     EmbedLayer,
     EuclideanLossLayer,
+    ExitWeightedLossLayer,
     FlattenLayer,
     GatedMemoryUnitLayer,
     GatedMLPLayer,
     InnerProductLayer,
     LatentAttentionLayer,
     LayerNormLayer,
+    LoopRegion,
     LRNLayer,
     MambaLayer,
     MoELayer,
@@ -1397,6 +1399,97 @@ def phi4_flash_solver() -> SolverConfig:
 
 
 # ---------------------------------------------------------------------------
+# Ouro — a looped language model (ByteDance, "Scaling Latent Reasoning via
+# Looped Language Models", 2025; ByteDance/Ouro-2.6B config.json,
+# ``model_type: ouro``; no reference analog).  ONE stack of decoder blocks
+# runs ``ut_steps`` times on the same weights: h_0 = Embed(tokens),
+# h_t = N_f(Stack(h_{t-1})), the final RMSNorm inside the loop.  A block
+# has four RMSNorms ("sandwich"): a = x + N2(Attn(N1(x))),
+# y = a + N4(MLP(N3(a))); attention without biases or QK-norm, rotate-half
+# RoPE, a SwiGLU MLP.  Behind the loop one exit gate (Linear hidden -> 1,
+# shared over the steps) and the untied head read the state of EVERY
+# step, and the loss is the expected cross-entropy under the gate's
+# distribution over exit steps with an entropy regulariser
+# (ops/loss.py ExitWeightedLoss).  The stack is declared once, as a looped
+# region of the layer graph (compiler/graph.py LoopRegion).
+# ---------------------------------------------------------------------------
+def ouro(
+    batch: int = 1,
+    seq_len: int = 4096,
+    vocab: int = 49152,
+    hidden: int = 2048,
+    heads: int = 16,
+    mlp_dim: int = 5632,
+    layers: int = 48,
+    ut_steps: int = 4,
+    rms_norm_eps: float = 1e-6,
+    rope_theta: float = 1e6,
+    entropy_weight: float = 0.1,
+    init_std: float = 0.02,
+) -> Message:
+    """Ouro-2.6B at its published sizes by default: [batch, seq_len]
+    token ids -> the next-token logits of every one of the ``ut_steps``
+    passes (``lm_head``, [ut_steps * batch, seq_len, vocab], pass-major)
+    and the exit gate's (``exit_gate``).  ``loss`` is the exit-weighted
+    loss; ``step_loss`` [ut_steps] and ``exit_mean_step`` are its
+    read-outs at weight 0.  Block i's layers are ``norm<i>a``, ``attn<i>``,
+    ``norm<i>b``, ``res<i>a``, ``norm<i>c``, ``mlp<i>``, ``norm<i>d``,
+    ``res<i>b``; the region ``ut`` spans ``norm0a`` .. ``norm_f`` and its
+    collected top is ``states``."""
+    init = _gauss(init_std)
+    norm = lambda name, bottom: RMSNormLayer(name, [bottom], eps=rms_norm_eps)
+    net = [
+        RDDLayer("data", shape=[batch, seq_len]),
+        RDDLayer("label", shape=[batch, seq_len]),
+        EmbedLayer("embed", ["data"], input_dim=vocab, num_output=hidden,
+                   weight_filler=init, bias_term=False, top="embed"),
+    ]
+    x = "embed"
+    for i in range(layers):
+        net += [
+            norm(f"norm{i}a", x),
+            MultiHeadAttentionLayer(
+                f"attn{i}", [f"norm{i}a"], num_heads=heads, causal=True,
+                rope=True, rope_theta=rope_theta, bias_term=False,
+                weight_filler=init),
+            norm(f"norm{i}b", f"attn{i}"),
+            EltwiseLayer(f"res{i}a", [x, f"norm{i}b"], top=f"res{i}a"),
+            norm(f"norm{i}c", f"res{i}a"),
+            GatedMLPLayer(f"mlp{i}", [f"norm{i}c"], mlp_dim,
+                          weight_filler=init),
+            norm(f"norm{i}d", f"mlp{i}"),
+            EltwiseLayer(f"res{i}b", [f"res{i}a", f"norm{i}d"],
+                         top=f"res{i}b"),
+        ]
+        x = f"res{i}b"
+    net += [
+        norm("norm_f", x),
+        InnerProductLayer("exit_gate", ["states"], num_output=1, axis=2,
+                          weight_filler=init),
+        InnerProductLayer("lm_head", ["states"], num_output=vocab, axis=2,
+                          weight_filler=init, bias_term=False),
+        ExitWeightedLossLayer("loss", ["lm_head", "label", "exit_gate"],
+                              steps=ut_steps, entropy_weight=entropy_weight),
+    ]
+    loop = LoopRegion("ut", ut_steps, "norm0a", "norm_f", carry_in="embed",
+                      carry_out="norm_f", collect=[("norm_f", "states")])
+    return NetParam("Ouro", *net, loops=[loop])
+
+
+def ouro_solver() -> SolverConfig:
+    """AdamW, lr 3e-4, betas 0.9 / 0.95, eps 1e-8, decoupled weight decay
+    0.1 on every parameter, gradient clipping at global norm 1.0, a FIXED
+    lr: the report's recipe as remembered, every value an assumption the
+    benchmark's configuration file lists.  The schedule is left to the
+    prototxt's lr_policy."""
+    return SolverConfig(
+        base_lr=3e-4, lr_policy="fixed", momentum=0.9, momentum2=0.95,
+        delta=1e-8, weight_decay=0.1, clip_gradients=1.0,
+        max_iter=10000, solver_type="AdamW", display=100,
+    )
+
+
+# ---------------------------------------------------------------------------
 # Cached per-token decode step (ISSUE 19, ROADMAP item 4).
 #
 # The rectangle decode path (serve/continuous.py) rebuilds the FULL
@@ -1452,6 +1545,11 @@ def decode_spec(network, end: str = "fc") -> DecodeSpec:
     from sparknet_tpu.ops.data_layers import InputLayer
     from sparknet_tpu.ops.neuron import ReLU
 
+    if network.loops:
+        raise ValueError(
+            f"net {network.name!r} has a looped region "
+            f"({network.loops[0].name!r}): the cached decode step keeps one "
+            "set of keys and values a layer, not one a pass")
     ei = network.layer_index(end)
     head = network.layers[ei]
     if not isinstance(head, InnerProduct) or head.lp.get_msg(

@@ -102,6 +102,76 @@ class SoftmaxWithLoss(_LossBase):
             outs, {"value": loss} if self._keeps_value() else {})
 
 
+def exit_distribution(gate_logits):
+    """[T, ...] exit-gate logits -> log p [T, ...], the distribution over
+    exit steps of a looped model: with lambda_t = sigmoid(g_t),
+    p_t = lambda_t prod_{j<t} (1 - lambda_j) for t < T and
+    p_T = prod_{j<T} (1 - lambda_j) (step T takes what is left; its own
+    gate is not read).  From log sigmoid(g) and log sigmoid(-g), never
+    from a product of rounded probabilities."""
+    stay = jax.nn.log_sigmoid(-gate_logits[:-1])  # log (1 - lambda_j), j < T
+    before = jnp.concatenate(
+        [jnp.zeros_like(gate_logits[:1]), jnp.cumsum(stay, axis=0)], axis=0)
+    return before + jnp.concatenate(
+        [jax.nn.log_sigmoid(gate_logits[:-1]),
+         jnp.zeros_like(gate_logits[:1])], axis=0)
+
+
+@register
+class ExitWeightedLoss(_LossBase):
+    """The training loss of a looped language model with an exit gate
+    (Ouro / LoopLM, "Scaling Latent Reasoning via Looped Language Models",
+    section 3, Stage I): the expected next-token cross-entropy under the
+    distribution over exit steps, with an entropy regulariser,
+
+        loss = mean_tokens[ sum_t p_t L_t - beta H(p) ],
+        H(p) = -sum_t p_t log p_t.
+
+    ``exit_loss_param { steps: T  entropy_weight: beta }``.  Bottoms: the
+    logits of the T passes, pass-major along axis 0 ([T N, S, V], as a
+    looped region collects them); the labels [N, S]; the exit gate's
+    logits [T N, S, 1].  Tops: ``loss``; at weight 0 the T mean per-step
+    cross-entropies [T] and the mean exit step, mean_tokens sum_t t p_t
+    (1-based).  The two weight-0 tops are kept in the layer's state too
+    (``step_loss`` / ``exit_mean_step``) for the fence's span
+    (``Solver._fence_stats``).  f32 throughout; the backward is jax's."""
+
+    TYPE = "ExitWeightedLoss"
+
+    def _conf(self):
+        p = self.lp.get_msg("exit_loss_param")
+        return p.get_int("steps", 1), p.get_float("entropy_weight", 0.0)
+
+    def init(self, key, in_shapes):
+        steps, _ = self._conf()
+        if in_shapes[0][0] != steps * in_shapes[1][0]:
+            raise ValueError(
+                f"{self.name}: {in_shapes[0][0]} rows of logits are not "
+                f"{steps} passes of the labels' {in_shapes[1][0]}")
+        return [], {"step_loss": jnp.zeros((steps,), jnp.float32),
+                    "exit_mean_step": jnp.zeros((), jnp.float32)}
+
+    def apply(self, params, state, inputs, *, train, rng=None):
+        steps, beta = self._conf()
+        logits, label, gate = inputs
+        lab = label.astype(jnp.int32)
+        logits = logits.reshape((steps, *lab.shape, logits.shape[-1]))
+        picked = jnp.take_along_axis(
+            logits, jnp.broadcast_to(lab, logits.shape[:-1])[..., None],
+            axis=-1)[..., 0]
+        nll = jax.nn.logsumexp(logits, axis=-1) - picked  # L_t, [T, N, S]
+        logp = exit_distribution(gate.reshape((steps, *lab.shape)))
+        p = jnp.exp(logp)
+        entropy = -jnp.sum(p * logp, axis=0)
+        loss = jnp.mean(jnp.sum(p * nll, axis=0) - beta * entropy)
+        step_loss = jnp.mean(nll, axis=tuple(range(1, nll.ndim)))
+        order = jnp.arange(1, steps + 1, dtype=jnp.float32).reshape(
+            (steps,) + (1,) * lab.ndim)
+        exit_mean = jnp.mean(jnp.sum(order * p, axis=0))
+        kept = {"step_loss": step_loss, "exit_mean_step": exit_mean}
+        return LayerOutput([loss, step_loss, exit_mean][:len(self.tops)], kept)
+
+
 @register
 class EuclideanLoss(_LossBase):
     """0.5/N * sum((a-b)^2) (ref: euclidean_loss_layer.cpp)."""

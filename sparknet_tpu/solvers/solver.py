@@ -830,10 +830,21 @@ class Solver:
         as the ``lax.scan`` loop form: on the CPU all of them), and, of
         the path that runs, the steps between two of the states the
         forward keeps for the backward and the bytes of those states
-        over the layers."""
+        over the layers.  A net with a looped region
+        (``compiler/graph.py LoopRegion``): the passes of the region
+        (``ut_steps``) and, where the exit-weighted loss kept them, the
+        mean cross-entropy of every pass (``ut_loss_<t>``, 1-based) and
+        the mean exit step of the last step before the fence."""
         state = self.variables.state
         stats = {name: float(st["value"]) for name, st in state.items()
                  if "value" in st}
+        if self.train_net.loops:
+            stats["ut_steps"] = self.train_net.loops[0].count
+            for st in state.values():
+                if "step_loss" in st:
+                    stats.update({f"ut_loss_{t}": float(v) for t, v in
+                                  enumerate(np.asarray(st["step_loss"]), 1)})
+                    stats["exit_mean_step"] = float(st["exit_mean_step"])
         scans = [l for l in self.train_net.layers if l.type == "Mamba"]
         if scans:
             stats.update(ssm_layers=len(scans),
