@@ -21,8 +21,8 @@ exportable through every weight path (caffemodel, HDF5, orbax).
 [q_norm (E), k_norm (E)], RMSNorm weights applied to the whole E-wide q
 and k projections before the head split (the OLMoE / OLMo-2 QK-norm,
 ``qk_norm_eps``); ``rope_theta`` is the rotary base.  The attention core
-(:func:`attention_core`) is chosen by shape and platform: long
-sequences on a TPU run jax's pallas flash kernels, the rest
+(:func:`attention_core`) is chosen by shape and platform: long causal
+sequences on a TPU run jax's pallas splash kernels, the rest
 :func:`flash_attention` (XLA by default; ``SPARKNET_ATTN_IMPL`` still
 selects the repo's own pallas forward there).
 
@@ -129,7 +129,7 @@ def _sp_attention(mesh, impl, q, k, v, causal):
 
 
 def rope(x: jax.Array, base: float = 10000.0,
-         interleave: bool = False) -> jax.Array:
+         interleave: bool = False, scale: float = 1.0) -> jax.Array:
     """Rotary position embedding over ``x`` [B, H, S, D] (D even).
 
     Parameter-free absolute-position encoding with the relative-position
@@ -144,6 +144,8 @@ def rope(x: jax.Array, base: float = 10000.0,
     (rotate-half, the Llama / OLMoE weight layout) and features
     (2i, 2i + 1) with ``interleave`` (the DeepSeek-V3 family's published
     layout, ``rope_interleave``); each feature stays where it was.
+    ``scale`` multiplies the result inside the f32 product, before its
+    one rounding to ``x``'s dtype (:func:`attention_core`'s ``scaled``).
     """
     B, H, S, D = x.shape
     if D % 2:
@@ -152,6 +154,8 @@ def rope(x: jax.Array, base: float = 10000.0,
     theta = base ** (-jnp.arange(half, dtype=jnp.float32) / half)  # [half]
     ang = jnp.arange(S, dtype=jnp.float32)[:, None] * theta[None, :]  # [S,half]
     cos, sin = jnp.cos(ang), jnp.sin(ang)
+    if scale != 1.0:
+        cos, sin = cos * scale, sin * scale
     if interleave:
         x1, x2 = x[..., 0::2], x[..., 1::2]
         return jnp.stack(
@@ -193,44 +197,57 @@ def rope_at(x: jax.Array, positions: jax.Array,
 CORE_SCOPE = "A.core"
 
 
-def attention_core(q, k, v, causal: bool, window: int = 0):
-    """Softmax attention over q [B, H, S, D], k [B, Hk, S, D] and v
-    [B, Hv, S, Dv] -> [B, H, S, Dv], chosen by shape and platform; the
-    scores are scaled by ``D ** -0.5``, D the key width.  Grouped heads:
-    Hk divides H and Hv divides Hk, query head h reads key head
-    ``h // (H / Hk)`` and value head ``h // (H / Hv)``.  ``window`` > 0
-    (causal only): query t sees keys t - window + 1 .. t.
+def core_kernel(backend: str, S: int, D: int, Dv: int, causal: bool) -> str:
+    """The kernel :func:`attention_core` runs over S tokens with keys of D
+    and values of Dv, read off the backend and the shapes: ``splash``
+    (jax's pallas splash attention, which never holds the [B, H, S, S]
+    scores: 1 GB per 4k sequence of 16 heads in f32) for a causal core
+    from S = 2048 on a TPU whose blocks tile, else ``xla`` (the scores
+    materialized).  Head counts and a window change the mask and the
+    grouping, not the kernel."""
+    tiles = S % 512 == 0 and D % 64 == 0 and Dv % 128 == 0
+    long_on_tpu = backend == "tpu" and S >= 2048
+    return "splash" if long_on_tpu and causal and tiles else "xla"
 
-    From S = 2048 on a TPU, kernels that never hold the [B, H, S, S]
-    scores (1 GB per 4k sequence of 16 heads in f32), forward and
-    backward.  Equal widths (a multiple of 128), equal head counts, no
-    window: jax's own pallas ``flash_attention``.  Timed once on the v5e
-    at 4 x 16 x 4096 x 128, causal, forward + backward (PERF.md section
-    6): 14.2 ms at 1024-wide blocks against 25.5 ms for an XLA loop over
-    query blocks with remat.
-    A value width that differs from the key width (latent attention: keys
-    of 192, values of 128): jax's own pallas splash attention, the one
-    kernel here that takes them as they are.  Timed once on the v5e at
-    1 x 32 x 4096, keys 192, values 128, causal, forward + backward
-    (PERF.md section 6): 7.41 ms at 1024-wide blocks with the fused
-    backward kernel (8.35 ms with separate dq and dkv kernels) against
-    12.82 ms for the flash kernels with all three padded to 256 and
-    49.9 ms for an XLA loop over query blocks with remat.
-    Keys of 64 under values of 128 with grouped heads and, in some
-    layers, a window (differential attention: 40 query heads on 20 key
-    and 10 value heads): the same splash kernels in their grouped form,
-    one key head and the query heads that read it a call, so that no key
-    head is repeated in HBM (a value head is written out once per key
-    head that reads it: the kernel takes one value head a key head); the
-    window is a ``LocalMask`` and 512-wide blocks, so that the blocks the
-    window never reaches are skipped.  Timed once on the v5e at
-    1 x 40 x 2048, keys 64, values 128, forward + backward (PERF.md
-    section 6, PR 32): full causal 1.75 ms at 1024-wide blocks (1.79 at
-    512) against 9.59 ms for the XLA formulation; under a window of 512
-    1.45 ms at 512-wide blocks (1.75 at 1024) against 9.58 ms.
+
+def attention_core(q, k, v, causal: bool, window: int = 0,
+                   scaled: bool = False):
+    """Softmax attention over q [B, H, S, D], k [B, Hk, S, D] and v
+    [B, Hv, S, Dv] -> [B, H, S, Dv]; the scores are scaled by
+    ``D ** -0.5``, D the key width.  Grouped heads: Hk divides H and Hv
+    divides Hk, query head h reads key head ``h // (H / Hk)`` and value
+    head ``h // (H / Hv)``.  ``window`` > 0 (causal only): query t sees
+    keys t - window + 1 .. t.  ``scaled``: q carries the ``D ** -0.5``
+    already (only where :func:`core_kernel` says ``splash``: those
+    kernels score q kᵀ as given, so q is scaled before them and rounded
+    once more, unless its layer folded the scale into an f32 value q
+    passed through anyway, as RoPE's).
+
+    ONE kernel family for every long causal core (:func:`core_kernel`):
+    jax's splash kernels with the fused backward (dq, dk and dv from one
+    pass over the scores), at equal widths (OLMoE, Ouro: 16 heads of
+    128), with values narrower than keys (latent attention: keys of 192,
+    values of 128) and in their grouped form with, in some layers, a
+    window (differential attention: 40 query heads of 64 on 20 key and 10
+    value heads; one key head and the query heads that read it a call, so
+    that no key head is repeated in HBM).  Blocks are 1024 wide, 512
+    where S is no multiple of 1024 or under a window (a ``LocalMask``:
+    the blocks the window never reaches are skipped).  Timed alone on the
+    v5e (TPU v5 lite), forward + backward, bf16, causal (PERF.md section
+    6; ``tools/attn_core_kernel.py``, ``benchmarks/scratch/hybrid_kernels.py``):
+    1 x 16 x 4096 x 128: 2.13 ms at 1024-wide blocks (2.36 at 512, 2.62
+    with separate dq and dkv kernels; jax's three-kernel pallas
+    ``flash_attention``, which this shape ran until PR 43, 3.26);
+    4 x 16 x 4096 x 128: 8.96 ms (10.32, 11.12; 14.31).
+    1 x 32 x 4096, keys 192, values 128: 7.41 ms (8.35 with separate
+    kernels; an XLA loop over query blocks with remat 49.9).
+    1 x 40 x 2048, keys 64, values 128, grouped: 1.75 ms (1.79 at 512; the
+    XLA formulation 9.59); under a window of 512 1.45 ms at 512-wide
+    blocks (1.75 at 1024; XLA 9.58).
     Everything else takes the XLA formulation, which materializes the
-    scores: through :func:`flash_attention` at equal widths and head
-    counts without a window."""
+    scores (no cell has a long core that is not causal): through
+    :func:`flash_attention` at equal widths and head counts without a
+    window."""
     H, S, D = q.shape[1:]
     Dv = v.shape[3]
     if window and not causal:
@@ -240,25 +257,14 @@ def attention_core(q, k, v, causal: bool, window: int = 0):
                          f"{v.shape[1]} value heads: each must divide the "
                          "one before")
     window = 0 if window >= S else window
-    block = next((b for b in (1024, 512) if S % b == 0), 0)
-    long_on_tpu = jax.default_backend() == "tpu" and S >= 2048 and block
-    plain = not window and k.shape[1] == H and v.shape[1] == H
-    if D != Dv or not plain:
-        if not (long_on_tpu and causal and D % 64 == 0 and Dv % 128 == 0):
-            return _attention_xla(q, k, v, causal, window)
-        return _splash_causal(q, k, v, min(block, 512) if window else block,
-                              window)
-    if not (long_on_tpu and D % 128 == 0):
-        return flash_attention(q, k, v, causal=causal)
-    from jax.experimental.pallas.ops.tpu import flash_attention as fa
-
-    sizes = fa.BlockSizes(
-        block_q=block, block_k_major=block, block_k=block, block_b=1,
-        block_q_major_dkv=block, block_k_major_dkv=block,
-        block_k_dkv=block, block_q_dkv=block,
-        block_k_major_dq=block, block_k_dq=block, block_q_dq=block)
-    return fa.flash_attention(q, k, v, causal=causal, sm_scale=D ** -0.5,
-                              block_sizes=sizes)
+    if core_kernel(jax.default_backend(), S, D, Dv, causal) == "splash":
+        block = 512 if window or S % 1024 else 1024
+        return _splash_causal(q, k, v, block, window, scaled)
+    if scaled:
+        raise ValueError("the XLA formulation scales the scores itself")
+    if D != Dv or window or not k.shape[1] == v.shape[1] == H:
+        return _attention_xla(q, k, v, causal, window)
+    return flash_attention(q, k, v, causal=causal)
 
 
 def _attention_xla(q, k, v, causal: bool, window: int):
@@ -280,10 +286,13 @@ def _attention_xla(q, k, v, causal: bool, window: int):
                       v.astype(jnp.float32)).astype(q.dtype)
 
 
-def _splash_causal(q, k, v, block: int, window: int = 0):
-    """Causal attention with unequal key and value widths through jax's
-    splash attention kernels (forward, dq and dkv); with fewer key heads
-    than query heads, through their grouped form."""
+def _splash_causal(q, k, v, block: int, window: int = 0,
+                   scaled: bool = False, interpret: bool = False):
+    """Causal attention through jax's splash attention kernels (forward
+    and ONE backward kernel for dq, dk and dv), whatever the key and
+    value widths; with fewer key heads than query heads, through their
+    grouped form.  ``interpret`` runs them in Pallas's interpreter (the
+    tests, on the CPU)."""
     from jax.experimental.pallas.ops.tpu.splash_attention import (
         splash_attention_kernel as sk, splash_attention_mask as sm)
 
@@ -296,22 +305,40 @@ def _splash_causal(q, k, v, block: int, window: int = 0):
         block_q_dkv=block, block_kv_dkv=block, block_kv_dkv_compute=block,
         use_fused_bwd_kernel=True)
     # the kernel takes [H, S, D] and scores q kᵀ as given: scale q first
-    q = (q * D ** -0.5).astype(q.dtype)
+    if not scaled:
+        q = (q * D ** -0.5).astype(q.dtype)
     if Hk == H and v.shape[1] == H:
         kernel = sk.make_splash_mha_single_device(
-            sm.MultiHeadMask([seen] * H), block_sizes=sizes)
+            sm.MultiHeadMask([seen] * H), block_sizes=sizes,
+            interpret=interpret)
         return jax.vmap(kernel)(q, k, v)
     # one key head and its H / Hk query heads a call
     kernel = sk.make_splash_mqa_single_device(
-        sm.MultiHeadMask([seen] * (H // Hk)), block_sizes=sizes)
+        sm.MultiHeadMask([seen] * (H // Hk)), block_sizes=sizes,
+        interpret=interpret)
     v = jnp.repeat(v, Hk // v.shape[1], axis=1)
     grouped = q.reshape((q.shape[0], Hk, H // Hk, S, D))
     o = jax.vmap(jax.vmap(kernel))(grouped, k, v)
     return o.reshape((q.shape[0], H, S, v.shape[3]))
 
 
+class AttentionLayer(Layer):
+    """A layer whose token mixer is :func:`attention_core`.  ``kernel`` is
+    what its last trace ran the core as (:func:`core_kernel`'s name, or
+    the sequence-parallel implementation's), for ``Solver._fence_stats``;
+    empty until the layer is traced."""
+
+    kernel = ""
+
+    def _core(self, q, k, v, causal: bool, window: int = 0):
+        self.kernel = core_kernel(jax.default_backend(), q.shape[2],
+                                  q.shape[3], v.shape[3], causal)
+        with jax.named_scope(CORE_SCOPE):
+            return attention_core(q, k, v, causal, window)
+
+
 @register
-class MultiHeadAttentionLayer(Layer):
+class MultiHeadAttentionLayer(AttentionLayer):
     TYPE = "MultiHeadAttention"
 
     def __init__(self, lp, phase):
@@ -370,9 +397,6 @@ class MultiHeadAttentionLayer(Layer):
         # [B, S, E] -> [B, H, S, D]
         split = lambda t: t.reshape(B, S, H, D).transpose(0, 2, 1, 3)
         q, k, v = split(q), split(k), split(v)
-        if self.rope:
-            # global positions — before any sequence-parallel split
-            q, k = rope(q, self.rope_theta), rope(k, self.rope_theta)
         sp = active_sequence_parallel()
         if sp is not None and S % sp[0].shape[get_config().seq_axis] != 0:
             # ring/Ulysses need equal sequence blocks; an indivisible S
@@ -386,11 +410,20 @@ class MultiHeadAttentionLayer(Layer):
                 stacklevel=2,
             )
             sp = None
+        self.kernel = sp[1] if sp is not None else core_kernel(
+            jax.default_backend(), S, D, D, self.causal)
+        # the splash kernels score q kᵀ as given: their scale rides RoPE's
+        # f32 tables, where it costs q no rounding of its own
+        fold = self.rope and self.kernel == "splash"
+        if self.rope:
+            # global positions — before any sequence-parallel split
+            q = rope(q, self.rope_theta, scale=D ** -0.5 if fold else 1.0)
+            k = rope(k, self.rope_theta)
         with jax.named_scope(CORE_SCOPE):
             if sp is not None:
                 o = _sp_attention(sp[0], sp[1], q, k, v, self.causal)
             else:
-                o = attention_core(q, k, v, self.causal)
+                o = attention_core(q, k, v, self.causal, scaled=fold)
         o = o.transpose(0, 2, 1, 3).reshape(B, S, E)
         y = jnp.einsum("bse,fe->bsf", o, w_out)
         if self.bias_term:
@@ -405,7 +438,7 @@ LATENT_SCOPE = "A.latent"
 
 
 @register
-class LatentAttentionLayer(Layer):
+class LatentAttentionLayer(AttentionLayer):
     """Multi-head latent attention (MLA; DeepSeek-V2 arXiv:2405.04434
     section 2.1, as the DeepSeek-V3 family's ``config.json`` sizes it), the
     training form: the compressed latents are expanded to full heads and
@@ -484,16 +517,15 @@ class LatentAttentionLayer(Layer):
             k = jnp.concatenate(
                 [kv[..., :nope], jnp.broadcast_to(k_rope, (B, H, S, rd))], -1)
             v = kv[..., nope:]
-        with jax.named_scope(CORE_SCOPE):
-            # scaled by (nope + rd) ** -0.5, the whole key's width
-            o = attention_core(q, k, v, self.causal)
+        # scaled by (nope + rd) ** -0.5, the whole key's width
+        o = self._core(q, k, v, self.causal)
         with jax.named_scope(LATENT_SCOPE):
             y = o.transpose(0, 2, 1, 3).reshape(B, S, H * vd) @ w_o.T
         return LayerOutput(outputs=[y])
 
 
 @register
-class DifferentialAttentionLayer(Layer):
+class DifferentialAttentionLayer(AttentionLayer):
     """Differential attention with grouped heads (Ye et al. 2024,
     arXiv:2410.05258, in its two-maps-over-doubled-values form, as the
     SambaY decoder uses it, arXiv:2507.06607), self or cross.
@@ -579,8 +611,7 @@ class DifferentialAttentionLayer(Layer):
         # (g, r, i), which reads key head 2g + r and value head g
         q = proj[..., :H * D].reshape(B, S, Hk // 2, rep, 2, D)
         q = q.transpose(0, 2, 4, 3, 1, 5).reshape(B, H, S, D)
-        with jax.named_scope(CORE_SCOPE):
-            a = attention_core(q, k, v, True, self.window)
+        a = self._core(q, k, v, True, self.window)
         a = a.reshape(B, Hk // 2, 2, rep, S, 2 * D).astype(jnp.float32)
         lam = (jnp.exp(jnp.sum(lq1 * lk1)) - jnp.exp(jnp.sum(lq2 * lk2))
                + self.lambda_init).astype(jnp.float32)

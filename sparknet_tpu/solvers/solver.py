@@ -28,6 +28,7 @@ from sparknet_tpu.common import Phase, get_config, root_key, step_key
 from sparknet_tpu.compiler.graph import Network, NetVars
 from sparknet_tpu.obs import get_recorder
 from sparknet_tpu.obs.recorder import Span
+from sparknet_tpu.ops.attention import AttentionLayer
 from sparknet_tpu.ops.moe import takes_compact
 from sparknet_tpu.proto.text_format import Message, parse_file
 from sparknet_tpu.solvers.lr_policy import learning_rate
@@ -830,7 +831,11 @@ class Solver:
         as the ``lax.scan`` loop form: on the CPU all of them), and, of
         the path that runs, the steps between two of the states the
         forward keeps for the backward and the bytes of those states
-        over the layers.  A net with a looped region
+        over the layers.  The attention layers (``ops/attention.py``)
+        likewise: how many there are, and how many of them ran their core
+        as the splash kernels at their last trace (the others in the XLA
+        formulation or sequence-parallel: on the CPU none).  A net with a
+        looped region
         (``compiler/graph.py LoopRegion``): the passes of the region
         (``ut_steps``) and, where the exit-weighted loss kept them, the
         mean cross-entropy of every pass (``ut_loss_<t>``, 1-based) and
@@ -851,6 +856,11 @@ class Solver:
                          ssm_kernel_layers=sum(l.kernel for l in scans),
                          ssm_chunk=scans[0].chunk,
                          ssm_saved_bytes=sum(l.saved_bytes for l in scans))
+        cores = [l for l in self.train_net.layers
+                 if isinstance(l, AttentionLayer)]
+        if cores:
+            stats.update(attn_core_layers=len(cores), attn_kernel_layers=sum(
+                l.kernel == "splash" for l in cores))
         loads = {name: np.asarray(st["load"]) for name, st in state.items()
                  if "load" in st}
         if not loads:

@@ -464,5 +464,6 @@ def test_the_fence_carries_the_new_counters():
     plain = Solver(models.olmoe_solver(), models.olmoe(
         batch=2, seq_len=32, vocab=97, hidden=64, heads=4, experts=8,
         top_k=2, expert_dim=32, layers=1))
-    assert set(plain._fence_stats()) == {"moe_load_max", "moe_pairs",
-                                         "moe_experts"}
+    assert set(plain._fence_stats()) == {
+        "moe_load_max", "moe_pairs", "moe_experts", "attn_core_layers",
+        "attn_kernel_layers"}

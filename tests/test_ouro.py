@@ -461,15 +461,17 @@ def test_the_fence_carries_the_loop(both):
     solver.step(2, lambda it: batch_of(it))
     stats = solver._fence_stats()
     assert set(stats) == {"ut_steps", "ut_loss_1", "ut_loss_2", "ut_loss_3",
-                          "exit_mean_step"}
+                          "exit_mean_step", "attn_core_layers",
+                          "attn_kernel_layers"}
     assert stats["ut_steps"] == 3 and 1.0 < stats["exit_mean_step"] < 3.0
     assert all(4.0 < stats[f"ut_loss_{t}"] < 5.5 for t in (1, 2, 3))
     # a net without a region prints none of these keys
     plain = Solver(models.olmoe_solver(), models.olmoe(
         batch=2, seq_len=32, vocab=97, hidden=64, heads=4, experts=8,
         top_k=2, expert_dim=32, layers=1))
-    assert set(plain._fence_stats()) == {"moe_load_max", "moe_pairs",
-                                         "moe_experts"}
+    assert set(plain._fence_stats()) == {
+        "moe_load_max", "moe_pairs", "moe_experts", "attn_core_layers",
+        "attn_kernel_layers"}
 
 
 def test_training_lowers_the_loss_and_parallel_replicas_agree():

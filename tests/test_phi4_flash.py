@@ -510,15 +510,17 @@ def test_the_fence_carries_the_new_counters():
     solver.step(2, lambda it: batch_of(it))
     stats = solver._fence_stats()
     assert stats == {"ssm_layers": 2, "ssm_kernel_layers": 0, "ssm_chunk": 32,
-                     "ssm_saved_bytes": 2 * 1 * 2 * 4 * 128 * 4}
+                     "ssm_saved_bytes": 2 * 1 * 2 * 4 * 128 * 4,
+                     "attn_core_layers": 4, "attn_kernel_layers": 0}
     assert ssm.chunking(2048) == (64, 32)
     assert ssm.saved_state_bytes(1, 2048, 5120, 16) == 32 * 16 * 5120 * 4
     # a net without a scan layer keeps to the counters it had
     plain = Solver(models.olmoe_solver(), models.olmoe(
         batch=2, seq_len=32, vocab=97, hidden=64, heads=4, experts=8,
         top_k=2, expert_dim=32, layers=1))
-    assert set(plain._fence_stats()) == {"moe_load_max", "moe_pairs",
-                                         "moe_experts"}
+    assert set(plain._fence_stats()) == {
+        "moe_load_max", "moe_pairs", "moe_experts", "attn_core_layers",
+        "attn_kernel_layers"}
 
 
 def test_decode_spec_refuses_the_new_layers():
