@@ -18,8 +18,8 @@ Input/output blobs are [B, S, E].  Params follow Caffe blob order:
 [W_qkv (3E, E), b_qkv (3E), W_out (E, E), b_out (E)] — importable/
 exportable through every weight path (caffemodel, HDF5, orbax).
 ``bias_term: false`` drops the two biases; ``qk_norm: true`` appends
-[q_norm (E), k_norm (E)], RMSNorm weights applied to the whole E-wide q
-and k projections before the head split (the OLMoE / OLMo-2 QK-norm,
+[q_norm (E), k_norm (E)], RMSNorm weights applied to a token's whole
+E-wide q and k projections (the OLMoE / OLMo-2 QK-norm,
 ``qk_norm_eps``); ``rope_theta`` is the rotary base.  The attention core
 (:func:`attention_core`) is chosen by shape and platform: long causal
 sequences on a TPU run jax's pallas splash kernels, the rest
@@ -213,7 +213,10 @@ def core_kernel(backend: str, S: int, D: int, Dv: int, causal: bool) -> str:
 def attention_core(q, k, v, causal: bool, window: int = 0,
                    scaled: bool = False):
     """Softmax attention over q [B, H, S, D], k [B, Hk, S, D] and v
-    [B, Hv, S, Dv] -> [B, H, S, Dv]; the scores are scaled by
+    [B, Hv, S, Dv] -> [B, H, S, Dv], head-major in and out: the layout
+    the kernels fix.  ``MultiHeadAttentionLayer``'s projections write q,
+    k, v so and read o so; the two other layers still transpose around
+    the core.  The scores are scaled by
     ``D ** -0.5``, D the key width.  Grouped heads: Hk divides H and Hv
     divides Hk, query head h reads key head ``h // (H / Hk)`` and value
     head ``h // (H / Hv)``.  ``window`` > 0 (causal only): query t sees
@@ -339,6 +342,21 @@ class AttentionLayer(Layer):
 
 @register
 class MultiHeadAttentionLayer(AttentionLayer):
+    """Multi-head self-attention, [B, S, E] -> [B, S, E] (the module
+    docstring has the prototxt surface and the blobs).
+
+    The head-major layout [B, H, S, D] the core takes is made BY THE
+    PROJECTIONS (PR 44): x is contracted with the three [H, D, E] views
+    of ``w_qkv`` as it is stored straight into q, k and v [B, H, S, D],
+    and o [B, H, S, D] is contracted over (H, D) with ``w_out`` viewed
+    [E, H, D].  Bias ([3, H, 1, D]), QK-norm (the mean of squares over
+    axes (H, D): a token's whole projection; the weight [H, 1, D]) and
+    RoPE act on the head-major value.  No token-major q, k, v or o
+    ([B, S, E], [B, S, H, D]) exists, forward or backward: each was a
+    full-size transposing ``copy`` around the kernels, ten a layer-pass
+    (``tools/expert_copies.py activation_copies``).  One path for every
+    backend, shape and option, the sequence-parallel branch included."""
+
     TYPE = "MultiHeadAttention"
 
     def __init__(self, lp, phase):
@@ -383,20 +401,20 @@ class MultiHeadAttentionLayer(AttentionLayer):
             w_qkv, b_qkv, w_out, b_out = params[:4]
         else:
             w_qkv, w_out = params[:2]
-        B, S, E = x.shape
+        S, E = x.shape[1:]
         H = self.num_heads
         D = E // H
-        qkv = jnp.einsum("bse,fe->bsf", x, w_qkv)  # [B, S, 3E]
+        # the head split rides the projections: x against the [H, D, E]
+        # views of w_qkv, straight into [B, H, S, D]
+        q, k, v = (jnp.einsum("bse,hde->bhsd", x, w)
+                   for w in w_qkv.reshape(3, H, D, E))
         if self.bias_term:
-            qkv = qkv + b_qkv
-        q, k, v = jnp.split(qkv, 3, axis=-1)
+            q, k, v = (t + b for t, b in
+                       zip((q, k, v), b_qkv.reshape(3, H, 1, D)))
         if self.qk_norm:
-            # over the whole E-wide projection, before the head split
-            q = rms_norm(q, params[-2], self.qk_norm_eps)
-            k = rms_norm(k, params[-1], self.qk_norm_eps)
-        # [B, S, E] -> [B, H, S, D]
-        split = lambda t: t.reshape(B, S, H, D).transpose(0, 2, 1, 3)
-        q, k, v = split(q), split(k), split(v)
+            # over a token's whole E-wide projection: axes (H, D) here
+            q, k = (rms_norm(t, w.reshape(H, 1, D), self.qk_norm_eps, (1, 3))
+                    for t, w in ((q, params[-2]), (k, params[-1])))
         sp = active_sequence_parallel()
         if sp is not None and S % sp[0].shape[get_config().seq_axis] != 0:
             # ring/Ulysses need equal sequence blocks; an indivisible S
@@ -424,8 +442,8 @@ class MultiHeadAttentionLayer(AttentionLayer):
                 o = _sp_attention(sp[0], sp[1], q, k, v, self.causal)
             else:
                 o = attention_core(q, k, v, self.causal, scaled=fold)
-        o = o.transpose(0, 2, 1, 3).reshape(B, S, E)
-        y = jnp.einsum("bse,fe->bsf", o, w_out)
+        # and the merge the output projection: over (H, D) of o as it is
+        y = jnp.einsum("bhsd,fhd->bsf", o, w_out.reshape(E, H, D))
         if self.bias_term:
             y = y + b_out
         return LayerOutput(outputs=[y])

@@ -574,11 +574,12 @@ class RMSNorm(Layer):
             self.lp.get_msg("rms_norm_param").get_float("eps", 1e-5))])
 
 
-def rms_norm(x, weight, eps: float):
+def rms_norm(x, weight, eps: float, axis=-1):
     """The RMSNorm layer's arithmetic (also the q/k norm of
-    ``MultiHeadAttention``)."""
+    ``MultiHeadAttention``, whose features lie on two axes of a
+    head-major value; ``weight`` broadcasts against ``x``)."""
     xf = x.astype(jnp.float32)
-    inv = jax.lax.rsqrt(jnp.mean(jnp.square(xf), axis=-1, keepdims=True) + eps)
+    inv = jax.lax.rsqrt(jnp.mean(jnp.square(xf), axis=axis, keepdims=True) + eps)
     return (xf * inv).astype(x.dtype) * weight.astype(x.dtype)
 
 

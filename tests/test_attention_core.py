@@ -208,3 +208,94 @@ def test_the_xla_formulation_refuses_a_scaled_q():
     q = jnp.zeros((1, 2, 64, 128), jnp.float32)
     with pytest.raises(ValueError, match="scales the scores itself"):
         attention.attention_core(q, q, q, True, scaled=True)
+
+
+def mha_layer(attention_param: str):
+    from sparknet_tpu.common import Phase
+    from sparknet_tpu.ops.registry import create_layer
+    from sparknet_tpu.proto.text_format import parse
+
+    return create_layer(parse(
+        'layer { name: "a" type: "MultiHeadAttention" bottom: "x" top: "y" '
+        f"attention_param {{ {attention_param} }} }}").get_all("layer")[0],
+        Phase.TRAIN)
+
+
+def token_major_layer(layer, params, x):
+    """``MultiHeadAttentionLayer.apply`` as it was until PR 44, written
+    out: ONE token-major projection to [B, S, 3E], bias and QK-norm on the
+    E-wide values, then ``reshape(B, S, H, D).transpose(0, 2, 1, 3)`` for
+    q, k and v and the transpose back for o."""
+    from sparknet_tpu.ops.blocks import rms_norm
+
+    if layer.bias_term:
+        w_qkv, b_qkv, w_out, b_out = params[:4]
+    else:
+        (w_qkv, w_out), b_qkv, b_out = params[:2], 0.0, 0.0
+    B, S, E = x.shape
+    H = layer.num_heads
+    q, k, v = jnp.split(jnp.einsum("bse,fe->bsf", x, w_qkv) + b_qkv, 3, -1)
+    if layer.qk_norm:
+        q = rms_norm(q, params[-2], layer.qk_norm_eps)
+        k = rms_norm(k, params[-1], layer.qk_norm_eps)
+    q, k, v = (t.reshape(B, S, H, E // H).transpose(0, 2, 1, 3)
+               for t in (q, k, v))
+    if layer.rope:
+        q = attention.rope(q, layer.rope_theta)
+        k = attention.rope(k, layer.rope_theta)
+    o = attention.attention_core(q, k, v, layer.causal)
+    o = o.transpose(0, 2, 1, 3).reshape(B, S, E)
+    return jnp.einsum("bse,fe->bsf", o, w_out) + b_out
+
+
+@pytest.mark.parametrize("causal", [True, False], ids=["causal", "full"])
+@pytest.mark.parametrize("with_rope", [True, False], ids=["rope", "norope"])
+@pytest.mark.parametrize("qk_norm", [True, False], ids=["qknorm", "nonorm"])
+@pytest.mark.parametrize("bias", [True, False], ids=["bias", "nobias"])
+def test_the_head_major_projections_are_the_token_major_layer(
+        bias, qk_norm, with_rope, causal):
+    """The head split and merge ride the projection matmuls (PR 44): the
+    same products in the same sums as the token-major layer, so outputs
+    and the gradients of x and of every blob agree to f32 rounding, and
+    the blobs keep their shapes (every wire format reads them)."""
+    B, S, E, H = 2, 24, 48, 4
+    flag = lambda b: "true" if b else "false"
+    layer = mha_layer(
+        f"num_heads: {H} causal: {flag(causal)} rope: {flag(with_rope)} "
+        f"rope_theta: 500.0 bias_term: {flag(bias)} qk_norm: {flag(qk_norm)}")
+    params, _ = layer.init(jax.random.key(0), [(B, S, E)])
+    shapes = [(3 * E, E)] + [(3 * E,)] * bias + [(E, E)] + [(E,)] * bias
+    assert [p.shape for p in params] == shapes + [(E,), (E,)] * qk_norm
+    # off their initial zeros and ones, so every blob's gradient is live
+    keys = jax.random.split(jax.random.key(1), len(params) + 2)
+    params = [p + 0.3 * jax.random.normal(k, p.shape, jnp.float32)
+              if p.ndim == 1 else p for p, k in zip(params, keys)]
+    x = jax.random.normal(keys[-2], (B, S, E), jnp.float32)
+    dy = jax.random.normal(keys[-1], (B, S, E), jnp.float32)
+
+    def both(fn):
+        y, vjp = jax.vjp(fn, params, x)
+        return (y,) + tuple(jax.tree_util.tree_leaves(vjp(dy)))
+
+    got = both(lambda p, x: layer.apply(p, {}, [x], train=True).outputs[0])
+    want = both(lambda p, x: token_major_layer(layer, p, x))
+    assert len(got) == len(params) + 2
+    for g, w in zip(got, want):
+        assert g.shape == w.shape and g.dtype == w.dtype
+        assert rel(g, w) <= 1e-5
+
+
+def test_no_value_of_the_layer_is_token_major_by_heads():
+    """No [B, S, H, D] value in the layer's forward jaxpr: q, k, v leave
+    the projections as [B, H, S, D] and o enters the output projection
+    so."""
+    layer = mha_layer("num_heads: 4 causal: true rope: true qk_norm: true "
+                      "bias_term: false")
+    params, _ = layer.init(jax.random.key(0), [(2, 16, 32)])
+    jaxpr = jax.make_jaxpr(
+        lambda p, x: layer.apply(p, {}, [x], train=True).outputs[0])(
+        params, jnp.zeros((2, 16, 32)))
+    shapes = {tuple(v.aval.shape) for e in jaxpr.eqns for v in e.outvars}
+    assert (2, 16, 4, 8) not in shapes
+    assert "transpose(0, 2, 1, 3)" not in inspect.getsource(
+        attention.MultiHeadAttentionLayer)

@@ -19,7 +19,8 @@ compiled branches are counted here (PR 35), and the selective scan's kernels
 (``ops/ssm.py``, PR 33) are compiled for that v5e here, at the hybrid
 cell's widths: Mosaic refuses here what it would refuse on the chip; and
 the solo feed's augment (``data/device_transform.py``, PR 39) at the two
-CNN solo cells' shapes.
+CNN solo cells' shapes; and toy multi-head decoder steps hold no copy of
+an activation between token-major and head-major (PR 44).
 """
 
 import dataclasses
@@ -167,6 +168,43 @@ def test_compiled_share_step_branches_to_a_path_with_no_array_of_all_pairs(
     shapes = expert_copies.expert_shapes(net, variables)
     assert shapes == {(4, D, D)}
     assert expert_copies.expert_copies(text, shapes) == []
+
+
+def toy_olmoe(B, S, E, heads):
+    return (models.olmoe(batch=B, seq_len=S, vocab=512, hidden=E, heads=heads,
+                         experts=G, top_k=2, expert_dim=H, layers=1),
+            models.olmoe_solver(), 1)
+
+
+def toy_ouro(B, S, E, heads):
+    return (models.ouro(batch=B, seq_len=S, vocab=512, hidden=E, heads=heads,
+                        mlp_dim=512, layers=1, ut_steps=2),
+            models.ouro_solver(), 2)
+
+
+@pytest.mark.parametrize("toy", [toy_olmoe, toy_ouro],
+                         ids=["qk_norm", "no_qk_norm_looped"])
+def test_compiled_step_copies_no_activation_between_head_layouts(
+        v5e, bf16_compute, no_compile_cache, toy):
+    """Multi-head attention's head split and merge ride its projection
+    matmuls (PR 44): a toy step at lane-aligned widths (2 heads of 128)
+    and 2,048 tokens, whose core takes the splash kernels as the cells'
+    do, compiled for the v5e holds NO ``copy`` of activation size shaped
+    [B, S, E] or [B, S, H, D], with QK-norm (OLMoE's block) and without
+    (Ouro's, looped twice).  With the token-major projections and the
+    ``reshape(B, S, H, D).transpose(0, 2, 1, 3)`` around the core the same
+    steps hold eight such copies a layer-pass (ten at the cells' widths)."""
+    B, S, E, heads = 2, 2048, 256, 2
+    net_param, solver, passes = toy(B, S, E, heads)
+    net = Network(net_param, Phase.TRAIN)
+    cfg = dataclasses.replace(solver, display=0)
+    compiled, _ = expert_copies.compile_step(cfg, net, (B, S), v5e)
+    text = compiled.as_text()
+    # forward and the fused backward, once a pass
+    assert text.count("tpu_custom_call") >= 2 * passes
+    copies = expert_copies.activation_copies(text, B * S * E)
+    token_major = {f"bf16[{B},{S},{E}]", f"bf16[{B},{S},{heads},{E // heads}]"}
+    assert not token_major & set(copies), copies
 
 
 @pytest.mark.parametrize("seq", [2048, 2000], ids=["whole", "padded"])

@@ -1,8 +1,9 @@
-"""Count the whole-array copies of expert matrices in a compiled train
-step, without a chip (on-chip-measurement guide, section 2).
+"""Count the whole-array copies of expert matrices and of activations in a
+compiled train step, without a chip (on-chip-measurement guide, section 2).
 
     JAX_PLATFORMS=cpu python tools/expert_copies.py \
-        [--config olmoe-1b-7b-l1-bf16 | joyai-llm-flash-l5-ep32-bf16]
+        [--config olmoe-1b-7b-l1-bf16 | joyai-llm-flash-l5-ep32-bf16 |
+                  ouro-2.6b-l4-ut4-v8-bf16 | phi4-mini-flash-l6-v8-bf16]
 
 Compiles the solo step program of a decoder configuration under
 ``benchmarks/configs/`` at full size for a described v5e (abstract state:
@@ -13,13 +14,20 @@ itself or one of its AdamW moments); exit code 1 if there is one.  Since
 PR 35 it also counts, in each branch of the ``conditional``s a
 share-holding layer compiles to (one a pass), the arrays over ALL T·k
 (token, slot) pairs (``pair_arrays``): the branch at the layer's capacity
-must hold none (exit code 1 otherwise).  PR 31 found 18 such copies of
-537 MB in the OLMoE step and 90 of 50 MB in JoyAI's: XLA had folded the
+must hold none (exit code 1 otherwise).  PR 31 found 18 expert copies
+of 537 MB in the OLMoE step and 90 of 50 MB in JoyAI's: XLA had folded the
 transpose megablox put behind its weight gradient into the layout of the
 update, and converted the matrix and both moments there and back in
 every step (``ops/moe.py grouped_matmul``).  The count is 0 while the
 gradient arrives as the matrices are stored;
 ``tests/test_moe_grad_layout.py`` holds a toy step to that.
+
+Since PR 44 it prints ``activation_copies`` too: the ENTRY ``copy``
+results of at least ``sequences x seq_len x hidden`` elements in the
+compute dtype, by shape (no part of the exit code).  Multi-head
+attention's head split and merge were ten of them a layer-pass, 160 in
+Ouro's step and 14 in OLMoE's; the same test file holds toy steps to
+none between token-major and head-major.
 
 The program picks its kernels by ``jax.default_backend()``, which says
 "cpu" here, so ``lowering_for_tpu`` points it at "tpu" for the lowering;
@@ -132,6 +140,24 @@ def expert_copies(hlo_text: str, shapes) -> list[dict]:
     return found
 
 
+def activation_copies(hlo_text: str, elements: int,
+                      dtype: str = "bf16") -> dict[str, int]:
+    """{shape: count} of the ENTRY computation's ``copy`` instructions
+    whose result is a ``dtype`` (the compute dtype) array of at least
+    ``elements`` elements: an activation passes through HBM once more
+    for each."""
+    found: dict[str, int] = {}
+    for line in entry_computation(hlo_text):
+        m = _COPY.match(line)
+        if not m or m["dtype"] != dtype:
+            continue
+        dims = [int(d) for d in m["dims"].split(",") if d]
+        if math.prod(dims) >= elements:
+            shape = f"{dtype}{dims}".replace(" ", "")
+            found[shape] = found.get(shape, 0) + 1
+    return found
+
+
 _COMPUTATION = re.compile(r"^(?:ENTRY )?%(?P<name>[\w.\-]+) \(.*\{$")
 _RESULT = re.compile(r"^\s*(?:ROOT\s+)?%[\w.\-]+ = \w+\[(?P<dims>[\d,]+)\]")
 _CALLED = re.compile(
@@ -194,13 +220,15 @@ def pair_arrays(hlo_text: str, pairs: int, top_k: int) -> list[list[int]]:
     return found
 
 
-def report(label, compiled, hlo_text, copies, seconds) -> dict:
+def report(label, compiled, hlo_text, copies, activations, seconds) -> dict:
     m = compiled.memory_analysis()
     gb = lambda b: round(b / 1e9, 3)
     return {
         "program": label, "compile_s": round(seconds, 1),
         "expert_copies": len(copies),
         "expert_copy_gb": gb(sum(c["bytes"] for c in copies)),
+        "activation_copies": sum(activations.values()),
+        "activation_copies_by_shape": activations,
         "argument_gb": gb(m.argument_size_in_bytes),
         "output_gb": gb(m.output_size_in_bytes),
         "alias_gb": gb(m.alias_size_in_bytes),
@@ -237,8 +265,10 @@ def main() -> int:
     compiled, variables = compile_step(cfg, net, batch, v5e_chip())
     text = compiled.as_text()
     copies = expert_copies(text, expert_shapes(net, variables))
+    activations = activation_copies(
+        text, math.prod(batch) * config["hidden_size"])
     row = report(f"{a.config} solo step, {batch[0]} sequences", compiled,
-                 text, copies, time.time() - t0)
+                 text, copies, activations, time.time() - t0)
     # a share-holding layer's two paths: [all rows, at the capacity] a cond
     shares = [l for l in net.layers if l.TYPE == "MoE" and l.capacity]
     branches = pair_arrays(text, math.prod(batch) * shares[0].top_k,
