@@ -125,20 +125,9 @@ def _build_step(batch: int, model: str, crop: int, dtype_name: str,
     # roofline itself.  Off by default: f32 master weights are the
     # accuracy-safe mixed-precision design.
     param_bf16 = os.environ.get("SPARKNET_BENCH_PARAM_DTYPE", "f32") == "bf16"
-    # A/B knob: one-pass fused optimizer update (Config.fused_update —
-    # solvers/arena.py flat arenas + ops/pallas_kernels.fused_update).
-    # The update chain's params+slots re-streaming is a bytes-bound
-    # slice of the step; the fused sweep reads/writes each arena byte
-    # once.  SPARKNET_BENCH_STORAGE_DTYPE=bf16 adds the bf16-storage
-    # arm (arenas in bf16, f32 register math — the bf16-params lever on
-    # a vehicle XLA cannot re-materialize).  Both off by default: the
-    # default path is bit-identical to every banked manifest.
-    fused = os.environ.get("SPARKNET_BENCH_FUSED", "0") == "1"
     set_config(
         compute_dtype=jnp.bfloat16 if dtype_name == "bf16" else jnp.float32,
         param_dtype=jnp.bfloat16 if param_bf16 else jnp.float32,
-        fused_update=fused,
-        storage_dtype=os.environ.get("SPARKNET_BENCH_STORAGE_DTYPE", "f32"),
     )
 
     net_param = getattr(models, model)(batch)
@@ -304,13 +293,6 @@ def measured_run(batch: int, iters: int, warmup: int, model: str, crop: int,
         rec["scan"] = scan  # iterations fused per dispatch
     if os.environ.get("SPARKNET_BENCH_PARAM_DTYPE", "f32") == "bf16":
         rec["param_dtype"] = "bf16"
-    if os.environ.get("SPARKNET_BENCH_FUSED", "0") == "1":
-        # A/B provenance: a fused-update record must never be mistaken
-        # for the headline (same rule as the layout/param_dtype stamps)
-        rec["fused_update"] = True
-        storage = os.environ.get("SPARKNET_BENCH_STORAGE_DTYPE", "f32")
-        if storage != "f32":
-            rec["storage_dtype"] = storage
     remat_env = os.environ.get("SPARKNET_BENCH_REMAT", "0")
     if remat_env not in ("", "0"):
         # "1" is the legacy boolean = the "full" policy; names are

@@ -35,7 +35,6 @@ The last stdout line of a passing chip run is
 from __future__ import annotations
 
 import argparse
-import dataclasses
 import glob
 import json
 import os
@@ -58,14 +57,13 @@ FULL = dict(batch=256, crop=227, classes=1000, warm=3, steps=5,
             # (B, S, d_inner, d_state): the selective scan, 4 time blocks
             # of 2 d-blocks
             scan=[(2, 256, 1024, 16)],
-            arena="alexnet", kernel_impl="pallas")
+            kernel_impl="pallas")
 REHEARSAL = dict(batch=4, crop=67, classes=10, warm=1, steps=5,
                  lrn=[("norm1", (2, 16, 9, 9))],
                  flash=[((1, 2, 160, 16), "float32")],
                  paged=[(3, 8, 2, 8, 16, 3)],
                  scan=[(1, 24, 256, 8)],
-                 # the interpreter walks the tile grid one cell at a time
-                 arena="cifar10_quick", kernel_impl="interpret")
+                 kernel_impl="interpret")
 
 _prefix = ""
 
@@ -371,11 +369,7 @@ def phase_kernels(size: dict) -> None:
     import jax.numpy as jnp
     import numpy as np
 
-    from sparknet_tpu import models
-    from sparknet_tpu.common import Phase
-    from sparknet_tpu.compiler.graph import Network
     from sparknet_tpu.ops import pallas_kernels as pk
-    from sparknet_tpu.solvers import arena
 
     impl = size["kernel_impl"]
     failures = []
@@ -423,28 +417,6 @@ def phase_kernels(size: dict) -> None:
         lambda t: jnp.sum(lrn(f)(t).astype(jnp.float32) ** 2))
     compare(f"lrn {lname} fwd+bwd bfloat16", lrn_grad(impl), lrn_grad("xla"),
             (x,), 2e-2)
-
-    # fused optimizer sweep over the AlexNet arena: SGD as the solver runs
-    # it, and Adam for the two-slot rule
-    net = Network(getattr(models, size["arena"])(size["batch"]), Phase.TRAIN)
-    shapes = jax.eval_shape(lambda: net.init(jax.random.key(0)))
-    specs = net.param_specs_for(shapes)
-    for rule in ("SGD", "Adam"):
-        cfg = dataclasses.replace(
-            getattr(models, size["arena"] + "_solver")(), solver_type=rule)
-        layout = arena.build_layout(shapes.params, specs, cfg,
-                                    storage_dtype="f32")
-        T = layout.total
-        w = normal((T,), scale=0.01)
-        g = normal((T,), scale=0.001)
-        hs = [jnp.abs(normal((T,), scale=1e-4))
-              for _ in range(layout.n_slots)]
-        upd = lambda f: (lambda w, g, hs: arena.arena_apply_update(  # noqa: E731
-            cfg, layout, w, g, hs, 0.01, 3, force=f)[0])
-        compare(f"fused_update {rule} {size['arena']} arena {T} "
-                f"({layout.n_tiles} tiles)",
-                upd(impl), upd("xla"), (w, g, hs), 1e-6)
-        del w, g, hs
 
     for (B, H, S, D), dt in size["flash"]:
         q, k, v = (normal((B, H, S, D), dt) for _ in range(3))

@@ -6,10 +6,9 @@ al., ICLR 2016, PAPER.md).  This module states that byte bill as
 checkable arithmetic, the fifth analysis surface beside source
 (graftlint), wire (graphcheck/comm_model), memory (memcheck/mem_model)
 and host-plane concurrency (conccheck): per train step, where every
-HBM byte goes — params read and written, grads, optimizer slots via
-the arena geometry, activations saved for the backward out of the
-jaxpr liveness walk, collective bytes from ``comm_model``, feed wire
-bytes — so the ``bytes`` engine can audit the lowered programs against
+HBM byte goes — params read and written, grads, optimizer slots,
+activations saved for the backward out of the jaxpr liveness walk,
+collective bytes from ``comm_model``, feed wire bytes — so the ``bytes`` engine can audit the lowered programs against
 the model with zero chip time, and the remat schedule search can price
 candidate ``jax.checkpoint`` policies BEFORE any of them burns chip
 time (the TensorFlow line of work's memory/recompute scheduling as a

@@ -118,7 +118,6 @@ BYTE_SOURCE_PATTERNS = (
     "sparknet_tpu/ops/layout.py",
     "sparknet_tpu/solvers/solver.py",
     "sparknet_tpu/solvers/updates.py",
-    "sparknet_tpu/solvers/arena.py",
     "sparknet_tpu/analysis/bytecheck.py",
     "sparknet_tpu/analysis/byte_model.py",
     "sparknet_tpu/analysis/comm_model.py",
@@ -223,8 +222,7 @@ def census_mode(target, prog) -> tuple:
         and not isinstance(a, int))
 
     exp = expected_comm(target.name, param_bytes=target.param_bytes,
-                        state_bytes=target.state_bytes,
-                        padded_param_bytes=meta.get("padded_param_bytes"))
+                        state_bytes=target.state_bytes)
     coll = sum(w[0] for w in exp.required.values() if w)
 
     policy = meta.get("remat") or "none"

@@ -92,20 +92,16 @@ def _window(model_bytes: int, state_bytes: int = 0) -> tuple:
     return (lo, hi)
 
 
-def expected_comm(mode: str, *, param_bytes: int, state_bytes: int = 0,
-                  padded_param_bytes: int | None = None) -> CommExpectation:
+def expected_comm(mode: str, *, param_bytes: int,
+                  state_bytes: int = 0) -> CommExpectation:
     """The analytic expectation for ``mode`` given the actual model
-    sizes.  ``padded_param_bytes``: the fused modes' flat-arena size
-    (params padded to the kernel tile) — widens only the hi bound,
-    since GSPMD may place the grad all-reduce on the concatenated
-    arena instead of the per-blob grads.  Raises KeyError for unknown
-    modes — a new parallel mode must state its communication contract
-    here before it can bank a manifest."""
+    sizes.  Raises KeyError for unknown modes — a new parallel mode
+    must state its communication contract here before it can bank a
+    manifest."""
     # solo_remat shares solo's contract: rematerialization recomputes
     # on-chip, it never creates a wire.  solo_act_bf16 likewise:
     # activation storage narrows on-chip residency, never a wire.
-    if mode in ("solo", "solo_nhwc", "solo_fused", "solo_remat",
-                "solo_act_bf16"):
+    if mode in ("solo", "solo_nhwc", "solo_remat", "solo_act_bf16"):
         return CommExpectation(
             required={},
             forbidden=COLLECTIVE_KINDS,
@@ -140,23 +136,6 @@ def expected_comm(mode: str, *, param_bytes: int, state_bytes: int = 0,
             forbidden=("all-to-all", "collective-permute", "all-gather"),
             note="tau=1 sync SGD: one grad-sized all-reduce per step; "
                  "an all-gather here means a param got resharded",
-        )
-    if mode == "dp_fused":
-        # dp's contract with one refinement: the fused step
-        # differentiates w.r.t. the flat arena, so the grad sync may be
-        # lowered per-blob (= exactly param bytes) OR post-concat on
-        # the padded arena; the window brackets both placements.  The
-        # update kernel itself never communicates.
-        padded = padded_param_bytes or param_bytes
-        lo = int(_LO_FRAC * param_bytes)
-        hi = int(_HI_FRAC * padded + 8 * state_bytes + _SLACK_BYTES)
-        return CommExpectation(
-            required={"all-reduce": (lo, hi)},
-            forbidden=("all-to-all", "collective-permute", "all-gather"),
-            note="tau=1 sync SGD + fused arena update: one grad-sized "
-                 "all-reduce per step (per-blob or on the padded flat "
-                 "arena); an all-gather here means a param got "
-                 "resharded",
         )
     if mode == "tau":
         return CommExpectation(

@@ -119,9 +119,6 @@ GRAPH_RULES = {
     "rank-4 (image-blob) transposes in its StableHLO — the channels-"
     "last path exists to carry orientation through dimension_numbers, "
     "so a data-formatting transpose means a layer fell off it",
-    "graph-fused-update": "a fused-update mode whose optimizer update "
-    "did not lower (TPU cross-export) as exactly ONE custom call — the "
-    "normalize/regularize/clip/rule chain fell back apart",
     "graph-manifest-missing": "no banked manifest for this mode "
     "(run `python -m sparknet_tpu.analysis graph --update`)",
     "graph-manifest-drift": "lowered contract differs from the banked "
@@ -129,10 +126,8 @@ GRAPH_RULES = {
 }
 
 # source files whose edits invalidate the banked manifests (hashed into
-# docs/graph_contracts/SOURCES.json by --update; the graftlint rules
-# graph-manifest-fresh and fused-update-manifest compare against it —
-# the solver/arena/pallas surface entered when the fused twin modes
-# started lowering through it)
+# docs/graph_contracts/SOURCES.json by --update; the graftlint rule
+# graph-manifest-fresh compares against it)
 GRAPH_SOURCE_PATTERNS = (
     "sparknet_tpu/parallel/",
     "sparknet_tpu/serve/",
@@ -142,7 +137,6 @@ GRAPH_SOURCE_PATTERNS = (
     "sparknet_tpu/analysis/comm_model.py",
     "sparknet_tpu/solvers/solver.py",
     "sparknet_tpu/solvers/updates.py",
-    "sparknet_tpu/solvers/arena.py",
     "sparknet_tpu/ops/pallas_kernels.py",
 )
 
@@ -583,23 +577,8 @@ def audit_target(target, art: Artifacts,
                            "recompiles every call",
             })
 
-    # -- 6. fused-update census (solo_fused/dp_fused only) -------------
-    update = None
-    if target.extra_contract is not None:
-        update = target.extra_contract()
-        if update.get("tpu_custom_calls") != 1:
-            problems.append({
-                "rule": "graph-fused-update",
-                "message": f"fused-update TPU cross-export lowered "
-                           f"{update.get('tpu_custom_calls')!r} custom "
-                           "call(s); the one-pass contract is exactly 1 "
-                           "— the update chain is not a single fused "
-                           "sweep",
-            })
-
     contract = {
         "comm": comm,
-        "update": update,
         "layout": lay,
         "sharding": {
             "params_sharded": art.sharded_params,
@@ -686,7 +665,6 @@ def sources_fingerprint(repo: str | None = None) -> dict:
                 "sparknet_tpu/analysis/comm_model.py",
                 "sparknet_tpu/solvers/solver.py",
                 "sparknet_tpu/solvers/updates.py",
-                "sparknet_tpu/solvers/arena.py",
                 "sparknet_tpu/ops/pallas_kernels.py"):
         p = os.path.join(repo, *rel.split("/"))
         if os.path.exists(p):
@@ -710,9 +688,7 @@ def _check_mode(name: str, banked_dir: str, update: bool,
 
     target = build_target(name, n_devices)
     exp = expected_comm(name, param_bytes=target.param_bytes,
-                        state_bytes=target.state_bytes,
-                        padded_param_bytes=target.meta.get(
-                            "padded_param_bytes"))
+                        state_bytes=target.state_bytes)
     art = trace_artifacts(target)
     problems, contract = audit_target(target, art, exp)
     manifest = _build_manifest(target, contract, exp, art)

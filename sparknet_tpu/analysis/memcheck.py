@@ -120,7 +120,6 @@ MEM_SOURCE_PATTERNS = (
     "sparknet_tpu/ops/layout.py",
     "sparknet_tpu/solvers/solver.py",
     "sparknet_tpu/solvers/updates.py",
-    "sparknet_tpu/solvers/arena.py",
     "sparknet_tpu/analysis/memcheck.py",
     "sparknet_tpu/analysis/mem_model.py",
 )
@@ -469,36 +468,8 @@ def audit_mem(target, art: MemArtifacts,
         "residency_delta_bytes": res_delta,
         "donated_bytes": art.program.donated_bytes(),
         "n_eqns": len(art.program.eqns),
-        "update": _fused_update_traffic(target),
     }
     return problems, contract
-
-
-def _fused_update_traffic(target) -> dict | None:
-    """The analytic single-pass traffic block for a fused-update mode
-    (``meta.arena_bytes`` present): the kernel's in-place aliasing
-    guarantees each param/slot arena byte exactly one HBM read + one
-    write per step and each grad arena byte one read — priced here from
-    the arena geometry (``pallas_kernels.fused_update_hbm_bytes``) so
-    the manifest carries the bytes model the bench A/B is predicted
-    from.  None for unfused modes (no arena exists)."""
-    meta = getattr(target, "meta", {}) or {}
-    if "arena_bytes" not in meta:
-        return None
-    from sparknet_tpu.ops.pallas_kernels import fused_update_hbm_bytes
-
-    ab = int(meta["arena_bytes"])
-    n_slots = int(meta.get("n_slots", 1))
-    return {
-        "arena_bytes": ab,
-        "n_slots": n_slots,
-        "reads_per_arena_byte": 1,
-        "writes_per_arena_byte": 1,
-        "params_slots_read_bytes": ab * (1 + n_slots),
-        "params_slots_write_bytes": ab * (1 + n_slots),
-        "grad_read_bytes": ab,
-        "single_pass_hbm_bytes": fused_update_hbm_bytes(ab, n_slots),
-    }
 
 
 # ---------------------------------------------------------------------------

@@ -92,6 +92,9 @@ _GRAPH_SOURCE_FILES = (
     "sparknet_tpu/models/zoo.py",
     "sparknet_tpu/analysis/graphcheck.py",
     "sparknet_tpu/analysis/comm_model.py",
+    "sparknet_tpu/solvers/solver.py",
+    "sparknet_tpu/solvers/updates.py",
+    "sparknet_tpu/ops/pallas_kernels.py",
 )
 _REGEN = ("regenerate with `python -m sparknet_tpu.analysis graph "
           "--update`")
@@ -112,7 +115,8 @@ def _graph_source_rel(path: str) -> tuple[str, str] | None:
 
 @rule(
     "graph-manifest-fresh",
-    "a PR touching parallel/ or models/zoo.py (or graphcheck itself) "
+    "a PR touching parallel/, models/zoo.py, solvers/solver.py, "
+    "solvers/updates.py or ops/pallas_kernels.py (or graphcheck itself) "
     "must regenerate the docs/graph_contracts/ manifests",
 )
 def check_graph_manifest_fresh(ctx: ModuleContext) -> Iterator[tuple[int, str]]:
@@ -298,99 +302,6 @@ def check_mem_manifest_fresh(ctx: ModuleContext) -> Iterator[tuple[int, str]]:
     elif want != digest:
         yield (1, f"{rel} changed since the memory manifests were banked "
                   f"— {_MEM_REGEN}")
-
-
-# ---------------------------------------------------------------------------
-# fused-update-manifest
-# ---------------------------------------------------------------------------
-
-# The fused-update source surface: the one-pass optimizer contract
-# twins (solo_fused/dp_fused) lower THROUGH the solver step builders,
-# the flat-arena layout, and the pallas kernel, so these files are
-# graph-contract source now too — and the arena layer is memory-
-# contract source (its geometry IS the priced arena bytes).  Checked
-# here against each family's SOURCES.json rather than folded into the
-# graph-/mem-manifest-fresh file lists: those rules keep their original
-# surfaces (one finding per stale file, not two), and this rule owns
-# the fused-update slice across BOTH manifest families.
-_FUSED_GRAPH_FILES = (
-    "sparknet_tpu/solvers/arena.py",
-    "sparknet_tpu/solvers/solver.py",
-    "sparknet_tpu/solvers/updates.py",
-    "sparknet_tpu/ops/pallas_kernels.py",
-)
-# solver/updates/pallas_kernels are already _MEM_SOURCE_FILES (the
-# mem-manifest-fresh surface); only the arena layer is NEW mem source
-_FUSED_MEM_FILES = ("sparknet_tpu/solvers/arena.py",)
-_FUSED_REGEN = {
-    "graph_contracts": "regenerate with `python -m sparknet_tpu.analysis "
-                       "graph --update`",
-    "mem_contracts": "regenerate with `python -m sparknet_tpu.analysis "
-                     "mem --update` (+ `--fit --update`)",
-}
-
-
-def _fused_source_rel(path: str) -> tuple[str, str] | None:
-    """(repo_root, repo_relative_path) when ``path`` is part of the
-    fused-update source surface, else None."""
-    norm = os.path.abspath(path).replace(os.sep, "/")
-    idx = norm.rfind("/sparknet_tpu/")
-    if idx < 0:
-        return None
-    root, rel = norm[:idx], norm[idx + 1:]
-    if rel in _FUSED_GRAPH_FILES or rel in _FUSED_MEM_FILES:
-        return root, rel
-    return None
-
-
-@rule(
-    "fused-update-manifest",
-    "a PR touching the fused-update surface (solvers/arena.py, "
-    "solvers/solver.py, solvers/updates.py, ops/pallas_kernels.py) "
-    "must regenerate the graph (and, for arena.py, memory) manifests",
-)
-def check_fused_update_manifest(ctx: ModuleContext) -> Iterator[tuple[int, str]]:
-    """The solo_fused/dp_fused twins made the solver/arena/pallas stack
-    part of what graphcheck lowers (and the arena geometry part of what
-    memcheck prices): an edit here that skips regeneration leaves the
-    banked fused manifests describing a kernel that no longer exists —
-    the same stale-baseline failure graph-/mem-manifest-fresh guard for
-    their surfaces, extended over the fused-update slice of BOTH
-    families.  Blind spot (shared with its siblings): an edit that
-    reverts to the banked bytes passes, correctly.
-    """
-    hit = _fused_source_rel(ctx.path)
-    if hit is None:
-        return
-    root, rel = hit
-    digest = hashlib.sha256(ctx.source.encode("utf-8")).hexdigest()
-    families = []
-    if rel in _FUSED_GRAPH_FILES:
-        families.append("graph_contracts")
-    if rel in _FUSED_MEM_FILES:
-        families.append("mem_contracts")
-    for fam in families:
-        regen = _FUSED_REGEN[fam]
-        src = os.path.join(root, "docs", fam, "SOURCES.json")
-        if not os.path.exists(src):
-            yield (1, f"{rel} is fused-update contract source but no "
-                      f"manifests are banked (docs/{fam}/SOURCES.json "
-                      f"missing) — {regen}")
-            continue
-        try:
-            with open(src, encoding="utf-8") as f:
-                recorded = json.load(f)
-        except (OSError, ValueError):
-            yield (1, f"docs/{fam}/SOURCES.json unreadable — {regen}")
-            continue
-        want = recorded.get(rel)
-        if want is None:
-            yield (1, f"{rel} is fused-update contract source not "
-                      f"covered by the banked docs/{fam} manifests — "
-                      f"{regen}")
-        elif want != digest:
-            yield (1, f"{rel} changed since the docs/{fam} manifests "
-                      f"were banked — {regen}")
 
 
 # ---------------------------------------------------------------------------
@@ -894,7 +805,6 @@ _BYTE_SOURCE_FILES = (
     "sparknet_tpu/ops/layout.py",
     "sparknet_tpu/solvers/solver.py",
     "sparknet_tpu/solvers/updates.py",
-    "sparknet_tpu/solvers/arena.py",
     "sparknet_tpu/analysis/bytecheck.py",
     "sparknet_tpu/analysis/byte_model.py",
     "sparknet_tpu/analysis/comm_model.py",

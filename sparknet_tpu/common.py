@@ -79,24 +79,6 @@ class Config:
     # and app loops), not inside jitted programs; ``SPARKNET_FEED`` seeds
     # the default, ``tpunet train --feed`` flips it per run.
     feed: str = os.environ.get("SPARKNET_FEED", "threaded").lower()
-    # One-pass optimizer update: ``True`` routes the Solver's update
-    # through the fused flat-arena kernel (``solvers/arena.py`` +
-    # ``ops/pallas_kernels.fused_update``) — params/grads/slots viewed
-    # as contiguous flat arenas and the full Caffe update (normalize/
-    # regularize/clip/rule) applied in ONE read-modify-write sweep.
-    # ``False`` (default) keeps the per-blob ``solvers/updates.py``
-    # chain, bit-identical to every banked manifest.  Read at Solver
-    # CONSTRUCTION time (like every Config field: trace-time, no
-    # retrace on later set_config); ``SPARKNET_FUSED_UPDATE`` seeds it,
-    # the bench A/B flips it via ``SPARKNET_BENCH_FUSED``.
-    fused_update: bool = os.environ.get("SPARKNET_FUSED_UPDATE", "0") == "1"
-    # Storage dtype of the fused arenas: "f32" (default) or "bf16" —
-    # the bf16-params+slots lever rebuilt on a vehicle that cannot lose
-    # the bytes win to XLA re-materialization: arenas live in bf16, the
-    # kernel computes in f32 registers, one cast at each boundary.
-    # Only consulted when ``fused_update`` is on; checkpoints stay
-    # blob-wise in the net's param dtype either way (dtype-invariant).
-    storage_dtype: str = os.environ.get("SPARKNET_STORAGE_DTYPE", "f32").lower()
     # Rematerialization policy for the train step's forward (the bytes
     # diet the bytecheck schedule search scores chip-free — ROADMAP
     # item 5): ``""`` (default — off, every traced program byte-
@@ -106,7 +88,7 @@ class Config:
     # ``"blocks"`` (per-block boundaries: pooling-layer outputs tagged
     # ``checkpoint_name`` in compiler/graph.py and saved via
     # save_only_these_names; everything between boundaries recomputed).
-    # Routed through ``solvers/solver.py remat_policy`` into every
+    # Routed through ``solvers/solver.py remat_policy`` into the
     # step builder; the banked winner per family lives in
     # ``docs/byte_contracts/remat_policy.json``.  Read at Solver
     # CONSTRUCTION/trace time like every Config field;
@@ -226,13 +208,6 @@ def set_config(**overrides) -> Config:
             raise ValueError(f"feed must be 'threaded' or 'process', got "
                              f"{overrides['feed']!r}")
         overrides = {**overrides, "feed": feed}
-    if "storage_dtype" in overrides:
-        sd = str(overrides["storage_dtype"]).lower()
-        sd = {"bfloat16": "bf16", "float32": "f32"}.get(sd, sd)
-        if sd not in ("f32", "bf16"):
-            raise ValueError(f"storage_dtype must be 'f32' or 'bf16', got "
-                             f"{overrides['storage_dtype']!r}")
-        overrides = {**overrides, "storage_dtype": sd}
     if "remat" in overrides:
         rp = str(overrides["remat"]).lower()
         rp = {"none": "", "off": ""}.get(rp, rp)

@@ -3,8 +3,8 @@
 The reference's whole pitch was ONE driver program owning both training
 and scoring (ref: apps/FeaturizerApp.scala:1 — train a net, then score
 an RDD with it, in the same app; SURVEY §1).  PRs 6–9 rebuilt every
-stage TPU-first — streaming feed, elastic τ-rounds, fused optimizer,
-AOT serving engine — and this package composes them into that single
+stage TPU-first — streaming feed, elastic τ-rounds, AOT serving
+engine — and this package composes them into that single
 system: a :class:`ProductionLoop` drives
 
     shard feed -> ElasticTrainer rounds -> atomic checkpoint ->

@@ -22,7 +22,6 @@ interpret mode is used by tests to pin equivalence chip-free.
 from __future__ import annotations
 
 import functools
-from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -348,313 +347,6 @@ def _flash_diff_bwd(causal, interpret, res, g):
 _flash_diff.defvjp(_flash_diff_fwd, _flash_diff_bwd)
 
 
-# ---------------------------------------------------------------------------
-# Fused optimizer update: the one-pass sweep over flat param/slot arenas.
-# ---------------------------------------------------------------------------
-#
-# The bench traffic analysis says the AlexNet headline is bytes-bound and
-# the optimizer update re-streams params+slots through HBM once per
-# elementwise op (SGD-with-momentum alone: read W, V, G; write W, V —
-# through a chain of separate XLA ops, plus the normalize/regularize/clip
-# prologue).  Caffe applies its update as one fused in-place axpy sweep
-# per blob (ref: sgd_solver.cpp ComputeUpdateValue + caffe_axpy); this
-# kernel is that design rebuilt over ONE flat arena per role
-# (solvers/arena.py): params, grads, and slot histories viewed as
-# contiguous [T] arrays, tiled (n_tiles, _ARENA_SUB, _ARENA_LANE), with
-# per-tile blob metadata (lr_mult, folded weight-decay) delivered via
-# scalar prefetch over a segment table — every blob is padded to a tile
-# multiple at arena build, so a tile never spans blobs and the kernel
-# body never branches per element.  All six Caffe solver rules share one
-# f32 math core (`_fused_rule_math`, mirroring solvers/updates.py op for
-# op); storage may be bf16 (`Config.storage_dtype`) with f32 compute in
-# registers — one cast at each boundary, so the bytes win cannot be lost
-# to XLA re-materialization.
-#
-# Three implementations, one math: `pallas` (TPU Mosaic — the measured
-# path; input/output aliasing makes the sweep in-place), `interpret`
-# (pallas interpreter, used by tests to pin the kernel body), and `xla`
-# (the same single-sweep formulation in plain HLO — the CPU-mesh path
-# the graph/mem contract twins lower, and the oracle).  ``auto`` routes
-# pallas on TPU backends and xla elsewhere.
-
-# arena tile geometry: SUB x LANE element tiles on the flat axis.  LANE
-# is the VPU lane width; SUB=16 satisfies the min sublane tile for both
-# f32 (8) and bf16 (16).  Per-blob padding waste is bounded by one tile
-# (2048 elements) per blob, so small-blob zoo families (cifar10_quick:
-# 10 blobs) stay within ~1.1x of their true param bytes.
-_ARENA_SUB = 16
-_ARENA_LANE = 128
-ARENA_TILE = _ARENA_SUB * _ARENA_LANE
-
-
-class UpdateStatics(NamedTuple):
-    """Trace-time solver constants the kernel closes over (the traced
-    scalars — rate, clip scale, adam correction — ride the ``scalars``
-    operand instead).  ``reg``: 'none' | 'l1' | 'l2' (weight_decay == 0
-    maps to 'none', matching solvers/updates.py's per-blob skip).
-    ``clip``: whether a clip scale is applied (clip_gradients > 0)."""
-
-    momentum: float = 0.0
-    momentum2: float = 0.999
-    rms_decay: float = 0.99
-    delta: float = 1e-8
-    iter_size: int = 1
-    reg: str = "none"
-    clip: bool = False
-
-
-# rule name -> number of slot histories (mirrors updates.OPTIMIZERS)
-FUSED_RULE_SLOTS = {
-    "SGD": 1, "Nesterov": 1, "AdaGrad": 1, "RMSProp": 1,
-    "AdaDelta": 2, "Adam": 2, "AdamW": 2,
-}
-
-
-def _fused_prologue(st: UpdateStatics, w, g, clip_scale, decay):
-    """normalize/regularize/clip, in Caffe's ApplyUpdate order and with
-    solvers/updates.py's exact op sequence (clip scale on raw grads ->
-    1/iter_size -> + decay*W or decay*sign(W)); ``decay`` is the per-
-    tile folded weight_decay * decay_mult."""
-    if st.clip:
-        g = g * clip_scale
-    if st.iter_size > 1:
-        g = g / st.iter_size
-    if st.reg == "l1":
-        g = g + decay * jnp.sign(w)
-    elif st.reg == "l2":
-        g = g + decay * w
-    return g
-
-
-def _fused_rule_math(st: UpdateStatics, rule: str, w, g, slots, lr, corr,
-                     corr2, decay):
-    """The six Caffe rules and AdamW on f32 operands (ref: the per-rule
-    solvers in caffe/src/caffe/solvers/, rebuilt in solvers/updates.py)
-    — op-for-op the same sequence, so the f32 fused path is EXACT vs the
-    unfused chain for SGD/Nesterov and allclose for the sqrt/div rules.
-    ``corr``/``corr2``: Adam's one correction factor, or AdamW's two
-    (1/(1-b1^t), 1/(1-b2^t)); ``decay``: the per-tile folded decay,
-    which AdamW takes here (decoupled) and every other rule in the
-    prologue.  Returns (delta_w, new_slots); W_new = w - delta_w."""
-    if rule == "SGD":
-        (h,) = slots
-        h = st.momentum * h + lr * g
-        return h, [h]
-    if rule == "Nesterov":
-        (h,) = slots
-        h_new = st.momentum * h + lr * g
-        return (1.0 + st.momentum) * h_new - st.momentum * h, [h_new]
-    if rule == "AdaGrad":
-        (h,) = slots
-        h = h + g * g
-        return lr * g / (jnp.sqrt(h) + st.delta), [h]
-    if rule == "RMSProp":
-        (h,) = slots
-        h = st.rms_decay * h + (1.0 - st.rms_decay) * g * g
-        return lr * g / (jnp.sqrt(h) + st.delta), [h]
-    if rule == "AdaDelta":
-        h, h2 = slots
-        mu = st.momentum
-        h = mu * h + (1.0 - mu) * g * g
-        val = g * jnp.sqrt((h2 + st.delta) / (h + st.delta))
-        h2 = mu * h2 + (1.0 - mu) * val * val
-        return lr * val, [h, h2]
-    if rule == "Adam":
-        m, v = slots
-        b1, b2 = st.momentum, st.momentum2
-        m = b1 * m + (1.0 - b1) * g
-        v = b2 * v + (1.0 - b2) * g * g
-        return (lr * corr) * m / (jnp.sqrt(v) + st.delta), [m, v]
-    if rule == "AdamW":
-        m, v = slots
-        b1, b2 = st.momentum, st.momentum2
-        m = b1 * m + (1.0 - b1) * g
-        v = b2 * v + (1.0 - b2) * g * g
-        step = (m * corr) / (jnp.sqrt(v * corr2) + st.delta) + decay * w
-        return lr * step, [m, v]
-    raise ValueError(f"unknown fused update rule {rule!r}")
-
-
-def _fused_kernel(st: UpdateStatics, rule: str, n_slots: int,
-                  lr_ref, decay_ref, scal_ref, w_ref, g_ref, *refs):
-    """One (tile,) grid cell: refs are [1, _ARENA_SUB, _ARENA_LANE]
-    blocks; lr/decay are scalar-prefetched per-tile segment tables
-    (SMEM), scal = [rate, clip_scale, adam_correction, adamw's second].
-    Storage dtype
-    may be bf16; every operand upcasts to f32 in registers and casts
-    back exactly once at the write."""
-    i = pl.program_id(0)
-    lr = scal_ref[0] * lr_ref[i]
-    clip_scale = scal_ref[1]
-    corr, corr2 = scal_ref[2], scal_ref[3]
-    decay = decay_ref[i]
-    w = w_ref[...].astype(jnp.float32)
-    g = g_ref[...].astype(jnp.float32)
-    slots = [r[...].astype(jnp.float32) for r in refs[:n_slots]]
-    g = _fused_prologue(st, w, g, clip_scale, decay)
-    dw, new_slots = _fused_rule_math(st, rule, w, g, slots, lr, corr,
-                                     corr2, decay)
-    w_out = refs[n_slots]
-    w_out[...] = (w - dw).astype(w_out.dtype)
-    for r, h in zip(refs[n_slots + 1:], new_slots):
-        r[...] = h.astype(r.dtype)
-
-
-def _fused_update_pallas(st: UpdateStatics, rule: str, w, g, slots,
-                         lr_tiles, decay_tiles, scalars,
-                         interpret: bool = False):
-    """The pallas arms: grid over tiles, params and slots aliased
-    in-place (input_output_aliases — the sweep reads and writes each
-    arena byte exactly once, Caffe's in-place axpy shape)."""
-    n = lr_tiles.shape[0]
-    shape3 = (n, _ARENA_SUB, _ARENA_LANE)
-    wr = w.reshape(shape3)
-    gr = g.reshape(shape3)
-    sr = [s.reshape(shape3) for s in slots]
-    kernel = functools.partial(_fused_kernel, st, rule, len(slots))
-    blk = lambda i, *_: (i, 0, 0)  # noqa: E731 — one tile per grid cell
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
-        grid=(n,),
-        in_specs=[pl.BlockSpec((1, _ARENA_SUB, _ARENA_LANE), blk)
-                  for _ in range(2 + len(slots))],
-        out_specs=[pl.BlockSpec((1, _ARENA_SUB, _ARENA_LANE), blk)
-                   for _ in range(1 + len(slots))],
-    )
-    # alias params + slots through (grads are consumed); indices count
-    # the 3 scalar-prefetch operands first
-    aliases = {3: 0}
-    for k in range(len(slots)):
-        aliases[5 + k] = 1 + k
-    outs = pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=[jax.ShapeDtypeStruct(shape3, w.dtype)]
-        + [jax.ShapeDtypeStruct(shape3, s.dtype) for s in slots],
-        input_output_aliases=aliases,
-        interpret=interpret,
-    )(lr_tiles, decay_tiles, scalars, wr, gr, *sr)
-    return outs[0].reshape(w.shape), [o.reshape(w.shape) for o in outs[1:]]
-
-
-def _fused_update_xla(st: UpdateStatics, rule: str, w, g, slots,
-                      lr_tiles, decay_tiles, scalars):
-    """The same single-sweep math in plain HLO over the (n_tiles, TILE)
-    view — the CPU-mesh formulation the solo_fused/dp_fused contract
-    twins lower (pallas has no CPU lowering), and the oracle the
-    interpret tests pin the kernel body against.  XLA:TPU fuses the
-    whole expression into one elementwise loop; the pallas arm exists
-    so that fusion is guaranteed by construction, not by the scheduler."""
-    n = lr_tiles.shape[0]
-    w32 = w.reshape(n, -1).astype(jnp.float32)
-    g32 = g.reshape(n, -1).astype(jnp.float32)
-    s32 = [s.reshape(n, -1).astype(jnp.float32) for s in slots]
-    lr = (scalars[0] * lr_tiles)[:, None]
-    decay = decay_tiles[:, None]
-    g32 = _fused_prologue(st, w32, g32, scalars[1], decay)
-    dw, new_slots = _fused_rule_math(st, rule, w32, g32, s32, lr,
-                                     scalars[2], scalars[3], decay)
-    new_w = (w32 - dw).astype(w.dtype).reshape(w.shape)
-    return new_w, [h.astype(s.dtype).reshape(s.shape)
-                   for h, s in zip(new_slots, slots)]
-
-
-def fused_update(rule: str, st: UpdateStatics, w, g, slots,
-                 lr_tiles, decay_tiles, scalars, force: str | None = None):
-    """One-pass optimizer update over flat arenas.
-
-    ``w``/``g``: [T] param and grad arenas (T a multiple of
-    ``ARENA_TILE``); ``slots``: list of [T] history arenas (1 or 2 per
-    ``FUSED_RULE_SLOTS[rule]``); ``lr_tiles``/``decay_tiles``: [T/TILE]
-    f32 segment tables (lr_mult and folded weight_decay*decay_mult per
-    tile); ``scalars``: [4] f32 = (rate, clip_scale, adam_correction,
-    adamw's second correction).
-    Returns (new_w, new_slots), same dtypes as the inputs.
-
-    ``force`` = 'pallas' | 'interpret' | 'xla' | 'auto' | None (None
-    consults ``SPARKNET_FUSED_IMPL``, default auto: pallas on TPU
-    backends, xla elsewhere — the CPU mesh cannot lower Mosaic)."""
-    import os
-
-    if w.shape[0] % ARENA_TILE:
-        raise ValueError(
-            f"arena length {w.shape[0]} is not a multiple of ARENA_TILE "
-            f"({ARENA_TILE}) — build it with solvers/arena.build_layout")
-    if len(slots) != FUSED_RULE_SLOTS[rule]:
-        raise ValueError(
-            f"rule {rule!r} takes {FUSED_RULE_SLOTS[rule]} slot arena(s), "
-            f"got {len(slots)}")
-    if force is None:
-        force = os.environ.get("SPARKNET_FUSED_IMPL", "auto")
-    if force == "auto":
-        force = "pallas" if jax.default_backend() == "tpu" else "xla"
-    if force == "xla":
-        return _fused_update_xla(st, rule, w, g, slots, lr_tiles,
-                                 decay_tiles, scalars)
-    if force in ("pallas", "interpret"):
-        return _fused_update_pallas(st, rule, w, g, slots, lr_tiles,
-                                    decay_tiles, scalars,
-                                    interpret=force == "interpret")
-    raise ValueError(f"unknown fused_update impl {force!r} "
-                     "(pallas|interpret|xla|auto)")
-
-
-def fused_update_vmem_bytes(n_slots: int, itemsize: int = 4) -> int:
-    """Static VMEM bound for one ``_fused_update_pallas`` grid cell.
-    Reads the kernel's actual tile constants so a retuned arena tile
-    moves the bound (and trips the banked memory manifest)
-    automatically.  Terms: the w/g/slot input blocks and w/slot output
-    blocks (double-buffered by the pallas pipeline, x2 each) at the
-    storage itemsize, plus the f32 register-file temporaries (w, g, the
-    slot upcasts, dw, and ~2 rule intermediates) and the SMEM segment
-    tables (negligible, excluded)."""
-    tile = _ARENA_SUB * _ARENA_LANE
-    blocks = 2 * (3 + 2 * n_slots) * tile * itemsize
-    temps = (4 + n_slots + 2) * tile * 4
-    return blocks + temps
-
-
-def fused_update_hbm_bytes(arena_bytes: int, n_slots: int) -> int:
-    """Analytic HBM traffic of ONE fused sweep: each param and slot
-    arena byte exactly one read + one write (the in-place aliased
-    pallas path), each grad arena byte one read; segment tables are
-    per-TILE scalars (arena_bytes / ARENA_TILE elements — noise) and
-    excluded.  This is the single-pass bytes term the memcheck kernels
-    manifest banks and docs/BENCHMARKS.md prices the per-family delta
-    from."""
-    return (2 + 2 * n_slots + 1) * arena_bytes
-
-
-def fused_update_tpu_custom_calls(rule: str = "SGD", n_slots: int = 1,
-                                  n_tiles: int = 2,
-                                  dtype=None) -> int:
-    """Count the custom calls in a CROSS-PLATFORM TPU lowering of the
-    fused pallas sweep — zero chip time (jax.export lowers Mosaic
-    host-side; the kernel binary compiles at XLA compile time, which
-    never runs here).  The graph-contract twins (solo_fused/dp_fused)
-    bank this as the 'update chain collapsed to one custom call' pin:
-    the whole normalize/regularize/clip/rule chain must lower as
-    exactly ONE tpu_custom_call."""
-    import re
-
-    from jax import export as jexport
-
-    dtype = dtype or jnp.float32
-    T = n_tiles * ARENA_TILE
-    st = UpdateStatics(momentum=0.9, reg="l2")
-    w = jnp.zeros((T,), dtype)
-    g = jnp.zeros((T,), dtype)
-    slots = [jnp.zeros((T,), dtype) for _ in range(n_slots)]
-    lr_tiles = jnp.ones((n_tiles,), jnp.float32)
-    decay_tiles = jnp.zeros((n_tiles,), jnp.float32)
-    scalars = jnp.ones((4,), jnp.float32)
-    fn = jax.jit(functools.partial(fused_update, rule, st, force="pallas"))
-    exported = jexport.export(fn, platforms=["tpu"])(
-        w, g, slots, lr_tiles, decay_tiles, scalars)
-    return len(re.findall(r"custom_call @tpu_custom_call",
-                          exported.mlir_module()))
-
-
 def lrn_vmem_bytes(channels: int, itemsize: int = 4) -> int:
     """Static VMEM bound for one ``_lrn_pallas`` grid cell at a given
     channel-fiber depth.  Reads the kernel's actual tile constant so a
@@ -703,16 +395,6 @@ def vmem_audit_points() -> list:
                                     "(S=8192, D=64, f32): the full-"
                                     "fiber K/V BlockSpec's ceiling",
          "bytes": flash_vmem_bytes(8192, 64)},
-        {"kernel": "fused_update", "note": "sgd/nesterov/adagrad/"
-                                           "rmsprop f32 arenas (1 slot)",
-         "bytes": fused_update_vmem_bytes(1)},
-        {"kernel": "fused_update", "note": "adam/adadelta f32 arenas "
-                                           "(2 slots, worst case)",
-         "bytes": fused_update_vmem_bytes(2)},
-        {"kernel": "fused_update", "note": "adam bf16-storage arenas "
-                                           "(2 slots, 2 B storage, f32 "
-                                           "register math)",
-         "bytes": fused_update_vmem_bytes(2, itemsize=2)},
         {"kernel": "paged", "note": "charlm decode block (T=16, H=4, "
                                     "D=16 per head, f32 pools)",
          "bytes": paged_vmem_bytes(16, 4, 16)},

