@@ -267,11 +267,12 @@ def require_chip(tool: str) -> dict:
 
 # the ``jax.named_scope`` names a trace reader may look for in this
 # program's executables (compiler/graph.py, solvers/solver.py,
-# data/device_transform.py, ops/moe.py, ops/attention.py): part of the
+# data/device_transform.py, ops/moe.py, ops/attention.py,
+# ops/linear_attention.py): part of the
 # compile-cache key.  Add a scope, add it here
 CACHE_SCOPES = ("scopes:L.<layer>,S.update,S.augment,"
                 "M.route,M.dispatch,M.experts,M.combine,M.shared,"
-                "A.core,A.latent,R.scan,R.gate,LOOP.<region>")
+                "A.core,A.latent,R.scan,R.gate,D.delta,LOOP.<region>")
 
 
 def enable_compile_cache() -> str:

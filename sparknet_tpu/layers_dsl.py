@@ -350,10 +350,15 @@ def EmbedLayer(
 
 
 def RMSNormLayer(name: str, bottoms: Sequence[str], eps: float = 1e-5,
-                 top: str | None = None) -> Message:
-    """RMSNorm over the last axis (ops/blocks.py RMSNorm)."""
+                 top: str | None = None,
+                 zero_centered: bool = False) -> Message:
+    """RMSNorm over the last axis (ops/blocks.py RMSNorm);
+    ``zero_centered``: the weight is 1 + w, w from zero."""
     m = _layer(name, "RMSNorm", bottoms, [top] if top else None)
-    return m.set("rms_norm_param", Message().set("eps", eps))
+    p = Message().set("eps", eps)
+    if zero_centered:
+        p.set("zero_centered", True)
+    return m.set("rms_norm_param", p)
 
 
 def SliceLayer(name: str, bottoms: Sequence[str], tops: Sequence[str],
@@ -476,6 +481,55 @@ def LatentAttentionLayer(
     return m.set("attention_param", p)
 
 
+def GatedAttentionLayer(
+    name: str,
+    bottoms: Sequence[str],
+    num_heads: int,
+    num_kv_heads: int,
+    head_dim: int,
+    rotary_dim: int | None = None,
+    rope_theta: float = 10000.0,
+    norm_eps: float = 1e-6,
+    weight_filler: Message | None = None,
+) -> Message:
+    """Causal grouped attention with heads of ``head_dim``, per-head
+    QK-norm, RoPE on the first ``rotary_dim`` features of a head and a
+    sigmoid gate on its output (ops/attention.py GatedAttentionLayer)."""
+    m = _layer(name, "GatedAttention", bottoms)
+    p = Message().set("num_heads", num_heads).set("num_kv_heads", num_kv_heads)
+    p.set("head_dim", head_dim)
+    if rotary_dim is not None:
+        p.set("rotary_dim", rotary_dim)
+    p.set("rope_theta", rope_theta).set("norm_eps", norm_eps)
+    p.set("causal", True)
+    if weight_filler is not None:
+        p.set("weight_filler", weight_filler)
+    return m.set("attention_param", p)
+
+
+def GatedDeltaNetLayer(
+    name: str,
+    bottoms: Sequence[str],
+    num_k_heads: int,
+    num_v_heads: int,
+    head_k_dim: int,
+    head_v_dim: int,
+    conv_kernel: int = 4,
+    norm_eps: float = 1e-6,
+    weight_filler: Message | None = None,
+) -> Message:
+    """Gated-DeltaNet linear attention (ops/linear_attention.py
+    GatedDeltaNetLayer)."""
+    m = _layer(name, "GatedDeltaNet", bottoms)
+    p = Message().set("num_k_heads", num_k_heads)
+    p.set("num_v_heads", num_v_heads).set("head_k_dim", head_k_dim)
+    p.set("head_v_dim", head_v_dim).set("conv_kernel", conv_kernel)
+    p.set("norm_eps", norm_eps)
+    if weight_filler is not None:
+        p.set("weight_filler", weight_filler)
+    return m.set("delta_param", p)
+
+
 def MultiHeadAttentionLayer(
     name: str,
     bottoms: Sequence[str],
@@ -530,6 +584,7 @@ def MoELayer(
     shared_hidden_dim: int = 0,
     experts_held: int | None = None,
     first_expert: int = 0,
+    shared_gate: bool = False,
 ) -> Message:
     """Mixture-of-experts extra (no reference analog; ops/moe.py).
     ``loss_tops``: up to three further (top name, loss_weight) pairs, in
@@ -538,7 +593,8 @@ def MoELayer(
     ``bias_update_rate`` / ``shared_hidden_dim``: the DeepSeek-V3
     family's router and shared expert; ``experts_held`` /
     ``first_expert``: the experts of the router's ``num_experts`` this
-    layer holds (all by default)."""
+    layer holds (all by default); ``shared_gate``: the shared expert's
+    output times sigmoid(x w_g)."""
     tops = [top or name, *(t for t, _ in loss_tops)]
     m = _layer(name, "MoE", bottoms, tops)
     if loss_tops:
@@ -563,6 +619,8 @@ def MoELayer(
         p.set("bias_update_rate", bias_update_rate)
     if shared_hidden_dim:
         p.set("shared_hidden_dim", shared_hidden_dim)
+    if shared_gate:
+        p.set("shared_gate", True)
     if experts_held is not None and (experts_held, first_expert) != (
             num_experts, 0):
         p.set("experts_held", experts_held).set("first_expert", first_expert)

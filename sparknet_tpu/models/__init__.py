@@ -47,6 +47,8 @@ from sparknet_tpu.models.zoo import (  # noqa: F401
     phi4_flash_lambda_init,
     phi4_flash_role,
     phi4_flash_solver,
+    qwen3_next,
+    qwen3_next_solver,
     resnet50,
     resnet50_solver,
     squeezenet,

@@ -33,6 +33,8 @@ from sparknet_tpu.layers_dsl import (
     EuclideanLossLayer,
     ExitWeightedLossLayer,
     FlattenLayer,
+    GatedAttentionLayer,
+    GatedDeltaNetLayer,
     GatedMemoryUnitLayer,
     GatedMLPLayer,
     InnerProductLayer,
@@ -1482,6 +1484,116 @@ def ouro_solver() -> SolverConfig:
     lr: the report's recipe as remembered, every value an assumption the
     benchmark's configuration file lists.  The schedule is left to the
     prototxt's lr_policy."""
+    return SolverConfig(
+        base_lr=3e-4, lr_policy="fixed", momentum=0.9, momentum2=0.95,
+        delta=1e-8, weight_decay=0.1, clip_gradients=1.0,
+        max_iter=10000, solver_type="AdamW", display=100,
+    )
+
+
+# ---------------------------------------------------------------------------
+# Qwen3-Next-80B-A3B — gated-DeltaNet linear attention three layers in four,
+# output-gated grouped softmax attention every fourth, and in every layer
+# top-10-of-512 softmax-routed experts beside one sigmoid-gated shared
+# expert (Qwen/Qwen3-Next-80B-A3B-Instruct config.json, ``model_type:
+# qwen3_next``; Gated Delta Networks, arXiv:2412.06464; no reference
+# analog).  Pre-norm blocks with zero-centred RMSNorm, untied head.
+# ---------------------------------------------------------------------------
+def qwen3_next(
+    batch: int = 1,
+    seq_len: int = 4096,
+    vocab: int = 151936,
+    hidden: int = 2048,
+    layers: int = 48,
+    full_attention_interval: int = 4,
+    heads: int = 16,
+    kv_heads: int = 2,
+    head_dim: int = 256,
+    partial_rotary_factor: float = 0.25,
+    rope_theta: float = 1e7,
+    linear_k_heads: int = 16,
+    linear_v_heads: int = 32,
+    linear_k_dim: int = 128,
+    linear_v_dim: int = 128,
+    conv_kernel: int = 4,
+    experts: int = 512,
+    top_k: int = 10,
+    expert_dim: int = 512,
+    shared_dim: int = 512,
+    experts_held: int | None = None,
+    first_expert: int = 0,
+    rms_norm_eps: float = 1e-6,
+    aux_loss_coef: float = 0.001,
+    init_std: float = 0.02,
+) -> Message:
+    """Qwen3-Next-80B-A3B at its published sizes by default: [batch,
+    seq_len] token ids -> per-token next-token logits.  Block i (from 0):
+    ``norm<i>a`` -> the mixer (``attn<i>`` where (i + 1) %
+    ``full_attention_interval`` == 0, else ``gdn<i>``) -> ``res<i>a`` ->
+    ``norm<i>b`` -> ``moe<i>`` -> ``res<i>b``.  ``loss`` is the mean
+    cross-entropy per token; each expert layer's ``lb<i>`` top carries the
+    load-balancing loss at ``aux_loss_coef`` (the family's
+    ``router_aux_loss_coef``: a sum over the layers, not a mean).
+    ``experts_held`` / ``first_expert`` give this chip's share of every
+    expert layer (all ``experts`` by default), ``vocab`` the rows of the
+    embedding and the head it holds.  The family's multi-token-prediction
+    module is not built."""
+    init = _gauss(init_std)
+    norm = lambda name, bottom: RMSNormLayer(
+        name, [bottom], eps=rms_norm_eps, zero_centered=True)
+    net = [
+        RDDLayer("data", shape=[batch, seq_len]),
+        RDDLayer("label", shape=[batch, seq_len]),
+        EmbedLayer("embed", ["data"], input_dim=vocab, num_output=hidden,
+                   weight_filler=init, bias_term=False, top="embed"),
+    ]
+    x = "embed"
+    for i in range(layers):
+        if (i + 1) % full_attention_interval == 0:
+            mixer = GatedAttentionLayer(
+                f"attn{i}", [f"norm{i}a"], num_heads=heads,
+                num_kv_heads=kv_heads, head_dim=head_dim,
+                rotary_dim=int(head_dim * partial_rotary_factor),
+                rope_theta=rope_theta, norm_eps=rms_norm_eps,
+                weight_filler=init)
+        else:
+            mixer = GatedDeltaNetLayer(
+                f"gdn{i}", [f"norm{i}a"], num_k_heads=linear_k_heads,
+                num_v_heads=linear_v_heads, head_k_dim=linear_k_dim,
+                head_v_dim=linear_v_dim, conv_kernel=conv_kernel,
+                norm_eps=rms_norm_eps, weight_filler=init)
+        name = mixer.get_str("name")
+        net += [
+            norm(f"norm{i}a", x),
+            mixer,
+            EltwiseLayer(f"res{i}a", [x, name], top=f"res{i}a"),
+            norm(f"norm{i}b", f"res{i}a"),
+            MoELayer(
+                f"moe{i}", [f"norm{i}b"], num_experts=experts,
+                hidden_dim=expert_dim, top_k=top_k, expert_act="swiglu",
+                norm_topk_prob=True, shared_hidden_dim=shared_dim,
+                shared_gate=True, experts_held=experts_held,
+                first_expert=first_expert, weight_filler=init,
+                loss_tops=((f"lb{i}", aux_loss_coef),)),
+            EltwiseLayer(f"res{i}b", [f"res{i}a", f"moe{i}"], top=f"res{i}b"),
+        ]
+        x = f"res{i}b"
+    net += [
+        norm("norm_f", x),
+        InnerProductLayer("lm_head", ["norm_f"], num_output=vocab, axis=2,
+                          weight_filler=init, bias_term=False),
+        SoftmaxWithLoss("loss", ["lm_head", "label"], axis=2),
+        AccuracyLayer("accuracy", ["lm_head", "label"], phase="TEST", axis=2),
+    ]
+    return NetParam("Qwen3-Next", *net)
+
+
+def qwen3_next_solver() -> SolverConfig:
+    """AdamW, lr 3e-4, betas 0.9 / 0.95, eps 1e-8, decoupled weight decay
+    0.1 on every parameter, gradient clipping at global norm 1.0, a FIXED
+    lr: the family's card states no recipe, so every value is an
+    assumption the benchmark's configuration file lists.  The schedule is
+    left to the prototxt's lr_policy."""
     return SolverConfig(
         base_lr=3e-4, lr_policy="fixed", momentum=0.9, momentum2=0.95,
         delta=1e-8, weight_decay=0.1, clip_gradients=1.0,

@@ -18,3 +18,4 @@ from sparknet_tpu.ops import python_layer  # noqa: F401
 from sparknet_tpu.ops import attention  # noqa: F401
 from sparknet_tpu.ops import moe  # noqa: F401
 from sparknet_tpu.ops import ssm  # noqa: F401
+from sparknet_tpu.ops import linear_attention  # noqa: F401

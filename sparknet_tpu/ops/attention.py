@@ -638,3 +638,94 @@ class DifferentialAttentionLayer(AttentionLayer):
         # [B, g, i, S, 2D] -> [B, S, pairs (g, i), 2D]
         y = o.transpose(0, 3, 1, 2, 4).reshape(B, S, H * D) @ w_o.T
         return LayerOutput(outputs=[y, k, v][:len(self.tops)])
+
+
+@register
+class GatedAttentionLayer(AttentionLayer):
+    """Softmax attention with grouped heads of a width of their own,
+    per-head QK-norm, a rotary embedding over PART of a head and a sigmoid
+    gate on its output (the Qwen3-Next family's full-attention layer, as
+    its ``config.json`` sizes it: ``head_dim``, ``num_key_value_heads``,
+    ``partial_rotary_factor``).
+
+    ``attention_param { num_heads num_kv_heads head_dim rotary_dim
+    rope_theta norm_eps causal }``; H query heads and Hk key and value
+    heads of D = ``head_dim`` (H D need not be E); ``rotary_dim`` (even,
+    <= D; default D) leading features of every q and k head turn
+    (rotate-half within them), the rest pass.  [B, S, E] -> [B, S, E];
+    blobs, every matrix ``[out, in]``, no biases:
+
+      W_q (2 H D, E)     per head [q (D) ; gate (D)], side by side
+      W_k (Hk D, E), W_v (Hk D, E)
+      W_o (E, H D)
+      q_norm (D), k_norm (D)   RMSNorm per head, weight 1 + w, w from 0
+
+    q, k <- RMSNorm_D (zero-centred weight) then RoPE; o = the causal core
+    over H heads on Hk (:func:`attention_core`'s grouped form), scores
+    scaled by D^-1/2; y = W_o (o * sigmoid(gate)).  Head-major in and out
+    of the projections, as ``MultiHeadAttentionLayer``: x against the
+    [H, 2, D, E], [Hk, D, E] views of the matrices straight into
+    [B, H, S, D], and o contracted over (H, D) with W_o viewed [E, H, D]:
+    no token-major q, gate, k, v or o exists."""
+
+    TYPE = "GatedAttention"
+
+    def __init__(self, lp, phase):
+        super().__init__(lp, phase)
+        p = lp.get_msg("attention_param")
+        self.num_heads = p.get_int("num_heads")
+        self.num_kv_heads = p.get_int("num_kv_heads", self.num_heads)
+        self.head_dim = p.get_int("head_dim")
+        self.rotary_dim = p.get_int("rotary_dim", self.head_dim)
+        self.rope_theta = p.get_float("rope_theta", 10000.0)
+        self.norm_eps = p.get_float("norm_eps", 1e-6)
+        self.causal = p.get_bool("causal", True)
+        if self.num_heads % self.num_kv_heads:
+            raise ValueError(
+                f"{self.name}: {self.num_kv_heads} key heads must divide "
+                f"{self.num_heads} query heads")
+        if self.rotary_dim % 2 or not 0 <= self.rotary_dim <= self.head_dim:
+            raise ValueError(
+                f"{self.name}: rotary_dim {self.rotary_dim} must be even "
+                f"and at most head_dim {self.head_dim}")
+        self.weight_filler = (
+            p.get_msg("weight_filler") if p.has("weight_filler")
+            else Message().set("type", "xavier"))
+
+    def init(self, key, in_shapes):
+        E = in_shapes[0][-1]
+        H, Hk, D = self.num_heads, self.num_kv_heads, self.head_dim
+        keys = jax.random.split(key, 4)
+        shapes = [(2 * H * D, E), (Hk * D, E), (Hk * D, E), (E, H * D)]
+        return [*(fill(self.weight_filler, k, s)
+                  for k, s in zip(keys, shapes)),
+                jnp.zeros((D,), jnp.float32), jnp.zeros((D,), jnp.float32)], {}
+
+    def _turn(self, t):
+        """RoPE on the first ``rotary_dim`` features of every head."""
+        r = self.rotary_dim
+        if r == 0:
+            return t
+        if r == t.shape[-1]:
+            return rope(t, self.rope_theta)
+        return jnp.concatenate([rope(t[..., :r], self.rope_theta),
+                                t[..., r:]], axis=-1)
+
+    def apply(self, params, state, inputs, *, train, rng=None) -> LayerOutput:
+        if active_sequence_parallel() is not None:
+            raise NotImplementedError(
+                f"{self.name}: gated attention has no sequence-parallel "
+                "core (ring / Ulysses take one head count)")
+        w_q, w_k, w_v, w_o, q_norm, k_norm = params
+        x = inputs[0]  # [B, S, E]
+        E = x.shape[-1]
+        H, Hk, D = self.num_heads, self.num_kv_heads, self.head_dim
+        q, gate = jnp.einsum("bse,hgde->gbhsd", x, w_q.reshape(H, 2, D, E))
+        k, v = (jnp.einsum("bse,hde->bhsd", x, w.reshape(Hk, D, E))
+                for w in (w_k, w_v))
+        q = self._turn(rms_norm(q, 1.0 + q_norm, self.norm_eps))
+        k = self._turn(rms_norm(k, 1.0 + k_norm, self.norm_eps))
+        o = self._core(q, k, v, self.causal)
+        y = jnp.einsum("bhsd,fhd->bsf", o * jax.nn.sigmoid(gate),
+                       w_o.reshape(E, H, D))
+        return LayerOutput(outputs=[y])

@@ -561,17 +561,25 @@ class RMSNorm(Layer):
     ``y = w · x / sqrt(mean(x²) + eps)``.  One weight blob (D,), ones at
     initialisation, no bias.  The statistics are taken in f32 whatever
     the compute dtype; the normalized value returns to the input's dtype
-    before the weight multiplies it."""
+    before the weight multiplies it.  ``rms_norm_param { zero_centered:
+    true }``: the blob starts at zeros and the weight is ``1 + w`` (the
+    Qwen3-Next family's norm: weight decay then draws the weight to 1)."""
 
     TYPE = "RMSNorm"
 
+    def __init__(self, lp, phase):
+        super().__init__(lp, phase)
+        p = lp.get_msg("rms_norm_param")
+        self.eps = p.get_float("eps", 1e-5)
+        self.zero_centered = p.get_bool("zero_centered", False)
+
     def init(self, key, in_shapes):
-        return [jnp.ones((in_shapes[0][-1],), get_config().param_dtype)], {}
+        fill = jnp.zeros if self.zero_centered else jnp.ones
+        return [fill((in_shapes[0][-1],), get_config().param_dtype)], {}
 
     def apply(self, params, state, inputs, *, train, rng=None):
-        return LayerOutput([rms_norm(
-            inputs[0], params[0],
-            self.lp.get_msg("rms_norm_param").get_float("eps", 1e-5))])
+        weight = 1.0 + params[0] if self.zero_centered else params[0]
+        return LayerOutput([rms_norm(inputs[0], weight, self.eps)])
 
 
 def rms_norm(x, weight, eps: float, axis=-1):

@@ -561,6 +561,12 @@ def moe_dropless(params, x, *, top_k: int, expert_act: str,
 
 @register
 class MoELayer(Layer):
+    """The expert layer (module docstring).  ``shared_gate: true`` (with
+    ``shared_hidden_dim``) appends one more blob, w_g (1, D): the shared
+    expert's output is multiplied by sigmoid(x w_g^T) a token before it
+    joins the routed experts' (the Qwen MoE families' gated shared
+    expert), under the same ``M.shared`` scope."""
+
     TYPE = "MoE"
 
     def __init__(self, lp, phase):
@@ -577,6 +583,11 @@ class MoELayer(Layer):
         self.bias_rate = p.get_float("bias_update_rate", 0.0)
         self.select_bias = self.bias_rate > 0
         self.shared_dim = p.get_int("shared_hidden_dim", 0)
+        self.shared_gate = p.get_bool("shared_gate", False)
+        if self.shared_gate and not self.shared_dim:
+            raise ValueError(
+                f"{self.name}: shared_gate gates a shared expert; give "
+                "shared_hidden_dim")
         # this chip's share: experts [first_expert, first_expert + held)
         self.first_expert = p.get_int("first_expert", 0)
         self.experts_held = p.get_int("experts_held", self.num_experts)
@@ -641,11 +652,16 @@ class MoELayer(Layer):
             params += [fill(self.weight_filler, ks[0], (self.shared_dim, D)),
                        fill(self.weight_filler, ks[1], (self.shared_dim, D)),
                        fill(self.weight_filler, ks[2], (D, self.shared_dim))]
+        if self.shared_gate:
+            params.append(fill(self.weight_filler,
+                               jax.random.fold_in(key, 2), (1, D)))
         return params, state
 
     def apply(self, params, state, inputs, *, train, rng=None) -> LayerOutput:
         x = inputs[0]
         flat = x.reshape(-1, x.shape[-1])
+        if self.shared_gate:
+            *params, w_g = params
         routed = params[:-3] if self.shared_dim else params
         bias = state["bias"] if self.select_bias else None
         y, logits, probs, experts, load = moe_dropless(
@@ -655,7 +671,10 @@ class MoELayer(Layer):
             first_expert=self.first_expert)
         if self.shared_dim:
             with jax.named_scope(SHARED_SCOPE):
-                y = y + gated_mlp(flat, *params[-3:])
+                shared = gated_mlp(flat, *params[-3:])
+                if self.shared_gate:
+                    shared = shared * jax.nn.sigmoid(flat @ w_g.T)
+                y = y + shared
         outputs = [y.reshape(x.shape)]
         if len(self.tops) > 1:
             with jax.named_scope(ROUTE_SCOPE):

@@ -610,6 +610,11 @@ class Solver:
         as the ``lax.scan`` loop form: on the CPU all of them), and, of
         the path that runs, the steps between two of the states the
         forward keeps for the backward and the bytes of those states
+        over the layers.  The gated-DeltaNet layers
+        (``ops/linear_attention.py``) the same way: how many there are,
+        how many of them took the chunked core at their last trace (the
+        one path that ships: all of them once traced), the tokens of a
+        chunk and the bytes of the chunk-start states the forward keeps
         over the layers.  The attention layers (``ops/attention.py``)
         likewise: how many there are, and how many of them ran their core
         as the splash kernels at their last trace (the others in the XLA
@@ -635,6 +640,13 @@ class Solver:
                          ssm_kernel_layers=sum(l.kernel for l in scans),
                          ssm_chunk=scans[0].chunk,
                          ssm_saved_bytes=sum(l.saved_bytes for l in scans))
+        deltas = [l for l in self.train_net.layers
+                  if l.type == "GatedDeltaNet"]
+        if deltas:
+            stats.update(gdn_layers=len(deltas),
+                         gdn_kernel_layers=sum(l.kernel for l in deltas),
+                         gdn_chunk=deltas[0].chunk,
+                         gdn_saved_bytes=sum(l.saved_bytes for l in deltas))
         cores = [l for l in self.train_net.layers
                  if isinstance(l, AttentionLayer)]
         if cores:
