@@ -20,7 +20,9 @@ compiled branches are counted here (PR 35), and the selective scan's kernels
 cell's widths: Mosaic refuses here what it would refuse on the chip; and
 the solo feed's augment (``data/device_transform.py``, PR 39) at the two
 CNN solo cells' shapes; and toy multi-head decoder steps hold no copy of
-an activation between token-major and head-major (PR 44).
+an activation between token-major and head-major (PR 44); and the gated
+delta rule's kernels (``ops/linear_attention.py``, PR 48) at the
+linear-attention cell's size.
 """
 
 import dataclasses
@@ -34,7 +36,7 @@ from sparknet_tpu import models
 from sparknet_tpu.common import Phase, get_config, set_config
 from sparknet_tpu.compiler.graph import Network
 from sparknet_tpu.ops import moe
-from tools import expert_copies, scan_kernel
+from tools import delta_kernel, expert_copies, scan_kernel
 
 ROWS, LIVE, SIZES = 500, 450, (200, 0, 150, 100)
 G, D, H = len(SIZES), 128, 256
@@ -229,6 +231,32 @@ def test_the_scan_kernels_compile_at_the_cell_widths(v5e, no_compile_cache,
     kept = ssm.saved_state_bytes(1, seq, d, n, ssm.TIME_BLOCK)
     temps = compiled.memory_analysis().temp_size_in_bytes
     assert kept <= temps < seq * d * n * 4 // 4
+
+
+@pytest.mark.parametrize("seq", [4096, 4000], ids=["whole", "padded"])
+def test_the_delta_rule_kernels_compile_at_the_cell_size(v5e, no_compile_cache,
+                                                         seq):
+    """1 x 4,096 tokens, 16 key and 32 value heads of 128, bf16 q / k / v
+    beside f32 gates: forward and backward are one Mosaic kernel each, no
+    loop is left, and between them they keep the state at every chunk's
+    start (134 MB) and nothing a [chunk-heads, 64, 64] f32 array would
+    need (30 of them at 34 MB each, lane-padded, was the XLA form's way)."""
+    from jax.sharding import SingleDeviceSharding
+
+    from sparknet_tpu.ops import linear_attention as la
+
+    args = [jax.ShapeDtypeStruct(shape, dtype,
+                                 sharding=SingleDeviceSharding(v5e))
+            for shape, dtype in delta_kernel.shapes(seq)]
+    compiled = delta_kernel.both(la.gated_delta_rule_kernel).lower(
+        *args).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 2
+    assert "while(" not in text
+    kept = la.saved_state_bytes(1, seq, delta_kernel.V_HEADS,
+                                delta_kernel.HEAD_DIM, delta_kernel.HEAD_DIM)
+    temps = compiled.memory_analysis().temp_size_in_bytes
+    assert kept <= temps < kept + 4 * 34 * 2 ** 20
 
 
 @pytest.mark.parametrize("batch, crop", [(1024, 227), (256, 224)],

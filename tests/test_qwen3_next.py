@@ -315,14 +315,15 @@ def test_training_lowers_the_loss():
 
 def test_the_fence_carries_the_new_counters():
     """After ``Solver.step``: the DeltaNet layers, how many of them took
-    the chunked core, the tokens of a chunk and the bytes of the kept
+    the Pallas kernels (none on the CPU, nor at these 8 x 16 heads
+    anywhere), the tokens of a chunk and the bytes of the kept
     chunk-start states (f32 [chunks, B, H_v, d_k, d_v]), beside the
     attention and expert counters under their present names."""
     solver = make_solver()
     solver.step(2, lambda it: batch_of(it))
     stats = solver._fence_stats()
     assert {k: stats[k] for k in stats if not k.startswith("moe_")} == {
-        "gdn_layers": 3, "gdn_kernel_layers": 3, "gdn_chunk": 32,
+        "gdn_layers": 3, "gdn_kernel_layers": 0, "gdn_chunk": 32,
         "gdn_saved_bytes": 3 * 1 * 2 * 4 * 8 * 16 * 4,
         "attn_core_layers": 1, "attn_kernel_layers": 0}
     assert stats["moe_layers"] == 4 and stats["moe_experts"] == 16
