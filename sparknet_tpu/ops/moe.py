@@ -59,29 +59,38 @@ expert blobs' leading axis is n, and a (token, slot) pair whose expert
 is not held adds nothing to ``y``; ``load`` still counts every router
 output.  Nothing stands in for the absent chips or their exchange.
 
-What a share-holding layer moves (PR 35).  A deployment's exchange would
-hand this chip only its own rows, so the layer touches only those where
-it can: the pairs are sorted with the held groups first, and when the
-step's held pairs fit the layer's ``capacity`` (``CAPACITY_FACTOR`` = 6
-times the level share n / E of the T·k pairs, in whole tiles of 512
-rows: 6,144 of 32,768 at 8 of 256 experts; a function of the shapes
-alone) it gathers that many rows of ``x``, runs the grouped matmuls, the
-activation and the masks at M = capacity, and adds the weighted rows to
-their tokens in f32 (a scatter-add of the capacity's rows): no [T·k, ·]
-array exists in either pass.  When they do not fit, the path over all
-T·k rows runs, as before: the layer is dropless at ANY routing, all
-pairs on held experts included.  One ``lax.cond`` a pass picks the path
-(``_held_rows``, a ``custom_vjp`` whose backward branches again, so that
-neither path's residuals are written by the other); ``takes_compact`` is
-the predicate, on the device and for the fence's ``moe_compact_layers``
-count alike.  A layer that holds every expert, or whose capacity would
-be all its pairs, has the one path and lowers as it did.
+What a share-holding layer moves (PRs 35 and 49).  A deployment's
+exchange would hand this chip only its own rows, so the layer touches
+only those: the pairs are sorted with the held groups first, so the LIVE
+rows are the first ``sum(group_sizes)`` sorted rows, and every per-row
+operation walks them in tiles of ``CAPACITY_TILE`` = 512 rows,
+``live_tiles`` = ceil(held pairs / 512) of them, a trip count READ ON THE
+DEVICE from the ``group_sizes`` the layer already has.  Two movers, each
+other's transpose, each a ``custom_vjp`` around ``lax.fori_loop``s (a loop
+with a traced trip count has no reverse mode of its own): ``gather_live``
+brings the live tiles' rows of ``x`` (its cotangent adds them back to
+their tokens in f32), the grouped matmuls visit no other tile, and
+``combine_live`` adds the weighted rows to their tokens in f32 (its
+cotangents are ``dy[token] · w`` and the row sums of ``dy[token] · out``,
+tile by tile).  What lies between the grouped matmuls walks the same
+tiles (``_LiveRows``: the activation and the biases through ``map_live``,
+the sum of the rows' two cotangents through ``_twice_live``).  The arrays
+are [R, ·] for R = T·k in whole tiles, never initialised (``_buffer``)
+and never passed over whole: the cost follows the live rows, about 2,500
+of 40,960 at 32 of 512 experts under a level router, at ANY routing, all
+pairs on held experts included.  So there is ONE path and no capacity
+(PR 35's ``lax.cond`` between a path at 6 x the level share and a path
+over all rows left with PR 49): the layer is dropless through it, the
+forward keeps only its operands (``_held_rows``) and the backward runs the
+path again.  ``Solver._fence_stats`` counts the rows the loops walked
+(``moe_rows_moved``) with the same ``live_tiles`` from the same ``load``.
+A layer that holds every expert has no dead row, takes ``_all_rows`` and
+lowers as it did.
 """
 
 from __future__ import annotations
 
 import functools
-import math
 
 import jax
 import jax.numpy as jnp
@@ -313,191 +322,343 @@ def grouped_matmul(x, w, group_sizes):
 
 
 # ---------------------------------------------------------------------------
-# a layer that holds a SHARE of its experts: the held pairs' rows only
+# a layer that holds a SHARE of its experts: the live rows only
 # ---------------------------------------------------------------------------
 
 # A chip that holds n of E experts is sent, under a level router, n / E
-# of the (token, slot) pairs.  The share-holding layer gathers, computes
-# and combines at a static CAPACITY of sorted rows, this many times that
-# level share, whenever the step's held pairs fit it, and at all T·k rows
-# (the exact path, dropless at any routing) when they do not.  One
-# constant for every layer: 6 x is 6,144 of 32,768 pairs at 8 of 256
-# experts.  Read off the held counts of the JoyAI cell's layer-steps and
-# one layer's times (PERF.md section 6, PR 35): a step at the capacity
-# costs in proportion to it (2.6 ms a layer at 4 x, 3.5 at 8 x, 7.5 over
-# all rows), a step OVER it costs more than the layer did before (its
-# forward runs twice), and of 500 layer-steps the four decoder blocks'
-# layers never held more than 5.3 x (two thirds of them under 1 x).
-CAPACITY_FACTOR = 6
-CAPACITY_TILE = 512  # rows: whole megablox row tiles at any capacity
+# of the (token, slot) pairs.  The pairs are sorted with the held groups
+# first, so the LIVE rows are the first ``sum(group_sizes)`` sorted rows,
+# and every per-row operation of the share-holding layer walks them in
+# tiles of this many rows (whole megablox row tiles), ``live_tiles`` of
+# them: a trip count read on the device from the ``group_sizes`` the layer
+# already has, and by ``Solver._fence_stats`` from the same ``load``.
+CAPACITY_TILE = 512
 
 
-def capacity(pairs: int, held_n: int, num_experts: int) -> int:
-    """Sorted rows a layer of ``pairs`` (token, slot) pairs that holds
-    ``held_n`` of ``num_experts`` experts dispatches when its held pairs
-    fit: ``CAPACITY_FACTOR`` times the level share, in whole tiles.  0
-    where that is no fewer than all the pairs (a layer that holds every
-    expert, or a large share of few): such a layer has one path."""
-    rows = -(-CAPACITY_FACTOR * pairs * held_n
-             // (num_experts * CAPACITY_TILE)) * CAPACITY_TILE
-    return rows if held_n < num_experts and rows < pairs else 0
+def live_tiles(held_pairs):
+    """Tiles of ``CAPACITY_TILE`` sorted rows that hold ``held_pairs``
+    live rows: the ONE count behind the device's loops (``held_pairs``
+    traced) and the host's ``moe_rows_moved`` (an int of the fence)."""
+    return (held_pairs + CAPACITY_TILE - 1) // CAPACITY_TILE
 
 
-def takes_compact(held_pairs, cap: int):
-    """Whether a step whose held experts got ``held_pairs`` pairs runs at
-    the capacity ``cap``: the ONE predicate behind the device's branch
-    (``held_pairs`` traced) and the host's count of it
-    (``Solver._fence_stats``, from the same ``load``)."""
-    return (cap > 0) & (held_pairs <= cap)
+def _buffer(shape, dtype):
+    """An array the live walk writes tile by tile.  Uninitialised where
+    the backend allows (a TPU: no pass over its bytes; the CPU gives
+    zeros): whatever lies past the last live tile is never read into a
+    sum, whatever it holds (the grouped matmuls skip those tiles, every
+    mover selects by its own ``live`` mask and never multiplies by one)."""
+    return jax.lax.empty(shape, dtype)
 
 
-def _add_rows(v, idx, rows: int):
-    """[rows, D] f32 whose row i is the sum of the rows ``v[r]`` with
-    ``idx[r] == i``: a scatter-add of ``len(idx)`` rows, nothing of T·k."""
-    return jnp.zeros((rows, v.shape[1]), jnp.float32).at[idx].add(v)
+def _tile(i, n_live):
+    """(start, [TILE] bool: the rows of tile ``i`` that are live)."""
+    start = i * CAPACITY_TILE
+    return start, start + jnp.arange(CAPACITY_TILE, dtype=jnp.int32) < n_live
+
+
+def _rows_of(a, start):
+    return jax.lax.dynamic_slice_in_dim(a, start, CAPACITY_TILE)
+
+
+def _put_rows(a, tile, start):
+    return jax.lax.dynamic_update_slice_in_dim(a, tile, start, 0)
+
+
+def _wide(mask, like):
+    """[TILE] bool against a tile [TILE, ...]."""
+    return mask.reshape(mask.shape + (1,) * (like.ndim - 1))
+
+
+def _take_live(x, token, n_live):
+    """``gather_live``'s walk (no gradient of its own: a loop with a
+    traced trip count has no reverse mode)."""
+    def body(i, rows):
+        start, live = _tile(i, n_live)
+        tile = x[_rows_of(token, start)]
+        return _put_rows(rows, jnp.where(_wide(live, tile), tile, 0), start)
+
+    return jax.lax.fori_loop(
+        0, live_tiles(n_live), body,
+        _buffer(token.shape + x.shape[1:], x.dtype))
 
 
 @jax.custom_vjp
-def _gather_rows(x, idx):
-    """``x[idx]`` for row indices that may repeat; the cotangent adds the
-    rows of one index in f32 before it is rounded to ``x``'s type."""
-    return x[idx]
+def gather_live(x, token, n_live):
+    """Rows [R, ...] whose first ``n_live`` are ``x[token]`` (indices may
+    repeat; R a multiple of ``CAPACITY_TILE``), moved a tile at a time
+    over the ``live_tiles`` alone: the last one is zero past ``n_live``,
+    rows past it are never written.  The cotangent adds the live tiles'
+    rows to their indices in f32 before it is rounded to ``x``'s type."""
+    return _take_live(x, token, n_live)
 
 
-def _gather_rows_fwd(x, idx):
-    return x[idx], (idx, x.shape[0])
+def _gather_live_fwd(x, token, n_live):
+    return _take_live(x, token, n_live), (token, n_live, x.shape[0])
 
 
-def _gather_rows_bwd(res, g):
-    idx, rows = res
-    return _add_rows(g.astype(jnp.float32), idx, rows).astype(g.dtype), None
+def _gather_live_bwd(res, g):
+    token, n_live, rows = res
+
+    def body(i, dx):
+        start, live = _tile(i, n_live)
+        tile = _rows_of(g, start).astype(jnp.float32)
+        return dx.at[_rows_of(token, start)].add(
+            jnp.where(_wide(live, tile), tile, 0))
+
+    with jax.named_scope(DISPATCH_SCOPE):
+        dx = jax.lax.fori_loop(
+            0, live_tiles(n_live), body,
+            jnp.zeros((rows,) + g.shape[1:], jnp.float32))
+        return dx.astype(g.dtype), None, None
 
 
-_gather_rows.defvjp(_gather_rows_fwd, _gather_rows_bwd)
+gather_live.defvjp(_gather_live_fwd, _gather_live_bwd)
 
 
-def _experts(rows, rest, group_sizes, flat, order, live, expert_act: str):
-    """The held experts on their sorted ``rows`` -> [M, D].  ``flat[order]``
-    is each row's expert, for the experts' biases; ``live`` [M, 1] marks
-    the rows inside the groups where some are not (a share), else None."""
+def _add_live(out, weights, order, n_live):
+    tokens, top_k = weights.shape
+    w_pair = weights.reshape(-1)
+
+    def body(i, y):
+        start, live = _tile(i, n_live)
+        pair = _rows_of(order, start)
+        tile = _rows_of(out, start).astype(jnp.float32) * w_pair[pair][:, None]
+        return y.at[pair // top_k].add(jnp.where(live[:, None], tile, 0))
+
+    y = jax.lax.fori_loop(
+        0, live_tiles(n_live), body,
+        jnp.zeros((tokens, out.shape[1]), jnp.float32))
+    return y.astype(out.dtype)
+
+
+@jax.custom_vjp
+def combine_live(out, weights, order, n_live):
+    """y [T, D]: sorted row r < ``n_live`` of ``out`` [R, D], the row of
+    pair ``order[r]`` = (token, slot), times ``weights`` [T, k] of that
+    pair, added to its token in f32, a tile at a time over the
+    ``live_tiles`` alone (the [R, D] f32 product is never a whole array).
+    The cotangents, tile by tile too: ``dy[token] · w`` to ``out`` and
+    the row sums of ``dy[token] · out`` to the pairs' weights."""
+    return _add_live(out, weights, order, n_live)
+
+
+def _combine_live_fwd(out, weights, order, n_live):
+    return _add_live(out, weights, order, n_live), (out, weights, order,
+                                                    n_live)
+
+
+def _combine_live_bwd(res, dy):
+    out, weights, order, n_live = res
+    top_k = weights.shape[1]
+    w_pair = weights.reshape(-1)
+
+    def body(i, carry):
+        d_out, d_w = carry
+        start, live = _tile(i, n_live)
+        pair = _rows_of(order, start)
+        g = dy[pair // top_k].astype(jnp.float32)
+        to_out = jnp.where(live[:, None], g * w_pair[pair][:, None], 0)
+        to_w = jnp.where(live, jnp.sum(
+            g * _rows_of(out, start).astype(jnp.float32), axis=1), 0)
+        return (_put_rows(d_out, to_out.astype(out.dtype), start),
+                d_w.at[pair].add(to_w))
+
+    with jax.named_scope(COMBINE_SCOPE):
+        d_out, d_w = jax.lax.fori_loop(
+            0, live_tiles(n_live), body,
+            (_buffer(out.shape, out.dtype), jnp.zeros_like(w_pair)))
+    return d_out, d_w.reshape(weights.shape), None, None
+
+
+combine_live.defvjp(_combine_live_fwd, _combine_live_bwd)
+
+
+def _map_tiles(fn, n_live, arrays):
+    """``fn`` of the live tiles of ``arrays`` [R, ·], row by row the same
+    function, into a buffer of whatever ``fn`` gives a tile."""
+    like = jax.eval_shape(fn, *(a[:CAPACITY_TILE] for a in arrays))
+
+    def body(i, out):
+        start = i * CAPACITY_TILE
+        return _put_rows(out, fn(*(_rows_of(a, start) for a in arrays)),
+                         start)
+
+    return jax.lax.fori_loop(
+        0, live_tiles(n_live), body,
+        _buffer(arrays[0].shape[:1] + like.shape[1:], like.dtype))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def map_live(fn, n_live, *arrays):
+    """``fn(*arrays)`` for an ELEMENTWISE ``fn`` (a row of the result
+    from the same row of every operand), over the live tiles alone; the
+    cotangents, ``fn``'s own a tile, over the same tiles."""
+    return _map_tiles(fn, n_live, arrays)
+
+
+def _map_live_fwd(fn, n_live, *arrays):
+    return _map_tiles(fn, n_live, arrays), (n_live, arrays)
+
+
+def _map_live_bwd(fn, res, g):
+    n_live, arrays = res
+
+    def body(i, grads):
+        start = i * CAPACITY_TILE
+        pull = jax.vjp(fn, *(_rows_of(a, start) for a in arrays))[1]
+        return tuple(_put_rows(d, tile, start)
+                     for d, tile in zip(grads, pull(_rows_of(g, start))))
+
+    with jax.named_scope(EXPERTS_SCOPE):
+        grads = jax.lax.fori_loop(
+            0, live_tiles(n_live), body,
+            tuple(_buffer(a.shape, a.dtype) for a in arrays))
+    return (None, *grads)
+
+
+map_live.defvjp(_map_live_fwd, _map_live_bwd)
+
+
+@jax.custom_vjp
+def _twice_live(rows, n_live):
+    """``rows`` for two readers, whose cotangents are added over the live
+    tiles alone (autodiff's own sum passes over all R rows)."""
+    return rows, rows
+
+
+_twice_live.defvjp(
+    lambda rows, n_live: ((rows, rows), n_live),
+    lambda n_live, gs: (_map_tiles(jnp.add, n_live, gs), None))
+
+
+class _EveryRow:
+    """How ``_experts`` treats its rows where every one is live (a layer
+    that holds every expert): whole-array operations."""
+
+    def __init__(self, flat, order):
+        self.flat, self.order = flat, order
+
+    def twice(self, rows):
+        return rows, rows
+
+    def each(self, fn, *arrays):
+        return fn(*arrays)
+
+    def biases(self, *biases):
+        of_row = self.flat[self.order]  # each sorted row's expert
+        return [b[of_row] for b in biases]
+
+
+class _LiveRows(_EveryRow):
+    """The same where only the first ``n_live`` sorted rows are live (a
+    share): every operation walks the live tiles alone."""
+
+    def __init__(self, flat, order, n_live):
+        super().__init__(flat, order)
+        self.n_live = n_live
+
+    def twice(self, rows):
+        return _twice_live(rows, self.n_live)
+
+    def each(self, fn, *arrays):
+        return map_live(fn, self.n_live, *arrays)
+
+    def biases(self, *biases):
+        of_row = _take_live(self.flat, self.order, self.n_live)
+        return [gather_live(b, of_row, self.n_live) for b in biases]
+
+
+def _experts(rows, rest, group_sizes, how, expert_act: str):
+    """The held experts on their sorted ``rows`` -> [M, D].  ``how``
+    (``_EveryRow`` / ``_LiveRows``) does what is not a grouped matmul:
+    the activation, the biases' rows, the sum of two cotangents."""
     with jax.named_scope(EXPERTS_SCOPE):
         if expert_act == "swiglu":
             w_gate, w_up, w_down = rest
-            h = jax.nn.silu(grouped_matmul(rows, w_gate, group_sizes)) \
-                * grouped_matmul(rows, w_up, group_sizes)
-            out = grouped_matmul(h, w_down, group_sizes)
-        elif len(rest) == 2:
+            to_gate, to_up = how.twice(rows)
+            h = how.each(lambda g, u: jax.nn.silu(g) * u,
+                         grouped_matmul(to_gate, w_gate, group_sizes),
+                         grouped_matmul(to_up, w_up, group_sizes))
+            return grouped_matmul(h, w_down, group_sizes)
+        if len(rest) == 2:
             w1, w2 = rest
-            h = jax.nn.relu(grouped_matmul(rows, w1, group_sizes))
-            out = grouped_matmul(h, w2, group_sizes)
-        else:
-            w1, b1, w2, b2 = rest
-            of_row = flat[order]  # each sorted row's expert, for its bias
-            if live is not None:
-                of_row = jnp.minimum(of_row, w1.shape[0] - 1)  # a dead row
-            h = jax.nn.relu(
-                grouped_matmul(rows, w1, group_sizes) + b1[of_row])
-            out = grouped_matmul(h, w2, group_sizes) + b2[of_row]
-        if live is not None:
-            out = jnp.where(live, out, 0)
-    return out
+            h = how.each(jax.nn.relu, grouped_matmul(rows, w1, group_sizes))
+            return grouped_matmul(h, w2, group_sizes)
+        w1, b1, w2, b2 = rest
+        of_b1, of_b2 = how.biases(b1, b2)
+        h = how.each(lambda a, b: jax.nn.relu(a + b),
+                     grouped_matmul(rows, w1, group_sizes), of_b1)
+        return how.each(jnp.add, grouped_matmul(h, w2, group_sizes), of_b2)
 
 
-def _all_rows(x, weights, rest, flat, order, group_sizes, *, live: bool,
-              expert_act: str):
-    """Dispatch, experts and combine over ALL T·k sorted rows -> y [T, D].
-    ``live``: rows past the groups' sum exist (a share) and must reach no
-    sum, forward or backward: the kernels never compute them."""
+def _all_rows(x, weights, rest, flat, order, group_sizes, *, expert_act: str):
+    """Dispatch, experts and combine over ALL T·k sorted rows -> y [T, D]:
+    a layer that holds every expert, so every row is live."""
     tokens, top_k = weights.shape
     with jax.named_scope(DISPATCH_SCOPE):
         inv = jnp.zeros_like(order).at[order].set(
             jnp.arange(order.shape[0], dtype=jnp.int32))
         rows = _spread_rows(x, order, inv)  # [T·k, D], expert-major
-        mask = None
-        if live:
-            mask = (jnp.arange(order.shape[0], dtype=jnp.int32)
-                    < jnp.sum(group_sizes))[:, None]
-            rows = jnp.where(mask, rows, 0)
-    out = _experts(rows, rest, group_sizes, flat, order, mask, expert_act)
+    out = _experts(rows, rest, group_sizes, _EveryRow(flat, order),
+                   expert_act)
     with jax.named_scope(COMBINE_SCOPE):
         per_pair = _take_rows(out, inv, order).reshape(tokens, top_k, -1)
         return jnp.sum(per_pair.astype(jnp.float32) * weights[..., None],
                        axis=1).astype(x.dtype)
 
 
-def _capacity_rows(x, weights, rest, flat, order, group_sizes, *, cap: int,
-                   expert_act: str):
-    """The same sum over the first ``cap`` sorted rows, which hold every
-    held pair of a step that ``takes_compact``: ``cap`` rows of ``x`` are
-    gathered, the experts and the masks run at M = ``cap``, and the
-    weighted rows are added to their tokens in f32.  No array of T·k rows
-    is made, forward or backward."""
-    tokens, top_k = weights.shape
+def _live_rows(x, weights, rest, flat, order, group_sizes, *, expert_act: str):
+    """The same sum for a SHARE of the experts, whose held pairs are the
+    first ``sum(group_sizes)`` sorted rows: ``gather_live`` brings those
+    rows of ``x``, the grouped matmuls visit no other, what lies between
+    them walks the same tiles (``_LiveRows``), ``combine_live`` adds them
+    to their tokens.  The arrays are [R, ·] for R = T·k in whole tiles,
+    and nothing passes over more of one than its live tiles."""
+    top_k = weights.shape[1]
+    n_live = jnp.sum(group_sizes)
     with jax.named_scope(DISPATCH_SCOPE):
-        order = order[:cap]
-        token = order // top_k
-        live = (jnp.arange(cap, dtype=jnp.int32)
-                < jnp.sum(group_sizes))[:, None]
-        rows = jnp.where(live, _gather_rows(x, token), 0)
-    out = _experts(rows, rest, group_sizes, flat, order, live, expert_act)
+        order = jnp.pad(order, (0, -order.shape[0] % CAPACITY_TILE))
+        rows = gather_live(x, order // top_k, n_live)
+    out = _experts(rows, rest, group_sizes, _LiveRows(flat, order, n_live),
+                   expert_act)
     with jax.named_scope(COMBINE_SCOPE):
-        weighted = out.astype(jnp.float32) * weights.reshape(-1)[order, None]
-        return _add_rows(weighted, token, tokens).astype(x.dtype)
+        return combine_live(out, weights, order, n_live)
 
 
-def _held_paths(cap, expert_act):
-    """(at the capacity, over all rows): the two paths of a share."""
-    return (functools.partial(_capacity_rows, cap=cap, expert_act=expert_act),
-            functools.partial(_all_rows, live=True, expert_act=expert_act))
+# jitted: a net's share-holding layers of one shape are traced once, the
+# path and its vjp, not once a layer (set-up time: PERF.md, PR 35)
+@functools.partial(jax.jit, static_argnames=("expert_act",))
+def _held_forward(x, weights, rest, flat, order, group_sizes, *, expert_act):
+    return _live_rows(x, weights, rest, flat, order, group_sizes,
+                      expert_act=expert_act)
 
 
-# jitted: a net's share-holding layers of one shape are traced once, both
-# paths and their vjps, not once a layer (set-up time: PERF.md, PR 35)
-@functools.partial(jax.jit, static_argnames=("cap", "expert_act"))
-def _held_forward(x, weights, rest, flat, order, group_sizes, *, cap,
-                  expert_act):
-    compact, full = _held_paths(cap, expert_act)
-    return jax.lax.cond(
-        takes_compact(jnp.sum(group_sizes), cap),
-        lambda *ops: compact(*ops), lambda *ops: full(*ops),
-        x, weights, rest, flat, order, group_sizes)
-
-
-@functools.partial(jax.jit, static_argnames=("cap", "expert_act"))
-def _held_backward(x, weights, rest, flat, order, group_sizes, dy, *, cap,
+@functools.partial(jax.jit, static_argnames=("expert_act",))
+def _held_backward(x, weights, rest, flat, order, group_sizes, dy, *,
                    expert_act):
-    def pull(path):
-        return lambda x, weights, rest, dy: jax.vjp(
-            lambda *diff: path(*diff, flat, order, group_sizes),
-            x, weights, rest)[1](dy)
-
-    compact, full = _held_paths(cap, expert_act)
-    return jax.lax.cond(takes_compact(jnp.sum(group_sizes), cap),
-                        pull(compact), pull(full), x, weights, rest, dy)
+    return jax.vjp(
+        lambda *diff: _live_rows(*diff, flat, order, group_sizes,
+                                 expert_act=expert_act),
+        x, weights, rest)[1](dy)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7))
-def _held_rows(x, weights, rest, flat, order, group_sizes, cap, expert_act):
-    """y [T, D] of a layer that holds a share of its experts: at the
-    capacity when the held pairs fit it, else over all rows.
-
-    A ``lax.cond`` that autodiff differentiates hands BOTH branches'
-    residuals out of the forward, the untaken ones as zeros: a step at the
-    capacity would still write the other branch's [T·k, ·] arrays.  So the
-    forward keeps only its operands and the backward branches again, each
-    branch the vjp of its own path."""
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6,))
+def _held_rows(x, weights, rest, flat, order, group_sizes, expert_act):
+    """y [T, D] of a layer that holds a share of its experts.  The forward
+    keeps only its operands and the backward runs the path again: its
+    [R, ·] arrays live inside one pass of one layer."""
     return _held_forward(x, weights, rest, flat, order, group_sizes,
-                         cap=cap, expert_act=expert_act)
+                         expert_act=expert_act)
 
 
-def _held_rows_fwd(x, weights, rest, flat, order, group_sizes, cap,
-                   expert_act):
+def _held_rows_fwd(x, weights, rest, flat, order, group_sizes, expert_act):
     ops = (x, weights, rest, flat, order, group_sizes)
-    return _held_forward(*ops, cap=cap, expert_act=expert_act), ops
+    return _held_forward(*ops, expert_act=expert_act), ops
 
 
-def _held_rows_bwd(cap, expert_act, ops, dy):
-    grads = _held_backward(*ops, dy, cap=cap, expert_act=expert_act)
+def _held_rows_bwd(expert_act, ops, dy):
+    grads = _held_backward(*ops, dy, expert_act=expert_act)
     return (*grads, None, None, None)
 
 
@@ -534,28 +695,24 @@ def moe_dropless(params, x, *, top_k: int, expert_act: str,
     here, ``[first_expert, first_expert + n)`` with n their leading axis
     (all E by default).  A pair routed to an expert that is not held adds
     nothing: its rows sort behind the last held group and its weight
-    reaches no sum.  Where the layer has a ``capacity`` and the step's
-    held pairs fit it, only that many sorted rows are gathered, computed
-    and combined (``_capacity_rows``); otherwise all T·k are, and the
-    grouped matmuls skip the dead ones (``_all_rows``).  Either way no
-    pair is dropped.  ``load`` counts the pairs of every router output,
-    held or not."""
+    reaches no sum.  A layer that holds a share moves, computes and
+    combines its live rows alone, in tiles read off ``group_sizes`` on the
+    device (``_live_rows``); one that holds every expert has no dead row
+    (``_all_rows``).  Either way no pair is dropped.  ``load`` counts the
+    pairs of every router output, held or not."""
     w_router, rest = params[0], tuple(params[1:])
     num_experts, held_n = w_router.shape[0], rest[0].shape[0]
-    whole = held_n == num_experts  # every expert lives here: no pair is dead
     with jax.named_scope(ROUTE_SCOPE):
         logits, probs, weights, experts = route(
             w_router, x, top_k, norm_topk_prob, scoring=scoring,
             select_bias=select_bias, scale=scale)
     flat, order, group_sizes, load = sort_pairs(
         experts, num_experts, held_n, first_expert)
-    cap = capacity(order.shape[0], held_n, num_experts)
-    if cap:
-        y = _held_rows(x, weights, rest, flat, order, group_sizes, cap,
-                       expert_act)
-    else:
+    if held_n < num_experts:
+        y = _held_rows(x, weights, rest, flat, order, group_sizes, expert_act)
+    else:  # every expert lives here: no pair is dead
         y = _all_rows(x, weights, rest, flat, order, group_sizes,
-                      live=not whole, expert_act=expert_act)
+                      expert_act=expert_act)
     return y, logits, probs, experts, load.astype(jnp.float32)
 
 
@@ -591,10 +748,6 @@ class MoELayer(Layer):
         # this chip's share: experts [first_expert, first_expert + held)
         self.first_expert = p.get_int("first_expert", 0)
         self.experts_held = p.get_int("experts_held", self.num_experts)
-        # sorted rows a share dispatches when its held pairs fit (0: the
-        # layer has one path); what Solver._fence_stats counts against,
-        # known once shapes are (init)
-        self.capacity = 0
         if self.expert_act not in ("relu", "swiglu"):
             raise ValueError(
                 f"{self.name}: unknown expert_act {self.expert_act!r} "
@@ -629,8 +782,6 @@ class MoELayer(Layer):
         D = in_shapes[0][-1]
         H = self.hidden_dim or 4 * D
         E, N = self.num_experts, self.experts_held
-        self.capacity = capacity(
-            math.prod(in_shapes[0][:-1]) * self.top_k, N, E)
         kg, k1, k2, k3 = jax.random.split(key, 4)
         w_router = fill(self.weight_filler, kg, (E, D))
         state = {"load": jnp.zeros((E,), jnp.float32)}

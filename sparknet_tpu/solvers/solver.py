@@ -29,7 +29,7 @@ from sparknet_tpu.compiler.graph import Network, NetVars
 from sparknet_tpu.obs import get_recorder
 from sparknet_tpu.obs.recorder import Span
 from sparknet_tpu.ops.attention import AttentionLayer
-from sparknet_tpu.ops.moe import takes_compact
+from sparknet_tpu.ops.moe import CAPACITY_TILE, live_tiles
 from sparknet_tpu.proto.text_format import Message, parse_file
 from sparknet_tpu.solvers.lr_policy import learning_rate
 from sparknet_tpu.solvers.updates import apply_update, init_slots
@@ -599,10 +599,12 @@ class Solver:
         experts of one layer, whose quotient is the mean.  Where a layer
         holds a share of its experts, or selects with a balancing bias:
         the layers counted, the pairs that landed on held experts over
-        all of them, how many of the layers ran that step at their
-        capacity (``ops/moe.py takes_compact``, the predicate the device
-        branched on, asked of the same ``load``), and the bias's
-        extremes.  A loss layer that keeps
+        all of them, the sorted rows those layers moved that step
+        (``moe_rows_moved``: whole tiles of 512, ``ops/moe.py
+        live_tiles``, the count the device's loops ran, asked of the same
+        ``load``), how many of the layers moved fewer rows than all
+        their pairs (``moe_compact_layers``), and the bias's extremes.
+        A loss layer that keeps
         its ``value`` (``loss_param { keep_value: true }``) gives it under
         the layer's name.  The selective-scan layers (``ops/ssm.py``)
         keep no state between steps: the fence names how many there are,
@@ -663,10 +665,11 @@ class Solver:
         if any(l.experts_held < l.num_experts for l in layers):
             held = [int(loads[l.name][l.first_expert:][:l.experts_held].sum())
                     for l in layers]
+            moved = [CAPACITY_TILE * live_tiles(n) for n in held]
             stats.update(moe_layers=len(layers), moe_pairs_held=sum(held),
+                         moe_rows_moved=sum(moved),
                          moe_compact_layers=sum(
-                             bool(takes_compact(n, l.capacity))
-                             for n, l in zip(held, layers)))
+                             rows < stats["moe_pairs"] for rows in moved))
         biases = [np.asarray(st["bias"]) for st in state.values()
                   if "bias" in st]
         if biases:

@@ -428,8 +428,9 @@ def test_tpunet_train_trains_joyai_from_prototxt_and_a_token_file(tmp_path):
 
 def test_the_fence_carries_the_new_counters():
     """After ``Solver.step``: pairs on held experts over the layers, the
-    layers that ran at their capacity, the bias's extremes, the MTP term
-    of the loss (kept in its layer's state)."""
+    sorted rows those layers moved (whole tiles of their held pairs) and
+    how many of them moved fewer than all their pairs, the bias's
+    extremes, the MTP term of the loss (kept in its layer's state)."""
     solver = make_solver()
     solver.step(2, lambda it: batch_of(it))
     stats = solver._fence_stats()
@@ -440,27 +441,46 @@ def test_the_fence_carries_the_new_counters():
     assert stats["moe_bias_min"] == pytest.approx(-0.002)
     assert stats["moe_bias_max"] == pytest.approx(0.002)
     assert 3.0 < stats["mtp_loss"] < 6.0  # ~ln(97) at initialisation
-    # 4 of 16 experts: 6 x the level share is every pair, so no layer has
-    # a capacity and none is counted at one
-    assert [l.capacity for l in solver.train_net.layers
-            if l.type == "MoE"] == [0, 0, 0]
+    # 256 pairs a layer: one tile of 512 rows holds whatever a layer holds
+    # and is no fewer rows than its pairs, so no layer counts as compact
+    assert stats["moe_rows_moved"] == 3 * 512
     assert stats["moe_compact_layers"] == 0
-    # 4 of 128 on 1,024 pairs: a capacity of 512 rows, and the count is
-    # what the device's own predicate says of the fence's loads
+
+
+def test_the_fence_span_counts_the_rows_the_loops_walked():
+    """4 of 128 experts on 1,024 pairs a layer: ``moe_rows_moved`` and
+    ``moe_compact_layers`` on the ``sn.step.fence`` span are what the
+    device's own count (``ops/moe.py live_tiles``) says of the ``load``
+    the stepped solver holds."""
+    from sparknet_tpu.obs import recorder
+
     wide = Solver(models.joyai_flash_solver(), models.joyai_flash(**dict(
         TINY, seq_len=128, experts=128)))
     wide.step(1, lambda it: {k: np.tile(v, (1, 4)) for k, v in
                              batch_of(it).items()})
     layers = [l for l in wide.train_net.layers if l.type == "MoE"]
-    assert [l.capacity for l in layers] == [512, 512, 512]
     held = [int(np.asarray(wide.variables.state[l.name]["load"])[4:8].sum())
             for l in layers]
-    stats = wide._fence_stats()
+    assert len(held) == 3 and all(0 < n < 1024 for n in held)
+    moved = [512 * int(moe.live_tiles(n)) for n in held]
+    fence = [s for s in recorder.flight()[0] if s[0] == "sn.step.fence"][-1]
+    stats = fence[4]
+    assert stats["it"] == wide.iter == 1
+    assert stats["moe_pairs"] == 1024 and stats["moe_layers"] == 3
     assert stats["moe_pairs_held"] == sum(held)
-    assert stats["moe_compact_layers"] == sum(
-        bool(moe.takes_compact(n, 512)) for n in held)
+    assert stats["moe_rows_moved"] == sum(moved)
+    assert stats["moe_compact_layers"] == sum(rows < 1024 for rows in moved)
     assert stats["moe_compact_layers"] >= 1
-    # a whole layer without a bias keeps to the counters it had
+    assert stats == {"it": 1, **wide._fence_stats()}
+    # and the benchmark's reader, as it stands, reads this fence
+    from benchmarks.harness import load_by_name
+    share = load_by_name("metrics", "moe.compact_share").read(
+        {"decoder_scopes": {"scope_s": {}, "fences": [stats]}}, {})
+    assert share == 100.0 * stats["moe_compact_layers"] / 3
+
+
+def test_a_whole_layer_keeps_to_the_counters_it_had():
+    # a whole layer without a bias: no share, so neither new counter
     plain = Solver(models.olmoe_solver(), models.olmoe(
         batch=2, seq_len=32, vocab=97, hidden=64, heads=4, experts=8,
         top_k=2, expert_dim=32, layers=1))

@@ -143,14 +143,15 @@ def test_compiled_step_copies_no_expert_matrix(v5e, bf16_compute,
     assert expert_copies.expert_copies(text, shapes) == []
 
 
-def test_compiled_share_step_branches_to_a_path_with_no_array_of_all_pairs(
+def test_compiled_share_step_moves_its_rows_a_tile_at_a_time(
         v5e, bf16_compute, no_compile_cache):
-    """A toy JoyAI step (4 of 128 experts held, 2,048 pairs a layer, a
-    capacity of 512 rows) compiled for the v5e: each share-holding layer
-    is one ``conditional`` a pass, whose branch at the capacity holds no
-    [T·k, ·] and no [T, k, ·] array, while the branch over all rows does
-    (PR 35; ``tools/expert_copies.py pair_arrays``).  Its expert matrices
-    are copied as little as OLMoE's."""
+    """A toy JoyAI step (4 of 128 experts held, 2,048 pairs a layer)
+    compiled for the v5e: no ``conditional`` is left, each share-holding
+    layer's movers are ``while`` loops whose every gather and scatter
+    moves one tile of 512 rows, and outside the loops nothing moves more
+    rows than the step's 512 tokens (the embedding's own), so none moves
+    a layer's 2,048 pairs (PR 49; ``tools/expert_copies.py row_moves``).
+    Its expert matrices are copied as little as OLMoE's."""
     toy = dict(batch=2, seq_len=256, vocab=512, hidden=D, heads=2,
                q_lora_rank=64, kv_lora_rank=64, qk_nope_head_dim=32,
                qk_rope_head_dim=16, v_head_dim=32, dense_dim=H, experts=128,
@@ -160,13 +161,17 @@ def test_compiled_share_step_branches_to_a_path_with_no_array_of_all_pairs(
     cfg = dataclasses.replace(models.joyai_flash_solver(), display=0)
     compiled, variables = expert_copies.compile_step(
         cfg, net, (toy["batch"], toy["seq_len"]), v5e)
-    assert [l.capacity for l in net.layers if l.TYPE == "MoE"] == [512, 512]
     text = compiled.as_text()
-    pairs = toy["batch"] * toy["seq_len"] * toy["top_k"]
-    branches = expert_copies.pair_arrays(text, pairs, toy["top_k"])
-    assert len(branches) == 4  # two layers, forward and backward
-    for over_all_rows, at_capacity in branches:
-        assert at_capacity == 0 and over_all_rows >= 8
+    assert " conditional(" not in text
+    tokens = toy["batch"] * toy["seq_len"]
+    moves = expert_copies.row_moves(text)
+    inside = [m for m in moves if m["in_loop"]]
+    # a layer: x's rows gathered in both passes and dy's in the backward,
+    # the weighted rows and x's cotangent added to their tokens
+    assert sorted((m["op"], m["rows"], m["width"]) for m in inside) == (
+        [("gather", moe.CAPACITY_TILE, D)] * 6
+        + [("scatter", moe.CAPACITY_TILE, D)] * 4)
+    assert max(m["rows"] for m in moves if not m["in_loop"]) == tokens
     shapes = expert_copies.expert_shapes(net, variables)
     assert shapes == {(4, D, D)}
     assert expert_copies.expert_copies(text, shapes) == []

@@ -10,11 +10,13 @@ Compiles the solo step program of a decoder configuration under
 nothing is materialized), and prints ``memory_analysis()`` and the
 ``copy`` instructions of the ENTRY computation whose result has the shape
 of an f32 expert matrix (a rank-3 blob of an ``MoE`` layer: the matrix
-itself or one of its AdamW moments); exit code 1 if there is one.  Since
-PR 35 it also counts, in each branch of the ``conditional``s a
-share-holding layer compiles to (one a pass), the arrays over ALL T·k
-(token, slot) pairs (``pair_arrays``): the branch at the layer's capacity
-must hold none (exit code 1 otherwise).  PR 31 found 18 expert copies
+itself or one of its AdamW moments); exit code 1 if there is one.  Where
+a layer holds a share of its experts it also lists the program's
+``gather`` / ``scatter`` instructions by the rows they move
+(``row_moves``): since PR 49 such a layer moves its rows inside loops
+over its live tiles, 512 rows a trip, so outside the loops nothing may
+move more rows than the step has tokens (exit code 1 otherwise: a mover
+over a capacity's or all T·k pairs' rows).  PR 31 found 18 expert copies
 of 537 MB in the OLMoE step and 90 of 50 MB in JoyAI's: XLA had folded the
 transpose megablox put behind its weight gradient into the layout of the
 update, and converted the matrix and both moments there and back in
@@ -159,7 +161,6 @@ def activation_copies(hlo_text: str, elements: int,
 
 
 _COMPUTATION = re.compile(r"^(?:ENTRY )?%(?P<name>[\w.\-]+) \(.*\{$")
-_RESULT = re.compile(r"^\s*(?:ROOT\s+)?%[\w.\-]+ = \w+\[(?P<dims>[\d,]+)\]")
 _CALLED = re.compile(
     r"(?:calls|to_apply|body|condition|true_computation|false_computation)="
     r"%([\w.\-]+)|branch_computations=\{([^}]*)\}")
@@ -185,38 +186,62 @@ def _callees(line: str) -> list[str]:
             for n in ([one] if one else many.split(","))]
 
 
-def pair_arrays(hlo_text: str, pairs: int, top_k: int) -> list[list[int]]:
-    """For each ``conditional`` of the compiled program whose branches
-    differ in it: per branch, the instructions (in the branch and in what
-    it calls) whose result is an array over ALL (token, slot) pairs:
-    [pairs, ·] or [pairs / top_k, top_k, ·].  ``ops/moe.py``'s share-holding
-    layer branches between a path at its capacity (branch 1) and the path
-    over all rows (branch 0), once a pass: the first must read 0."""
+_MOVE = re.compile(
+    r"^\s*(?:ROOT\s+)?%(?P<name>[\w.\-]+) = \w+\[(?P<dims>[\d,]*)\]\S* "
+    r"(?P<op>gather|scatter)\((?P<operands>[^)]*)\)(?P<attrs>.*)$")
+_DEFINED = re.compile(
+    r"^\s*(?:ROOT\s+)?%(?P<name>[\w.\-]+) = \w+\[(?P<dims>[\d,]*)\]")
+
+
+def row_moves(hlo_text: str) -> list[dict]:
+    """Every ``gather`` and ``scatter`` of the compiled program that moves
+    rows wider than one element: {"op", "rows", "width", "in_loop"}.
+    ``rows`` is how many index positions it moves (a gather's result
+    without its offset dims, a scatter's updates without their window
+    dims), ``width`` the elements of one of them, ``in_loop`` whether a
+    ``while``'s body (or what it calls) holds it.  ``ops/moe.py``'s
+    share-holding layer moves its rows inside loops over the live tiles,
+    512 rows a trip (PR 49): outside the loops no mover of such a layer
+    is left, so none there moves more rows than the step has tokens."""
     comps = computations(hlo_text)
+    in_loops: set[str] = set()
 
-    def over_pairs(line):
-        m = _RESULT.match(line)
-        dims = [int(d) for d in m["dims"].split(",")] if m else []
-        return len(dims) >= 2 and (dims[0] == pairs or (
-            len(dims) == 3 and dims[:2] == [pairs // top_k, top_k]))
+    def mark(name):
+        if name in comps and name not in in_loops:
+            in_loops.add(name)
+            for line in comps[name]:
+                for callee in _callees(line):
+                    mark(callee)
 
-    def count(name, seen):
-        if name in seen or name not in comps:
-            return 0
-        seen.add(name)
-        return sum(over_pairs(l) + sum(count(c, seen) for c in _callees(l))
-                   for l in comps[name])
-
-    found = []
     for lines in comps.values():
         for line in lines:
-            m = re.search(r" conditional\(.*branch_computations=\{([^}]*)\}",
-                          line)
-            if m:
-                counts = [count(n.strip().lstrip("%"), set())
-                          for n in m[1].split(",")]
-                if len(set(counts)) > 1:
-                    found.append(counts)
+            if " while(" in line:
+                for body in re.findall(r"body=%([\w.\-]+)", line):
+                    mark(body)
+    dims_of = lambda text: [int(d) for d in text.split(",") if d]
+    listed = lambda attrs, key: [int(d) for d in re.search(
+        key + r"=\{([\d,]*)\}", attrs)[1].split(",") if d]
+    found = []
+    for name, lines in comps.items():
+        defined = {m["name"]: dims_of(m["dims"])
+                   for m in map(_DEFINED.match, lines) if m}
+        for line in lines:
+            m = _MOVE.match(line)
+            if not m:
+                continue
+            if m["op"] == "gather":
+                dims, wide = dims_of(m["dims"]), listed(m["attrs"],
+                                                        "offset_dims")
+            else:  # (operand, indices, updates): the updates are what moves
+                updates = m["operands"].split(",")[-1].split()[-1].lstrip("%")
+                dims, wide = defined[updates], listed(m["attrs"],
+                                                      "update_window_dims")
+            width = math.prod(d for i, d in enumerate(dims) if i in wide)
+            if width > 1:
+                found.append({
+                    "op": m["op"], "width": width, "in_loop": name in in_loops,
+                    "rows": math.prod(d for i, d in enumerate(dims)
+                                      if i not in wide)})
     return found
 
 
@@ -269,15 +294,20 @@ def main() -> int:
         text, math.prod(batch) * config["hidden_size"])
     row = report(f"{a.config} solo step, {batch[0]} sequences", compiled,
                  text, copies, activations, time.time() - t0)
-    # a share-holding layer's two paths: [all rows, at the capacity] a cond
-    shares = [l for l in net.layers if l.TYPE == "MoE" and l.capacity]
-    branches = pair_arrays(text, math.prod(batch) * shares[0].top_k,
-                           shares[0].top_k) if shares else []
-    row["pair_arrays_by_branch"] = branches
+    # a share-holding layer moves its rows inside loops over its live tiles
+    shares = [l for l in net.layers
+              if l.TYPE == "MoE" and l.experts_held < l.num_experts]
+    moves = row_moves(text) if shares else []
+    by_rows = lambda ms: {str(r): sum(m["rows"] == r for m in ms)
+                          for r in sorted({m["rows"] for m in ms})}
+    wide = [m for m in moves
+            if not m["in_loop"] and m["rows"] > math.prod(batch)]
+    row["row_moves_in_loops"] = by_rows([m for m in moves if m["in_loop"]])
+    row["row_moves_over_the_tokens_outside_loops"] = by_rows(wide)
     print(json.dumps(row), flush=True)
     for c in copies:
         print(json.dumps(c))
-    return 1 if copies or any(b[1:] != [0] for b in branches) else 0
+    return 1 if copies or wide else 0
 
 
 if __name__ == "__main__":
