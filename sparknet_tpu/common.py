@@ -272,7 +272,8 @@ def require_chip(tool: str) -> dict:
 # compile-cache key.  Add a scope, add it here
 CACHE_SCOPES = ("scopes:L.<layer>,S.update,S.augment,"
                 "M.route,M.dispatch,M.experts,M.combine,M.shared,"
-                "A.core,A.latent,R.scan,R.gate,D.delta,LOOP.<region>")
+                "A.core,A.latent,A.rope,A.gate,R.scan,R.gate,D.delta,"
+                "LOOP.<region>")
 
 
 def enable_compile_cache() -> str:

@@ -491,10 +491,19 @@ def GatedAttentionLayer(
     rope_theta: float = 10000.0,
     norm_eps: float = 1e-6,
     weight_filler: Message | None = None,
+    qk_norm: bool = True,
+    head_gate: bool = False,
+    window: int = 0,
+    rope_scaling: dict | None = None,
 ) -> Message:
-    """Causal grouped attention with heads of ``head_dim``, per-head
-    QK-norm, RoPE on the first ``rotary_dim`` features of a head and a
-    sigmoid gate on its output (ops/attention.py GatedAttentionLayer)."""
+    """Causal grouped attention with heads of ``head_dim``, RoPE on the
+    first ``rotary_dim`` features of a head and a sigmoid gate on its
+    output (ops/attention.py GatedAttentionLayer).  The defaults are the
+    Qwen3-Next layer (per-head QK-norm, a gate as wide as the heads);
+    ``qk_norm=False``, ``head_gate`` (one gate a head), ``window`` and
+    ``rope_scaling`` (YaRN: ``factor``, ``original_max_position_embeddings``,
+    ``beta_fast``, ``beta_slow``, ``attention_factor``) are written only
+    where they differ from them."""
     m = _layer(name, "GatedAttention", bottoms)
     p = Message().set("num_heads", num_heads).set("num_kv_heads", num_kv_heads)
     p.set("head_dim", head_dim)
@@ -502,6 +511,17 @@ def GatedAttentionLayer(
         p.set("rotary_dim", rotary_dim)
     p.set("rope_theta", rope_theta).set("norm_eps", norm_eps)
     p.set("causal", True)
+    if not qk_norm:
+        p.set("qk_norm", False)
+    if head_gate:
+        p.set("head_gate", True)
+    if window:
+        p.set("window", window)
+    if rope_scaling is not None:
+        r = Message().set("type", "yarn")
+        for key, value in rope_scaling.items():
+            r.set(key, value)
+        p.set("rope_scaling", r)
     if weight_filler is not None:
         p.set("weight_filler", weight_filler)
     return m.set("attention_param", p)

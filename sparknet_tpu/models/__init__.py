@@ -39,6 +39,8 @@ from sparknet_tpu.models.zoo import (  # noqa: F401
     mnist_siamese_solver,
     joyai_flash,
     joyai_flash_solver,
+    laguna,
+    laguna_solver,
     olmoe,
     olmoe_solver,
     ouro,

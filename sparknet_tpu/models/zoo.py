@@ -1602,6 +1602,141 @@ def qwen3_next_solver() -> SolverConfig:
 
 
 # ---------------------------------------------------------------------------
+# Laguna-XS.2 — grouped softmax attention whose layers differ by KIND: three
+# in four see a window of 512 keys with 64 query heads and plain RoPE over
+# the whole head, every fourth sees every key with 48 query heads and YaRN
+# over half a head; all over 8 key/value heads of 128, each with a
+# head-wise sigmoid gate on its output; one leading dense SwiGLU, then
+# top-8-of-256 sigmoid-routed experts beside one shared expert
+# (poolside/Laguna-XS.2 config.json, ``model_type: laguna``; no reference
+# analog).  Pre-norm blocks, plain RMSNorm, untied head.
+# ---------------------------------------------------------------------------
+LAGUNA_ROPE_PARAMETERS = {
+    "full_attention": {
+        "rope_theta": 500000, "rope_type": "yarn", "factor": 64,
+        "original_max_position_embeddings": 4096, "beta_slow": 1,
+        "beta_fast": 64, "attention_factor": 1.4158883083359672,
+        "partial_rotary_factor": 0.5},
+    "sliding_attention": {
+        "rope_type": "default", "rope_theta": 10000,
+        "partial_rotary_factor": 1},
+}
+
+
+def laguna(
+    batch: int = 1,
+    seq_len: int = 8192,
+    vocab: int = 100352,
+    hidden: int = 2048,
+    layers: int = 40,
+    layer_types: tuple = ("full_attention",) + ("sliding_attention",) * 3,
+    heads_per_layer: tuple = (48, 64, 64, 64),
+    mlp_layer_types: tuple = ("dense",) + ("sparse",) * 39,
+    kv_heads: int = 8,
+    head_dim: int = 128,
+    window: int = 512,
+    rope_parameters: dict | None = None,
+    dense_dim: int = 8192,
+    experts: int = 256,
+    top_k: int = 8,
+    expert_dim: int = 512,
+    shared_dim: int = 512,
+    routed_scaling_factor: float = 2.5,
+    experts_held: int | None = None,
+    first_expert: int = 0,
+    rms_norm_eps: float = 1e-6,
+    aux_loss_coef: float = 0.001,
+    init_std: float = 0.02,
+) -> Message:
+    """Laguna-XS.2 at its published sizes by default: [batch, seq_len]
+    token ids -> per-token next-token logits.  Block i (from 0):
+    ``norm<i>a`` -> ``attn<i>`` -> ``res<i>a`` -> ``norm<i>b`` ->
+    ``mlp<i>`` (dense) or ``moe<i>`` -> ``res<i>b``, each sized by the
+    published per-layer lists: ``layer_types[i]`` (window or not, and
+    which entry of ``rope_parameters``, the family's own group of that
+    name, turns its heads), ``heads_per_layer[i]`` query heads,
+    ``mlp_layer_types[i]``.  A list shorter than ``layers`` is a period
+    and repeats; a longer one (the whole model's, beside a cut's depth)
+    is read from its start.  ``loss`` is the mean cross-entropy per
+    token; each expert layer's ``lb<i>`` top carries the load-balancing
+    loss at ``aux_loss_coef`` (a sum over the layers).  ``experts_held``
+    / ``first_expert`` give this chip's share of every expert layer (all
+    ``experts`` by default), ``vocab`` the rows of the embedding and the
+    head it holds."""
+    init = _gauss(init_std)
+    ropes = rope_parameters or LAGUNA_ROPE_PARAMETERS
+    net = [
+        RDDLayer("data", shape=[batch, seq_len]),
+        RDDLayer("label", shape=[batch, seq_len]),
+        EmbedLayer("embed", ["data"], input_dim=vocab, num_output=hidden,
+                   weight_filler=init, bias_term=False, top="embed"),
+    ]
+    x = "embed"
+    for i in range(layers):
+        kind = layer_types[i % len(layer_types)]
+        if kind not in ("full_attention", "sliding_attention"):
+            raise ValueError(f"layer_types[{i}] = {kind!r}")
+        r = ropes[kind]
+        rope_type = r.get("rope_type", "default")
+        if rope_type not in ("default", "yarn"):
+            raise ValueError(f"rope_type {rope_type!r} of {kind}")
+        if mlp_layer_types[i % len(mlp_layer_types)] == "dense":
+            ffn = GatedMLPLayer(f"mlp{i}", [f"norm{i}b"], dense_dim,
+                                weight_filler=init)
+        else:
+            ffn = MoELayer(
+                f"moe{i}", [f"norm{i}b"], num_experts=experts,
+                hidden_dim=expert_dim, top_k=top_k, expert_act="swiglu",
+                norm_topk_prob=True, scoring_func="sigmoid",
+                routed_scaling_factor=routed_scaling_factor,
+                shared_hidden_dim=shared_dim, experts_held=experts_held,
+                first_expert=first_expert, weight_filler=init,
+                loss_tops=((f"lb{i}", aux_loss_coef),))
+        name = ffn.get_str("name")
+        net += [
+            RMSNormLayer(f"norm{i}a", [x], eps=rms_norm_eps),
+            GatedAttentionLayer(
+                f"attn{i}", [f"norm{i}a"],
+                num_heads=heads_per_layer[i % len(heads_per_layer)],
+                num_kv_heads=kv_heads, head_dim=head_dim,
+                rotary_dim=int(head_dim * r.get("partial_rotary_factor", 1)),
+                rope_theta=float(r["rope_theta"]), norm_eps=rms_norm_eps,
+                weight_filler=init, qk_norm=False, head_gate=True,
+                window=window if kind == "sliding_attention" else 0,
+                rope_scaling={k: r[k] for k in (
+                    "factor", "original_max_position_embeddings",
+                    "beta_fast", "beta_slow", "attention_factor")
+                    if k in r} if rope_type == "yarn" else None),
+            EltwiseLayer(f"res{i}a", [x, f"attn{i}"], top=f"res{i}a"),
+            RMSNormLayer(f"norm{i}b", [f"res{i}a"], eps=rms_norm_eps),
+            ffn,
+            EltwiseLayer(f"res{i}b", [f"res{i}a", name], top=f"res{i}b"),
+        ]
+        x = f"res{i}b"
+    net += [
+        RMSNormLayer("norm_f", [x], eps=rms_norm_eps),
+        InnerProductLayer("lm_head", ["norm_f"], num_output=vocab, axis=2,
+                          weight_filler=init, bias_term=False),
+        SoftmaxWithLoss("loss", ["lm_head", "label"], axis=2),
+        AccuracyLayer("accuracy", ["lm_head", "label"], phase="TEST", axis=2),
+    ]
+    return NetParam("Laguna", *net)
+
+
+def laguna_solver() -> SolverConfig:
+    """AdamW, lr 3e-4, betas 0.9 / 0.95, eps 1e-8, decoupled weight decay
+    0.1 on every parameter, gradient clipping at global norm 1.0, a FIXED
+    lr: the model's card states no recipe, so every value is an
+    assumption the benchmark's configuration file lists.  The schedule is
+    left to the prototxt's lr_policy."""
+    return SolverConfig(
+        base_lr=3e-4, lr_policy="fixed", momentum=0.9, momentum2=0.95,
+        delta=1e-8, weight_decay=0.1, clip_gradients=1.0,
+        max_iter=10000, solver_type="AdamW", display=100,
+    )
+
+
+# ---------------------------------------------------------------------------
 # Cached per-token decode step (ISSUE 19, ROADMAP item 4).
 #
 # The rectangle decode path (serve/continuous.py) rebuilds the FULL

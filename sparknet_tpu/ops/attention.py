@@ -36,11 +36,13 @@ the same prototxt model scales to long contexts with no model changes.
 from __future__ import annotations
 
 import contextlib
+import math
 import threading
 from functools import partial
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from sparknet_tpu.common import get_config
 from sparknet_tpu.ops.base import Layer, LayerOutput
@@ -128,8 +130,57 @@ def _sp_attention(mesh, impl, q, k, v, causal):
     )(q, k, v)
 
 
+def yarn_ramp_bounds(rotary_dim: int, base: float, original_max: int,
+                     beta_fast: float, beta_slow: float) -> tuple[float, float]:
+    """(low, high) of :func:`yarn_inv_freq`'s ramp, in feature pairs."""
+    dim = lambda turns: (rotary_dim * math.log(
+        original_max / (2 * math.pi * turns)) / (2 * math.log(base)))
+    low = max(math.floor(dim(beta_fast)), 0)
+    high = min(math.ceil(dim(beta_slow)), rotary_dim - 1)
+    # the public code keeps the ramp from dividing by zero the same way
+    return float(low), float(high) + (0.001 if low == high else 0.0)
+
+
+def yarn_inv_freq(rotary_dim: int, base: float, factor: float,
+                  original_max: int, beta_fast: float = 32.0,
+                  beta_slow: float = 1.0):
+    """YaRN's blended inverse frequencies over ``rotary_dim`` features
+    (Peng et al. 2023, arXiv:2309.00071, as the public
+    ``_compute_yarn_parameters`` computes them) -> numpy f32
+    [rotary_dim / 2], for :func:`rope`'s ``inv_freq``.
+
+    With pos_i = base^(2i / r), i in [0, r / 2): a feature pair that turns
+    more than ``beta_fast`` times over the ``original_max`` positions
+    keeps its frequency 1 / pos_i, one that turns fewer than ``beta_slow``
+    times is interpolated to 1 / (factor pos_i), and between the two a
+    linear ramp blends them: dim(n) = r ln(original_max / (2 pi n)) /
+    (2 ln base), low = max(floor(dim(beta_fast)), 0), high =
+    min(ceil(dim(beta_slow)), r - 1), ramp_i = clip((i - low) /
+    (high - low), 0, 1), inv_freq_i = ramp_i / (factor pos_i) +
+    (1 - ramp_i) / pos_i.  Computed in float64 on the host and rounded
+    once; the ``attention_factor`` on cos and sin is :func:`rope`'s
+    ``scale``."""
+    low, high = yarn_ramp_bounds(rotary_dim, base, original_max, beta_fast,
+                                 beta_slow)
+    i = np.arange(rotary_dim // 2, dtype=np.float64)
+    pos = float(base) ** (2.0 * i / rotary_dim)
+    ramp = np.clip((i - low) / (high - low), 0.0, 1.0)
+    return (ramp / (factor * pos) + (1.0 - ramp) / pos).astype(np.float32)
+
+
+def _rope_theta(half: int, base: float, inv_freq) -> jax.Array:
+    """The angle a position turns feature pair i by: ``base^(-i / half)``,
+    or the table ``inv_freq`` [half] (:func:`yarn_inv_freq`) as given."""
+    if inv_freq is None:
+        return base ** (-jnp.arange(half, dtype=jnp.float32) / half)
+    if inv_freq.shape != (half,):
+        raise ValueError(f"inv_freq {inv_freq.shape} for {half} feature pairs")
+    return jnp.asarray(inv_freq, jnp.float32)
+
+
 def rope(x: jax.Array, base: float = 10000.0,
-         interleave: bool = False, scale: float = 1.0) -> jax.Array:
+         interleave: bool = False, scale: float = 1.0,
+         inv_freq=None) -> jax.Array:
     """Rotary position embedding over ``x`` [B, H, S, D] (D even).
 
     Parameter-free absolute-position encoding with the relative-position
@@ -145,13 +196,16 @@ def rope(x: jax.Array, base: float = 10000.0,
     (2i, 2i + 1) with ``interleave`` (the DeepSeek-V3 family's published
     layout, ``rope_interleave``); each feature stays where it was.
     ``scale`` multiplies the result inside the f32 product, before its
-    one rounding to ``x``'s dtype (:func:`attention_core`'s ``scaled``).
+    one rounding to ``x``'s dtype (:func:`attention_core`'s ``scaled``;
+    YaRN's ``attention_factor`` on cos and sin).  ``inv_freq`` [D / 2]
+    f32 replaces the θ_i (:func:`yarn_inv_freq`); ``base`` is then not
+    read.
     """
     B, H, S, D = x.shape
     if D % 2:
         raise ValueError(f"rope needs an even head dim, got {D}")
     half = D // 2
-    theta = base ** (-jnp.arange(half, dtype=jnp.float32) / half)  # [half]
+    theta = _rope_theta(half, base, inv_freq)  # [half]
     ang = jnp.arange(S, dtype=jnp.float32)[:, None] * theta[None, :]  # [S,half]
     cos, sin = jnp.cos(ang), jnp.sin(ang)
     if scale != 1.0:
@@ -168,24 +222,28 @@ def rope(x: jax.Array, base: float = 10000.0,
 
 
 def rope_at(x: jax.Array, positions: jax.Array,
-            base: float = 10000.0) -> jax.Array:
+            base: float = 10000.0, scale: float = 1.0,
+            inv_freq=None) -> jax.Array:
     """:func:`rope` at explicit absolute positions — the decode-path
     twin.  ``x`` is [B, H, W, D] (W the proposed-token width, 1 for
     plain decode) and ``positions`` [B, W] int32 absolute positions.
     Bitwise contract with :func:`rope`: for ``positions[b, w] == t`` the
     rotation applied here is the SAME float expression :func:`rope`
     applies at sequence index t (identical theta/cos/sin/rotate-half
-    arithmetic), so a cached K written through this path equals the K
-    the full-window forward computes at that row.
+    arithmetic, under ``scale`` and ``inv_freq`` too), so a cached K
+    written through this path equals the K the full-window forward
+    computes at that row.
     """
     B, H, W, D = x.shape
     if D % 2:
         raise ValueError(f"rope needs an even head dim, got {D}")
     half = D // 2
-    theta = base ** (-jnp.arange(half, dtype=jnp.float32) / half)  # [half]
+    theta = _rope_theta(half, base, inv_freq)  # [half]
     ang = positions.astype(jnp.float32)[..., None] * theta  # [B, W, half]
     cos = jnp.cos(ang)[:, None]  # [B, 1, W, half] — broadcast over heads
     sin = jnp.sin(ang)[:, None]
+    if scale != 1.0:
+        cos, sin = cos * scale, sin * scale
     x1, x2 = x[..., :half], x[..., half:]
     return jnp.concatenate(
         [x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1
@@ -208,6 +266,14 @@ def core_kernel(backend: str, S: int, D: int, Dv: int, causal: bool) -> str:
     tiles = S % 512 == 0 and D % 64 == 0 and Dv % 128 == 0
     long_on_tpu = backend == "tpu" and S >= 2048
     return "splash" if long_on_tpu and causal and tiles else "xla"
+
+
+def core_block(S: int, window: int = 0) -> int:
+    """The width of the query and key blocks :func:`attention_core` hands
+    the splash kernels: 1024, 512 where S is no multiple of 1024 or under
+    a window (a ``LocalMask``: the blocks the window never reaches are
+    skipped).  :func:`window_blocks` counts the same blocks."""
+    return 512 if window or S % 1024 else 1024
 
 
 def attention_core(q, k, v, causal: bool, window: int = 0,
@@ -261,8 +327,7 @@ def attention_core(q, k, v, causal: bool, window: int = 0,
                          "one before")
     window = 0 if window >= S else window
     if core_kernel(jax.default_backend(), S, D, Dv, causal) == "splash":
-        block = 512 if window or S % 1024 else 1024
-        return _splash_causal(q, k, v, block, window, scaled)
+        return _splash_causal(q, k, v, core_block(S, window), window, scaled)
     if scaled:
         raise ValueError("the XLA formulation scales the scores itself")
     if D != Dv or window or not k.shape[1] == v.shape[1] == H:
@@ -640,35 +705,80 @@ class DifferentialAttentionLayer(AttentionLayer):
         return LayerOutput(outputs=[y, k, v][:len(self.tops)])
 
 
+# device scopes of the rotary pass over q and k and of the output gate
+# (its sigmoid and product; a head-wise gate's own projection too) inside
+# the layer's ``L.<name>`` scope, beside ``A.core``; in common.CACHE_SCOPES
+ROPE_SCOPE = "A.rope"
+GATE_SCOPE = "A.gate"
+
+
+def window_blocks(S: int, window: int) -> tuple[int, int]:
+    """(visited, causal): of the (query block, key block) pairs at or
+    below the diagonal of an S x S causal core, at the width
+    :func:`core_block` gives the kernels, how many hold a key some query
+    of theirs sees under ``window`` (all of them without one): the blocks
+    a ``LocalMask`` leaves the splash kernels to visit."""
+    window = 0 if window >= S else window
+    block = core_block(S, window)
+    n = -(-S // block)
+    causal = n * (n + 1) // 2
+    if not window:
+        return causal, causal
+    # block j < i is seen from block i when its last key lies inside the
+    # window of block i's first query: (i - j - 1) * block + 1 < window
+    reach = (window - 2) // block + 1
+    return sum(min(i, reach) + 1 for i in range(n)), causal
+
+
 @register
 class GatedAttentionLayer(AttentionLayer):
-    """Softmax attention with grouped heads of a width of their own,
-    per-head QK-norm, a rotary embedding over PART of a head and a sigmoid
-    gate on its output (the Qwen3-Next family's full-attention layer, as
-    its ``config.json`` sizes it: ``head_dim``, ``num_key_value_heads``,
-    ``partial_rotary_factor``).
+    """Causal softmax attention with grouped heads of a width of their
+    own and a sigmoid gate on its output; what else the layer does is
+    said by its fields, so ONE class serves the Qwen3-Next family's
+    full-attention layer (per-head QK-norm, RoPE over a quarter of a
+    head, a gate as wide as the heads: the defaults) and both of the
+    Laguna family's (no QK-norm, a gate of one scalar a head; the sliding
+    kind under a window with plain RoPE over the whole head, the full
+    kind with YaRN over half of one), each as its ``config.json`` sizes
+    it.
 
     ``attention_param { num_heads num_kv_heads head_dim rotary_dim
-    rope_theta norm_eps causal }``; H query heads and Hk key and value
-    heads of D = ``head_dim`` (H D need not be E); ``rotary_dim`` (even,
-    <= D; default D) leading features of every q and k head turn
-    (rotate-half within them), the rest pass.  [B, S, E] -> [B, S, E];
+    rope_theta norm_eps causal qk_norm head_gate window rope_scaling {
+    type factor original_max_position_embeddings beta_fast beta_slow
+    attention_factor } }``; H query heads and Hk key and value heads of
+    D = ``head_dim`` (H D need not be E); ``rotary_dim`` (even, <= D;
+    default D) leading features of every q and k head turn (rotate-half
+    within them), the rest pass.  ``window`` > 0: query t sees keys
+    t - window + 1 .. t (:func:`attention_core`'s ``LocalMask`` form),
+    else every key up to t.  ``rope_scaling { type: "yarn" }``: the
+    turned features' frequencies are :func:`yarn_inv_freq`'s and cos and
+    sin carry ``attention_factor`` (default 0.1 ln(factor) + 1), so a
+    score's turned part carries its square.  [B, S, E] -> [B, S, E];
     blobs, every matrix ``[out, in]``, no biases:
 
-      W_q (2 H D, E)     per head [q (D) ; gate (D)], side by side
+      W_q (2 H D, E)     per head [q (D) ; gate (D)], side by side; or
+          (H D, E)       with ``head_gate``: the queries alone
       W_k (Hk D, E), W_v (Hk D, E)
       W_o (E, H D)
-      q_norm (D), k_norm (D)   RMSNorm per head, weight 1 + w, w from 0
+      q_norm (D), k_norm (D)   RMSNorm per head, weight 1 + w, w from 0;
+                               absent with ``qk_norm: false``
+      W_g (H, E)         with ``head_gate``: one gate a head and token
+                         (the head-wise form of arXiv:2505.06708)
 
-    q, k <- RMSNorm_D (zero-centred weight) then RoPE; o = the causal core
-    over H heads on Hk (:func:`attention_core`'s grouped form), scores
-    scaled by D^-1/2; y = W_o (o * sigmoid(gate)).  Head-major in and out
-    of the projections, as ``MultiHeadAttentionLayer``: x against the
-    [H, 2, D, E], [Hk, D, E] views of the matrices straight into
-    [B, H, S, D], and o contracted over (H, D) with W_o viewed [E, H, D]:
-    no token-major q, gate, k, v or o exists."""
+    q, k <- RMSNorm_D (zero-centred weight; ``qk_norm``) then RoPE
+    (``A.rope``); o = the causal core over H heads on Hk
+    (:func:`attention_core`'s grouped form, ``A.core``), scores scaled by
+    D^-1/2; y = W_o (o * sigmoid(gate)) (``A.gate``), the gate [.., D]
+    from W_q or [.., 1] from W_g.  Head-major in and out of the
+    projections, as ``MultiHeadAttentionLayer``: x against the
+    [H, 2, D, E] (or [H, D, E]), [Hk, D, E] and [H, E] views of the
+    matrices straight into [B, H, S, D] and [B, H, S], and o contracted
+    over (H, D) with W_o viewed [E, H, D]: no token-major q, gate, k, v
+    or o exists.  ``visited`` is :func:`window_blocks` of the last trace,
+    for ``Solver._fence_stats``."""
 
     TYPE = "GatedAttention"
+    visited = (0, 0)
 
     def __init__(self, lp, phase):
         super().__init__(lp, phase)
@@ -680,6 +790,9 @@ class GatedAttentionLayer(AttentionLayer):
         self.rope_theta = p.get_float("rope_theta", 10000.0)
         self.norm_eps = p.get_float("norm_eps", 1e-6)
         self.causal = p.get_bool("causal", True)
+        self.qk_norm = p.get_bool("qk_norm", True)
+        self.head_gate = p.get_bool("head_gate", False)
+        self.window = p.get_int("window", 0)
         if self.num_heads % self.num_kv_heads:
             raise ValueError(
                 f"{self.name}: {self.num_kv_heads} key heads must divide "
@@ -688,6 +801,27 @@ class GatedAttentionLayer(AttentionLayer):
             raise ValueError(
                 f"{self.name}: rotary_dim {self.rotary_dim} must be even "
                 f"and at most head_dim {self.head_dim}")
+        if self.window < 0 or (self.window and not self.causal):
+            raise ValueError(
+                f"{self.name}: window {self.window}: a window is causal "
+                "here, keys t - window + 1 .. t")
+        # the turned features' frequency table and the factor on cos, sin
+        self.inv_freq, self.rope_scale = None, 1.0
+        if p.has("rope_scaling"):
+            r = p.get_msg("rope_scaling")
+            kind = r.get_str("type", "yarn")
+            if kind != "yarn" or not self.rotary_dim:
+                raise ValueError(
+                    f"{self.name}: rope_scaling type {kind!r} over "
+                    f"{self.rotary_dim} features: yarn over a rotary span "
+                    "is what there is")
+            factor = r.get_float("factor")
+            self.inv_freq = yarn_inv_freq(
+                self.rotary_dim, self.rope_theta, factor,
+                r.get_int("original_max_position_embeddings"),
+                r.get_float("beta_fast", 32.0), r.get_float("beta_slow", 1.0))
+            self.rope_scale = r.get_float(
+                "attention_factor", 0.1 * math.log(factor) + 1.0)
         self.weight_filler = (
             p.get_msg("weight_filler") if p.has("weight_filler")
             else Message().set("type", "xavier"))
@@ -696,36 +830,54 @@ class GatedAttentionLayer(AttentionLayer):
         E = in_shapes[0][-1]
         H, Hk, D = self.num_heads, self.num_kv_heads, self.head_dim
         keys = jax.random.split(key, 4)
-        shapes = [(2 * H * D, E), (Hk * D, E), (Hk * D, E), (E, H * D)]
-        return [*(fill(self.weight_filler, k, s)
-                  for k, s in zip(keys, shapes)),
-                jnp.zeros((D,), jnp.float32), jnp.zeros((D,), jnp.float32)], {}
+        shapes = [((1 if self.head_gate else 2) * H * D, E), (Hk * D, E),
+                  (Hk * D, E), (E, H * D)]
+        params = [fill(self.weight_filler, k, s) for k, s in zip(keys, shapes)]
+        if self.qk_norm:
+            params += [jnp.zeros((D,), jnp.float32),
+                       jnp.zeros((D,), jnp.float32)]
+        if self.head_gate:
+            params.append(fill(self.weight_filler,
+                               jax.random.fold_in(key, 4), (H, E)))
+        return params, {}
 
     def _turn(self, t):
         """RoPE on the first ``rotary_dim`` features of every head."""
         r = self.rotary_dim
         if r == 0:
             return t
-        if r == t.shape[-1]:
-            return rope(t, self.rope_theta)
-        return jnp.concatenate([rope(t[..., :r], self.rope_theta),
-                                t[..., r:]], axis=-1)
+        turn = lambda u: rope(u, self.rope_theta, scale=self.rope_scale,
+                              inv_freq=self.inv_freq)
+        with jax.named_scope(ROPE_SCOPE):
+            if r == t.shape[-1]:
+                return turn(t)
+            return jnp.concatenate([turn(t[..., :r]), t[..., r:]], axis=-1)
 
     def apply(self, params, state, inputs, *, train, rng=None) -> LayerOutput:
         if active_sequence_parallel() is not None:
             raise NotImplementedError(
                 f"{self.name}: gated attention has no sequence-parallel "
                 "core (ring / Ulysses take one head count)")
-        w_q, w_k, w_v, w_o, q_norm, k_norm = params
+        w_q, w_k, w_v, w_o = params[:4]
         x = inputs[0]  # [B, S, E]
-        E = x.shape[-1]
+        S, E = x.shape[1:]
         H, Hk, D = self.num_heads, self.num_kv_heads, self.head_dim
-        q, gate = jnp.einsum("bse,hgde->gbhsd", x, w_q.reshape(H, 2, D, E))
+        if self.head_gate:
+            q = jnp.einsum("bse,hde->bhsd", x, w_q.reshape(H, D, E))
+        else:
+            q, gate = jnp.einsum("bse,hgde->gbhsd", x,
+                                 w_q.reshape(H, 2, D, E))
         k, v = (jnp.einsum("bse,hde->bhsd", x, w.reshape(Hk, D, E))
                 for w in (w_k, w_v))
-        q = self._turn(rms_norm(q, 1.0 + q_norm, self.norm_eps))
-        k = self._turn(rms_norm(k, 1.0 + k_norm, self.norm_eps))
-        o = self._core(q, k, v, self.causal)
-        y = jnp.einsum("bhsd,fhd->bsf", o * jax.nn.sigmoid(gate),
-                       w_o.reshape(E, H, D))
+        norms = params[4:6] if self.qk_norm else (None, None)
+        q, k = (self._turn(t if w is None
+                           else rms_norm(t, 1.0 + w, self.norm_eps))
+                for t, w in zip((q, k), norms))
+        self.visited = window_blocks(S, self.window)
+        o = self._core(q, k, v, self.causal, self.window)
+        with jax.named_scope(GATE_SCOPE):
+            if self.head_gate:
+                gate = jnp.einsum("bse,he->bhs", x, params[-1])[..., None]
+            o = o * jax.nn.sigmoid(gate)
+        y = jnp.einsum("bhsd,fhd->bsf", o, w_o.reshape(E, H, D))
         return LayerOutput(outputs=[y])

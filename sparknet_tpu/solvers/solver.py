@@ -620,7 +620,13 @@ class Solver:
         over the layers.  The attention layers (``ops/attention.py``)
         likewise: how many there are, and how many of them ran their core
         as the splash kernels at their last trace (the others in the XLA
-        formulation or sequence-parallel: on the CPU none).  A net with a
+        formulation or sequence-parallel: on the CPU none); where gated
+        attention layers of one net differ by a window (``swa_*``): the
+        window, how many of them have it and how many see every key, and
+        of the causal (query block, key block) pairs of the windowed
+        cores, at the width the core hands its kernels, the share that
+        holds a key some query sees (``ops/attention.py window_blocks``:
+        what a ``LocalMask`` leaves the kernels to visit).  A net with a
         looped region
         (``compiler/graph.py LoopRegion``): the passes of the region
         (``ut_steps``) and, where the exit-weighted loss kept them, the
@@ -654,6 +660,15 @@ class Solver:
         if cores:
             stats.update(attn_core_layers=len(cores), attn_kernel_layers=sum(
                 l.kernel == "splash" for l in cores))
+        gated = [l for l in cores if l.type == "GatedAttention"]
+        windowed = [l for l in gated if l.window]
+        if windowed:
+            visited, causal = (sum(n) for n in
+                               zip(*(l.visited for l in windowed)))
+            stats.update(
+                swa_window=windowed[0].window, swa_window_layers=len(windowed),
+                swa_full_layers=len(gated) - len(windowed),
+                swa_block_share=100.0 * visited / causal if causal else 0.0)
         loads = {name: np.asarray(st["load"]) for name, st in state.items()
                  if "load" in st}
         if not loads:
