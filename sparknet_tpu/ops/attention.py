@@ -261,8 +261,9 @@ def core_kernel(backend: str, S: int, D: int, Dv: int, causal: bool) -> str:
     (jax's pallas splash attention, which never holds the [B, H, S, S]
     scores: 1 GB per 4k sequence of 16 heads in f32) for a causal core
     from S = 2048 on a TPU whose blocks tile, else ``xla`` (the scores
-    materialized).  Head counts and a window change the mask and the
-    grouping, not the kernel."""
+    materialized).  Head counts change the grouping and a window the
+    mask and the pair of kernels that walks it (:func:`band_backward`),
+    not this name: ``splash`` says the core runs as Pallas kernels."""
     tiles = S % 512 == 0 and D % 64 == 0 and Dv % 128 == 0
     long_on_tpu = backend == "tpu" and S >= 2048
     return "splash" if long_on_tpu and causal and tiles else "xla"
@@ -270,10 +271,30 @@ def core_kernel(backend: str, S: int, D: int, Dv: int, causal: bool) -> str:
 
 def core_block(S: int, window: int = 0) -> int:
     """The width of the query and key blocks :func:`attention_core` hands
-    the splash kernels: 1024, 512 where S is no multiple of 1024 or under
-    a window (a ``LocalMask``: the blocks the window never reaches are
-    skipped).  :func:`window_blocks` counts the same blocks."""
+    its kernels: 1024, 512 where S is no multiple of 1024 or under a
+    window (the blocks the window never reaches are skipped: a
+    ``LocalMask`` in jax's kernels, the band's walk in the repo's own,
+    which cut a query block into sub-blocks of 256 rows that take only
+    the keys they can see).  :func:`window_blocks` counts the same blocks
+    and :func:`band_backward` says which kernels walk them."""
     return 512 if window or S % 1024 else 1024
+
+
+def band_backward(S: int, window: int = 0) -> bool:
+    """Whether the core runs as the kernels that follow the window's band
+    (``ops/band_attention.py``): a grid step a query block and head, the
+    key blocks its window reaches held in VMEM (2 at a window of 512 in
+    512-wide blocks), dq summed in f32 over them and written once, dK and
+    dV summed in a VMEM ring over the query blocks that reach a key
+    block.  The other arm is jax's fused backward, whose grid is every
+    (key block, head, query block) whatever the mask and whose dq is one
+    q-sized partial a KEY block, zeros where the block is masked, summed
+    by XLA afterwards (16 x q at 8,192 tokens: 2.1 GB a sliding layer of
+    64 heads).  The rule, read off S and the window alone: under a window
+    that hides some key, the band (timed at both windowed shapes the
+    cells run, :func:`attention_core`'s table); without one the fused
+    kernel's one pass over the scores (PR 43's table)."""
+    return 0 < window < S
 
 
 def attention_core(q, k, v, causal: bool, window: int = 0,
@@ -296,12 +317,18 @@ def attention_core(q, k, v, causal: bool, window: int = 0,
     jax's splash kernels with the fused backward (dq, dk and dv from one
     pass over the scores), at equal widths (OLMoE, Ouro: 16 heads of
     128), with values narrower than keys (latent attention: keys of 192,
-    values of 128) and in their grouped form with, in some layers, a
-    window (differential attention: 40 query heads of 64 on 20 key and 10
-    value heads; one key head and the query heads that read it a call, so
-    that no key head is repeated in HBM).  Blocks are 1024 wide, 512
-    where S is no multiple of 1024 or under a window (a ``LocalMask``:
-    the blocks the window never reaches are skipped).  Timed alone on the
+    values of 128) and in their grouped form (one key head and the query
+    heads that read it a call, so that no key head is repeated in HBM).
+    Blocks are 1024 wide, 512 where S is no multiple of 1024 or under a
+    window.  UNDER A WINDOW (:func:`band_backward`; Laguna's sliding
+    layers: 64 query heads on 8 key heads of 128 at 8,192 tokens;
+    differential attention's windowed layer: 40 query heads of 64 on 20
+    key and 10 value heads) the core is the repo's own pair of kernels
+    that walk the window's band (``ops/band_attention.py``): no grid step
+    for a (query block, key block) pair the window never reaches, dq
+    summed in f32 and written once; the fused backward's grid is every
+    pair whatever the mask and its dq is one q-sized partial a KEY block
+    (16 x q at 8,192 tokens: 2.1 GB a sliding layer).  Timed alone on the
     v5e (TPU v5 lite), forward + backward, bf16, causal (PERF.md section
     6; ``tools/attn_core_kernel.py``, ``benchmarks/scratch/hybrid_kernels.py``):
     1 x 16 x 4096 x 128: 2.13 ms at 1024-wide blocks (2.36 at 512, 2.62
@@ -311,8 +338,15 @@ def attention_core(q, k, v, causal: bool, window: int = 0,
     1 x 32 x 4096, keys 192, values 128: 7.41 ms (8.35 with separate
     kernels; an XLA loop over query blocks with remat 49.9).
     1 x 40 x 2048, keys 64, values 128, grouped: 1.75 ms (1.79 at 512; the
-    XLA formulation 9.59); under a window of 512 1.45 ms at 512-wide
-    blocks (1.75 at 1024; XLA 9.58).
+    XLA formulation 9.59); under a window of 512 1.09 ms as the band kernels (1.21 in
+    sub-blocks of 128 rows, 1.20 of 512), where the fused splash form that
+    ran until PR 51 takes 1.50 at 512-wide blocks (1.83 at 1024; separate
+    dq and dkv kernels 1.51; XLA 9.58).
+    1 x 64 over 8 x 8192 x 128 under a window of 512: 7.46 ms as the band
+    kernels (8.23 in sub-blocks of 128, 8.15 of 512; 7.49 at 1024-wide
+    blocks), where the fused splash form takes 17.43 (17.09 at 1024) and
+    jax's separate dq and dkv kernels 11.36 at 512-wide blocks (16.07 at
+    256, 33.02 at 128); the forward alone 2.90 in every form at 512.
     Everything else takes the XLA formulation, which materializes the
     scores (no cell has a long core that is not causal): through
     :func:`flash_attention` at equal widths and head counts without a
@@ -356,11 +390,39 @@ def _attention_xla(q, k, v, causal: bool, window: int):
 
 def _splash_causal(q, k, v, block: int, window: int = 0,
                    scaled: bool = False, interpret: bool = False):
-    """Causal attention through jax's splash attention kernels (forward
-    and ONE backward kernel for dq, dk and dv), whatever the key and
-    value widths; with fewer key heads than query heads, through their
-    grouped form.  ``interpret`` runs them in Pallas's interpreter (the
-    tests, on the CPU)."""
+    """Causal attention through jax's splash attention kernels at
+    ``block``-wide query and key blocks, whatever the key and value
+    widths; with fewer key heads than query heads, through their grouped
+    form (one key head and the query heads that read it a call).  The
+    backward is ONE fused kernel of jax's for dq, dk and dv, or under a
+    window (:func:`band_backward`) the repo's own kernel that walks the
+    band (``ops/band_attention.py``).  ``interpret`` runs them in
+    Pallas's interpreter (the tests, on the CPU)."""
+    from jax.experimental.pallas.ops.tpu.splash_attention import (
+        splash_attention_kernel as sk)
+
+    B, H, S, D = q.shape
+    if not band_backward(S, window):
+        sizes = sk.BlockSizes(
+            block_q=block, block_kv=block, block_kv_compute=block,
+            block_q_dkv=block, block_kv_dkv=block,
+            block_kv_dkv_compute=block, use_fused_bwd_kernel=True)
+        return _splash(q, k, v, sizes, window, scaled, interpret)
+    from sparknet_tpu.ops.band_attention import band_core
+
+    Hk = k.shape[1]
+    if not scaled:
+        q = (q * D ** -0.5).astype(q.dtype)
+    v = jnp.repeat(v, Hk // v.shape[1], axis=1)
+    o = band_core(q.reshape(B, Hk, H // Hk, S, D), k, v, block, window,
+                  interpret)
+    return o.reshape(B, H, S, v.shape[3])
+
+
+def _splash(q, k, v, sizes, window: int = 0, scaled: bool = False,
+            interpret: bool = False):
+    """jax's splash kernels at the ``BlockSizes`` given, under the causal
+    mask or, with a window, a ``LocalMask``."""
     from jax.experimental.pallas.ops.tpu.splash_attention import (
         splash_attention_kernel as sk, splash_attention_mask as sm)
 
@@ -368,10 +430,6 @@ def _splash_causal(q, k, v, block: int, window: int = 0,
     Hk = k.shape[1]
     seen = (sm.LocalMask((S, S), (window - 1, 0), 0) if window
             else sm.CausalMask((S, S)))
-    sizes = sk.BlockSizes(
-        block_q=block, block_kv=block, block_kv_compute=block,
-        block_q_dkv=block, block_kv_dkv=block, block_kv_dkv_compute=block,
-        use_fused_bwd_kernel=True)
     # the kernel takes [H, S, D] and scores q kᵀ as given: scale q first
     if not scaled:
         q = (q * D ** -0.5).astype(q.dtype)
@@ -393,14 +451,19 @@ def _splash_causal(q, k, v, block: int, window: int = 0,
 class AttentionLayer(Layer):
     """A layer whose token mixer is :func:`attention_core`.  ``kernel`` is
     what its last trace ran the core as (:func:`core_kernel`'s name, or
-    the sequence-parallel implementation's), for ``Solver._fence_stats``;
-    empty until the layer is traced."""
+    the sequence-parallel implementation's) and ``band`` whether those
+    kernels' backward followed a window's band (:func:`band_backward`),
+    for ``Solver._fence_stats``; empty and False until the layer is
+    traced."""
 
     kernel = ""
+    band = False
 
     def _core(self, q, k, v, causal: bool, window: int = 0):
         self.kernel = core_kernel(jax.default_backend(), q.shape[2],
                                   q.shape[3], v.shape[3], causal)
+        self.band = (self.kernel == "splash"
+                     and band_backward(q.shape[2], window))
         with jax.named_scope(CORE_SCOPE):
             return attention_core(q, k, v, causal, window)
 

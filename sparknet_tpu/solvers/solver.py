@@ -626,7 +626,10 @@ class Solver:
         of the causal (query block, key block) pairs of the windowed
         cores, at the width the core hands its kernels, the share that
         holds a key some query sees (``ops/attention.py window_blocks``:
-        what a ``LocalMask`` leaves the kernels to visit).  A net with a
+        what a ``LocalMask`` leaves the kernels to visit), and how many of
+        the windowed layers ran the backward that walks only those blocks
+        and writes dq once at their last trace (``swa_band_layers``,
+        ``ops/attention.py band_backward``: on the CPU none).  A net with a
         looped region
         (``compiler/graph.py LoopRegion``): the passes of the region
         (``ut_steps``) and, where the exit-weighted loss kept them, the
@@ -668,6 +671,7 @@ class Solver:
             stats.update(
                 swa_window=windowed[0].window, swa_window_layers=len(windowed),
                 swa_full_layers=len(gated) - len(windowed),
+                swa_band_layers=sum(l.band for l in windowed),
                 swa_block_share=100.0 * visited / causal if causal else 0.0)
         loads = {name: np.asarray(st["load"]) for name, st in state.items()
                  if "load" in st}

@@ -1,7 +1,9 @@
 """Which kernel the attention core takes (``ops/attention.py core_kernel``:
 read off the backend and the shapes, no flag), what the fence says of it,
-and the splash kernels' equal-head arm against the XLA formulation in
-Pallas's interpreter."""
+the splash kernels' equal-head arm against the XLA formulation in
+Pallas's interpreter, and the windowed core: every head grouping under
+windows narrower than, equal to and wider than a block, the window's
+edge exactly, and what its gradient's program holds."""
 
 from __future__ import annotations
 
@@ -72,15 +74,29 @@ def test_the_kernel_is_read_off_the_backend_and_the_shapes(
     if want == "splash":
         block = 512 if window or q[2] % 1024 else 1024
         assert taken[0][1:] == (block, window, False)
+    # the backward's form is read off S and the window alone: the band
+    # under a window that hides some key, the fused kernel elsewhere
+    assert attention.band_backward(q[2], window) == bool(window)
+    assert not attention.band_backward(q[2], q[2])
 
 
 def test_one_kernel_family_and_no_switch():
     """The module imports jax's splash kernels and no other attention
-    kernel of jax's, and no environment variable chooses the core."""
+    kernel of jax's, beside them the repo's own banded pair (which
+    imports none of jax's), and no environment variable chooses the core
+    or its form."""
+    from sparknet_tpu.ops import band_attention
+
     src = inspect.getsource(attention)
     assert "ops.tpu.splash_attention" in src
     assert "ops.tpu import flash_attention" not in src
-    assert "os.environ" not in src and "getenv" not in src
+    assert "ops.band_attention import band_core" in src
+    band = inspect.getsource(band_attention)
+    assert "pallas.ops.tpu" not in band
+    for text in (src, band):
+        assert "os.environ" not in text and "getenv" not in text
+    # ONE windowed form: jax's separate dq and dkv kernels are not built
+    assert "block_q_dq" not in src + band
 
 
 def rel(a, b):
@@ -99,14 +115,7 @@ def test_splash_equal_heads_against_xla_in_the_interpreter(S, block, scaled):
     shape = (2, 2, S, 128)
     q, k, v = (jax.random.normal(key, shape, jnp.bfloat16) for key in ks[:3])
     do = jax.random.normal(ks[3], shape, jnp.float32)
-
-    def both(core):
-        def loss(q, k, v):
-            o = core(q, k, v)
-            return jnp.sum(o.astype(jnp.float32) * do), o
-        (_, o), grads = jax.jit(jax.value_and_grad(
-            loss, argnums=(0, 1, 2), has_aux=True))(q, k, v)
-        return (o,) + grads
+    both = lambda core: both_ways(core, q, k, v, do)
 
     def splash(q, k, v):
         if scaled:
@@ -119,6 +128,178 @@ def test_splash_equal_heads_against_xla_in_the_interpreter(S, block, scaled):
     for name, g, w in zip(("o", "dq", "dk", "dv"), got, want):
         assert g.shape == w.shape and g.dtype == w.dtype
         assert rel(g, w) <= 2e-2, name
+
+
+def both_ways(core, q, k, v, do):
+    """(o, dq, dk, dv) of ``core`` under the cotangent ``do``."""
+    def loss(q, k, v):
+        o = core(q, k, v)
+        return jnp.sum(o.astype(jnp.float32) * do), o
+    (_, o), grads = jax.jit(jax.value_and_grad(
+        loss, argnums=(0, 1, 2), has_aux=True))(q, k, v)
+    return (o,) + grads
+
+
+# [query, key, value] heads: equal; 8 query heads a key head (Laguna's
+# sliding layers); value heads fewer than key heads (the hybrid's)
+GROUPINGS = {"equal": (2, 2, 2), "grouped_8": (8, 1, 1),
+             "fewer_values": (4, 2, 1)}
+
+
+@pytest.mark.parametrize("window", [0, 64, 128, 200],
+                         ids=["fused", "narrower", "a_block", "wider"])
+@pytest.mark.parametrize("heads", GROUPINGS.values(), ids=GROUPINGS)
+def test_the_windowed_core_against_xla_in_the_interpreter(heads, window):
+    """Forward and the three gradients, bf16, rel <= 2e-2 of the XLA
+    formulation, at S = 4 key blocks of 128 so that under every window
+    some block is out of reach; window 0 is the rule's other arm (the
+    fused backward, as every core without a window runs)."""
+    S, block = 512, 128
+    assert attention.band_backward(S, window) == bool(window)
+    ks = jax.random.split(jax.random.key(window + heads[0]), 4)
+    q, k, v = (jax.random.normal(key, (1, h, S, 128), jnp.bfloat16)
+               for key, h in zip(ks, heads))
+    do = jax.random.normal(ks[3], q.shape, jnp.float32)
+    got = both_ways(lambda q, k, v: attention._splash_causal(
+        q, k, v, block, window, interpret=True), q, k, v, do)
+    want = both_ways(lambda q, k, v: attention._attention_xla(
+        q, k, v, True, window), q, k, v, do)
+    for name, g, w in zip(("o", "dq", "dk", "dv"), got, want):
+        assert g.shape == w.shape and g.dtype == w.dtype
+        assert rel(g, w) <= 2e-2, name
+
+
+@pytest.mark.parametrize("window", [512, 700])
+def test_the_cells_own_blocks_in_the_interpreter(window):
+    """The same at the width the cells run, 512-wide blocks cut into two
+    sub-blocks of 256 rows, 8 query heads a key head, S = 4 key blocks:
+    a window of one block back and of two."""
+    S = 2048
+    block = attention.core_block(S, window)
+    ks = jax.random.split(jax.random.key(window), 4)
+    q, k, v = (jax.random.normal(key, (1, h, S, 128), jnp.bfloat16)
+               for key, h in zip(ks, (8, 1, 1)))
+    do = jax.random.normal(ks[3], q.shape, jnp.float32)
+    got = both_ways(lambda q, k, v: attention._splash_causal(
+        q, k, v, block, window, interpret=True), q, k, v, do)
+    want = both_ways(lambda q, k, v: attention._attention_xla(
+        q, k, v, True, window), q, k, v, do)
+    for name, g, w in zip(("o", "dq", "dk", "dv"), got, want):
+        assert rel(g, w) <= 2e-2, name
+
+
+def test_the_windows_edge_is_exact_through_the_band():
+    """f32 inputs, grouped heads, the band-following backward: key
+    t - window + 1 moves query t's output and takes a dk and a dv from
+    it, key t - window does neither, to the bit."""
+    S, block, window, t = 512, 128, 128, 300
+    ks = jax.random.split(jax.random.key(7), 3)
+    q, k, v = (jax.random.normal(key, (1, h, S, 128), jnp.float32)
+               for key, h in zip(ks, (4, 2, 2)))
+    core = lambda q, k, v: attention._splash_causal(
+        q, k, v, block, window, interpret=True)
+    # the cotangent lives on query t alone: dk, dv say which keys it saw
+    do = jnp.zeros(q.shape, jnp.float32).at[:, :, t].set(1.0)
+    o, dq, dk, dv = both_ways(core, q, k, v, do)
+    saw = lambda g: np.asarray(jnp.any(g != 0, axis=(0, 1, 3)))
+    inside = np.zeros(S, bool)
+    inside[t - window + 1:t + 1] = True
+    assert np.array_equal(saw(dk), inside)
+    assert np.array_equal(saw(dv), inside)
+    assert np.array_equal(saw(dq), np.arange(S) == t)
+    # and the output: moving one key's value moves the queries that see it
+    for key, moved in ((t - window + 1, True), (t - window, False)):
+        o2 = jax.jit(core)(q, k, v.at[:, :, key].add(1.0))
+        assert bool(jnp.any(o2[:, :, t] != o[:, :, t])) == moved
+        rows = np.asarray(jnp.any(o2 != o, axis=(0, 1, 3)))
+        assert np.array_equal(rows, (np.arange(S) >= key)
+                              & (np.arange(S) < key + window))
+
+
+@pytest.mark.parametrize("window", [100, 128, 300])
+def test_the_band_forward_keeps_the_scores_logsumexp(window):
+    """What the banded backward reads beside o: a query's logsumexp over
+    the keys its window shows, f32, one lane a query."""
+    from sparknet_tpu.ops.band_attention import band_fwd
+
+    S, block = 512, 128
+    ks = jax.random.split(jax.random.key(window), 3)
+    q = jax.random.normal(ks[0], (1, 2, 2, S, 128), jnp.float32)
+    k, v = (jax.random.normal(key, (1, 2, S, 128), jnp.float32)
+            for key in ks[1:])
+    o, lse = jax.jit(lambda q, k, v: band_fwd(
+        q, k, v, block, window, interpret=True))(q, k, v)
+    s = jnp.einsum("bhgqd,bhkd->bhgqk", q, k)
+    ahead = jnp.arange(S)[:, None] - jnp.arange(S)[None, :]
+    s = jnp.where((ahead >= 0) & (ahead < window), s, -jnp.inf)
+    assert lse.shape == (1, 2, 2, S) and lse.dtype == jnp.float32
+    np.testing.assert_allclose(lse, jax.nn.logsumexp(s, axis=-1), rtol=1e-5)
+    want = jnp.einsum("bhgqk,bhkd->bhgqd", jax.nn.softmax(s, axis=-1), v)
+    assert rel(o, want) <= 1e-5
+
+
+def walk(jaxpr):
+    """Every equation of a jaxpr and of the jaxprs its equations hold
+    (pallas kernels' bodies aside: their values live in VMEM)."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        if eqn.primitive.name == "pallas_call":
+            continue
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from walk(sub)
+
+
+@pytest.mark.parametrize("name, shapes, window", [
+    ("laguna_sliding", ((1, 64, 8192, 128), (1, 8, 8192, 128),
+                        (1, 8, 8192, 128)), 512),
+    ("hybrid_window", HYBRID, 512),
+    ("window_of_three_blocks", ((1, 16, 4096, 128),) * 3, 1500),
+    ("ouro_no_window", OURO[:3], 0),
+])
+def test_what_the_cores_gradient_holds(monkeypatch, name, shapes, window):
+    """Chip-free, at the cells' own shapes (traced, nothing runs): under
+    a window the gradient of ``attention_core`` holds NO array of
+    ``S / block`` times q's size (the fused backward's dq partials, one a
+    key block: ``bf16[8,16,8,8192,128]`` = 2.1 GB in Laguna's compiled
+    step until PR 51), nothing of more elements than q or o at all, and
+    both kernels' grids are the band: a step a query block and head (the
+    backward ``reach`` more to flush its ring) that holds the key blocks
+    the window reaches, so per head no more (query block, key block)
+    pairs than ``window_blocks`` counts, padded to whole rows.  Without a window the fused kernel and
+    its partials are there: the test sees what it looks for."""
+    from sparknet_tpu.ops.band_attention import reach
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    q, k, v = (jax.ShapeDtypeStruct(s, jnp.bfloat16) for s in shapes)
+    S = q.shape[2]
+    block = attention.core_block(S, window)
+    n = S // block
+
+    def loss(q, k, v):
+        return jnp.sum(attention.attention_core(q, k, v, True, window)
+                       .astype(jnp.float32))
+
+    eqns = list(walk(jax.make_jaxpr(jax.grad(loss, (0, 1, 2)))(q, k, v).jaxpr))
+    sizes = [int(np.prod(var.aval.shape)) for e in eqns for var in e.outvars]
+    q_size = int(np.prod(q.shape))
+    grids = {e.params["name"]: e.params["grid_mapping"].grid
+             for e in eqns if e.primitive.name == "pallas_call"}
+    assert len(grids) == 2, grids  # the forward and ONE backward kernel
+    if not window:
+        assert n * q_size in sizes
+        assert not any("band" in kernel for kernel in grids)
+        return
+    o_size = q_size // q.shape[3] * v.shape[3]
+    assert max(sizes) <= max(q_size, o_size), max(sizes) / q_size
+    visited, causal = attention.window_blocks(S, window)
+    held = reach(window, block) + 1  # key blocks a query block reaches
+    group = q.shape[1] // k.shape[1]
+    # a step a query block and head, ``held`` key blocks in VMEM each
+    assert grids == {
+        "band_attention_fwd": (q.shape[0], k.shape[1], n, group),
+        "band_attention_bwd": (q.shape[0], k.shape[1], n + held - 1, group)}
+    # whole rows: the first blocks' rows are short of ``held`` by a triangle
+    assert n * held - visited == held * (held - 1) // 2 and n * held < causal
 
 
 def olmoe_solver():

@@ -353,7 +353,10 @@ def test_the_fence_carries_the_window_counters():
     have it and how many see every key, and the share of the causal
     block pairs the windowed cores' mask reaches, at the width the core
     hands its kernels (one block at 32 tokens: all of it; 31 of 136
-    512-wide ones at 8,192 under 512), beside the attention and expert
+    512-wide ones at 8,192 under 512), and how many of the windowed
+    layers' backward walked only those blocks (none where the core runs
+    in the XLA formulation, as here; ``tests/test_window_band.py`` has a
+    layer traced as on the chip), beside the attention and expert
     counters under their present names."""
     from sparknet_tpu.ops.attention import core_block, window_blocks
 
@@ -363,7 +366,7 @@ def test_the_fence_carries_the_window_counters():
     assert {k: stats[k] for k in stats if not k.startswith("moe_")} == {
         "attn_core_layers": 5, "attn_kernel_layers": 0, "swa_window": 8,
         "swa_window_layers": 3, "swa_full_layers": 2,
-        "swa_block_share": 100.0}
+        "swa_band_layers": 0, "swa_block_share": 100.0}
     assert stats["moe_layers"] == 4 and stats["moe_experts"] == 16
     assert stats["moe_pairs"] == 2 * 32 * 3
     assert 0 <= stats["moe_pairs_held"] <= 4 * stats["moe_pairs"]
