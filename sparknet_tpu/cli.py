@@ -165,8 +165,10 @@ def _setup_line() -> str | None:
     """Where this job's set-up went, from the program's own record
     (``obs.recorder.flight`` reduced by ``obs.recorder.stages``): the
     front door to the first fenced step or round, by stage, with what
-    each built and compiled, and the main thread's compile seconds by
-    jax's own events.  ``setup_s`` as a user of ``tpunet train`` feels it
+    each built and compiled, the main thread's compile seconds by jax's
+    own events, and the HBM account of the step program that first step
+    compiled (``utils/profiling.step_account``, on its span).
+    ``setup_s`` as a user of ``tpunet train`` feels it
     (docs/OBSERVABILITY.md, "The record")."""
     import threading
 
@@ -194,10 +196,11 @@ def _setup_line() -> str | None:
     for row in (*stages(mine), *stages(first, ("sn.step", "sn.round")),
                 *stages([fence], (fence[0],))):
         text = f"{labels[row['name']]} {row['wall_s']:.1f}s"
-        if row["stats"]:
+        stats = {k: v for k, v in row["stats"].items()
+                 if not k.startswith("hbm_")}  # the account ends the line
+        if stats:
             text += " (" + ", ".join(
-                f"{k} {'/'.join(map(str, v))}"
-                for k, v in row["stats"].items()) + ")"
+                f"{k} {'/'.join(map(str, v))}" for k, v in stats.items()) + ")"
         if row["compiles"]:
             text += (f" [{row['compiles']} compiles {row['compile_s']:.1f}s"
                      + (f", {row['cache_hits']} from the cache"
@@ -208,10 +211,20 @@ def _setup_line() -> str | None:
                       for event, label in EVENT_LABELS.items()
                       if event in seconds)
     total = (fence[2] + fence[3] - front[2]) / 1e9
+    account = first[0][4] if first else {}
+    program = ""
+    if "hbm_args_bytes" in account:
+        held = account["hbm_args_bytes"] + account["hbm_temps_bytes"]
+        limit = account.get("hbm_limit_bytes")
+        program = (f"; step program: {account['hbm_args_bytes'] / 1e9:.2f} GB "
+                   f"arguments + {account['hbm_temps_bytes'] / 1e9:.2f} GB "
+                   "temporaries"
+                   + (f" of {limit / 1e9:.2f} ({100 * held / limit:.0f} %)"
+                      if limit else ""))
     return (f"set-up: {total:.1f}s from the front door to the first fenced "
             "step: " + ", ".join(parts)
             + (f"; this thread's compile seconds by event: {split}"
-               if split else ""))
+               if split else "") + program)
 
 
 def _load_weights_into(
@@ -544,15 +557,17 @@ def cmd_time(args) -> int:
             cost = cost[0] if cost else {}
         flops = float(cost.get("flops", 0.0))
         batch = next(iter(feeds.values())).shape[0]
-        mem = compiled.memory_analysis()
+        from sparknet_tpu.utils.profiling import step_account
+
+        account = step_account(step, v, s, 0, feeds, key)
         print(json.dumps({
             "flops_per_step": flops,
             "hbm_bytes_per_step": bytes_,
             "arithmetic_intensity": round(flops / bytes_, 2) if bytes_ else None,
             "batch": int(batch),
             "gflops_per_image": round(flops / batch / 1e9, 3) if batch else None,
-            "temp_bytes": getattr(mem, "temp_size_in_bytes", None),
-            "argument_bytes": getattr(mem, "argument_size_in_bytes", None),
+            "temp_bytes": account.get("hbm_temps_bytes"),
+            "argument_bytes": account.get("hbm_args_bytes"),
         }))
         return 0
 

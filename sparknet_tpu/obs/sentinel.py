@@ -186,6 +186,16 @@ class RecompileSentinel:
                     self._busy.get(tid, (0,))[0] / 1e9,
                     self._timed.get(tid, {}).get(_CACHE_HIT_EVENT, (0,))[0])
 
+    def thread_lowerings(self, tid: int | None = None) -> int:
+        """Functions jax lowered afresh on one thread (default: the
+        calling thread) so far; a lowering served from jax's own cache
+        fires no event.  ``utils/profiling.step_account`` asks it before
+        and after it lowers, and takes no account of a fresh one."""
+        if tid is None:
+            tid = threading.get_ident()
+        with self._lock:
+            return self._timed.get(tid, {}).get(_STAGE_EVENTS[1], (0,))[0]
+
     @property
     def last_compile_ns(self) -> int:
         """``time.time_ns()`` when the newest backend compilation of any

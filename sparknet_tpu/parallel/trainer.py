@@ -46,7 +46,8 @@ from sparknet_tpu.parallel.sharding import (
     place,
 )
 from sparknet_tpu.solvers.solver import Solver
-from sparknet_tpu.utils.profiling import step_span
+from sparknet_tpu.utils.profiling import (account_compiled, hbm_live,
+                                          step_span)
 
 DataFn = Callable[[int], dict[str, Any]]
 
@@ -97,6 +98,11 @@ class ParallelTrainer:
             # driver loop should build batches for)
             self.num_local_workers = max(self.num_workers // self._mesh_procs, 1)
             self.iter = 0
+            # jitted round -> the entries of its jit cache whose HBM account
+            # a span carries (``account_compiled``); the chips whose live
+            # bytes the fences read
+            self._accounted: dict = {}
+            self._devices = self.mesh.local_devices
             # Optional post-placement feed hook (``fn(feeds, it) -> feeds``,
             # e.g. DeviceAugment.trainer_device_fn): applied AFTER _put_feeds
             # and BEFORE the jitted round program, so the uint8 wire's
@@ -407,28 +413,21 @@ class ParallelTrainer:
         # dispatch -> data, put, augment of the NEXT round -> fence, each
         # stage with its own wall on the profiler's clock; a round nothing
         # was placed ahead for begins with its own data, put, augment
-        with step_span("sn.round", it0):
+        with step_span("sn.round", it0) as round_sp:
             batch, feeds = (
                 self._take_ahead(data_fn, it0)
                 or self._stage_round(data_fn, it0, stacked, staged=0))
-            with rec.span("sn.round.dispatch", host=True, it=it0):
-                if self._elastic:
-                    (self.variables, self.slots, self.center,
-                     loss) = self._train(
-                        self.variables, self.slots, self.center, it0,
-                        feeds, self.solver._key,
-                    )
-                elif self.tau == 1:
-                    with self._sp_context():
-                        self.variables, self.slots, loss = self._train(
-                            self.variables, self.slots, it0, feeds,
-                            self.solver._key,
-                        )
-                else:
-                    self.variables, self.slots, loss = self._train(
-                        self.variables, self.slots, it0, feeds,
-                        self.solver._key
-                    )
+            args = (self.variables, self.slots,
+                    *((self.center,) if self._elastic else ()),
+                    it0, feeds, self.solver._key)
+            with rec.span("sn.round.dispatch", host=True, it=it0), \
+                    self._sp_context():
+                out = self._train(*args)
+                account_compiled(round_sp, self._accounted, self._train, *args)
+            if self._elastic:
+                self.variables, self.slots, self.center, loss = out
+            else:
+                self.variables, self.slots, loss = out
             self.iter += self.tau
             if stacked:
                 self._place_ahead(data_fn)
@@ -440,6 +439,7 @@ class ParallelTrainer:
                 else:
                     loss_val = float(loss)
                 sp.fence_value(loss_val)
+                sp.set(**hbm_live(self._devices))
         return loss_val
 
     def _stage_round(self, data_fn: DataFn, it: int, stacked: bool,
@@ -621,44 +621,51 @@ class ParallelTrainer:
             )
         rec = get_recorder()
         t0 = time.perf_counter() if rec else 0.0
-        host = [data_fn(self.iter + i) for i in range(n)]
-        stacked = {
-            k: np.stack([np.asarray(h[k]) for h in host]) for k in host[0]
-        }
-        # [n, B, ...]: the tau-shaped feed placement shards axis 1 over
-        # 'data' and leaves the round axis unsharded — exactly the scan
-        # xs layout
-        # (the device hook's rank-5 arm: [n, B, ...] scanned rounds take
-        # per-slot keys exactly like a [tau, B, ...] round)
-        feeds = self._stage_feeds(stacked, self.iter, with_tau_axis=True)
-        with self._sp_context():
-            self.variables, self.slots, losses = self._round_scan_fns[n](
-                self.variables, self.slots, self.iter, feeds,
-                self.solver._key,
-            )
+        it0, fn = self.iter, self._round_scan_fns[n]
+        # sn.round: the data, the placement and the dispatch of the n
+        # fused rounds, then their one fence
+        with step_span("sn.round", it0) as sp:
+            host = [data_fn(it0 + i) for i in range(n)]
+            stacked = {
+                k: np.stack([np.asarray(h[k]) for h in host]) for k in host[0]
+            }
+            # [n, B, ...]: the tau-shaped feed placement shards axis 1 over
+            # 'data' and leaves the round axis unsharded — exactly the scan
+            # xs layout
+            # (the device hook's rank-5 arm: [n, B, ...] scanned rounds take
+            # per-slot keys exactly like a [tau, B, ...] round)
+            feeds = self._stage_feeds(stacked, it0, with_tau_axis=True)
+            args = (self.variables, self.slots, it0, feeds, self.solver._key)
+            with self._sp_context():
+                self.variables, self.slots, losses = fn(*args)
+                account_compiled(sp, self._accounted, fn, *args)
         self.iter += n
-        if rec:
-            # one obs record for the fused n-round dispatch; value_fence
-            # on the [n] loss vector fetches its LAST element — the same
-            # number the plain return materializes
-            from sparknet_tpu.common import value_fence
+        with Span(None, "sn.round.fence", it=it0) as sp:
+            if rec:
+                # one obs record for the fused n-round dispatch; value_fence
+                # on the [n] loss vector fetches its LAST element — the same
+                # number the plain return materializes
+                from sparknet_tpu.common import value_fence
 
-            loss_val = value_fence(losses)
-            batch = next(
-                (int(np.shape(v)[0]) for v in host[0].values()
-                 if np.shape(v)), 0)
-            from sparknet_tpu.obs import lineage as obs_lineage
+                loss_val = value_fence(losses)
+                batch = next(
+                    (int(np.shape(v)[0]) for v in host[0].values()
+                     if np.shape(v)), 0)
+                from sparknet_tpu.obs import lineage as obs_lineage
 
-            rec.round(
-                mode="dp", tau=1, devices=int(self.mesh.devices.size),
-                workers=self.num_workers, iters=n, batch=batch,
-                wall_s=time.perf_counter() - t0, loss=loss_val,
-                fenced=True, comm=self._obs_comm(), iteration=self.iter,
-                lineage=obs_lineage.round_lineage(
-                    "dp", self.iter - n, self.iter - n, self.iter - 1),
-            )
-            return loss_val
-        return float(losses[-1])
+                rec.round(
+                    mode="dp", tau=1, devices=int(self.mesh.devices.size),
+                    workers=self.num_workers, iters=n, batch=batch,
+                    wall_s=time.perf_counter() - t0, loss=loss_val,
+                    fenced=True, comm=self._obs_comm(), iteration=self.iter,
+                    lineage=obs_lineage.round_lineage(
+                        "dp", self.iter - n, self.iter - n, self.iter - 1),
+                )
+            else:
+                loss_val = float(losses[-1])
+            sp.fence_value(loss_val)
+            sp.set(**hbm_live(self._devices))
+        return loss_val
 
     # ------------------------------------------------------------------
     def _sp_context(self):

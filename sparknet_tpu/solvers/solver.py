@@ -33,7 +33,8 @@ from sparknet_tpu.ops.moe import CAPACITY_TILE, live_tiles
 from sparknet_tpu.proto.text_format import Message, parse_file
 from sparknet_tpu.solvers.lr_policy import learning_rate
 from sparknet_tpu.solvers.updates import apply_update, init_slots
-from sparknet_tpu.utils.profiling import step_span
+from sparknet_tpu.utils.profiling import (account_compiled, hbm_live,
+                                          step_span)
 
 # device scope of the optimizer update inside every jitted train step.
 # Not an ``L.`` scope: a trace reader books ``L.<name>`` as a net layer
@@ -398,6 +399,12 @@ class Solver:
             # need the pre-step buffers use jitted_train_step(donate=False).
             self._train_step = jax.jit(self._make_train_step(),
                                        donate_argnums=(0, 1))
+            # jitted step -> the entries of its jit cache whose HBM account
+            # a span carries (``account_compiled``); the devices whose live
+            # bytes the fences read
+            self._accounted: dict = {}
+            leaves = jax.tree_util.tree_leaves(self.variables)
+            self._devices = list(leaves[0].devices()) if leaves else []
             self._eval_steps = [
                 jax.jit(self._make_eval_step(net)) for net in self.test_nets
             ]
@@ -550,13 +557,14 @@ class Solver:
                                       scan_chunk)
         for _ in range(num_iters):
             # sn.step: the data wait and the dispatch of one iteration
-            with step_span("sn.step", self.iter):
+            with step_span("sn.step", self.iter) as sp:
                 feeds = data_fn(self.iter)
                 if self._obs_in_step:
                     self._obs_images_per_iter = self._feed_images(feeds)
-                out = self._train_step(
-                    self.variables, self.slots, self.iter, feeds, self._key
-                )
+                args = (self.variables, self.slots, self.iter, feeds,
+                        self._key)
+                out = self._train_step(*args)
+                account_compiled(sp, self._accounted, self._train_step, *args)
             if cfg.debug_info:
                 self.variables, self.slots, loss, stats = out
                 self._print_debug_info(stats)
@@ -578,6 +586,7 @@ class Solver:
             if callback:
                 with Span(None, "sn.step.fence", it=self.iter) as sp:
                     loss_val = sp.fence_value(float(loss))
+                    sp.set(**hbm_live(self._devices))
                 callback(self.iter, loss_val)
             if cfg.snapshot and self.iter % cfg.snapshot == 0 and cfg.snapshot_prefix:
                 self.save(f"{cfg.snapshot_prefix}_iter_{self.iter}")
@@ -586,7 +595,7 @@ class Solver:
         # round record that step() closes on this value is its line
         with Span(None, "sn.step.fence", it=self.iter) as sp:
             self.smoothed_loss = sp.fence_value(self._smoothed())
-            sp.set(**self._fence_stats())
+            sp.set(**self._fence_stats(), **hbm_live(self._devices))
         return self.smoothed_loss
 
     def _fence_stats(self) -> dict:
@@ -609,20 +618,14 @@ class Solver:
         the layer's name.  The selective-scan layers (``ops/ssm.py``)
         keep no state between steps: the fence names how many there are,
         how many of them run their scan as the Pallas kernels (the others
-        as the ``lax.scan`` loop form: on the CPU all of them), and, of
-        the path that runs, the steps between two of the states the
-        forward keeps for the backward and the bytes of those states
-        over the layers.  The gated-DeltaNet layers
-        (``ops/linear_attention.py``) the same way: how many there are,
-        how many of them took the chunked core at their last trace (the
-        one path that ships: all of them once traced), the tokens of a
-        chunk and the bytes of the chunk-start states the forward keeps
-        over the layers.  The attention layers (``ops/attention.py``)
+        as the ``lax.scan`` loop form: on the CPU all of them).  The
+        gated-DeltaNet layers (``ops/linear_attention.py``) the same way:
+        how many there are, and how many of them took the Pallas kernels
+        at their last trace.  The attention layers (``ops/attention.py``)
         likewise: how many there are, and how many of them ran their core
         as the splash kernels at their last trace (the others in the XLA
         formulation or sequence-parallel: on the CPU none); where gated
-        attention layers of one net differ by a window (``swa_*``): the
-        window, how many of them have it and how many see every key, and
+        attention layers have a window (``swa_*``): how many of them, and
         of the causal (query block, key block) pairs of the windowed
         cores, at the width the core hands its kernels, the share that
         holds a key some query sees (``ops/attention.py window_blocks``:
@@ -634,7 +637,13 @@ class Solver:
         (``compiler/graph.py LoopRegion``): the passes of the region
         (``ut_steps``) and, where the exit-weighted loss kept them, the
         mean cross-entropy of every pass (``ut_loss_<t>``, 1-based) and
-        the mean exit step of the last step before the fence."""
+        the mean exit step of the last step before the fence.
+
+        Which path a layer took (``*_kernel_layers``, ``swa_band_layers``)
+        is here because a silent fall-back shows nowhere else; what a path
+        keeps for the backward is not: the step program's HBM account on
+        its ``sn.step`` span measures that whole
+        (``utils/profiling.step_account``)."""
         state = self.variables.state
         stats = {name: float(st["value"]) for name, st in state.items()
                  if "value" in st}
@@ -648,29 +657,24 @@ class Solver:
         scans = [l for l in self.train_net.layers if l.type == "Mamba"]
         if scans:
             stats.update(ssm_layers=len(scans),
-                         ssm_kernel_layers=sum(l.kernel for l in scans),
-                         ssm_chunk=scans[0].chunk,
-                         ssm_saved_bytes=sum(l.saved_bytes for l in scans))
+                         ssm_kernel_layers=sum(l.kernel for l in scans))
         deltas = [l for l in self.train_net.layers
                   if l.type == "GatedDeltaNet"]
         if deltas:
             stats.update(gdn_layers=len(deltas),
-                         gdn_kernel_layers=sum(l.kernel for l in deltas),
-                         gdn_chunk=deltas[0].chunk,
-                         gdn_saved_bytes=sum(l.saved_bytes for l in deltas))
+                         gdn_kernel_layers=sum(l.kernel for l in deltas))
         cores = [l for l in self.train_net.layers
                  if isinstance(l, AttentionLayer)]
         if cores:
             stats.update(attn_core_layers=len(cores), attn_kernel_layers=sum(
                 l.kernel == "splash" for l in cores))
-        gated = [l for l in cores if l.type == "GatedAttention"]
-        windowed = [l for l in gated if l.window]
+        windowed = [l for l in cores
+                    if l.type == "GatedAttention" and l.window]
         if windowed:
             visited, causal = (sum(n) for n in
                                zip(*(l.visited for l in windowed)))
             stats.update(
-                swa_window=windowed[0].window, swa_window_layers=len(windowed),
-                swa_full_layers=len(gated) - len(windowed),
+                swa_window_layers=len(windowed),
                 swa_band_layers=sum(l.band for l in windowed),
                 swa_block_share=100.0 * visited / causal if causal else 0.0)
         loads = {name: np.asarray(st["load"]) for name, st in state.items()
@@ -733,7 +737,7 @@ class Solver:
             fn = self._scan_fns[n]
             start = self.iter
             # sn.step: the data wait and the dispatch of one scanned chunk
-            with step_span("sn.step", start):
+            with step_span("sn.step", start) as sp:
                 host = [data_fn(start + i) for i in range(n)]
                 if self._obs_in_step:
                     self._obs_images_per_iter = self._feed_images(host[0])
@@ -750,12 +754,13 @@ class Solver:
                         k: np.stack([np.asarray(h[k]) for h in host])
                         for k in host[0]
                     })
-                self.variables, self.slots, losses = fn(
-                    self.variables, self.slots, start, stacked, self._key
-                )
+                args = (self.variables, self.slots, start, stacked, self._key)
+                self.variables, self.slots, losses = fn(*args)
+                account_compiled(sp, self._accounted, fn, *args)
             with Span(None, "sn.step.fence", it=start + n) as sp:
                 losses = np.asarray(losses)
                 sp.fence_value(losses[-1])
+                sp.set(**hbm_live(self._devices))
             # solver state is at the CHUNK END from here on: advance iter
             # BEFORE replaying the per-iteration hooks so a callback that
             # snapshots (the CLI's signal hook) or stops records iter and

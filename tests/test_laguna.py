@@ -349,8 +349,9 @@ def test_training_lowers_the_loss():
 
 
 def test_the_fence_carries_the_window_counters():
-    """After ``Solver.step``: the window, how many gated attention layers
-    have it and how many see every key, and the share of the causal
+    """After ``Solver.step``: how many gated attention layers have a
+    window (the window itself and how many see every key are the layers'
+    own since PR 52, not the fence's), and the share of the causal
     block pairs the windowed cores' mask reaches, at the width the core
     hands its kernels (one block at 32 tokens: all of it; 31 of 136
     512-wide ones at 8,192 under 512), and how many of the windowed
@@ -364,9 +365,11 @@ def test_the_fence_carries_the_window_counters():
     solver.step(2, lambda it: batch_of(it))
     stats = solver._fence_stats()
     assert {k: stats[k] for k in stats if not k.startswith("moe_")} == {
-        "attn_core_layers": 5, "attn_kernel_layers": 0, "swa_window": 8,
-        "swa_window_layers": 3, "swa_full_layers": 2,
-        "swa_band_layers": 0, "swa_block_share": 100.0}
+        "attn_core_layers": 5, "attn_kernel_layers": 0,
+        "swa_window_layers": 3, "swa_band_layers": 0,
+        "swa_block_share": 100.0}
+    gated = [l for l in solver.train_net.layers if l.type == "GatedAttention"]
+    assert sorted(l.window for l in gated) == [0, 0, 8, 8, 8]
     assert stats["moe_layers"] == 4 and stats["moe_experts"] == 16
     assert stats["moe_pairs"] == 2 * 32 * 3
     assert 0 <= stats["moe_pairs_held"] <= 4 * stats["moe_pairs"]

@@ -503,15 +503,18 @@ def test_training_lowers_the_loss():
 
 
 def test_the_fence_carries_the_new_counters():
-    """After ``Solver.step``: the scan layers, how many of them run the
-    kernels (none on the CPU), the steps between two kept states and the
-    bytes of those states (f32 [chunks, B, N, d_inner])."""
+    """After ``Solver.step``: the scan layers and how many of them run
+    the kernels (none on the CPU).  The steps between two kept states and
+    the bytes of those states (f32 [chunks, B, N, d_inner]) are the
+    layers' own since PR 52, not the fence's."""
     solver = make_solver()
     solver.step(2, lambda it: batch_of(it))
     stats = solver._fence_stats()
-    assert stats == {"ssm_layers": 2, "ssm_kernel_layers": 0, "ssm_chunk": 32,
-                     "ssm_saved_bytes": 2 * 1 * 2 * 4 * 128 * 4,
+    assert stats == {"ssm_layers": 2, "ssm_kernel_layers": 0,
                      "attn_core_layers": 4, "attn_kernel_layers": 0}
+    scans = [l for l in solver.train_net.layers if l.type == "Mamba"]
+    assert [l.chunk for l in scans] == [32, 32]
+    assert sum(l.saved_bytes for l in scans) == 2 * 1 * 2 * 4 * 128 * 4
     assert ssm.chunking(2048) == (64, 32)
     assert ssm.saved_state_bytes(1, 2048, 5120, 16) == 32 * 16 * 5120 * 4
     # a net without a scan layer keeps to the counters it had

@@ -316,16 +316,19 @@ def test_training_lowers_the_loss():
 def test_the_fence_carries_the_new_counters():
     """After ``Solver.step``: the DeltaNet layers, how many of them took
     the Pallas kernels (none on the CPU, nor at these 8 x 16 heads
-    anywhere), the tokens of a chunk and the bytes of the kept
-    chunk-start states (f32 [chunks, B, H_v, d_k, d_v]), beside the
-    attention and expert counters under their present names."""
+    anywhere), beside the attention and expert counters under their
+    present names.  The tokens of a chunk and the bytes of the kept
+    chunk-start states (f32 [chunks, B, H_v, d_k, d_v]) are the layers'
+    own since PR 52, not the fence's."""
     solver = make_solver()
     solver.step(2, lambda it: batch_of(it))
     stats = solver._fence_stats()
     assert {k: stats[k] for k in stats if not k.startswith("moe_")} == {
-        "gdn_layers": 3, "gdn_kernel_layers": 0, "gdn_chunk": 32,
-        "gdn_saved_bytes": 3 * 1 * 2 * 4 * 8 * 16 * 4,
+        "gdn_layers": 3, "gdn_kernel_layers": 0,
         "attn_core_layers": 1, "attn_kernel_layers": 0}
+    deltas = [l for l in solver.train_net.layers if l.type == "GatedDeltaNet"]
+    assert [l.chunk for l in deltas] == [32, 32, 32]
+    assert sum(l.saved_bytes for l in deltas) == 3 * 1 * 2 * 4 * 8 * 16 * 4
     assert stats["moe_layers"] == 4 and stats["moe_experts"] == 16
     assert stats["moe_pairs"] == 2 * 32 * 3
     assert 0 <= stats["moe_pairs_held"] <= 4 * stats["moe_pairs"]

@@ -419,11 +419,22 @@ def test_profiling_trace_writes_files(tmp_path):
     assert found, "profiler produced no artifacts"
 
 
-def test_device_memory_stats_shape():
-    from sparknet_tpu.utils.profiling import device_memory_stats
+def test_hbm_live_is_the_fullest_device_or_nothing():
+    """``hbm_live`` (which took ``device_memory_stats``' place): the
+    largest ``bytes_in_use``, and no stat at all, not 0, from a backend
+    that exposes nothing (the CPU)."""
+    import jax
 
-    stats = device_memory_stats()
-    assert isinstance(stats, dict)  # CPU backends may expose nothing
+    from sparknet_tpu.utils.profiling import hbm_live
+
+    class Chip:
+        def __init__(self, stats):
+            self.memory_stats = lambda: stats
+
+    assert hbm_live(jax.devices()) == {}
+    assert hbm_live([Chip({"bytes_in_use": 5}), Chip(None),
+                     Chip({"bytes_in_use": 9, "bytes_limit": 16})]) == {
+        "hbm_live_bytes": 9}
 
 
 def test_cli_dataset_tools_pipeline(tmp_path, monkeypatch, capsys):
