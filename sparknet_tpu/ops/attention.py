@@ -47,7 +47,7 @@ import numpy as np
 from sparknet_tpu.common import get_config
 from sparknet_tpu.ops.base import Layer, LayerOutput
 from sparknet_tpu.ops.blocks import rms_norm
-from sparknet_tpu.ops.fillers import fill
+from sparknet_tpu.ops.fillers import fill, normal
 from sparknet_tpu.ops.pallas_kernels import attention_xla, flash_attention
 from sparknet_tpu.ops.registry import register
 from sparknet_tpu.proto.text_format import Message
@@ -729,7 +729,7 @@ class DifferentialAttentionLayer(AttentionLayer):
         D = E // H
         k_in, k_out, k_lam = jax.random.split(key, 3)
         rows = H * D if self.cross else (H + 2 * Hk) * D
-        lam = 0.1 * jax.random.normal(k_lam, (4, D), jnp.float32)
+        lam = 0.1 * normal(k_lam, (4, D), jnp.float32)
         return [fill(self.weight_filler, k_in, (rows, E)),
                 fill(self.weight_filler, k_out, (E, H * D)),
                 *lam, jnp.ones((2 * D,), jnp.float32)], {}

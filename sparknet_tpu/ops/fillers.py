@@ -24,6 +24,13 @@ def _fans(shape) -> tuple[int, int]:
     return fan_in, fan_out
 
 
+def normal(key: jax.Array, shape, dtype) -> jax.Array:
+    """The sampler's result as a program of its own leaves it: inside one
+    jitted init XLA would fold a filler's ``std`` into the sampler's last
+    product (one ulp off the eager value); the barrier is a no-op eagerly."""
+    return jax.lax.optimization_barrier(jax.random.normal(key, shape, dtype))
+
+
 def fill(filler: Message, key: jax.Array, shape, dtype=jnp.float32) -> jax.Array:
     ftype = filler.get_str("type", "constant")
     if ftype == "constant":
@@ -33,7 +40,7 @@ def fill(filler: Message, key: jax.Array, shape, dtype=jnp.float32) -> jax.Array
         return jax.random.uniform(key, shape, dtype, lo, hi)
     if ftype == "gaussian":
         mean, std = filler.get_float("mean", 0.0), filler.get_float("std", 1.0)
-        out = mean + std * jax.random.normal(key, shape, dtype)
+        out = mean + std * normal(key, shape, dtype)
         sparse = filler.get_int("sparse", -1)
         if sparse >= 0:
             # ref filler.hpp GaussianFiller: bernoulli mask with
@@ -41,7 +48,9 @@ def fill(filler: Message, key: jax.Array, shape, dtype=jnp.float32) -> jax.Array
             num_outputs = shape[0] if shape else 1
             prob = min(1.0, sparse / max(num_outputs, 1))
             k2 = jax.random.split(key, 2)[1]
-            out = out * jax.random.bernoulli(k2, prob, shape).astype(dtype)
+            # a select: what XLA makes of ``out * mask`` inside a larger
+            # program anyway (a dropped entry is +0, never a -0 product)
+            out = jnp.where(jax.random.bernoulli(k2, prob, shape), out, 0)
         return out
     if ftype == "positive_unitball":
         x = jax.random.uniform(key, shape, dtype)
@@ -57,7 +66,7 @@ def fill(filler: Message, key: jax.Array, shape, dtype=jnp.float32) -> jax.Array
         fan_in, fan_out = _fans(shape)
         n = _variance_norm_n(filler, fan_in, fan_out)
         std = float(np.sqrt(2.0 / n))
-        return std * jax.random.normal(key, shape, dtype)
+        return std * normal(key, shape, dtype)
     if ftype == "bilinear":
         return jnp.asarray(_bilinear_kernel(shape), dtype)
     raise ValueError(f"unknown filler type {ftype!r}")

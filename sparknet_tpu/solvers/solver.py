@@ -12,6 +12,7 @@ weight IO, ref: Net.scala:131-171) — on TPU the weights never leave HBM.
 
 from __future__ import annotations
 
+import collections
 import contextlib
 import dataclasses
 import json
@@ -30,7 +31,7 @@ from sparknet_tpu.obs import get_recorder
 from sparknet_tpu.obs.recorder import Span
 from sparknet_tpu.ops.attention import AttentionLayer
 from sparknet_tpu.ops.moe import CAPACITY_TILE, live_tiles
-from sparknet_tpu.proto.text_format import Message, parse_file
+from sparknet_tpu.proto.text_format import Message, parse_file, serialize
 from sparknet_tpu.solvers.lr_policy import learning_rate
 from sparknet_tpu.solvers.updates import apply_update, init_slots
 from sparknet_tpu.utils.profiling import (account_compiled, hbm_live,
@@ -292,17 +293,68 @@ def build_train_step(cfg: SolverConfig, net: Network, specs,
     return train_step
 
 
+# signature of a fresh state -> its jitted ``key -> (variables, slots)``;
+# the few nets of a job, bounded (a closure keeps the net it was made for)
+_FRESH_STATES: collections.OrderedDict = collections.OrderedDict()
+_FRESH_STATES_HELD = 16
+
+
+def fresh_train_state(cfg: SolverConfig, net: Network,
+                      feed_shapes: dict[str, tuple] | None = None,
+                      feed_dtypes: dict[str, Any] | None = None):
+    """The jitted ``key -> (variables, slots)`` of a fresh training state:
+    ``net.init`` (shape inference and every filler) and the optimizer's
+    zero slots inside ONE trace, so a ``Solver`` is born in one program
+    where an eager init ran one per filler and shape.  The PRNG key is
+    the program's only argument (a closed-over key would compile anew a
+    seed); the prototxt, the phase view, the feed shapes and dtypes, the
+    framework ``Config`` and the solver type are the signature it is
+    kept under, so equal nets share one callable and one executable
+    whatever their seeds.  The values are the eager call's, to the bit
+    for every zoo net's fillers (``ops/fillers.py`` fences its normal
+    sampler for that; where a backend fuses a layer's own product and
+    sum, within ulps: ``tests/test_fresh_state.py`` names the places)."""
+    def fresh(key):
+        variables = net.init(key, feed_shapes, feed_dtypes)
+        return variables, init_slots(cfg.solver_type, variables.params)
+
+    shapes = {**net.feed_shapes(), **(feed_shapes or {})}
+    signature = (
+        serialize(net.net_param), net.phase, net.batch_override,
+        tuple((type(l), serialize(l.lp)) for l in net.layers),
+        tuple(sorted((k, tuple(v)) for k, v in shapes.items())),
+        tuple(sorted((k, jnp.dtype(v).name)
+                     for k, v in (feed_dtypes or {}).items())),
+        dataclasses.replace(get_config(), seed=0), cfg.solver_type,
+    )
+    held = _FRESH_STATES.pop(signature, None)
+    if held is None:
+        held = jax.jit(fresh)
+    else:
+        # an equal net came before: the executable is its, but what
+        # ``init`` notes on THIS net's layers as it learns their shapes
+        # (``blob_info``, a scan's chunk) takes a trace of its own
+        jax.eval_shape(fresh, _key_struct())
+    _FRESH_STATES[signature] = held  # the newest last
+    while len(_FRESH_STATES) > _FRESH_STATES_HELD:
+        _FRESH_STATES.popitem(last=False)
+    return held
+
+
+def _key_struct() -> jax.ShapeDtypeStruct:
+    """The aval of :func:`common.root_key`'s key, so an abstract state
+    and a ``Solver`` meet the same trace."""
+    return jax.eval_shape(jax.random.key, 0)
+
+
 def abstract_train_state(cfg: SolverConfig, net: Network):
     """``(variables, slots)`` of a fresh training state as
-    ``ShapeDtypeStruct`` pytrees — ``jax.eval_shape`` over the same
-    ``net.init`` + ``init_slots`` path the Solver runs, so nothing
-    materializes (vgg16's half-gigabyte of params stays abstract).  The
-    memcheck batch-fit solver builds its footprint model from these."""
-    key = jax.ShapeDtypeStruct((2,), jnp.uint32)
-    variables = jax.eval_shape(net.init, key)
-    slots = jax.eval_shape(
-        lambda p: init_slots(cfg.solver_type, p), variables.params)
-    return variables, slots
+    ``ShapeDtypeStruct`` pytrees: ``jax.eval_shape`` of the very callable
+    a ``Solver`` runs (:func:`fresh_train_state`: one definition of what
+    a fresh state is), so nothing materializes (vgg16's half-gigabyte of
+    params stays abstract).  The memcheck batch-fit solver builds its
+    footprint model from these."""
+    return jax.eval_shape(fresh_train_state(cfg, net), _key_struct())
 
 
 class Solver:
@@ -311,6 +363,13 @@ class Solver:
     ``data_fn(it)`` supplies the train feed dict for iteration ``it``
     (with iter_size>1: arrays carry a leading [iter_size] axis and the
     jitted step scans over micro-batches, ref: solver.cpp:221-224).
+
+    Construction runs ONE program for the fresh state
+    (:func:`fresh_train_state`: every filler and the optimizer's zero
+    slots), keyed by the net's shape and read by the key of
+    ``random_seed``: a second solver of an equal net, whatever its seed,
+    compiles nothing, and a new process finds the executable in jax's
+    persistent cache.
     """
 
     def __init__(
@@ -374,12 +433,26 @@ class Solver:
                 )
             seed = self.config.random_seed if self.config.random_seed >= 0 else None
             self._key = root_key(seed)
-            # sn.solver.init: shape inference and the fillers, the
-            # optimizer's slots, the per-parameter specs
+            # sn.solver.init: the fresh state's ONE program (shape
+            # inference and the fillers, the optimizer's slots: a trace
+            # and a compile or a cache load, then its run), the
+            # per-parameter specs
             with Span(None, "sn.solver.init", host=True,
                       compile_stats=True) as sp:
-                self.variables = self.train_net.init(self._key, feed_shapes, feed_dtypes)
-                self.slots = init_slots(self.config.solver_type, self.variables.params)
+                variables, slots = fresh_train_state(
+                    self.config, self.train_net, feed_shapes, feed_dtypes
+                )(self._key)
+                # a jitted call hands its dicts back sorted by key (as
+                # ``init_slots``'s tree_map always did); until the first
+                # step does the same the variables stay in the net's
+                # layer order, as ``net.init`` builds them (a snapshot or
+                # a weight export at iteration 0 walks them)
+                in_order = lambda d: {
+                    layer.name: d[layer.name]
+                    for layer in self.train_net.layers if layer.name in d}
+                self.variables = NetVars(params=in_order(variables.params),
+                                         state=in_order(variables.state))
+                self.slots = slots
                 self.iter = 0
                 self.smoothed_loss = 0.0
                 self._loss_window: list[float] = []
@@ -388,7 +461,9 @@ class Solver:
                 self._obs_in_step = False
                 self._obs_images_per_iter = 0
                 self._specs = self.train_net.param_specs_for(self.variables)
-                sp.set(params=sum(
+                # programs: the executables the init asks for (the span's
+                # compiles / cache_hits say how that one was come by)
+                sp.set(programs=1, params=sum(
                     int(p.size) for ps in self.variables.params.values()
                     for p in ps))
             # Donate the (variables, slots) carry: step() rebinds both from

@@ -758,7 +758,8 @@ def test_train_logs_one_set_up_line_from_the_record(job, capsys, extra,
         assert stage in lines[0], (stage, lines[0])
     assert "compiles" in lines[0]  # the first step's program, at least
     # what each stage built, and the listener's seconds by event
-    built = ["(nets 2, layers 6)", f"(params {3 * 12 * 12 * 4 + 4})",
+    built = ["(nets 2, layers 6)",
+             f"(programs 1, params {3 * 12 * 12 * 4 + 4})",
              "(source db)"] + (["(devices "] if extra else [])
     for stat in built:
         assert stat in lines[0], (stat, lines[0])
